@@ -39,9 +39,11 @@ from typing import Any, Dict, List, Optional
 
 import numpy as np
 
+from ray_tpu.accelerators import jax_backend
 from ray_tpu.models.llama import (
     LlamaConfig, llama_decode_step, llama_init, llama_init_cache,
     llama_prefill, llama_verify_step)
+from ray_tpu.ops import attention as _attention_op
 from ray_tpu.util import flight_recorder as _flight
 from ray_tpu.util import metrics as _metrics
 
@@ -363,11 +365,21 @@ class ContinuousBatchingEngine:
         import jax
         import jax.numpy as jnp
 
+        jax_backend.track_compile_time()
         self.config = config
         c = config.model
+        # name -> (jitted, abstract args, static kwargs) of the first
+        # call of each hot program, and the Pallas kernels its lowered
+        # text holds (filled by stats())
+        self._program_sigs: Dict[str, tuple] = {}
+        self._program_kernels: Dict[str, List[str]] = {}
+        # Random weights (real checkpoints load via orbax/train), drawn
+        # under jit: eagerly, llama_init holds each stacked weight in
+        # float32 twice before casting, which took a 16-layer 7B-width
+        # engine to 12.75 GiB of a v5e's 15.75 at construction.
+        init = jax.jit(llama_init, static_argnums=1)
         if params is None:
-            # random weights — real checkpoints load via orbax/train
-            params = llama_init(jax.random.PRNGKey(config.seed), c)
+            params = init(jax.random.PRNGKey(config.seed), c)
         if config.quantization is not None:
             if config.quantization != "int8":
                 raise ValueError(
@@ -415,7 +427,7 @@ class ContinuousBatchingEngine:
                 raise ValueError("spec_tokens must be >= 2 (1 draft + "
                                  "1 verified token minimum)")
             if draft_params is None:
-                draft_params = llama_init(
+                draft_params = init(
                     jax.random.PRNGKey(config.seed + 1), dc)
             self.draft_params = draft_params
             self.draft_cache_k, self.draft_cache_v = llama_init_cache(
@@ -894,6 +906,20 @@ class ContinuousBatchingEngine:
             slot.pos = plen
             self._emit(slot, tok)
 
+    def _call_program(self, name: str, jitted, *args, **static):
+        """Run a jitted program, keeping the abstract signature of its
+        first call so stats() can lower it again and name the Pallas
+        kernels it holds."""
+        if name not in self._program_sigs:
+            jax = self._jax
+            sig = (jitted, jax.tree.map(
+                lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype)
+                if isinstance(x, (jax.Array, np.ndarray)) else x,
+                args), static)
+            with self._lock:
+                self._program_sigs[name] = sig
+        return jitted(*args, **static)
+
     def _run_prefill(self, ids: List[int], adapter: Optional[str],
                      temperature: float, top_k: int,
                      bias_row=None, want_logprobs: bool = False):
@@ -916,7 +942,8 @@ class ContinuousBatchingEngine:
                     self.prefix_misses += 1
             padded = self._pad_bucket(ids)
             lora = self._adapter_prefill.get(adapter) if adapter else None
-            logits, ks, vs = self._prefill(
+            logits, ks, vs = self._call_program(
+                f"prefill_{padded.shape[1]}", self._prefill,
                 self.params, jnp.asarray(padded), lora)
             last_logits = logits[0, len(ids) - 1]
         else:
@@ -1445,7 +1472,8 @@ class ContinuousBatchingEngine:
         self._step_counter += 1  # graftlint: disable=GL001  # stepper-thread-only
         want_lp = any(s.request.logprobs is not None for s in active)
         sampled, chosen_lp, top_vals, top_ids, self.cache_k, \
-            self.cache_v = self._decode(
+            self.cache_v = self._call_program(
+                "decode_lp" if want_lp else "decode", self._decode,
                 self.params, self.cache_k, self.cache_v,
                 jnp.asarray(tokens), jnp.asarray(pos),
                 jnp.asarray(temp), jnp.asarray(topk),
@@ -1583,7 +1611,19 @@ class ContinuousBatchingEngine:
 
     def stats(self) -> Dict[str, Any]:
         self._mbuf.maybe_flush(self, force=True)
+        # lower each hot program seen since the last call once more to
+        # read its kernels (outside the lock: tracing takes seconds at
+        # full width)
         with self._lock:
+            unread = [(name, sig) for name, sig
+                      in self._program_sigs.items()
+                      if name not in self._program_kernels]
+        kernels = {
+            name: jax_backend.pallas_kernels(
+                jitted.lower(*args, **static).as_text())
+            for name, (jitted, args, static) in unread}
+        with self._lock:
+            self._program_kernels.update(kernels)
             out = {
                 "waiting": len(self.waiting),
                 "active": sum(1 for s in self.slots
@@ -1593,6 +1633,11 @@ class ContinuousBatchingEngine:
                                   and s.prefilling),
                 "max_batch": self.config.max_batch,
                 "total_generated": self.total_generated,
+                # which device served, what it compiled, and whether
+                # flash attention stepped aside for any shape
+                "device": jax_backend.device_report(),
+                "programs": dict(self._program_kernels),
+                "flash_fallbacks": list(_attention_op.kernel_fallbacks),
             }
             if self._prefix_cache is not None:
                 out["prefix_cache_entries"] = len(self._prefix_cache)

@@ -28,6 +28,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
+from ray_tpu.accelerators import jax_backend
 from ray_tpu.ops.attention import flash_attention
 from ray_tpu.ops.rmsnorm import rms_norm
 from ray_tpu.ops.rope import apply_rope, rope_frequencies
@@ -205,7 +206,7 @@ def _attention(q, k, v, config: LlamaConfig, mesh):
         from ray_tpu.parallel.ring_attention import ulysses_attention
         return ulysses_attention(q, k, v, mesh, causal=True)
     if config.attention == "flash":
-        return flash_attention(q, k, v, True)
+        return flash_attention(q, k, v, True, mesh)
     from ray_tpu.ops.attention import _attention_reference
     return _attention_reference(q, k, v, True)
 
@@ -215,7 +216,7 @@ def _int8_mm(x2d, w8, scale):
     on TPU (halved HBM weight traffic — ops/quant_matmul.py), an XLA
     dequant matmul elsewhere (CPU tests; same math, so outputs agree
     across backends up to accumulation order)."""
-    if jax.default_backend() == "tpu":
+    if jax_backend.on_tpu():
         from ray_tpu.ops.quant_matmul import int8_matmul
         return int8_matmul(x2d.astype(jnp.bfloat16), w8, scale) \
             .astype(x2d.dtype)
@@ -279,7 +280,7 @@ def _block(layer_params, x, cos, sin, config: LlamaConfig, mesh,
     c = config
     b, s, _ = x.shape
     hd = c.head_dim
-    h = rms_norm(x, layer_params["attn_norm"], c.norm_eps)
+    h = rms_norm(x, layer_params["attn_norm"], c.norm_eps, mesh)
     q = h @ layer_params["wq"]
     k = h @ layer_params["wk"]
     v = h @ layer_params["wv"]
@@ -294,7 +295,7 @@ def _block(layer_params, x, cos, sin, config: LlamaConfig, mesh,
     k = apply_rope(k, cos, sin)
     attn = _attention(q, k, v, c, mesh)
     x = x + attn.reshape(b, s, c.n_heads * hd) @ layer_params["wo"]
-    h = rms_norm(x, layer_params["mlp_norm"], c.norm_eps)
+    h = rms_norm(x, layer_params["mlp_norm"], c.norm_eps, mesh)
     y, aux = _ffn(layer_params, h, c)
     return x + y, (k, v), aux
 
@@ -322,7 +323,7 @@ def llama_forward(params, tokens, config: LlamaConfig, mesh=None,
 
     (x, aux_sum), _ = jax.lax.scan(
         scan_body, (x, jnp.zeros((), jnp.float32)), params["layers"])
-    x = rms_norm(x, params["final_norm"], c.norm_eps)
+    x = rms_norm(x, params["final_norm"], c.norm_eps, mesh)
     if return_hidden:
         return (x, aux_sum) if return_aux else x
     logits = (x @ params["lm_head"]).astype(jnp.float32)

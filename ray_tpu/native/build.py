@@ -1,8 +1,10 @@
 """Build the native library on demand.
 
-The .so is compiled once per machine into ray_tpu/native/_build/ and
-reused; rebuilt automatically when any source file is newer than the
-binary. Keeps the repo pip-install-free (no pybind11; plain ctypes ABI).
+The .so is compiled into ray_tpu/native/_build/ under a name that
+carries a hash of its sources' contents and the compile command, so a
+binary is reused only for the exact sources it was built from: _build/
+is git-ignored but travels with a copied tree, where mtimes say nothing.
+Keeps the repo pip-install-free (no pybind11; plain ctypes ABI).
 
 Build failures (g++ missing, compile error) raise NativeBuildError and
 are cached: the first failure logs one warning, later calls fail fast
@@ -12,8 +14,10 @@ route onto their pure-Python fallbacks cheaply.
 
 from __future__ import annotations
 
+import hashlib
 import logging
 import os
+import re
 import subprocess
 import threading
 
@@ -24,7 +28,6 @@ logger = logging.getLogger(__name__)
 _DIR = os.path.dirname(os.path.abspath(__file__))
 _SRC_DIR = os.path.join(_DIR, "src")
 _BUILD_DIR = os.path.join(_DIR, "_build")
-_LIB_PATH = os.path.join(_BUILD_DIR, "libray_tpu_native.so")
 _lock = locktrace.traced_lock("native.build")
 # target key -> failure detail; guarded by _lock. A key present here
 # means "don't retry the compile this process".
@@ -46,22 +49,29 @@ def _sources():
     )
 
 
-def _fresh(out: str, srcs) -> bool:
-    if not os.path.exists(out):
-        return False
-    out_mtime = os.path.getmtime(out)
-    return all(os.path.getmtime(s) <= out_mtime for s in srcs)
-
-
-def _compile(key: str, cmd, out: str) -> str:
-    """Run one g++ invocation OUTSIDE any lock (compiles take seconds;
-    holding a lock across them would serialize unrelated callers and
-    trip the blocking-under-lock lint). Concurrent duplicate compiles
-    are benign: each writes a unique tmp and os.replace is atomic."""
+def _build(key: str, stem: str, ext: str, cmd, srcs) -> str:
+    """Path of ``_build/<stem>-<hash><ext>``, compiled if absent. The
+    hash covers the compile command and every source's name and
+    contents. The g++ run holds no lock (compiles take seconds; a lock
+    across them would serialize unrelated callers and trip the
+    blocking-under-lock lint); concurrent duplicate compiles are benign:
+    each writes a unique tmp and os.replace is atomic. Outputs of other
+    source versions are removed afterwards (a process that already
+    mapped one keeps it)."""
+    _check_cached_failure(key)
+    h = hashlib.sha256(" ".join(cmd).encode())
+    for src in srcs:
+        h.update(os.path.basename(src).encode())
+        with open(src, "rb") as f:
+            h.update(f.read())
+    out = os.path.join(_BUILD_DIR, f"{stem}-{h.hexdigest()[:16]}{ext}")
+    if os.path.exists(out):
+        return out
+    os.makedirs(_BUILD_DIR, exist_ok=True)
     tmp = f"{out}.tmp.{os.getpid()}.{threading.get_ident()}"
     try:
-        proc = subprocess.run(cmd + ["-o", tmp], capture_output=True,
-                              text=True)
+        proc = subprocess.run(cmd + srcs + ["-o", tmp],
+                              capture_output=True, text=True)
     except OSError as exc:  # g++ not installed at all
         _record_failure(key, f"toolchain unavailable: {exc}")
         raise NativeBuildError(f"native build failed ({key}): {exc}") \
@@ -72,6 +82,15 @@ def _compile(key: str, cmd, out: str) -> str:
         raise NativeBuildError(
             f"native build failed ({key}, rc={proc.returncode}):\n{detail}")
     os.replace(tmp, out)
+    versioned = re.compile(
+        re.escape(stem) + "-[0-9a-f]{16}" + re.escape(ext) + "$")
+    for name in os.listdir(_BUILD_DIR):
+        path = os.path.join(_BUILD_DIR, name)
+        if versioned.match(name) and path != out:
+            try:
+                os.unlink(path)
+            except OSError:
+                pass
     return out
 
 
@@ -95,15 +114,9 @@ def _check_cached_failure(key: str) -> None:
 
 
 def ensure_built() -> str:
-    _check_cached_failure("lib")
-    with _lock:
-        srcs = _sources()
-        if _fresh(_LIB_PATH, srcs):
-            return _LIB_PATH
-        os.makedirs(_BUILD_DIR, exist_ok=True)
-    cmd = ["g++", "-O2", "-g", "-fPIC", "-shared", "-std=c++17",
-           "-Wall", "-pthread", *srcs]
-    return _compile("lib", cmd, _LIB_PATH)
+    return _build("lib", "libray_tpu_native", ".so",
+                  ["g++", "-O2", "-g", "-fPIC", "-shared", "-std=c++17",
+                   "-Wall", "-pthread"], _sources())
 
 
 def build_stress(sanitizer: str = "",
@@ -120,16 +133,8 @@ def build_stress(sanitizer: str = "",
     stem = "shm_stress" if main_src == "stress_test_main.cc" \
         else main_src[:-len("_main.cc")]
     suffix = f"-{sanitizer}" if sanitizer else ""
-    out = os.path.join(_BUILD_DIR, f"{stem}{suffix}")
-    key = f"{stem}{suffix}"
-    _check_cached_failure(key)
-    with _lock:
-        srcs = _sources() + [os.path.join(_SRC_DIR, main_src)]
-        if _fresh(out, srcs):
-            return out
-        os.makedirs(_BUILD_DIR, exist_ok=True)
     cmd = ["g++", "-O1", "-g", "-std=c++17", "-Wall", "-pthread"]
     if sanitizer:
         cmd += [f"-fsanitize={sanitizer}", "-fno-omit-frame-pointer"]
-    cmd += srcs
-    return _compile(key, cmd, out)
+    return _build(f"{stem}{suffix}", f"{stem}{suffix}", "", cmd,
+                  _sources() + [os.path.join(_SRC_DIR, main_src)])

@@ -19,7 +19,6 @@ Axis conventions (the "How to Scale Your Model" recipe):
 from __future__ import annotations
 
 import math
-import os
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Sequence, Tuple
 
@@ -94,6 +93,15 @@ def mesh_axis_size(mesh, name: str) -> int:
     return dict(zip(mesh.axis_names, mesh.devices.shape)).get(name, 1)
 
 
+def mesh_axes(mesh, *names: str):
+    """Those of `names` that are axes of this mesh, as one
+    PartitionSpec entry (None when the mesh has none of them), and the
+    product of their sizes."""
+    present = tuple(n for n in names if n in mesh.axis_names)
+    size = math.prod(mesh.shape[n] for n in present)
+    return (present or None), size
+
+
 def initialize_distributed(coordinator_address: Optional[str] = None,
                            num_processes: Optional[int] = None,
                            process_id: Optional[int] = None) -> None:
@@ -107,18 +115,12 @@ def initialize_distributed(coordinator_address: Optional[str] = None,
     import jax
     if num_processes in (None, 0, 1):
         return
-    # CPU gangs (virtual-device CI, JAX_PLATFORMS=cpu) need the gloo
-    # collectives selected before initialize, or every cross-process
-    # computation dies with "not implemented on the CPU backend".
-    # Checked via the env var, NOT jax.default_backend(): touching the
-    # backend here would finalize it pre-initialize.
-    if "cpu" in os.environ.get("JAX_PLATFORMS", "").lower():
-        from ray_tpu.parallel import _compat
-        _compat.enable_cpu_collectives()
     try:
         jax.distributed.initialize(
             coordinator_address=coordinator_address,
             num_processes=num_processes,
             process_id=process_id)
-    except RuntimeError:
-        pass  # already initialized
+    except RuntimeError as exc:
+        # jax's own wording for a second call in one process
+        if "should only be called once" not in str(exc):
+            raise

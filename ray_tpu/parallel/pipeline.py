@@ -41,7 +41,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
-from ray_tpu.parallel._compat import require_shard_map, shard_map
 
 
 def _pipeline_local(params, x_local, *, fn, axis_name: str,
@@ -111,7 +110,6 @@ def pipeline(fn: Callable[[Any, jax.Array], jax.Array], stage_params: Any,
     strided input sharding is even). ``remat``: checkpoint the stage fn
     for training (backward recomputes within-stage activations).
     """
-    require_shard_map()
     n_stages = mesh.shape[axis_name]
     if x.shape[0] % num_microbatches:
         raise ValueError(
@@ -129,7 +127,7 @@ def pipeline(fn: Callable[[Any, jax.Array], jax.Array], stage_params: Any,
     body = jax.checkpoint(fn) if remat else fn
     local = functools.partial(_pipeline_local, fn=body,
                               axis_name=axis_name, n_stages=n_stages)
-    out = shard_map(
+    out = jax.shard_map(
         local, mesh=mesh,
         in_specs=(param_specs, P(None, axis_name)),
         out_specs=P(None, axis_name),

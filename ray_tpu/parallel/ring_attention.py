@@ -31,7 +31,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
-from ray_tpu.parallel._compat import shard_map
 
 NEG_INF = -1e30
 
@@ -105,8 +104,8 @@ def ring_attention(q, k, v, mesh: Mesh, *, causal: bool = True,
     spec = P(None, axis_name, None, None)
     fn = functools.partial(_ring_attention_local, axis_name=axis_name,
                            causal=causal)
-    return shard_map(fn, mesh=mesh, in_specs=(spec, spec, spec),
-                     out_specs=spec, check_vma=False)(q, k, v)
+    return jax.shard_map(fn, mesh=mesh, in_specs=(spec, spec, spec),
+                         out_specs=spec, check_vma=False)(q, k, v)
 
 
 def _ulysses_local(q, k, v, *, axis_name: str, causal: bool):
@@ -148,8 +147,8 @@ def ulysses_attention(q, k, v, mesh: Mesh, *, causal: bool = True,
     spec = P(None, axis_name, None, None)
     fn = functools.partial(_ulysses_local, axis_name=axis_name,
                            causal=causal)
-    return shard_map(fn, mesh=mesh, in_specs=(spec, spec, spec),
-                     out_specs=spec, check_vma=False)(q, k, v)
+    return jax.shard_map(fn, mesh=mesh, in_specs=(spec, spec, spec),
+                         out_specs=spec, check_vma=False)(q, k, v)
 
 
 def reference_attention(q, k, v, *, causal: bool = True):

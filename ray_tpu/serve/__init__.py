@@ -62,7 +62,10 @@ def run(app: Application, *, name: str = "default",
         route_prefix: Optional[str] = "/", blocking_ready: bool = True,
         timeout_s: float = 60.0, local_testing_mode: bool = False):
     """Deploy an application; returns the ingress handle
-    (reference: python/ray/serve/api.py serve.run:694).
+    (reference: python/ray/serve/api.py serve.run:694). ``timeout_s``
+    bounds both this call's wait for a healthy replica and the
+    controller's wait for each replica's constructor (a 7 GB model's
+    cold start on a chip took longer than the 60 s default).
 
     ``local_testing_mode=True`` instantiates the whole deployment
     graph in-process — no controller, no cluster, no ray_tpu.init —
@@ -73,7 +76,8 @@ def run(app: Application, *, name: str = "default",
         return run_local(app)
     controller = _get_or_start_controller()
     specs = flatten_application(app, name, route_prefix)
-    ray_tpu.get(controller.deploy_application.remote(name, specs))
+    ray_tpu.get(controller.deploy_application.remote(name, specs,
+                                                     timeout_s))
     ingress = app.deployment.name
     if blocking_ready:
         deadline = time.monotonic() + timeout_s

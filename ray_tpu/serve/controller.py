@@ -29,13 +29,17 @@ CONTROLLER_NAME = "SERVE_CONTROLLER"
 class _DeploymentState:
     def __init__(self, name: str, app_name: str, callable_blob: bytes,
                  init_args_blob: bytes, config: DeploymentConfig,
-                 route_prefix: Optional[str]):
+                 route_prefix: Optional[str],
+                 ready_timeout_s: float = 60.0):
         self.name = name
         self.app_name = app_name
         self.callable_blob = callable_blob
         self.init_args_blob = init_args_blob
         self.config = config
         self.route_prefix = route_prefix
+        # how long a new replica's constructor may take before it is
+        # discarded and replaced (serve.run's timeout_s)
+        self.ready_timeout_s = ready_timeout_s
         self.replicas: Dict[str, Any] = {}  # replica_id -> actor handle
         self.target = (config.autoscaling_config.min_replicas
                        if config.autoscaling_config
@@ -68,8 +72,8 @@ class ServeController:
 
     # -- API (called by serve.run / handles / proxy) --
 
-    def deploy_application(self, app_name: str,
-                           deployments: List[dict]) -> None:
+    def deploy_application(self, app_name: str, deployments: List[dict],
+                           ready_timeout_s: float = 60.0) -> None:
         """deployments: [{name, callable_blob, init_args_blob, config,
         route_prefix}] — full target state for the app (reference:
         application_state.py apply_deployment_args)."""
@@ -85,6 +89,7 @@ class ServeController:
                     old_config = existing.config
                     existing.config = d["config"]
                     existing.route_prefix = d.get("route_prefix")
+                    existing.ready_timeout_s = ready_timeout_s
                     if not existing.config.autoscaling_config:
                         existing.target = d["config"].num_replicas
                     if (d["config"].user_config is not None
@@ -99,7 +104,7 @@ class ServeController:
                     self._deployments[name] = _DeploymentState(
                         name, app_name, d["callable_blob"],
                         d["init_args_blob"], d["config"],
-                        d.get("route_prefix"))
+                        d.get("route_prefix"), ready_timeout_s)
             # drop deployments of this app that were removed
             for name, st in list(self._deployments.items()):
                 if st.app_name == app_name and name not in keep:
@@ -381,7 +386,8 @@ class ServeController:
             # wait for constructors so routers never see half-born replicas
             for rid, h in list(new.items()):  # failures pop from `new`
                 try:
-                    ray_tpu.get(h.check_health.remote(), timeout=60.0)
+                    ray_tpu.get(h.check_health.remote(),
+                                timeout=st.ready_timeout_s)
                     events.emit("REPLICA_STARTED",
                                 message=f"{st.name}/{rid}")
                 except Exception:

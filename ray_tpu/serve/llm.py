@@ -43,6 +43,12 @@ class LLMConfig:
     model_id: str = "llama-tiny"
     engine: EngineConfig = field(default_factory=EngineConfig)
     num_replicas: int = 1
+    # Each replica owns this many chips (the trainer's idiom,
+    # ScalingConfig.use_tpu / tpu_chips_per_worker): it is placed in a
+    # "tpu:<k>" worker that sees exactly those chips. Without use_tpu a
+    # replica runs in a worker held to the CPU.
+    use_tpu: bool = False
+    tpu_chips_per_replica: int = 1
     max_ongoing_requests: int = 16
     # route by prompt-prefix affinity (KV/prefix-cache locality;
     # reference: llm/_internal/serve/routing_policies/prefix_aware/)
@@ -50,6 +56,11 @@ class LLMConfig:
     # generation defaults
     max_tokens: int = 64
     temperature: float = 0.0
+
+    def ray_actor_options(self) -> Dict[str, Any]:
+        if not self.use_tpu:
+            return {}
+        return {"num_tpus": self.tpu_chips_per_replica}
 
 
 def stream_text_deltas(tokenizer, request):
@@ -1293,6 +1304,7 @@ def build_llm_deployment(config: LLMConfig, params=None,
         name=name or config.model_id,
         num_replicas=config.num_replicas,
         max_ongoing_requests=config.max_ongoing_requests,
+        ray_actor_options=config.ray_actor_options(),
         request_router=("prefix_aware" if config.prefix_routing
                         else "pow2"))
     return dep.bind(config, params_blob)
@@ -1329,6 +1341,11 @@ def build_openai_app(llm_configs: List[LLMConfig] = None, *,
         num_replicas=max(c.num_replicas for c in configs),
         max_ongoing_requests=max(c.max_ongoing_requests
                                  for c in configs),
+        # one replica hosts every model, so it owns the most chips any
+        # of them asks for
+        ray_actor_options=max(
+            (c.ray_actor_options() for c in configs),
+            key=lambda o: o.get("num_tpus", 0)),
         request_router=("prefix_aware"
                         if any(c.prefix_routing for c in configs)
                         else "pow2"))

@@ -49,7 +49,8 @@ class TrainContext:
                  zero1: bool = False, pipeline_stages: int = 1,
                  microbatches: int = 1, schedule: str = "1f1b",
                  pipeline_stage: int = 0, pipeline_replica: int = 0,
-                 stage_group_name: Optional[str] = None):
+                 stage_group_name: Optional[str] = None,
+                 use_tpu: bool = False):
         self.world_size = world_size
         self.world_rank = world_rank
         self.storage_path = storage_path
@@ -69,6 +70,9 @@ class TrainContext:
         self.pipeline_stage = pipeline_stage
         self.pipeline_replica = pipeline_replica
         self.stage_group_name = stage_group_name
+        # ScalingConfig.use_tpu: this worker owns chips, so its first
+        # report names the device it computes on
+        self.use_tpu = use_tpu
         self.reported: list = []
         self.pending_checkpoint_dirs: list = []
         self._lock = locktrace.traced_lock("train.context")
@@ -139,8 +143,12 @@ def report(metrics: Dict[str, Any],
                            if isinstance(v, (int, float, str, bool))}, f)
         except OSError:
             pass
+    metrics = dict(metrics)
+    if ctx.use_tpu and not ctx.reported:
+        from ray_tpu.accelerators import jax_backend
+        metrics.setdefault("device", jax_backend.device_report())
     with ctx._lock:
-        ctx.reported.append((dict(metrics), persisted))
+        ctx.reported.append((metrics, persisted))
         n_reports = len(ctx.reported)
         prev = getattr(ctx, "_last_report_t", None)
         now = time.perf_counter()

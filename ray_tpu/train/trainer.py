@@ -43,13 +43,18 @@ class _TrainWorker:
                  group_name: str, jax_env: Optional[dict] = None,
                  grad_compression: Optional[str] = None,
                  zero1: bool = False, pipeline_stages: int = 1,
-                 microbatches: int = 1, schedule: str = "1f1b"):
+                 microbatches: int = 1, schedule: str = "1f1b",
+                 use_tpu: bool = False):
         self.rank = rank
         self.world_size = world_size
         self.storage_path = storage_path
         self.group_name = group_name
         self.grad_compression = grad_compression
         self.zero1 = zero1
+        self.use_tpu = use_tpu
+        if use_tpu:
+            from ray_tpu.accelerators import jax_backend
+            jax_backend.track_compile_time()
         # pipeline topology: stage-major rank layout (adjacent ranks =
         # adjacent stages of one replica), gradient sync per stage
         self.pipeline_stages = max(1, pipeline_stages)
@@ -71,6 +76,18 @@ class _TrainWorker:
                         jax_env.get("process_id", 0))
             from ray_tpu.parallel.mesh import initialize_distributed
             initialize_distributed(**jax_env)
+            import jax
+            if jax.device_count() != world_size * jax.local_device_count():
+                # e.g. several one-chip workers on ONE TPU host: each
+                # is bounded to its own chip and the TPU runtime does
+                # not join them into one slice
+                raise RuntimeError(
+                    f"rank {rank}: {world_size} workers joined "
+                    f"jax.distributed but see {jax.device_count()} "
+                    f"devices in all, {jax.local_device_count()} local "
+                    "— not one mesh. On one host, give ONE worker all "
+                    "its chips (num_workers=1, tpu_chips_per_worker="
+                    "<chips>); several workers are for several hosts.")
         from ray_tpu.parallel import collective
         collective.init_collective_group(world_size, rank, group_name)
         if self.pipeline_stages > 1:
@@ -126,7 +143,8 @@ class _TrainWorker:
             microbatches=self.microbatches, schedule=self.schedule,
             pipeline_stage=self.pipeline_stage,
             pipeline_replica=self.pipeline_replica,
-            stage_group_name=self.stage_group_name)
+            stage_group_name=self.stage_group_name,
+            use_tpu=self.use_tpu)
         ctx_mod.set_context(ctx)
         try:
             if loop_config is not None:
@@ -424,7 +442,8 @@ class JaxTrainer:
                     zero1=scaling.zero1,
                     pipeline_stages=stages,
                     microbatches=scaling.microbatches,
-                    schedule=scaling.schedule))
+                    schedule=scaling.schedule,
+                    use_tpu=scaling.use_tpu))
         # Fail fast if any worker can't construct — and release every
         # reservation on the way out, or the next (resized) attempt sees
         # the failed gang still holding the cluster's resources.

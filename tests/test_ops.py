@@ -1,5 +1,6 @@
-"""Kernel correctness vs jnp references (CPU fallback paths; the TPU
-kernel paths are exercised by bench.py on hardware)."""
+"""Kernel correctness vs jnp references: the CPU fallback paths and the
+Pallas kernels in interpreter mode (chip_smoke.py checks the compiled
+kernels on a TPU; tests/test_tpu_compile.py compiles them for one)."""
 
 import jax
 import jax.numpy as jnp
@@ -69,8 +70,7 @@ def test_rope_with_positions():
 
 def test_flash_kernels_interpret_vs_reference():
     # Run the actual Pallas kernels (forward + fused backward) in
-    # interpreter mode on CPU and compare against the jnp reference —
-    # the same code path bench.py exercises on hardware.
+    # interpreter mode on CPU and compare against the jnp reference.
     from ray_tpu.ops import attention as att
 
     prev = att._INTERPRET
@@ -130,3 +130,87 @@ def test_int8_matmul_kernel_interpret_vs_reference():
             qm.int8_matmul(x, w8[:, :1000], scale[:1000])
     finally:
         qm._INTERPRET = prev
+
+
+@pytest.mark.parametrize("rules", ["fsdp", "fsdp_tp"])
+def test_kernels_under_a_mesh_match_references(cpu_mesh8, monkeypatch,
+                                               rules):
+    # flash_attention and rms_norm called with a mesh run per shard
+    # under jax.shard_map (a Mosaic kernel cannot be partitioned by
+    # GSPMD); here the real kernels in interpreter mode, inputs sharded
+    # as each rule set leaves them: batch over (data, fsdp) and, with
+    # tensor parallelism, heads over model.
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from ray_tpu.ops import attention as att
+    from ray_tpu.ops import rmsnorm as rn
+    from ray_tpu.parallel.mesh import MeshSpec, make_mesh
+
+    monkeypatch.setattr(att, "_INTERPRET", True)
+    monkeypatch.setattr(rn, "_INTERPRET", True)
+    mesh = make_mesh(MeshSpec(data=2, fsdp=2, model=2), cpu_mesh8)
+    heads = "model" if rules == "fsdp_tp" else None
+    ks = jax.random.split(jax.random.PRNGKey(0), 5)
+    q, k, v = (jax.random.normal(kk, (4, 128, 2, 128), jnp.float32)
+               for kk in ks[:3])
+    sharded = [jax.device_put(a, NamedSharding(
+        mesh, P(("data", "fsdp"), None, heads, None))) for a in (q, k, v)]
+
+    def loss(fn):
+        return lambda q, k, v: jnp.sum(fn(q, k, v) * 0.1)
+
+    kern = lambda q, k, v: att.flash_attention(q, k, v, True, mesh)  # noqa: E731
+    ref = lambda q, k, v: att._attention_reference(q, k, v, True)  # noqa: E731
+    np.testing.assert_allclose(np.asarray(jax.jit(kern)(*sharded)),
+                               np.asarray(ref(q, k, v)), atol=1e-2)
+    for a, b in zip(
+            jax.jit(jax.grad(loss(kern), argnums=(0, 1, 2)))(*sharded),
+            jax.grad(loss(ref), argnums=(0, 1, 2))(q, k, v)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=5e-3)
+
+    x = jax.random.normal(ks[3], (4, 128, 256), jnp.float32)
+    w = jax.random.normal(ks[4], (256,), jnp.float32)
+    xs = jax.device_put(x, NamedSharding(mesh, P(("data", "fsdp"))))
+    norm = lambda x, w: rn.rms_norm(x, w, 1e-5, mesh)  # noqa: E731
+    norm_ref = lambda x, w: rn._rms_norm_reference(x, w, 1e-5)  # noqa: E731
+    np.testing.assert_allclose(np.asarray(jax.jit(norm)(xs, w)),
+                               np.asarray(norm_ref(x, w)), atol=1e-5)
+    # the weight is replicated: its gradient sums over every shard
+    for a, b in zip(
+            jax.jit(jax.grad(lambda x, w: jnp.sum(norm(x, w) ** 2),
+                             argnums=(0, 1)))(xs, w),
+            jax.grad(lambda x, w: jnp.sum(norm_ref(x, w) ** 2),
+                     argnums=(0, 1))(x, w)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-4, atol=1e-4)
+
+
+def test_kernels_under_a_mesh_reject_uncovered_layouts(cpu_mesh8):
+    from ray_tpu.ops.rmsnorm import rms_norm as rms
+    from ray_tpu.parallel.mesh import MeshSpec, make_mesh
+
+    q = jnp.zeros((4, 128, 2, 128))
+    with pytest.raises(ValueError, match="sequence is sharded"):
+        flash_attention(q, q, q, True,
+                        make_mesh(MeshSpec(data=4, seq=2), cpu_mesh8))
+    mesh = make_mesh(MeshSpec(data=8), cpu_mesh8)
+    with pytest.raises(ValueError, match="does not divide"):
+        flash_attention(q, q, q, True, mesh)
+    with pytest.raises(ValueError, match="does not divide"):
+        rms(jnp.zeros((4, 128, 256)), jnp.ones((256,)), 1e-5, mesh)
+
+
+def test_flash_fallback_on_a_tpu_is_counted(monkeypatch):
+    # on a TPU a shape the kernels do not cover (head_dim 16) still
+    # computes, through the O(S^2) reference, and says so
+    from ray_tpu.accelerators import jax_backend
+    from ray_tpu.ops import attention as att
+
+    monkeypatch.setattr(jax_backend, "on_tpu", lambda: True)
+    monkeypatch.setattr(att, "kernel_fallbacks", [])
+    q = jax.random.normal(jax.random.PRNGKey(0), (1, 128, 2, 16))
+    out = att.flash_attention(q, q, q, True)
+    np.testing.assert_allclose(
+        np.asarray(out), np.asarray(att._attention_reference(q, q, q, True)),
+        atol=1e-6)
+    assert att.kernel_fallbacks == ["q[1, 128, 2, 16] k[128] float32"]

@@ -7,7 +7,6 @@ import numpy as np
 import pytest
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from ray_tpu.parallel import _compat
 from ray_tpu.parallel.mesh import MeshSpec, make_mesh, mesh_axis_size
 from ray_tpu.parallel.pipeline import pipeline
 from ray_tpu.parallel.ring_attention import (
@@ -16,12 +15,6 @@ from ray_tpu.parallel.ring_attention import (
     ulysses_attention,
 )
 
-# ring/ulysses/pipeline all lower through shard_map; its import home
-# moves across jax versions (see parallel/_compat.py). Skip those tests
-# with the detected reason rather than erroring at collection.
-needs_shard_map = pytest.mark.skipif(
-    not _compat.SHARD_MAP_AVAILABLE,
-    reason=_compat.SHARD_MAP_UNAVAILABLE_REASON or "shard_map available")
 from ray_tpu.parallel.sharding import (
     ShardingConfig,
     ShardingRules,
@@ -61,7 +54,6 @@ def test_shard_pytree_places_shards(cpu_mesh8):
 
 
 @pytest.mark.parametrize("causal", [True, False])
-@needs_shard_map
 def test_ring_attention_matches_reference(causal):
     mesh = make_mesh(MeshSpec(seq=4, data=2))
     B, S, H, D = 2, 64, 4, 16
@@ -74,7 +66,6 @@ def test_ring_attention_matches_reference(causal):
                                atol=2e-5, rtol=2e-5)
 
 
-@needs_shard_map
 def test_ulysses_matches_reference():
     mesh = make_mesh(MeshSpec(seq=4, data=2))
     B, S, H, D = 2, 64, 8, 16
@@ -87,7 +78,6 @@ def test_ulysses_matches_reference():
                                atol=2e-5, rtol=2e-5)
 
 
-@needs_shard_map
 def test_ring_attention_sharded_inputs():
     """Ring attention with inputs actually sharded over seq."""
     mesh = make_mesh(MeshSpec(seq=8))
@@ -103,7 +93,6 @@ def test_ring_attention_sharded_inputs():
                                atol=2e-5, rtol=2e-5)
 
 
-@needs_shard_map
 def test_pipeline_matches_sequential():
     mesh = make_mesh(MeshSpec(pipe=4, data=2))
     n_stages, d = 4, 32
@@ -122,7 +111,6 @@ def test_pipeline_matches_sequential():
                                atol=1e-5, rtol=1e-5)
 
 
-@needs_shard_map
 def test_pipeline_rejects_bad_microbatch():
     mesh = make_mesh(MeshSpec(pipe=4, data=2))
     params = {"w": jnp.zeros((4, 8, 8))}
@@ -131,7 +119,6 @@ def test_pipeline_rejects_bad_microbatch():
         pipeline(lambda p, x: x, params, x, mesh, num_microbatches=4)
 
 
-@needs_shard_map
 def test_pipeline_multi_round_and_grad():
     """More microbatches than stages (R=3 rounds of the sharded input
     stream) and gradient flow with remat."""
@@ -167,7 +154,6 @@ def test_pipeline_multi_round_and_grad():
                                atol=2e-4, rtol=2e-4)
 
 
-@needs_shard_map
 def test_pipeline_rejects_uneven_stage_split():
     mesh = make_mesh(MeshSpec(pipe=4, data=2))
     params = {"w": jnp.zeros((4, 8, 8))}

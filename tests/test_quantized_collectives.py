@@ -342,7 +342,6 @@ _MULTIDEVICE_SCRIPT = textwrap.dedent("""
     import numpy as np
     import jax
     from jax.sharding import Mesh, PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
     from ray_tpu.parallel import collective as C
 
     assert jax.device_count() == 8, jax.devices()
@@ -353,9 +352,9 @@ _MULTIDEVICE_SCRIPT = textwrap.dedent("""
 
     def run(fn, *args):
         specs = tuple(P("d") for _ in args)
-        return np.asarray(shard_map(fn, mesh=mesh, in_specs=specs,
-                                    out_specs=P("d"),
-                                    check_rep=False)(*args))
+        return np.asarray(jax.shard_map(fn, mesh=mesh, in_specs=specs,
+                                        out_specs=P("d"),
+                                        check_vma=False)(*args))
 
     out = run(lambda x: C.quantized_psum(x, "d", dtype="int8"), xs)
     rel = np.abs(out[0] - truth).max() / np.abs(truth).max()

@@ -1,0 +1,166 @@
+"""Compile-only checks for a TPU v5e, with no chip: each Pallas kernel
+and the programs chip_smoke.py runs are lowered from abstract arguments
+and compiled against ``v5e:2x2`` topology devices (libtpu ships the
+compiler). Catches what only the TPU compiler says — scoped-VMEM
+overflow, "Mosaic kernels cannot be automatically partitioned" — before
+any chip time is spent. Skipped where the topology cannot be had."""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import optax
+import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+import chip_smoke
+from ray_tpu.accelerators import jax_backend
+from ray_tpu.models.llama import (
+    LlamaConfig, llama_decode_step, llama_init, llama_init_cache,
+    llama_prefill)
+from ray_tpu.ops import attention, quant_matmul, rmsnorm
+from ray_tpu.parallel.mesh import AXIS_ORDER
+
+HBM_BYTES = 16 * 2**30  # one v5e chip
+
+
+@pytest.fixture(scope="module")
+def v5e():
+    try:
+        from jax.experimental import topologies
+        return topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2").devices
+    except Exception as exc:  # noqa: BLE001 — no libtpu, other jax
+        pytest.skip(f"no v5e:2x2 compile-only topology here: {exc!r}")
+
+
+@pytest.fixture(autouse=True)
+def lowering_for_tpu(monkeypatch):
+    # the host backend is the CPU; the programs are lowered for the
+    # topology's devices, so kernel selection must answer for those
+    monkeypatch.setattr(jax_backend, "on_tpu", lambda: True)
+
+
+def _mesh(devices, fsdp):
+    shape = tuple(fsdp if a == "fsdp" else 1 for a in AXIS_ORDER)
+    return Mesh(np.asarray(devices[:fsdp]).reshape(shape), AXIS_ORDER)
+
+
+def _abstract(tree, shardings):
+    return jax.tree.map(
+        lambda a, s: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=s),
+        tree, shardings)
+
+
+def _on(mesh, spec, *shape_dtype):
+    return jax.ShapeDtypeStruct(*shape_dtype,
+                                sharding=NamedSharding(mesh, spec))
+
+
+def _kernels(lowered):
+    return jax_backend.pallas_kernels(lowered.as_text())
+
+
+def test_rms_block_rows_fit_vmem(v5e):
+    mesh = _mesh(v5e, 1)
+    for d, rows in ((2560, 512), (4096, 256), (8192, 128)):
+        assert rmsnorm._block_rows(8192, d, 2) == rows
+        lowered = jax.jit(lambda x, w: rmsnorm.rms_norm(x, w, 1e-5)).lower(
+            _on(mesh, P(), (8192, d), jnp.bfloat16),
+            _on(mesh, P(), (d,), jnp.bfloat16))
+        assert _kernels(lowered) == [
+            f"rms_norm(tensor<8192x{d}xbf16>, tensor<{d}xbf16>)"]
+        lowered.compile()
+    # whole input as one block; float32 rows take twice the room
+    assert rmsnorm._block_rows(8, 4096, 2) == 8
+    assert rmsnorm._block_rows(8192, 4096, 4) == 128
+    assert rmsnorm._block_rows(8192, 4000, 2) is None
+
+
+def test_flash_and_int8_kernels_compile(v5e):
+    mesh = _mesh(v5e, 1)
+    qkv = _on(mesh, P(), (2, 2048, 32, 128), jnp.bfloat16)
+    lowered = jax.jit(jax.grad(
+        lambda q, k, v: attention.flash_attention(q, k, v, True)
+        .astype(jnp.float32).sum(), argnums=(0, 1, 2))).lower(qkv, qkv, qkv)
+    assert [k.split("(")[0] for k in _kernels(lowered)] == [
+        "flash_dkv", "flash_dq", "flash_fwd"]
+    lowered.compile()
+    lowered = jax.jit(quant_matmul.int8_matmul).lower(
+        _on(mesh, P(), (8, 4096), jnp.bfloat16),
+        _on(mesh, P(), (4096, 14336), jnp.int8),
+        _on(mesh, P(), (14336,), jnp.float32))
+    assert [k.split("(")[0] for k in _kernels(lowered)] == ["int8_matmul"]
+    lowered.compile()
+    assert attention.kernel_fallbacks == []
+
+
+def _compile_train_step(devices, *, chips, n_layers, batch, seq=2048):
+    """chip_smoke's trainer step, from abstract state sharded as its
+    loop shards it. Returns (kernels, bytes per chip)."""
+    cfg = LlamaConfig.llama2_7b(n_layers=n_layers, max_seq_len=seq,
+                                ce_chunk_tokens=4096)
+    mesh = _mesh(devices, chips)
+    init, shardings, train_step = chip_smoke.train_programs(
+        cfg, mesh, optax.adamw(1e-3))
+    params, opt_state = _abstract(
+        jax.eval_shape(init, jax.random.PRNGKey(0)), shardings)
+    tokens = _on(mesh, P(("data", "fsdp")), (batch, seq), jnp.int32)
+    lowered = jax.jit(train_step, donate_argnums=(0, 1)).lower(
+        params, opt_state, tokens, tokens)
+    memory = lowered.compile().memory_analysis()
+    return _kernels(lowered), (memory.argument_size_in_bytes
+                               + memory.temp_size_in_bytes)
+
+
+@pytest.mark.slow
+def test_one_chip_train_step_compiles(v5e):
+    kernels, bytes_per_chip = _compile_train_step(
+        v5e, chips=1, n_layers=4, batch=4)
+    assert [k.split("(")[0] for k in kernels] == [
+        "flash_dkv", "flash_dq", "flash_fwd", "rms_norm"]
+    assert bytes_per_chip < HBM_BYTES
+    assert attention.kernel_fallbacks == []
+
+
+@pytest.mark.slow
+def test_fsdp4_train_step_compiles_with_per_shard_kernels(v5e):
+    kernels, bytes_per_chip = _compile_train_step(
+        v5e, chips=4, n_layers=16, batch=8)
+    # batch 8 over fsdp=4: every kernel sees 2 sequences
+    shard = "tensor<2x32x2048x128xbf16>"
+    assert f"flash_fwd({shard}, {shard}, {shard})" in kernels
+    assert any(k.startswith(f"flash_dq({shard}") for k in kernels)
+    assert any(k.startswith(f"flash_dkv({shard}") for k in kernels)
+    assert "rms_norm(tensor<4096x4096xbf16>, tensor<4096xbf16>)" in kernels
+    assert bytes_per_chip < HBM_BYTES
+
+
+@pytest.mark.slow
+def test_serving_programs_compile(v5e):
+    """The engine's prefill (buckets 128 and 1024) and decode programs
+    at the smoke's serving size: 16 layers, batch 8, seq 1024."""
+    cfg = LlamaConfig.llama2_7b(n_layers=16, max_seq_len=1024)
+    mesh = _mesh(v5e, 1)
+    params = jax.eval_shape(lambda k: llama_init(k, cfg),
+                            jax.random.PRNGKey(0))
+    params = _abstract(params, jax.tree.map(
+        lambda _: NamedSharding(mesh, P()), params))
+    for bucket in (128, 1024):
+        lowered = jax.jit(lambda p, t: llama_prefill(p, t, cfg)).lower(
+            params, _on(mesh, P(), (1, bucket), jnp.int32))
+        assert [k.split("(")[0] for k in _kernels(lowered)] == [
+            "flash_fwd", "rms_norm"]
+        lowered.compile()
+    cache = jax.eval_shape(lambda: llama_init_cache(cfg, 8, 1024))
+    cache_k, cache_v = _abstract(cache, jax.tree.map(
+        lambda _: NamedSharding(mesh, P()), cache))
+    ints = _on(mesh, P(), (8,), jnp.int32)
+    lowered = jax.jit(
+        lambda p, tok, ck, cv, pos: llama_decode_step(p, tok, ck, cv,
+                                                      pos, cfg),
+        donate_argnums=(2, 3)).lower(params, ints, cache_k, cache_v, ints)
+    assert [k.split("(")[0] for k in _kernels(lowered)] == ["rms_norm"]
+    memory = lowered.compile().memory_analysis()
+    assert (memory.argument_size_in_bytes
+            + memory.temp_size_in_bytes) < HBM_BYTES
+    assert attention.kernel_fallbacks == []

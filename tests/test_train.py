@@ -8,10 +8,8 @@ air_benchmark_torch_mnist (release_tests.yaml:197) as the DDP recipe.
 import os
 
 import numpy as np
-import pytest
 
 import ray_tpu
-from ray_tpu.parallel import _compat
 from ray_tpu.train import (
     Checkpoint,
     FailureConfig,
@@ -176,11 +174,6 @@ def test_failure_policy_exhausted(ray_start_regular, tmp_path):
     assert "ERRORED" in trainer.state_history
 
 
-@pytest.mark.skipif(
-    "cpu" in os.environ.get("JAX_PLATFORMS", "").lower()
-    and not _compat.CPU_COLLECTIVES_AVAILABLE,
-    reason="CPU gang needs gloo collectives in jaxlib: "
-           + _compat.CPU_COLLECTIVES_UNAVAILABLE_REASON)
 def test_gang_multiprocess_spmd_global_mesh(ray_start_cluster, tmp_path):
     """VERDICT round-1 item 6: gang-launch N real worker processes,
     jax.distributed.initialize over loopback, and prove the gang shares
