@@ -31,6 +31,7 @@ from __future__ import annotations
 
 import itertools
 import threading
+import weakref
 
 from ray_tpu.devtools import locktrace
 import time
@@ -49,11 +50,11 @@ from ray_tpu.util import metrics as _metrics
 
 # --- built-in engine metrics (reference: vLLM engine stats surfaced
 # through serve) ----------------------------------------------------
-# TTFT is observed per request (request-rate — direct record). Step
-# metrics are produced by the stepper hot loop, so they aggregate
-# locally in _MetricsBuffer and flush as ONE batched update per
-# interval — a per-step RPC from a replica worker would serialize the
-# decode loop on the control plane.
+# Everything the stepper thread records, and what a request thread
+# records before the first token, aggregates locally in _MetricsBuffer
+# and is flushed by the buffer's own thread: an RPC from the stepper of
+# a replica worker would serialize the decode loop on the control
+# plane, with every active slot waiting.
 _TTFT_BOUNDS = [0.01, 0.025, 0.05, 0.1, 0.25, 0.5, 1.0, 2.5, 5.0,
                 10.0, 30.0, 60.0]
 _STEP_BOUNDS = [0.001, 0.0025, 0.005, 0.01, 0.025, 0.05, 0.1, 0.25,
@@ -62,14 +63,22 @@ ENGINE_TTFT = _metrics.Histogram(
     "ray_tpu_engine_ttft_seconds",
     "Time from request admission to its first emitted token",
     boundaries=_TTFT_BOUNDS)
+ENGINE_STAGE_SECONDS = _metrics.Histogram(
+    "ray_tpu_engine_request_stage_seconds",
+    "Where a request waits before its first token: dispatch (proxy "
+    "receipt to the replica's handler), prepare (handler entry to "
+    "add_request), queue (add_request to admission), prefill "
+    "(admission to the first token)",
+    boundaries=_TTFT_BOUNDS, tag_keys=("stage",))
 ENGINE_STEP_SECONDS = _metrics.Histogram(
     "ray_tpu_engine_step_seconds",
     "Engine step wall time, by phase (prefill-admitting vs pure decode)",
     boundaries=_STEP_BOUNDS, tag_keys=("phase",))
-ENGINE_TOKEN_SECONDS = _metrics.Histogram(
-    "ray_tpu_engine_token_seconds",
-    "Per-token decode latency (step time per token emitted per slot)",
-    boundaries=_STEP_BOUNDS)
+ENGINE_STEP_HOST_SECONDS = _metrics.Histogram(
+    "ray_tpu_engine_step_host_seconds",
+    "Engine step wall time less the time the stepper waited for the "
+    "device in it (its blocking read-backs), by phase",
+    boundaries=_STEP_BOUNDS, tag_keys=("phase",))
 ENGINE_TOKENS = _metrics.Counter(
     "ray_tpu_engine_tokens_generated_total",
     "Tokens emitted by the engine")
@@ -84,77 +93,80 @@ ENGINE_WAITING = _metrics.Gauge(
     "Requests queued for a free decode slot")
 
 
-class _MetricsBuffer:
-    """Local aggregation for stepper-loop metrics: bounded samples per
-    flush window, shipped via ONE metrics.record_batch call (one
-    control-plane RPC from a worker) instead of per-step updates."""
+class _MetricsBuffer(_metrics.LocalBuffer):
+    """The engine's metrics, aggregated in its process: histograms as
+    bucket counts, so a flush is one metrics.record_batch (one
+    control-plane RPC from a worker) of a size that does not grow with
+    the step rate. No caller on the stepper thread ever flushes: a
+    daemon thread owned by the buffer does, every ``flush_interval_s``,
+    and stats() / flush_metrics() do on their caller's thread. The
+    thread starts with the first recorded step, knows the engine only
+    by a weak reference and ends when the engine is collected or
+    close() runs."""
 
-    _SAMPLE_CAP = 64  # histogram samples kept per flush window
-
-    def __init__(self, flush_interval_s: float = 0.5):
+    def __init__(self, engine, flush_interval_s: float = 0.5):
+        super().__init__()
         self.flush_interval_s = flush_interval_s
+        self._engine = weakref.ref(engine)
         self._last_flush = time.perf_counter()
-        self._step_samples: List[tuple] = []   # (phase, dt)
-        self._token_samples: List[float] = []
-        self._tokens = 0
-        # stats()/flush_metrics() run on request threads concurrently
-        # with the stepper's note_step — cheap uncontended lock
-        self._buf_lock = locktrace.traced_lock("llm.engine.buf")
+        self._flushed_tokens = 0
+        # flushers: the buffer's thread and stats()/flush_metrics()
+        # callers on request threads; never the stepper
+        self._flush_lock = locktrace.traced_lock("llm.engine.flush")
+        self._thread: Optional[threading.Thread] = None
+        self._stop = threading.Event()
 
-    def note_step(self, phase: str, dt: float, tokens: int,
-                  active: int) -> None:
-        with self._buf_lock:
-            self._tokens += tokens
-            if len(self._step_samples) < self._SAMPLE_CAP:
-                self._step_samples.append((phase, dt))
-            if tokens > 0 and active > 0 \
-                    and len(self._token_samples) < self._SAMPLE_CAP:
-                # per-slot per-token latency: a dense step emits one
-                # token per active slot, so this is just dt; fused
-                # multi-token paths amortize
-                self._token_samples.append(dt * active / tokens)
-
-    def maybe_flush(self, engine, force: bool = False) -> None:
-        now = time.perf_counter()
-        with self._buf_lock:
-            elapsed = now - self._last_flush
-            if not force and elapsed < self.flush_interval_s:
-                return
-            step_samples = self._step_samples
-            token_samples = self._token_samples
-            tokens = self._tokens
-            self._step_samples = []
-            self._token_samples = []
-            self._tokens = 0
-            self._last_flush = now
-        if not step_samples and not tokens and not force:
-            return
-        items = [
-            ("histogram", "ray_tpu_engine_step_seconds", {"phase": ph},
-             dt, _STEP_BOUNDS)
-            for ph, dt in step_samples
-        ]
-        items += [
-            ("histogram", "ray_tpu_engine_token_seconds", {}, dt,
-             _STEP_BOUNDS)
-            for dt in token_samples
-        ]
+    def note_step(self, phase: str, dt: float, host_dt: float,
+                  tokens: int) -> None:
+        tags = {"phase": phase}
+        self.observe(ENGINE_STEP_SECONDS, dt, tags)
+        self.observe(ENGINE_STEP_HOST_SECONDS, host_dt, tags)
         if tokens:
-            items.append(("counter",
-                          "ray_tpu_engine_tokens_generated_total", {},
-                          float(tokens), None))
-        if elapsed > 0:
-            items.append(("gauge", "ray_tpu_engine_tokens_per_second",
-                          {}, tokens / elapsed, None))
-        active = sum(1 for s in engine.slots if s.request is not None)
-        items.append(("gauge", "ray_tpu_engine_batch_occupancy", {},
-                      float(active), None))
-        items.append(("gauge", "ray_tpu_engine_waiting_requests", {},
-                      float(len(engine.waiting)), None))
-        try:
-            _metrics.record_batch(items)
-        except Exception:  # graftlint: disable=GL004
-            pass  # observability is best-effort
+            self.inc(ENGINE_TOKENS, float(tokens))
+        if self._thread is None and not self._stop.is_set():
+            # only the stepper gets here, so one thread per engine
+            self._thread = threading.Thread(
+                target=self._flush_loop, name="engine-metrics-flush",
+                daemon=True)
+            self._thread.start()
+
+    def _flush_loop(self) -> None:
+        while not self._stop.wait(self.flush_interval_s):
+            if self._engine() is None:
+                return
+            self.flush()
+
+    def flush(self, force: bool = False) -> List[tuple]:
+        """Ship what gathered since the last flush plus the gauges as
+        they stand; nothing (and no RPC) when nothing gathered, unless
+        forced."""
+        engine = self._engine()
+        with self._flush_lock:
+            now = time.perf_counter()
+            if engine is not None and (force or self._pending.histograms
+                                       or self._pending.counters):
+                elapsed = now - self._last_flush
+                self._last_flush = now
+                total = engine.total_generated
+                if elapsed > 0:
+                    self.set(ENGINE_TOKENS_PER_S,
+                             (total - self._flushed_tokens) / elapsed)
+                self._flushed_tokens = total
+                self.set(ENGINE_OCCUPANCY, float(sum(
+                    1 for s in engine.slots if s.request is not None)))
+                self.set(ENGINE_WAITING, float(len(engine.waiting)))
+            try:
+                return super().flush()
+            except Exception:  # graftlint: disable=GL004
+                return []  # observability is best-effort
+
+    def close(self) -> None:
+        """Stop the flush thread after one last flush."""
+        self._stop.set()
+        thread = self._thread
+        if thread is not None and thread is not threading.current_thread():
+            thread.join(timeout=2.0)
+        self.flush()
 
 
 class EngineSaturatedError(RuntimeError):
@@ -305,6 +317,16 @@ class GenerationRequest:
     logprob_data: List[Dict[str, Any]] = field(default_factory=list)
     finish_reason: Optional[str] = None
     error: Optional[str] = None
+    # time.perf_counter() at add_request, at the moment the stepper
+    # popped the request from the waiting queue, and at its first
+    # token: queue = t_admit - t_submit, prefill = t_first_token -
+    # t_admit, and their sum is the engine's TTFT
+    t_submit: Optional[float] = field(default=None, repr=False,
+                                      compare=False)
+    t_admit: Optional[float] = field(default=None, repr=False,
+                                     compare=False)
+    t_first_token: Optional[float] = field(default=None, repr=False,
+                                           compare=False)
     # set when finish_reason lands; waiters block on this instead of
     # polling `done` in a sleep loop (graftlint GL003)
     done_event: threading.Event = field(default_factory=threading.Event,
@@ -459,8 +481,14 @@ class ContinuousBatchingEngine:
         self.total_generated = 0
         self._base_key = jax.random.PRNGKey(config.seed)
         self._step_counter = 0
-        self._mbuf = _MetricsBuffer()
+        self._mbuf = _MetricsBuffer(self)
         self._admitted_last_step = 0
+        # step() calls so far: the number a flight-recorder
+        # engine_step event and its child spans share
+        self._steps = 0
+        # seconds of the current step the stepper spent waiting for
+        # the device (see _readback)
+        self._blocked_s = 0.0
         # multi-LoRA bank: slot 0 is the all-zero base adapter, so
         # "no adapter" needs no conditional in the decode program
         self._adapters: Dict[str, int] = {}
@@ -485,6 +513,7 @@ class ContinuousBatchingEngine:
         lp_k = min(20, c.vocab_size)  # static top-logprobs width
         self._lp_k = lp_k
 
+        @jax.named_scope("sampler")
         def sample_tokens(logits, temp, topk, key, bias=None):
             """On-device sampling: greedy / temperature / top-k per
             slot, [B, V] logits -> [B] int32 — only the token ids cross
@@ -829,14 +858,14 @@ class ContinuousBatchingEngine:
             # adapter raising inside step() would fail_all the replica
         if request.top_k > self.config.max_top_k:
             request.top_k = self.config.max_top_k
-        request._t_submit = time.perf_counter()
+        request.t_submit = time.perf_counter()
         with self._lock:
             self._prefilled_waiting.append(
                 (request, ks, vs, prompt_len, first_token))
         return request
 
     def add_request(self, request: GenerationRequest) -> GenerationRequest:
-        request._t_submit = time.perf_counter()
+        request.t_submit = time.perf_counter()
         self._validate_logit_bias(request.logit_bias)
         self._validate_guided(request)
         limit = self._pos_limit
@@ -884,6 +913,7 @@ class ContinuousBatchingEngine:
                 request, ks, vs, plen, tok = self._prefilled_waiting.pop(0)
                 slot = free[0]
                 slot.request = request
+            self._note_admitted(request)
             self._install_bias(request, slot.index)
             self.cache_k, self.cache_v = self._insert(
                 self.cache_k, self.cache_v, jnp.asarray(ks),
@@ -906,6 +936,49 @@ class ContinuousBatchingEngine:
             slot.pos = plen
             self._emit(slot, tok)
 
+    def _note_admitted(self, request: GenerationRequest) -> None:
+        """The stepper popped ``request`` from a waiting queue: its
+        queue stage ends here and its prefill stage starts."""
+        request.t_admit = now = time.perf_counter()
+        if request.t_submit is None:
+            return
+        waited = max(0.0, now - request.t_submit)
+        self.record_stage("queue", waited)
+        rec = _flight.RECORDER
+        if rec is not None:
+            waited_ns = int(waited * 1e9)
+            rec.record("serve", "request_queue", rec.clock() - waited_ns,
+                       waited_ns, {"req": request.request_id,
+                                   "step": self._steps})
+
+    def _span(self, name: str, **args) -> "_flight.span":
+        """A phase of the current step, on the profiler's clock and in
+        the flight recorder (category serve), tagged with the step."""
+        return _flight.span("serve", name, step=self._steps, **args)
+
+    def _upload(self, *arrays) -> list:
+        """Host arrays of one step onto the device. Callers ``del``
+        the results once the program that reads them is launched, as
+        call-site temporaries would go: a device array dropped after
+        the step's read-back is released on the stepper thread while
+        the device sits idle (0.4 ms each on a v5e); dropped earlier,
+        its release is paid inside the next step's uploads, a little
+        cheaper in sum."""
+        with self._span("engine.upload"):
+            return [self._jnp.asarray(a) for a in arrays]
+
+    def _readback(self, *arrays) -> list:
+        """Device results as numpy arrays. These reads block the
+        stepper until the device has finished the program before them,
+        so every step program's and the prefill's go through here and
+        the time waited is counted once: a step's host time is its
+        wall time less this."""
+        t0 = time.perf_counter()
+        with self._span("engine.readback"):
+            out = [np.asarray(a) for a in arrays]
+        self._blocked_s += time.perf_counter() - t0  # graftlint: disable=GL001  # stepper-thread-only
+        return out
+
     def _call_program(self, name: str, jitted, *args, **static):
         """Run a jitted program, keeping the abstract signature of its
         first call so stats() can lower it again and name the Pallas
@@ -927,7 +1000,6 @@ class ContinuousBatchingEngine:
         prefill, sample the first token. Both the colocated admit path
         and prefill_only (disaggregation) call this — one copy, so the
         exact-parity guarantee between the two modes can't drift."""
-        jnp = self._jnp
         use_cache = self._prefix_cache is not None and adapter is None
         hit = self._match_prefix(ids) if use_cache else None
         if hit is not None:
@@ -942,10 +1014,12 @@ class ContinuousBatchingEngine:
                     self.prefix_misses += 1
             padded = self._pad_bucket(ids)
             lora = self._adapter_prefill.get(adapter) if adapter else None
-            logits, ks, vs = self._call_program(
-                f"prefill_{padded.shape[1]}", self._prefill,
-                self.params, jnp.asarray(padded), lora)
-            last_logits = logits[0, len(ids) - 1]
+            (tokens_dev,) = self._upload(padded)
+            with self._span("engine.launch"):
+                logits, ks, vs = self._call_program(
+                    f"prefill_{padded.shape[1]}", self._prefill,
+                    self.params, tokens_dev, lora)
+                last_logits = logits[0, len(ids) - 1]
         else:
             # suffix-only prefill: ONE fused program pads the cached
             # prefix KV to the target bucket and scores the suffix
@@ -964,22 +1038,32 @@ class ContinuousBatchingEngine:
             bucket = self._bucket_len(plen_p + chunk_len)
             chunk = np.zeros((1, chunk_len), dtype=np.int32)
             chunk[0, : len(suffix)] = suffix
-            logits, ks, vs = self._suffix_prefill(
-                self.params, cks, cvs, jnp.asarray(chunk),
-                jnp.asarray([plen_p], dtype=jnp.int32), bucket=bucket)
-            last_logits = logits[0, len(suffix) - 1]
+            chunk_dev, start_dev = self._upload(
+                chunk, np.asarray([plen_p], dtype=np.int32))
+            with self._span("engine.launch"):
+                logits, ks, vs = self._suffix_prefill(
+                    self.params, cks, cvs, chunk_dev, start_dev,
+                    bucket=bucket)
+                last_logits = logits[0, len(suffix) - 1]
         # stepper-thread-only RNG state
         self._step_counter += 1  # graftlint: disable=GL001
         bias_dev = (self._zero_bias_row if bias_row is None
-                    else jnp.asarray(bias_row))
-        token, chosen, top_vals, top_ids = self._sample_one(
-            last_logits, float(temperature), int(top_k),
-            self._jax.random.fold_in(self._base_key, self._step_counter),
-            bias_dev, want_lp=want_logprobs)
+                    else self._upload(bias_row)[0])
+        with self._span("engine.launch"):
+            token, chosen, top_vals, top_ids = self._sample_one(
+                last_logits, float(temperature), int(top_k),
+                self._jax.random.fold_in(self._base_key,
+                                         self._step_counter),
+                bias_dev, want_lp=want_logprobs)
         if use_cache:
             self._store_prefix(ids, ks, vs)
-        first_lp = (float(chosen), np.asarray(top_vals),
-                    np.asarray(top_ids)) if want_logprobs else None
+        if want_logprobs:
+            token, chosen, top_vals, top_ids = self._readback(
+                token, chosen, top_vals, top_ids)
+            first_lp = (float(chosen), top_vals, top_ids)
+        else:
+            (token,) = self._readback(token)
+            first_lp = None
         return ks, vs, int(token), first_lp
 
     def _validate_logit_bias(self, logit_bias) -> None:
@@ -1051,10 +1135,13 @@ class ContinuousBatchingEngine:
     def _install_bias(self, request: GenerationRequest,
                       slot_index: int) -> None:
         if request.logit_bias or self._has_dynamic_bias(request):
-            row = self._jnp.asarray(self._bias_row(request))
-        else:
-            row = self._zero_bias_row  # no per-request host build/copy
-        self._bias = self._set_bias(self._bias, row,
+            with self._span("engine.bias"):
+                self._bias = self._set_bias(
+                    self._bias, self._jnp.asarray(self._bias_row(request)),
+                    self._jnp.asarray(slot_index))
+            return
+        # no per-request host build/copy
+        self._bias = self._set_bias(self._bias, self._zero_bias_row,
                                     self._jnp.asarray(slot_index))
 
     def _bucket_len(self, n: int) -> int:
@@ -1142,39 +1229,52 @@ class ContinuousBatchingEngine:
                 slot = free[0]
                 slot.request = request
             self._admitted_last_step += 1  # graftlint: disable=GL001  # stepper-thread-only
+            self._note_admitted(request)
             ids = request.prompt_ids
-            self._install_bias(request, slot.index)
-            C = self.config.chunked_prefill_tokens
-            if C > 0 and request.adapter is None \
-                    and request.logprobs is None:
-                # chunked admission: no blocking prefill — step() will
-                # advance this prompt one chunk at a time. Every chunk
-                # write stays in bounds because add_request truncated
-                # the prompt to _pos_limit = max_seq-1-scratch with
-                # scratch >= C. LoRA requests lack a chunk-program
-                # path and take the blocking prefill below.
-                slot.prefilling = True
-                slot.prefill_ids = list(ids)
-                slot.prefill_pos = 0
-                slot.pos = 0
-                slot.next_token = 0
-                continue
-            ks, vs, token, first_lp = self._run_prefill(
-                ids, request.adapter, request.temperature,
-                request.top_k,
-                bias_row=(self._bias_row(request)
-                          if request.logit_bias
-                          or self._has_dynamic_bias(request) else None),
-                want_logprobs=request.logprobs is not None)
-            if request.logprobs is not None:
-                slot.pending_lp = first_lp
+            with self._span("engine.prefill", req=request.request_id,
+                            prompt_len=len(ids),
+                            bucket=self._bucket_len(len(ids))):
+                self._prefill_into(slot, request, ids)
+
+    def _prefill_into(self, slot: _Slot, request: GenerationRequest,
+                      ids: List[int]) -> None:
+        """One admitted prompt: its bias row, its prefill (or, chunked,
+        the bookkeeping that lets step() run it), its first token."""
+        self._install_bias(request, slot.index)
+        C = self.config.chunked_prefill_tokens
+        if C > 0 and request.adapter is None \
+                and request.logprobs is None:
+            # chunked admission: no blocking prefill — step() will
+            # advance this prompt one chunk at a time. Every chunk
+            # write stays in bounds because add_request truncated
+            # the prompt to _pos_limit = max_seq-1-scratch with
+            # scratch >= C. LoRA requests lack a chunk-program
+            # path and take the blocking prefill below.
+            slot.prefilling = True
+            slot.prefill_ids = list(ids)
+            slot.prefill_pos = 0
+            slot.pos = 0
+            slot.next_token = 0
+            return
+        bias_row = None
+        if request.logit_bias or self._has_dynamic_bias(request):
+            with self._span("engine.bias"):
+                bias_row = self._bias_row(request)
+        ks, vs, token, first_lp = self._run_prefill(
+            ids, request.adapter, request.temperature,
+            request.top_k, bias_row=bias_row,
+            want_logprobs=request.logprobs is not None)
+        if request.logprobs is not None:
+            slot.pending_lp = first_lp
+        with self._span("engine.launch"):
             self.cache_k, self.cache_v = self._insert(
                 self.cache_k, self.cache_v, ks, vs, slot.index)
             if self._spec:
                 self._draft_prefill_slot(ids, slot.index)
                 slot.draft_ready = True
-            slot.next_token = token
-            slot.pos = len(ids)
+        slot.next_token = token
+        slot.pos = len(ids)
+        with self._span("engine.emit"):
             self._emit(slot, slot.next_token)
 
     def _emit(self, slot: _Slot, token: int) -> None:
@@ -1186,15 +1286,12 @@ class ContinuousBatchingEngine:
             return
         request.output_ids.append(token)
         self.total_generated += 1
-        if len(request.output_ids) == 1:
-            t_submit = getattr(request, "_t_submit", None)
-            if t_submit is not None:
-                # per-request, not per-step: direct record is fine
-                try:
-                    ENGINE_TTFT.observe(
-                        max(0.0, time.perf_counter() - t_submit))
-                except Exception:  # graftlint: disable=GL004
-                    pass  # metric observe is best-effort
+        if len(request.output_ids) == 1 and request.t_submit is not None:
+            request.t_first_token = now = time.perf_counter()
+            self._mbuf.observe(ENGINE_TTFT,
+                               max(0.0, now - request.t_submit))
+            if request.t_admit is not None:
+                self.record_stage("prefill", now - request.t_admit)
         if request.logprobs is not None and slot.pending_lp is not None:
             chosen, top_vals, top_ids = slot.pending_lp
             k = min(request.logprobs, len(top_ids))
@@ -1234,18 +1331,19 @@ class ContinuousBatchingEngine:
         speculative paths so a new per-request field cannot desync
         them. ``pos_fill`` is where idle slots park their writes."""
         n = self.config.max_batch
-        tokens = np.zeros(n, dtype=np.int32)
-        pos = np.full(n, pos_fill, dtype=np.int32)
-        temp = np.zeros(n, dtype=np.float32)
-        topk = np.zeros(n, dtype=np.int32)
-        lora_idx = np.zeros(n, dtype=np.int32)
-        for slot in active:
-            request = slot.request
-            tokens[slot.index] = slot.next_token
-            pos[slot.index] = slot.pos
-            temp[slot.index] = request.temperature
-            topk[slot.index] = request.top_k
-            lora_idx[slot.index] = self._adapter_index(request)
+        with self._span("engine.gather"):
+            tokens = np.zeros(n, dtype=np.int32)
+            pos = np.full(n, pos_fill, dtype=np.int32)
+            temp = np.zeros(n, dtype=np.float32)
+            topk = np.zeros(n, dtype=np.int32)
+            lora_idx = np.zeros(n, dtype=np.int32)
+            for slot in active:
+                request = slot.request
+                tokens[slot.index] = slot.next_token
+                pos[slot.index] = slot.pos
+                temp[slot.index] = request.temperature
+                topk[slot.index] = request.top_k
+                lora_idx[slot.index] = self._adapter_index(request)
         return tokens, pos, temp, topk, lora_idx
 
     def _spec_step(self, active) -> int:
@@ -1254,73 +1352,79 @@ class ContinuousBatchingEngine:
         its accepted draft prefix plus the target's correction (1..G
         tokens per round, every one of them exactly what greedy
         target-only decoding would have produced)."""
-        jax, jnp = self._jax, self._jnp
+        jnp = self._jnp
         G = self.config.spec_tokens
         park = self.config.max_seq - G  # scratch rows for idle slots
         tokens, pos, temp, topk, _lora = self._gather_batch(
             active, pos_fill=park)
-        tokens_j = jnp.asarray(tokens)
-        pos_j = jnp.asarray(pos)
-
-        # draft proposals d_1..d_{G-1}: one fused dispatch
-        drafts_dev, self.draft_cache_k, self.draft_cache_v = \
-            self._draft_propose(self.draft_params, self.draft_cache_k,
-                                self.draft_cache_v, tokens_j, pos_j)
-
-        # one target forward scores the whole chunk
-        chunk = jnp.concatenate(
-            [tokens_j[:, None], drafts_dev.T], axis=1)       # [B, G]
+        tokens_j, pos_j, temp_j, topk_j = self._upload(
+            tokens, pos, temp, topk)
         self._step_counter += 1  # graftlint: disable=GL001  # stepper-thread-only
-        greedy, first_sampled, self.cache_k, self.cache_v = \
-            self._verify(self.params, self.cache_k, self.cache_v,
-                         chunk, pos_j, jnp.asarray(temp),
-                         jnp.asarray(topk), self._base_key,
-                         self._step_counter, self._bias)
-        greedy = np.asarray(greedy)                          # [B, G]
-        first_sampled = np.asarray(first_sampled)            # [B]
-        drafts_np = np.asarray(drafts_dev).T                 # [B, G-1]
+        with self._span("engine.launch"):
+            # draft proposals d_1..d_{G-1}: one fused dispatch
+            drafts_dev, self.draft_cache_k, self.draft_cache_v = \
+                self._draft_propose(self.draft_params, self.draft_cache_k,
+                                    self.draft_cache_v, tokens_j, pos_j)
+            # one target forward scores the whole chunk
+            chunk = jnp.concatenate(
+                [tokens_j[:, None], drafts_dev.T], axis=1)   # [B, G]
+            greedy, first_sampled, self.cache_k, self.cache_v = \
+                self._verify(self.params, self.cache_k, self.cache_v,
+                             chunk, pos_j, temp_j, topk_j,
+                             self._base_key, self._step_counter,
+                             self._bias)
+        del tokens_j, pos_j, temp_j, topk_j, chunk       # see _upload
+        # greedy [B, G], first_sampled [B], drafts [G-1, B]
+        greedy, first_sampled, drafts_np = self._readback(
+            greedy, first_sampled, drafts_dev)
+        drafts_np = drafts_np.T                              # [B, G-1]
 
-        for slot in active:
-            b = slot.index
-            if slot.request.temperature > 0.0:
-                # sampled request: one properly-sampled token from the
-                # target's first-position logits (no speculation)
-                emitted = [int(first_sampled[b])]
-            else:
-                m = 0  # accepted draft tokens
-                while m < G - 1 and drafts_np[b, m] == greedy[b, m]:
-                    m += 1
-                emitted = [int(greedy[b, i]) for i in range(m + 1)]
-            for token in emitted:
-                slot.pos += 1
-                slot.next_token = token
-                self._emit(slot, token)
-                if slot.request is None:  # finished mid-chunk
-                    break
+        with self._span("engine.emit"):
+            for slot in active:
+                b = slot.index
+                if slot.request.temperature > 0.0:
+                    # sampled request: one properly-sampled token from
+                    # the target's first-position logits (no
+                    # speculation)
+                    emitted = [int(first_sampled[b])]
+                else:
+                    m = 0  # accepted draft tokens
+                    while m < G - 1 and drafts_np[b, m] == greedy[b, m]:
+                        m += 1
+                    emitted = [int(greedy[b, i]) for i in range(m + 1)]
+                for token in emitted:
+                    slot.pos += 1
+                    slot.next_token = token
+                    self._emit(slot, token)
+                    if slot.request is None:  # finished mid-chunk
+                        break
         return len(active)
 
     def _multi_step(self, active, K: int) -> int:
         """K fused decode iterations in one dispatch; per-slot tokens
         past a stop/max_tokens finish are discarded host-side, so
         outputs match single-step decoding exactly."""
-        jnp = self._jnp
         tokens, pos, temp, topk, lora_idx = self._gather_batch(
             active, pos_fill=self.config.max_seq - K)
         self._step_counter += 1  # graftlint: disable=GL001  # stepper-thread-only
-        toks, self.cache_k, self.cache_v = self._decode_multi(
-            self.params, self.cache_k, self.cache_v,
-            jnp.asarray(tokens), jnp.asarray(pos),
-            jnp.asarray(temp), jnp.asarray(topk),
-            self._base_key, self._step_counter,
-            self.lora_bank, jnp.asarray(lora_idx), self._bias)
-        toks = np.asarray(toks)                          # [K, B]
-        for slot in active:
-            for k in range(K):
-                slot.pos += 1
-                slot.next_token = int(toks[k, slot.index])
-                self._emit(slot, slot.next_token)
-                if slot.request is None:  # finished mid-chunk:
-                    break                 # later tokens are discarded
+        tokens_j, pos_j, temp_j, topk_j, lora_j = self._upload(
+            tokens, pos, temp, topk, lora_idx)
+        with self._span("engine.launch"):
+            toks, self.cache_k, self.cache_v = self._decode_multi(
+                self.params, self.cache_k, self.cache_v,
+                tokens_j, pos_j, temp_j, topk_j,
+                self._base_key, self._step_counter,
+                self.lora_bank, lora_j, self._bias)
+        del tokens_j, pos_j, temp_j, topk_j, lora_j      # see _upload
+        (toks,) = self._readback(toks)                   # [K, B]
+        with self._span("engine.emit"):
+            for slot in active:
+                for k in range(K):
+                    slot.pos += 1
+                    slot.next_token = int(toks[k, slot.index])
+                    self._emit(slot, slot.next_token)
+                    if slot.request is None:  # finished mid-chunk:
+                        break             # later tokens are discarded
         return len(active)
 
     def _prefill_chunk_step(self, prefilling, decoding) -> None:
@@ -1331,7 +1435,6 @@ class ContinuousBatchingEngine:
         separate chunk + decode dispatches doubled the inter-token gap
         on dispatch-bound links, making chunked prefill slower than
         the blocking admission it replaces."""
-        jnp = self._jnp
         C = self.config.chunked_prefill_tokens
         n = self.config.max_batch
         park = self.config.max_seq - C  # scratch rows for idle slots
@@ -1351,39 +1454,45 @@ class ContinuousBatchingEngine:
             pos[slot.index] = p
             last_idx[slot.index] = len(part) - 1
         self._step_counter += 1  # graftlint: disable=GL001  # stepper-thread-only
-        tok, self.cache_k, self.cache_v = self._chunk_prefill(
-            self.params, self.cache_k, self.cache_v,
-            jnp.asarray(chunk), jnp.asarray(pos),
-            jnp.asarray(last_idx), jnp.asarray(temp),
-            jnp.asarray(topk), self._base_key, self._step_counter,
-            self._bias)
-        tok = np.asarray(tok)
-        for slot in prefilling:
-            remaining = len(slot.prefill_ids) - slot.prefill_pos
-            slot.prefill_pos += min(C, remaining)
-            if slot.prefill_pos >= len(slot.prefill_ids):
-                slot.prefilling = False
-                slot.pos = len(slot.prefill_ids)
-                slot.prefill_ids = None
+        chunk_j, pos_j, last_j, temp_j, topk_j = self._upload(
+            chunk, pos, last_idx, temp, topk)
+        with self._span("engine.launch"):
+            tok, self.cache_k, self.cache_v = self._chunk_prefill(
+                self.params, self.cache_k, self.cache_v,
+                chunk_j, pos_j, last_j, temp_j, topk_j,
+                self._base_key, self._step_counter, self._bias)
+        del chunk_j, pos_j, last_j, temp_j, topk_j       # see _upload
+        (tok,) = self._readback(tok)
+        with self._span("engine.emit"):
+            for slot in prefilling:
+                remaining = len(slot.prefill_ids) - slot.prefill_pos
+                slot.prefill_pos += min(C, remaining)
+                if slot.prefill_pos >= len(slot.prefill_ids):
+                    slot.prefilling = False
+                    slot.pos = len(slot.prefill_ids)
+                    slot.prefill_ids = None
+                    slot.next_token = int(tok[slot.index])
+                    self._emit(slot, slot.next_token)
+            for slot in decoding:
+                slot.pos += 1
                 slot.next_token = int(tok[slot.index])
                 self._emit(slot, slot.next_token)
-        for slot in decoding:
-            slot.pos += 1
-            slot.next_token = int(tok[slot.index])
-            self._emit(slot, slot.next_token)
 
     def step(self) -> int:
         """Admit + one whole-batch decode step (sampling fused on
         device — only [B] token ids come back). Returns #active slots.
 
-        Instrumented wrapper: step wall time (phase-tagged prefill vs
-        decode), tokens/sec, and batch occupancy accumulate in the
-        local buffer and flush as one batched metrics update."""
+        Instrumented wrapper: step wall time and host time (phase-
+        tagged prefill vs decode) and tokens accumulate in the local
+        buffer, which its own thread flushes: nothing here reaches the
+        control plane."""
         t0 = time.perf_counter()
         rec = _flight.RECORDER
         t0_ns = rec.clock() if rec is not None else 0
         tokens_before = self.total_generated
         self._admitted_last_step = 0
+        self._blocked_s = 0.0
+        self._steps += 1  # graftlint: disable=GL001  # stepper-thread-only
         handled = self._step_impl()
         dt = time.perf_counter() - t0
         emitted = self.total_generated - tokens_before
@@ -1394,15 +1503,27 @@ class ContinuousBatchingEngine:
         if rec is not None and handled:
             rec.record("serve", "engine_step", t0_ns,
                        rec.clock() - t0_ns,
-                       {"phase": phase, "slots": handled,
-                        "tokens": emitted})
-        self._mbuf.note_step(phase, dt, emitted, handled)
-        self._mbuf.maybe_flush(self)
+                       {"step": self._steps, "phase": phase,
+                        "slots": handled, "tokens": emitted})
+        self._mbuf.note_step(phase, dt, max(0.0, dt - self._blocked_s),
+                             emitted)
         return handled
 
+    def record_stage(self, stage: str, seconds: float) -> None:
+        """One stage of one request: the engine's own "queue" and
+        "prefill", and what the serving layer saw pass before
+        add_request ("dispatch", "prepare"), all into one buffer."""
+        self._mbuf.observe(ENGINE_STAGE_SECONDS, max(0.0, seconds),
+                           {"stage": stage})
+
     def flush_metrics(self) -> None:
-        """Force the buffered step metrics out (tests / shutdown)."""
-        self._mbuf.maybe_flush(self, force=True)
+        """Send the buffered metrics now, on the caller's thread."""
+        self._mbuf.flush(force=True)
+
+    def close(self) -> None:
+        """Flush once more and end the buffer's flush thread (a
+        collected engine ends it too)."""
+        self._mbuf.close()
 
     def _step_impl(self) -> int:
         self._admit()
@@ -1466,42 +1587,46 @@ class ContinuousBatchingEngine:
             # tokens, which a fused K-step scan cannot do — dense
             # fallback while any such request is active
             return self._multi_step(active, K) + handled
-        jnp = self._jnp
         tokens, pos, temp, topk, lora_idx = self._gather_batch(
             active, pos_fill=self._dense_park)
         self._step_counter += 1  # graftlint: disable=GL001  # stepper-thread-only
         want_lp = any(s.request.logprobs is not None for s in active)
-        sampled, chosen_lp, top_vals, top_ids, self.cache_k, \
-            self.cache_v = self._call_program(
-                "decode_lp" if want_lp else "decode", self._decode,
-                self.params, self.cache_k, self.cache_v,
-                jnp.asarray(tokens), jnp.asarray(pos),
-                jnp.asarray(temp), jnp.asarray(topk),
-                self._base_key, self._step_counter,
-                self.lora_bank, jnp.asarray(lora_idx), self._bias,
-                want_lp=want_lp)
-        if self._spec:
-            # keep the draft cache in lockstep through dense rounds,
-            # or the next _spec_step would condition on KV gaps
-            self.draft_cache_k, self.draft_cache_v = self._draft_sync(
-                self.draft_params, self.draft_cache_k,
-                self.draft_cache_v, jnp.asarray(tokens),
-                jnp.asarray(pos))
-        sampled = np.asarray(sampled)
+        tokens_j, pos_j, temp_j, topk_j, lora_j = self._upload(
+            tokens, pos, temp, topk, lora_idx)
+        with self._span("engine.launch"):
+            sampled, chosen_lp, top_vals, top_ids, self.cache_k, \
+                self.cache_v = self._call_program(
+                    "decode_lp" if want_lp else "decode", self._decode,
+                    self.params, self.cache_k, self.cache_v,
+                    tokens_j, pos_j, temp_j, topk_j,
+                    self._base_key, self._step_counter,
+                    self.lora_bank, lora_j, self._bias,
+                    want_lp=want_lp)
+            if self._spec:
+                # keep the draft cache in lockstep through dense
+                # rounds, or the next _spec_step would condition on KV
+                # gaps
+                self.draft_cache_k, self.draft_cache_v = \
+                    self._draft_sync(
+                        self.draft_params, self.draft_cache_k,
+                        self.draft_cache_v, tokens_j, pos_j)
+        # dropped while the device runs (see _upload)
+        del tokens_j, pos_j, temp_j, topk_j, lora_j
+        (sampled,) = self._readback(sampled)
         if want_lp:
             # only logprob requests pay the extra device-to-host syncs
-            chosen_lp = np.asarray(chosen_lp)
-            top_vals = np.asarray(top_vals)
-            top_ids = np.asarray(top_ids)
+            chosen_lp, top_vals, top_ids = self._readback(
+                chosen_lp, top_vals, top_ids)
             for slot in active:
                 if slot.request.logprobs is not None:
                     slot.pending_lp = (chosen_lp[slot.index],
                                        top_vals[slot.index],
                                        top_ids[slot.index])
-        for slot in active:
-            slot.pos += 1
-            slot.next_token = int(sampled[slot.index])
-            self._emit(slot, slot.next_token)
+        with self._span("engine.emit"):
+            for slot in active:
+                slot.pos += 1
+                slot.next_token = int(sampled[slot.index])
+                self._emit(slot, slot.next_token)
         return len(active) + handled
 
     # ------------------------------------------------------------------
@@ -1610,7 +1735,7 @@ class ContinuousBatchingEngine:
             jnp.asarray(len(ids), jnp.int32)))
 
     def stats(self) -> Dict[str, Any]:
-        self._mbuf.maybe_flush(self, force=True)
+        self._mbuf.flush(force=True)
         # lower each hot program seen since the last call once more to
         # read its kernels (outside the lock: tracing takes seconds at
         # full width)

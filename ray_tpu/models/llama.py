@@ -193,6 +193,15 @@ def llama_init(rng, config: LlamaConfig) -> Dict[str, Any]:
     return params
 
 
+# jax.named_scope names on the bodies that prefill, decode and the
+# loss share, so that a trace viewer groups device ops by them
+# (metadata only: the programs are the same)
+SCOPE_ATTENTION = "attention"   # with its cache slice and update
+SCOPE_FFN = "ffn"
+SCOPE_HEAD = "head"             # final norm and output projection
+SCOPE_LOSS = "cross_entropy"
+
+
 def _attention(q, k, v, config: LlamaConfig, mesh):
     """Dispatch to the configured attention implementation."""
     n_rep = config.n_heads // config.n_kv_heads
@@ -280,24 +289,27 @@ def _block(layer_params, x, cos, sin, config: LlamaConfig, mesh,
     c = config
     b, s, _ = x.shape
     hd = c.head_dim
-    h = rms_norm(x, layer_params["attn_norm"], c.norm_eps, mesh)
-    q = h @ layer_params["wq"]
-    k = h @ layer_params["wk"]
-    v = h @ layer_params["wv"]
-    if lora is not None:
-        a_q, b_q, a_v, b_v, scale = lora
-        q = q + scale * _lora_delta(h, a_q, b_q)
-        v = v + scale * _lora_delta(h, a_v, b_v)
-    q = q.reshape(b, s, c.n_heads, hd)
-    k = k.reshape(b, s, c.n_kv_heads, hd)
-    v = v.reshape(b, s, c.n_kv_heads, hd)
-    q = apply_rope(q, cos, sin)
-    k = apply_rope(k, cos, sin)
-    attn = _attention(q, k, v, c, mesh)
-    x = x + attn.reshape(b, s, c.n_heads * hd) @ layer_params["wo"]
-    h = rms_norm(x, layer_params["mlp_norm"], c.norm_eps, mesh)
-    y, aux = _ffn(layer_params, h, c)
-    return x + y, (k, v), aux
+    with jax.named_scope(SCOPE_ATTENTION):
+        h = rms_norm(x, layer_params["attn_norm"], c.norm_eps, mesh)
+        q = h @ layer_params["wq"]
+        k = h @ layer_params["wk"]
+        v = h @ layer_params["wv"]
+        if lora is not None:
+            a_q, b_q, a_v, b_v, scale = lora
+            q = q + scale * _lora_delta(h, a_q, b_q)
+            v = v + scale * _lora_delta(h, a_v, b_v)
+        q = q.reshape(b, s, c.n_heads, hd)
+        k = k.reshape(b, s, c.n_kv_heads, hd)
+        v = v.reshape(b, s, c.n_kv_heads, hd)
+        q = apply_rope(q, cos, sin)
+        k = apply_rope(k, cos, sin)
+        attn = _attention(q, k, v, c, mesh)
+        x = x + attn.reshape(b, s, c.n_heads * hd) @ layer_params["wo"]
+    with jax.named_scope(SCOPE_FFN):
+        h = rms_norm(x, layer_params["mlp_norm"], c.norm_eps, mesh)
+        y, aux = _ffn(layer_params, h, c)
+        x = x + y
+    return x, (k, v), aux
 
 
 def llama_forward(params, tokens, config: LlamaConfig, mesh=None,
@@ -323,10 +335,11 @@ def llama_forward(params, tokens, config: LlamaConfig, mesh=None,
 
     (x, aux_sum), _ = jax.lax.scan(
         scan_body, (x, jnp.zeros((), jnp.float32)), params["layers"])
-    x = rms_norm(x, params["final_norm"], c.norm_eps, mesh)
-    if return_hidden:
-        return (x, aux_sum) if return_aux else x
-    logits = (x @ params["lm_head"]).astype(jnp.float32)
+    with jax.named_scope(SCOPE_HEAD):
+        x = rms_norm(x, params["final_norm"], c.norm_eps, mesh)
+        if return_hidden:
+            return (x, aux_sum) if return_aux else x
+        logits = (x @ params["lm_head"]).astype(jnp.float32)
     if return_aux:
         return logits, aux_sum
     return logits
@@ -367,12 +380,13 @@ def chunked_cross_entropy(hidden, lm_head, targets, mask=None, *,
         tgt = jnp.take_along_axis(logits, t_c[:, None], axis=-1)[:, 0]
         return carry + jnp.sum((lse - tgt) * m_c), None
 
-    total, _ = jax.lax.scan(
-        jax.checkpoint(body), jnp.zeros((), jnp.float32),
-        (flat_h.reshape(n_chunks, chunk, dim),
-         flat_t.reshape(n_chunks, chunk),
-         flat_m.reshape(n_chunks, chunk)))
-    return total / jnp.maximum(jnp.sum(flat_m), 1.0)
+    with jax.named_scope(SCOPE_LOSS):
+        total, _ = jax.lax.scan(
+            jax.checkpoint(body), jnp.zeros((), jnp.float32),
+            (flat_h.reshape(n_chunks, chunk, dim),
+             flat_t.reshape(n_chunks, chunk),
+             flat_m.reshape(n_chunks, chunk)))
+        return total / jnp.maximum(jnp.sum(flat_m), 1.0)
 
 
 def llama_loss(params, tokens, targets, config: LlamaConfig, mesh=None,
@@ -389,12 +403,15 @@ def llama_loss(params, tokens, targets, config: LlamaConfig, mesh=None,
     else:
         logits, aux = llama_forward(params, tokens, config, mesh,
                                     return_aux=True)
-        logp = jax.nn.log_softmax(logits, axis=-1)
-        ll = jnp.take_along_axis(logp, targets[..., None], axis=-1)[..., 0]
-        if mask is None:
-            loss = -jnp.mean(ll)
-        else:
-            loss = -jnp.sum(ll * mask) / jnp.maximum(jnp.sum(mask), 1.0)
+        with jax.named_scope(SCOPE_LOSS):
+            logp = jax.nn.log_softmax(logits, axis=-1)
+            ll = jnp.take_along_axis(logp, targets[..., None],
+                                     axis=-1)[..., 0]
+            if mask is None:
+                loss = -jnp.mean(ll)
+            else:
+                loss = (-jnp.sum(ll * mask)
+                        / jnp.maximum(jnp.sum(mask), 1.0))
     if config.moe_experts:
         loss = loss + config.moe_aux_weight * aux / config.n_layers
     return loss
@@ -540,8 +557,9 @@ def llama_prefill(params, tokens, config: LlamaConfig, lora=None):
         x, (ks, vs) = jax.lax.scan(
             body, x, (params["layers"], lora["A_q"], lora["B_q"],
                       lora["A_v"], lora["B_v"]))
-    x = rms_norm(x, params["final_norm"], c.norm_eps)
-    logits = (x @ params["lm_head"]).astype(jnp.float32)
+    with jax.named_scope(SCOPE_HEAD):
+        x = rms_norm(x, params["final_norm"], c.norm_eps)
+        logits = (x @ params["lm_head"]).astype(jnp.float32)
     return logits, ks, vs
 
 
@@ -577,33 +595,38 @@ def llama_decode_step(params, token, cache_k, cache_v, pos,
             layer_params, ck, cv, a_q, b_q, a_v, b_v = layer
         else:
             layer_params, ck, cv = layer                        # ck [B,S,KVH,HD]
-        h = rms_norm(x, layer_params["attn_norm"], c.norm_eps)
-        q = (h @ layer_params["wq"]).reshape(b, 1, c.n_heads, hd)
-        k = (h @ layer_params["wk"]).reshape(b, 1, kvh, hd)
-        v = (h @ layer_params["wv"]).reshape(b, 1, kvh, hd)
-        if lora_bank is not None:
-            dq = _lora_delta(h, a_q[lora_idx], b_q[lora_idx])
-            dv = _lora_delta(h, a_v[lora_idx], b_v[lora_idx])
-            q = q + (lora_scale * dq).reshape(b, 1, c.n_heads, hd)
-            v = v + (lora_scale * dv).reshape(b, 1, kvh, hd)
-        q = apply_rope(q, cos, sin, positions=pos_2d)
-        k = apply_rope(k, cos, sin, positions=pos_2d)
-        write = jax.vmap(
-            lambda cache, new, p: jax.lax.dynamic_update_slice(
-                cache, new, (p, 0, 0)))
-        ck = write(ck, k, pos)
-        cv = write(cv, v, pos)
-        kk = jnp.repeat(ck, n_rep, axis=2) if n_rep > 1 else ck
-        vv = jnp.repeat(cv, n_rep, axis=2) if n_rep > 1 else cv
-        scores = jnp.einsum("bqhd,bshd->bhqs", q, kk).astype(jnp.float32)
-        scores = scores * (hd ** -0.5)
-        scores = jnp.where(visible[:, None, None, :], scores, -1e30)
-        weights = jax.nn.softmax(scores, axis=-1).astype(c.dtype)
-        attn = jnp.einsum("bhqs,bshd->bqhd", weights, vv)
-        x = x + attn.reshape(b, 1, c.n_heads * hd) @ layer_params["wo"]
-        h = rms_norm(x, layer_params["mlp_norm"], c.norm_eps)
-        y, _aux = _ffn(layer_params, h, c)  # MoE-aware (decode too)
-        return x + y, (ck, cv)
+        with jax.named_scope(SCOPE_ATTENTION):
+            h = rms_norm(x, layer_params["attn_norm"], c.norm_eps)
+            q = (h @ layer_params["wq"]).reshape(b, 1, c.n_heads, hd)
+            k = (h @ layer_params["wk"]).reshape(b, 1, kvh, hd)
+            v = (h @ layer_params["wv"]).reshape(b, 1, kvh, hd)
+            if lora_bank is not None:
+                dq = _lora_delta(h, a_q[lora_idx], b_q[lora_idx])
+                dv = _lora_delta(h, a_v[lora_idx], b_v[lora_idx])
+                q = q + (lora_scale * dq).reshape(b, 1, c.n_heads, hd)
+                v = v + (lora_scale * dv).reshape(b, 1, kvh, hd)
+            q = apply_rope(q, cos, sin, positions=pos_2d)
+            k = apply_rope(k, cos, sin, positions=pos_2d)
+            write = jax.vmap(
+                lambda cache, new, p: jax.lax.dynamic_update_slice(
+                    cache, new, (p, 0, 0)))
+            ck = write(ck, k, pos)
+            cv = write(cv, v, pos)
+            kk = jnp.repeat(ck, n_rep, axis=2) if n_rep > 1 else ck
+            vv = jnp.repeat(cv, n_rep, axis=2) if n_rep > 1 else cv
+            scores = jnp.einsum("bqhd,bshd->bhqs", q,
+                                kk).astype(jnp.float32)
+            scores = scores * (hd ** -0.5)
+            scores = jnp.where(visible[:, None, None, :], scores, -1e30)
+            weights = jax.nn.softmax(scores, axis=-1).astype(c.dtype)
+            attn = jnp.einsum("bhqs,bshd->bqhd", weights, vv)
+            x = x + (attn.reshape(b, 1, c.n_heads * hd)
+                     @ layer_params["wo"])
+        with jax.named_scope(SCOPE_FFN):
+            h = rms_norm(x, layer_params["mlp_norm"], c.norm_eps)
+            y, _aux = _ffn(layer_params, h, c)  # MoE-aware (decode too)
+            x = x + y
+        return x, (ck, cv)
 
     if lora_bank is not None:
         xs = (params["layers"], cache_k, cache_v,
@@ -611,8 +634,9 @@ def llama_decode_step(params, token, cache_k, cache_v, pos,
     else:
         xs = (params["layers"], cache_k, cache_v)
     x, (new_k, new_v) = jax.lax.scan(body, x, xs)
-    x = rms_norm(x, params["final_norm"], c.norm_eps)
-    logits = (x[:, 0] @ params["lm_head"]).astype(jnp.float32)
+    with jax.named_scope(SCOPE_HEAD):
+        x = rms_norm(x, params["final_norm"], c.norm_eps)
+        logits = (x[:, 0] @ params["lm_head"]).astype(jnp.float32)
     return logits, new_k, new_v
 
 

@@ -13,6 +13,7 @@ the continuous-batching path the reference gets from vLLM.
 
 from __future__ import annotations
 
+import inspect
 import json
 import re
 import threading
@@ -29,6 +30,7 @@ from ray_tpu.llm.guided import (
     json_object_constraint, json_schema_constraint, parse_tool_call,
     tool_call_constraint)
 from ray_tpu.llm.tokenizer import get_tokenizer
+from ray_tpu.serve.proxy import RECEIVED_KEY
 
 
 # cap on per-replica compiled guided-decoding constraints (LRU)
@@ -150,6 +152,10 @@ class LLMServer:
         self._token_strs: Optional[List[Optional[str]]] = None
         self._wake = threading.Event()
         self._stopped = False
+        # per request thread, from __call__ until _make_request hands
+        # the request to the engine: (seconds from the proxy's receipt
+        # to __call__, time.perf_counter() at __call__)
+        self._entered = threading.local()
         self._stepper = threading.Thread(target=self._step_loop,
                                          daemon=True)
         self._stepper.start()
@@ -160,6 +166,7 @@ class LLMServer:
         self._stopped = True
         self._wake.set()
         self.engine.fail_all("model evicted from replica")
+        self.engine.close()
 
     def _step_loop(self) -> None:
         while not self._stopped:
@@ -567,6 +574,12 @@ class LLMServer:
             stop_ids=(self.tokenizer.eos_id,)
             if self.tokenizer.eos_id is not None else (),
             stream_queue=stream_queue)
+        entered = self._take_entered()
+        if entered is not None:
+            dispatch_s, at = entered
+            self.engine.record_stage("dispatch", dispatch_s)
+            self.engine.record_stage("prepare",
+                                     time.perf_counter() - at)
         try:
             self.engine.add_request(request)
         except EngineSaturatedError as exc:
@@ -844,6 +857,34 @@ class LLMServer:
 
     # -- OpenAI-compatible surface (routed by path) --------------------
     def __call__(self, request: Dict[str, Any]) -> Dict[str, Any]:
+        """The proxy's entry. For a request it stamped (RECEIVED_KEY,
+        wall clock of the same machine) the time until here is the
+        request's "dispatch" stage: router, task submission and the
+        wait for a replica thread. What follows until add_request is
+        "prepare". Both are recorded if the request reaches the
+        engine."""
+        received = request.pop(RECEIVED_KEY, None)
+        if isinstance(received, float):
+            self._entered.at = (time.time() - received,
+                                time.perf_counter())
+        result = None
+        try:
+            result = self._route(request)
+            return result
+        finally:
+            # a streamed answer's generator reaches _make_request only
+            # when this thread iterates it, after this return
+            if not inspect.isgenerator(result):
+                self._take_entered()
+
+    def _take_entered(self):
+        """This thread's (dispatch seconds, entry time) note from
+        __call__, once; None for a request that came another way."""
+        entered = getattr(self._entered, "at", None)
+        self._entered.at = None
+        return entered
+
+    def _route(self, request: Dict[str, Any]) -> Dict[str, Any]:
         path = request.get("__path__", "")
         if path.endswith("/chat/completions"):
             return self.chat_completions(request)

@@ -24,6 +24,12 @@ from ray_tpu.serve.admission import BackpressureError
 from ray_tpu.util import tracing
 from ray_tpu.util.metrics import Counter, Histogram
 
+# Reserved request key, beside "__path__": the proxy's wall clock
+# (time.time()) at its receipt of a sub-path request. A handler on the
+# same machine can subtract it from its own clock to see how long
+# router, task submission and the wait for a replica thread took.
+RECEIVED_KEY = "__received_at__"
+
 PROXY_REQUESTS = Counter(
     "ray_tpu_serve_proxy_requests_total",
     "HTTP requests through the serve proxy, by deployment and outcome",
@@ -106,6 +112,7 @@ def _make_handler(state: _ProxyState):
 
         def _handle_traced(self, body: Optional[dict]) -> None:
             import time as _time
+            received_at = _time.time()
             t0 = _time.perf_counter()
             parsed = urllib.parse.urlparse(self.path)
             match = state.match(parsed.path)
@@ -123,13 +130,16 @@ def _make_handler(state: _ProxyState):
             if body:
                 request.update(body)
             # Sub-path routing (e.g. the OpenAI /v1/* surface): expose
-            # the remainder under the reserved "__path__" key. Always
-            # strip any client-supplied value first — routing metadata
-            # must come from the proxy, never the payload. Root requests
+            # the remainder under the reserved "__path__" key and the
+            # time of receipt under RECEIVED_KEY. Always strip any
+            # client-supplied values first — routing metadata must
+            # come from the proxy, never the payload. Root requests
             # keep a pristine payload.
             request.pop("__path__", None)
+            request.pop(RECEIVED_KEY, None)
             if rest != "/":
                 request["__path__"] = rest
+                request[RECEIVED_KEY] = received_at
             streaming_started = False
             try:
                 # Streaming-first protocol: the replica's header item

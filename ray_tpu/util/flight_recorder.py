@@ -47,8 +47,9 @@ dispatch / stream chunks), ``object`` (put/get/transfer), ``pipeline``
 (stage instructions, tagged phase=warmup/steady/drain), ``shuffle``
 (map/reduce waves), ``prefetch`` (producer/consumer waits),
 ``collective`` (allreduce &co with compression ratio), ``serve``
-(engine prefill/decode steps), ``rl`` (podracer spans: rollout /
-infer_batch / replay_wait / learn_step / weight_push).
+(engine steps, their phases as ``span``s, request queue waits), ``rl``
+(podracer spans: rollout / infer_batch / replay_wait / learn_step /
+weight_push).
 """
 
 from __future__ import annotations
@@ -192,6 +193,50 @@ def phase_end(cat: str, name: str, t0: Optional[int],
     rec = RECORDER
     if rec is not None and t0 is not None:
         rec.record(cat, name, t0, clock_ns() - t0, args)
+
+
+_trace_annotation: Any = None      # jax.profiler.TraceAnnotation, or False
+
+
+class span:
+    """``with span(cat, name, **args):`` puts one interval on both
+    clocks an engineer reads: the ``jax.profiler`` trace (as a
+    ``TraceAnnotation`` on the calling thread's host line, so a device
+    trace's idle gaps can be named by it) and, when the recorder is
+    on, this journal under ``cat`` with ``args``. With neither a
+    profiler session nor a recorder it costs the annotation's
+    construction. jax is looked up on first use; without it the span
+    is journal-only."""
+
+    __slots__ = ("_cat", "_name", "_args", "_annotation", "_t0")
+
+    def __init__(self, cat: str, name: str, **args):
+        global _trace_annotation
+        if _trace_annotation is None:
+            try:
+                from jax.profiler import TraceAnnotation
+                _trace_annotation = TraceAnnotation
+            except ImportError:
+                _trace_annotation = False
+        self._cat, self._name, self._args = cat, name, args
+        self._annotation = (_trace_annotation(name, **args)
+                            if _trace_annotation else None)
+        self._t0: Optional[int] = None
+
+    def __enter__(self) -> "span":
+        rec = RECORDER
+        self._t0 = rec.clock() if rec is not None else None
+        if self._annotation is not None:
+            self._annotation.__enter__()
+        return self
+
+    def __exit__(self, *exc_info) -> None:
+        if self._annotation is not None:
+            self._annotation.__exit__(*exc_info)
+        rec = RECORDER
+        if rec is not None and self._t0 is not None:
+            rec.record(self._cat, self._name, self._t0,
+                       rec.clock() - self._t0, self._args or None)
 
 
 # --- wall-clock anchoring -----------------------------------------------

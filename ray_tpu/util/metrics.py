@@ -27,8 +27,8 @@ class _Registry:
     """Process-global metric state (driver holds the authoritative
     copy; workers forward updates to it)."""
 
-    def __init__(self):
-        self.lock = locktrace.traced_lock("util.metrics")
+    def __init__(self, lock_name: str = "util.metrics"):
+        self.lock = locktrace.traced_lock(lock_name)
         # (name, tag_items) -> value
         self.counters: Dict[Tuple, float] = {}
         self.gauges: Dict[Tuple, float] = {}
@@ -59,6 +59,25 @@ class _Registry:
             buckets[bisect.bisect_left(bounds, value)] += 1
             entry[2] += value
             entry[3] += 1
+        elif kind == "histogram_counts":
+            # a histogram bucketed where it was observed (LocalBuffer):
+            # value = (bucket counts, sum, count) over ``boundaries``
+            counts, total, count = value
+            bounds = list(boundaries)
+            entry = self.histograms.get(key)
+            if entry is None:
+                entry = [bounds, [0] * (len(bounds) + 1), 0.0, 0]
+                self.histograms[key] = entry
+            if entry[0] != bounds or len(counts) != len(bounds) + 1:
+                raise ValueError(
+                    f"histogram {name}: merged buckets {bounds} do not "
+                    f"match the series' {entry[0]}")
+            for i, n in enumerate(counts):
+                entry[1][i] += n
+            entry[2] += total
+            entry[3] += count
+        else:
+            raise ValueError(f"unknown metric kind {kind!r}")
 
     def apply_batch(self, items) -> None:
         """Apply many updates under ONE lock acquisition — the flush
@@ -68,6 +87,24 @@ class _Registry:
             for kind, name, tags, value, boundaries in items:
                 self._apply_locked(kind, name, tuple(tags), value,
                                    boundaries)
+
+    def drain(self) -> List[tuple]:
+        """Take everything recorded so far, as ``apply_batch`` items
+        (histograms pre-bucketed: ``histogram_counts``), and start
+        empty again."""
+        with self.lock:
+            counters, self.counters = self.counters, {}
+            gauges, self.gauges = self.gauges, {}
+            histograms, self.histograms = self.histograms, {}
+        items = [("counter", name, tags, value, None)
+                 for (name, tags), value in counters.items()]
+        items += [("gauge", name, tags, value, None)
+                  for (name, tags), value in gauges.items()]
+        items += [("histogram_counts", name, tags,
+                   (buckets, total, count), bounds)
+                  for (name, tags), (bounds, buckets, total, count)
+                  in histograms.items()]
+        return items
 
     def remove_series(self, name: str, tags: Tuple) -> None:
         """Drop one labeled series (a gauge whose subject — node,
@@ -120,12 +157,18 @@ def record_local(kind: str, name: str, tags: Dict[str, str], value: float,
 
 def record_batch(items) -> None:
     """Apply a batch of metric updates in one shot. ``items``: iterable
-    of ``(kind, name, tags_dict, value, boundaries)``. On a worker the
-    whole batch rides ONE control-plane RPC instead of one per update —
-    the flush path for hot loops that aggregate locally."""
+    of ``(kind, name, tags, value, boundaries)``, tags a dict or the
+    sorted item tuple the registry keys by. On a worker the whole batch
+    rides ONE control-plane RPC instead of one per update — the flush
+    path for hot loops that aggregate locally. The kind
+    ``histogram_counts`` merges a histogram bucketed by the sender
+    (``value`` = (bucket counts, sum, count) over ``boundaries``); the
+    registry ends as if each sample had been observed here."""
     normalized = [
-        (kind, name, tuple(sorted((tags or {}).items())), value,
-         list(boundaries) if boundaries else None)
+        (kind, name,
+         tags if isinstance(tags, tuple)
+         else tuple(sorted((tags or {}).items())),
+         value, list(boundaries) if boundaries else None)
         for kind, name, tags, value, boundaries in items]
     if not normalized:
         return
@@ -223,6 +266,42 @@ class Histogram(Metric):
         histograms never forget a slow start; control loops need the
         recent distribution)."""
         return histogram_snapshot(self._name, self._tags(tags))
+
+
+class LocalBuffer:
+    """Metric updates aggregated in this process, for a hot loop that
+    must not pay a lock shared with scrapers, let alone a worker->driver
+    round trip, per update. ``observe`` / ``inc`` / ``set`` touch only
+    the buffer; ``flush`` ships what gathered as ONE ``record_batch``
+    whose size does not depend on how many updates there were: each
+    histogram series travels as bucket counts, a sum and a count."""
+
+    def __init__(self):
+        self._pending = _Registry("util.metrics.local")
+
+    def _apply(self, kind: str, metric: Metric, value: float, tags,
+               boundaries=None) -> None:
+        self._pending.apply(kind, metric._name,
+                            tuple(sorted(metric._tags(tags).items())),
+                            value, boundaries)
+
+    def observe(self, hist: "Histogram", value: float,
+                tags: Optional[Dict[str, str]] = None) -> None:
+        self._apply("histogram", hist, value, tags, hist._boundaries)
+
+    def inc(self, counter: "Counter", value: float = 1.0,
+            tags: Optional[Dict[str, str]] = None) -> None:
+        self._apply("counter", counter, value, tags)
+
+    def set(self, gauge: "Gauge", value: float,
+            tags: Optional[Dict[str, str]] = None) -> None:
+        self._apply("gauge", gauge, value, tags)
+
+    def flush(self) -> List[tuple]:
+        """Ship and forget what gathered; returns the items sent."""
+        items = self._pending.drain()
+        record_batch(items)
+        return items
 
 
 def histogram_snapshot(name: str, tags: Optional[Dict[str, str]] = None

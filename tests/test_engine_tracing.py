@@ -1,0 +1,322 @@
+"""The served path's own clock: request stages and step host time as
+engine counters, step phases as spans on the profiler's clock and in
+the flight recorder, and no control-plane call on the stepper thread.
+"""
+
+import gc
+import json
+import threading
+import time
+import urllib.request
+
+import pytest
+
+import ray_tpu
+from ray_tpu import serve
+from ray_tpu.llm.engine import (
+    ContinuousBatchingEngine, EngineConfig, GenerationRequest)
+from ray_tpu.models.llama import LlamaConfig
+from ray_tpu.serve.proxy import RECEIVED_KEY
+from ray_tpu.util import flight_recorder
+from ray_tpu.util import metrics as metrics_mod
+from ray_tpu.util.metrics import prometheus_text, remove_series
+
+STAGES = "ray_tpu_engine_request_stage_seconds"
+TTFT = "ray_tpu_engine_ttft_seconds"
+STEP = "ray_tpu_engine_step_seconds"
+STEP_HOST = "ray_tpu_engine_step_host_seconds"
+
+
+def _engine_config(max_batch=2, **kw):
+    return EngineConfig(
+        model=LlamaConfig.tiny(vocab_size=258, max_seq_len=64,
+                               attention="reference", remat=False),
+        max_batch=max_batch, max_seq=64, **kw)
+
+
+def _hist(name, **tags):
+    """(sum, count) of one histogram series in this process's registry."""
+    snap = metrics_mod.histogram_snapshot(name, tags)
+    return (0.0, 0) if snap is None else (snap[2], snap[3])
+
+
+def _hist_lines(name, label, renamed):
+    """The exposition lines of the series carrying ``label``, with the
+    label rewritten so that two series can be compared line by line."""
+    return [line.replace(label, renamed)
+            for line in prometheus_text().splitlines()
+            if line.startswith(name) and label in line]
+
+
+# -- (a) nothing on the stepper thread reaches the control plane ---------
+
+def test_stepper_thread_makes_no_control_plane_call(monkeypatch):
+    from ray_tpu.core import worker as worker_mod
+    from ray_tpu.serve.llm import LLMConfig, LLMServer
+
+    callers = []   # (what, thread ident, thread name)
+
+    def noting(what, real):
+        def wrapper(*args, **kwargs):
+            thread = threading.current_thread()
+            callers.append((what, thread.ident, thread.name))
+            return real(*args, **kwargs)
+        return wrapper
+
+    monkeypatch.setattr(metrics_mod, "record_batch",
+                        noting("record_batch", metrics_mod.record_batch))
+    monkeypatch.setattr(metrics_mod, "_record",
+                        noting("_record", metrics_mod._record))
+    for method in ("request", "gcs_call"):
+        monkeypatch.setattr(
+            worker_mod.WorkerRuntime, method,
+            noting(method, getattr(worker_mod.WorkerRuntime, method)))
+
+    server = LLMServer(LLMConfig(model_id="tiny", engine=_engine_config()))
+    server.engine._mbuf.flush_interval_s = 0.05
+    try:
+        stepper = server._stepper.ident
+        steps_before = server.engine._steps
+        # six requests through two slots: some tens of steps, with
+        # admissions (first tokens) in between
+        outs = []
+        pool = [threading.Thread(target=lambda i=i: outs.append(server({
+            "__path__": "/v1/completions", "prompt": "hello %d" % i,
+            "max_tokens": 12, RECEIVED_KEY: time.time()})))
+            for i in range(6)]
+        for t in pool:
+            t.start()
+        for t in pool:
+            t.join(120)
+        assert len(outs) == 6 and all("choices" in o for o in outs)
+        assert server.engine._steps - steps_before >= 30
+        deadline = time.monotonic() + 10
+        while not any(name == "engine-metrics-flush"
+                      for _, _, name in callers):
+            assert time.monotonic() < deadline, callers
+            time.sleep(0.02)
+        server({"__path__": "/v1/stats"})   # flushes on this thread
+    finally:
+        server.stop()
+    assert callers
+    assert stepper not in {ident for _, ident, _ in callers}, callers
+    assert ("record_batch", threading.get_ident(),
+            threading.current_thread().name) in callers
+    # the flush thread ended with stop()
+    assert not server.engine._mbuf._thread.is_alive()
+    # all four stages of every request were recorded
+    text = prometheus_text()
+    for stage in ("dispatch", "prepare", "queue", "prefill"):
+        assert f'{STAGES}_count{{stage="{stage}"}}' in text
+
+
+def test_flush_thread_ends_with_its_engine():
+    engine = ContinuousBatchingEngine(_engine_config())
+    engine._mbuf.flush_interval_s = 0.05
+    engine.generate([[1, 2, 3]], max_tokens=2)
+    thread = engine._mbuf._thread
+    assert thread is not None and thread.is_alive()
+    del engine
+    gc.collect()
+    thread.join(5)
+    assert not thread.is_alive()
+
+
+# -- (b) a pre-bucketed histogram merges to what observe() gives ---------
+
+SAMPLES = [0.0, 0.125, 0.25, 0.25, 0.5, 0.75, 1.0, 1.0, 3.5, 64.0]
+BOUNDS = [0.25, 1.0, 4.0]
+
+
+def _observe_both_ways(series):
+    from ray_tpu.util import metrics
+    hist = metrics.Histogram(series, "merge test", boundaries=BOUNDS,
+                             tag_keys=("how",))
+    buf = metrics.LocalBuffer()
+    for v in SAMPLES[:6]:
+        hist.observe(v, {"how": "each"})
+        buf.observe(hist, v, {"how": "merged"})
+    buf.flush()
+    for v in SAMPLES[6:]:        # a second flush merges into the first
+        hist.observe(v, {"how": "each"})
+        buf.observe(hist, v, {"how": "merged"})
+    assert len(buf.flush()) == 1
+    assert buf.flush() == []
+    return True
+
+
+@pytest.mark.parametrize("where", ["driver", "worker"])
+def test_merged_histogram_equals_observed(ray_start_regular, where):
+    series = f"ray_tpu_test_merge_{where}_seconds"
+    if where == "driver":
+        _observe_both_ways(series)
+    else:
+        assert ray_tpu.get(
+            ray_tpu.remote(_observe_both_ways).remote(series))
+    try:
+        each = _hist_lines(series, 'how="each"', "how=X")
+        merged = _hist_lines(series, 'how="merged"', "how=X")
+        assert len(each) == len(BOUNDS) + 3
+        assert each == merged
+        assert f"{series}_count{{how=X}} {len(SAMPLES)}" in each
+        assert f"{series}_sum{{how=X}} {sum(SAMPLES)}" in each
+    finally:
+        for how in ("each", "merged"):
+            remove_series(series, {"how": how})
+
+
+def test_merged_histogram_needs_the_same_boundaries():
+    series = "ray_tpu_test_merge_bounds_seconds"
+    metrics_mod.record_batch([
+        ("histogram", series, {}, 0.5, [1.0, 2.0])])
+    try:
+        with pytest.raises(ValueError):
+            metrics_mod.record_batch([
+                ("histogram_counts", series, {}, ([1, 0], 0.5, 1),
+                 [1.0])])
+    finally:
+        remove_series(series, {})
+
+
+# -- (c) queue + prefill = engine TTFT; host time inside step time -------
+
+def test_request_stages_add_up_and_host_time_is_inside_step_time():
+    engine = ContinuousBatchingEngine(_engine_config())
+    engine.flush_metrics()
+    before = {key: _hist(*key[:1], **dict(key[1])) for key in (
+        (TTFT, ()), (STAGES, (("stage", "queue"),)),
+        (STAGES, (("stage", "prefill"),)),
+        (STEP, (("phase", "decode"),)), (STEP, (("phase", "prefill"),)),
+        (STEP_HOST, (("phase", "decode"),)),
+        (STEP_HOST, (("phase", "prefill"),)))}
+
+    def window(name, **tags):
+        total, count = _hist(name, **tags)
+        was = before[(name, tuple(tags.items()))]
+        return total - was[0], count - was[1]
+
+    # five requests through two slots: three of them wait in the queue
+    requests = [engine.add_request(GenerationRequest(
+        prompt_ids=[1, 2, 3, i], max_tokens=4)) for i in range(5)]
+    while any(not r.done for r in requests):
+        engine.step()
+    engine.flush_metrics()
+    for r in requests:
+        assert r.t_submit <= r.t_admit <= r.t_first_token
+        ttft = r.t_first_token - r.t_submit
+        assert abs((r.t_admit - r.t_submit)
+                   + (r.t_first_token - r.t_admit) - ttft) < 1e-3
+    assert max(r.t_admit - r.t_submit for r in requests) > \
+        min(r.t_first_token - r.t_admit for r in requests)
+    ttft_sum, ttft_n = window(TTFT)
+    queue_sum, queue_n = window(STAGES, stage="queue")
+    prefill_sum, prefill_n = window(STAGES, stage="prefill")
+    assert ttft_n == queue_n == prefill_n == len(requests)
+    assert abs(queue_sum + prefill_sum - ttft_sum) < 1e-3 * len(requests)
+    assert abs(ttft_sum - sum(r.t_first_token - r.t_submit
+                              for r in requests)) < 1e-6
+    steps = 0
+    for phase in ("decode", "prefill"):
+        step_sum, step_n = window(STEP, phase=phase)
+        host_sum, host_n = window(STEP_HOST, phase=phase)
+        assert host_n == step_n
+        assert 0.0 <= host_sum <= step_sum
+        steps += step_n
+    assert steps == engine._steps
+
+
+# -- (d) one step's spans in the flight recorder -------------------------
+
+@pytest.fixture
+def recorder():
+    was = flight_recorder.RECORDER
+    rec = flight_recorder.enable(label="test", capacity=4096)
+    yield rec
+    flight_recorder.RECORDER = was
+
+
+def test_step_spans_lie_inside_their_step_and_carry_its_number(recorder):
+    engine = ContinuousBatchingEngine(_engine_config())
+    requests = [engine.add_request(GenerationRequest(
+        prompt_ids=[1, 2, 3, i], max_tokens=3,
+        logit_bias={7: -100.0} if i == 0 else None)) for i in range(3)]
+    while any(not r.done for r in requests):
+        engine.step()
+    events = [ev for ev in recorder.snapshot() if ev[3] == "serve"]
+    steps = {ev[5]["step"]: ev for ev in events if ev[4] == "engine_step"}
+    assert sorted(steps) == list(range(1, engine._steps + 1))
+    children = [ev for ev in events if ev[4].startswith("engine.")]
+    names = {ev[4] for ev in children}
+    assert names == {"engine.prefill", "engine.bias", "engine.gather",
+                     "engine.upload", "engine.launch", "engine.readback",
+                     "engine.emit"}
+    for _seq, t0, dur, _cat, name, args in children:
+        _, p0, pdur, _, _, _ = steps[args["step"]]
+        assert p0 <= t0 and t0 + dur <= p0 + pdur, (name, args)
+    # a prefill span names the request it served, and the phases of
+    # that prefill lie inside it
+    prefills = [ev for ev in children if ev[4] == "engine.prefill"]
+    assert sorted(ev[5]["req"] for ev in prefills) == \
+        sorted(r.request_id for r in requests)
+    for ev in prefills:
+        assert ev[5]["prompt_len"] == 4 and ev[5]["bucket"] == 4
+    first = next(ev for ev in prefills
+                 if ev[5]["req"] == requests[0].request_id)
+    inside = [ev[4] for ev in children
+              if ev is not first and first[1] <= ev[1]
+              and ev[1] + ev[2] <= first[1] + first[2]]
+    assert inside.count("engine.bias") == 2     # the only biased request
+    assert {"engine.upload", "engine.launch", "engine.readback",
+            "engine.emit"} <= set(inside)
+    assert sum(ev[4] == "engine.bias" for ev in children) == 2
+    # the wait in the queue, per request, ends where its prefill starts
+    queued = {ev[5]["req"]: ev for ev in events
+              if ev[4] == "request_queue"}
+    assert sorted(queued) == sorted(r.request_id for r in requests)
+    for ev in prefills:
+        q = queued[ev[5]["req"]]
+        assert q[5]["step"] == ev[5]["step"]
+        assert abs(q[1] + q[2] - ev[1]) < 5e6   # ns
+
+
+def test_span_without_recorder_or_profiler_is_inert():
+    was = flight_recorder.RECORDER
+    flight_recorder.disable()
+    try:
+        with flight_recorder.span("serve", "engine.nothing", step=1):
+            pass
+    finally:
+        flight_recorder.RECORDER = was
+
+
+# -- (e) the proxy's stamp cannot come from the client -------------------
+
+def test_proxy_strips_a_client_supplied_stamp(ray_start_shared):
+    @serve.deployment
+    def echo(req):
+        return {"got": req}
+
+    try:
+        serve.start(proxy=True, http_options=serve.HTTPOptions(port=0))
+        port = serve._proxy.port
+        serve.run(echo.bind(), name="stamp_app", route_prefix="/stamp")
+
+        def post(path, payload):
+            req = urllib.request.Request(
+                f"http://127.0.0.1:{port}{path}",
+                data=json.dumps(payload).encode(),
+                headers={"Content-Type": "application/json"})
+            with urllib.request.urlopen(req, timeout=30) as resp:
+                return json.loads(resp.read())["got"]
+
+        t0 = time.time()
+        got = post("/stamp/v1/x", {"a": 1, RECEIVED_KEY: "1.0",
+                                   "__path__": "/evil"})
+        assert got["__path__"] == "/v1/x" and got["a"] == 1
+        assert isinstance(got[RECEIVED_KEY], float)
+        assert t0 <= got[RECEIVED_KEY] <= time.time()
+        # a root request keeps a pristine payload: no stamp, and none
+        # of the client's either
+        assert post("/stamp", {"a": 1, RECEIVED_KEY: 5.0}) == {"a": 1}
+    finally:
+        serve.shutdown()
