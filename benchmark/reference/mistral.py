@@ -68,10 +68,18 @@ def _swiglu(h, w1, w3, w2):
 
 
 def _moe(h, layer, top_k):
-    probs = jax.nn.softmax(h @ _f32(layer["router"]), -1)     # [S, E]
+    """-> (output [S, D], margin [S]): how far the router was from
+    choosing another expert, the k-th less the (k+1)-th logit."""
+    router_logits = h @ _f32(layer["router"])                 # [S, E]
+    probs = jax.nn.softmax(router_logits, -1)
     top_vals, top_idx = jax.lax.top_k(probs, top_k)
     top_vals = top_vals / top_vals.sum(-1, keepdims=True)
     n_experts = probs.shape[-1]
+    if top_k < n_experts:
+        ranked, _ = jax.lax.top_k(router_logits, top_k + 1)
+        margin = ranked[:, top_k - 1] - ranked[:, top_k]
+    else:
+        margin = jnp.full(h.shape[:1], jnp.inf)
     gates = jnp.zeros_like(probs).at[
         jnp.arange(h.shape[0])[:, None], top_idx].set(top_vals)
 
@@ -81,13 +89,17 @@ def _moe(h, layer, top_k):
 
     out, _ = jax.lax.scan(one_expert, jnp.zeros_like(h),
                           jnp.arange(n_experts))
-    return out
+    return out, margin
 
 
-def logits(params: Dict[str, Any], tokens, *, n_heads: int,
-           n_kv_heads: int, rope_theta: float, norm_eps: float,
-           moe_top_k: int = 0):
-    """tokens [S] int32 -> logits [S, vocab] float32, one sequence."""
+def logits_and_margins(params: Dict[str, Any], tokens, *, n_heads: int,
+                       n_kv_heads: int, rope_theta: float,
+                       norm_eps: float, moe_top_k: int = 0):
+    """tokens [S] int32 -> (logits [S, vocab], margins [S]) float32,
+    one sequence, one pass. A position's margin is the smallest over
+    the layers of its router's k-th less (k+1)-th logit: under it, a
+    rounding of the hidden state sends the token to another expert.
+    Infinite for a model without a router."""
     with jax.default_matmul_precision("highest"):
         x = _f32(params["embedding"][tokens])
 
@@ -96,13 +108,24 @@ def logits(params: Dict[str, Any], tokens, *, n_heads: int,
             x = x + _attention(h, layer, n_heads, n_kv_heads, rope_theta)
             h = _rms_norm(x, layer["mlp_norm"], norm_eps)
             if "router" in layer:
-                return x + _moe(h, layer, moe_top_k), None
-            return x + _swiglu(h, layer["w1"], layer["w3"],
-                               layer["w2"]), None
+                y, margin = _moe(h, layer, moe_top_k)
+                return x + y, margin
+            return (x + _swiglu(h, layer["w1"], layer["w3"], layer["w2"]),
+                    jnp.full(x.shape[:1], jnp.inf))
 
-        x, _ = jax.lax.scan(one_layer, x, params["layers"])
+        x, margins = jax.lax.scan(one_layer, x, params["layers"])
         x = _rms_norm(x, params["final_norm"], norm_eps)
-        return x @ _f32(params["lm_head"])
+        return x @ _f32(params["lm_head"]), margins.min(0)
+
+
+def logits(params: Dict[str, Any], tokens, **kw):
+    """tokens [S] int32 -> logits [S, vocab] float32, one sequence."""
+    return logits_and_margins(params, tokens, **kw)[0]
+
+
+def router_margins(params: Dict[str, Any], tokens, **kw):
+    """tokens [S] int32 -> margins [S] float32, one sequence."""
+    return logits_and_margins(params, tokens, **kw)[1]
 
 
 def loss(params, tokens, targets, **kw):

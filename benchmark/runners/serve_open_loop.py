@@ -32,6 +32,9 @@ REQUEST_TIMEOUT_S = 600.0
 # all on the flash kernel
 CHECK_PROMPTS = (100, 200, 300)
 CHECK_TOKENS = 6
+# a model with a router is judged by shares and means over its tokens
+# (reference_check.routed_report): enough tokens that a share is one
+ROUTED_CHECK_TOKENS = 64
 MAX_MEAN_LAG_S = 0.010
 _SERIES = re.compile(r"^(ray_tpu_engine_\w+?)(\{[^}]*\})? (\S+)$")
 
@@ -152,7 +155,9 @@ def run(cell: Dict[str, Any], args, t_start: float) -> Dict[str, Any]:
         check = ray_tpu.get(
             ray_tpu.remote(num_tpus=cell["chips"])(
                 reference_check.check_serving).remote(
-                engine, config["reference"], lens, CHECK_TOKENS, seed),
+                engine, config["reference"], lens,
+                ROUTED_CHECK_TOKENS if model.moe_experts else CHECK_TOKENS,
+                seed),
             timeout=DEPLOY_TIMEOUT_S)
         log("reference check:", json.dumps(check))
         where = check["device"]

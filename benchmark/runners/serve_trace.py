@@ -38,7 +38,13 @@ class TracedLLMServer(LLMServer):
 
         from benchmark import trace_reduce
         logdir = tempfile.mkdtemp(prefix="bench_trace_")
-        jax.profiler.start_trace(logdir)
+        # without the Python tracer: its hook on every call slowed the
+        # engine's steps by 7% while it ran, and stop_trace held the
+        # interpreter 0.6-0.9 s to collect what it had recorded. The
+        # engine's own spans and the runtime's (TraceMe) name the gaps.
+        options = jax.profiler.ProfileOptions()
+        options.python_tracer_level = 0
+        jax.profiler.start_trace(logdir, profiler_options=options)
         try:
             with jax.profiler.TraceAnnotation(trace_reduce.WINDOW_SPAN):
                 time.sleep(seconds)

@@ -63,6 +63,42 @@ def test_initial_burst_comes_first_and_adds_to_the_rate():
     assert [r["due"] for r in rs] == sorted(r["due"] for r in rs)
 
 
+def test_order_in_rounds_deals_every_stratum_once_a_round():
+    mix = harness.load_json("traffic", "chat-short-saturated.json")
+    k = mix["order"]["strata"]
+    a = traffic.open_loop_schedule(mix, 3000000011, 40.0)
+    b = traffic.open_loop_schedule(mix, 3000000012, 40.0)
+    plain = traffic.open_loop_schedule(
+        {**mix, "order": {"strata": 1}}, 3000000011, 40.0)
+    for key in (lambda r: r["max_tokens"], lambda r: len(r["prompt"])):
+        # the same set as under a plain shuffle, in an order of the seed's
+        assert sorted(map(key, a)) == sorted(map(key, b)) \
+            == sorted(map(key, plain))
+        assert list(map(key, a)) != list(map(key, b))
+        grid = sorted(map(key, a))
+        n = len(grid)
+        cuts = [grid[n * i // k] for i in range(k)] + [grid[-1] + 1]
+        for start in range(0, n - k + 1, k):
+            round_ = sorted(map(key, a[start:start + k]))
+            assert all(cuts[i] <= v <= cuts[i + 1]
+                       for i, v in enumerate(round_)), (start, round_)
+    # any 16 consecutive requests ask for about the same number of tokens
+    sums = [sum(r["max_tokens"] for r in a[i:i + k])
+            for i in range(0, len(a) - k + 1, k)]
+    assert max(sums) - min(sums) < 0.2 * sums[0], sums
+    plain_sums = [sum(r["max_tokens"] for r in plain[i:i + k])
+                  for i in range(0, len(plain) - k + 1, k)]
+    assert max(plain_sums) - min(plain_sums) > max(sums) - min(sums)
+
+
+def test_order_without_strata_is_the_plain_shuffle():
+    """A mix file without "order" gets the schedule it always got."""
+    import hashlib
+    got = traffic.open_loop_schedule(_mix(), 7, 40.0)
+    assert hashlib.sha256(json.dumps(got).encode()).hexdigest()[:16] \
+        == "c4c1ad43fc7dbfa3"
+
+
 def test_schedule_fits_window_and_limits():
     mix = _mix()
     rs = traffic.open_loop_schedule(mix, 7, 40.0)
@@ -234,25 +270,229 @@ def test_every_cell_reports_enough():
 
 # -- the plain reference ------------------------------------------------
 
-def test_reference_agrees_with_the_program_on_the_cpu():
+@pytest.mark.parametrize("experts,capacity_factor", [
+    (0, 2.0),
+    # 4 experts, top-2: at that size no expert can overflow, so the
+    # program drops nothing and has to agree
+    (4, 2.0),
+    # 8 experts overflow at the program's default; at 4.0 an expert's
+    # capacity is every token of the step, nothing is dropped, and
+    # this is the case that has to agree
+    (8, 4.0)])
+def test_reference_agrees_with_the_program_on_the_cpu(experts,
+                                                      capacity_factor):
     """Tiny sizes, float32: the program's loss and the reference's on
-    the same seeded weights, dense (grouped-query) and with experts
-    (4 experts, top-2: at that size no expert can overflow, so the
-    program drops nothing and has to agree)."""
+    the same seeded weights, dense (grouped-query) and with experts."""
     import jax
-    import jax.numpy as jnp
 
     from benchmark.reference import mistral as ref
     from ray_tpu.models.llama import LlamaConfig, llama_init, llama_loss
 
-    for experts in (0, 4):
-        cfg = LlamaConfig.tiny(vocab_size=300, moe_experts=experts,
-                               moe_aux_weight=0.0)
+    cfg = LlamaConfig.tiny(vocab_size=300, moe_experts=experts,
+                           moe_capacity_factor=capacity_factor,
+                           moe_aux_weight=0.0)
+    params = llama_init(jax.random.PRNGKey(7), cfg)
+    tokens = jax.random.randint(jax.random.PRNGKey(8), (2, 33), 0, 300)
+    x, y = tokens[:, :-1], tokens[:, 1:]
+    got = float(llama_loss(params, x, y, cfg, None))
+    want = sum(float(ref.loss(params, x[i], y[i], **ref.kwargs_from(cfg)))
+               for i in range(2)) / 2
+    assert got == pytest.approx(want, abs=1e-4)
+
+
+def test_router_margins_come_with_the_logits():
+    import jax
+    import jax.numpy as jnp
+
+    from benchmark.reference import mistral as ref
+    from ray_tpu.models.llama import LlamaConfig, llama_init
+
+    tokens = jax.random.randint(jax.random.PRNGKey(8), (33,), 0, 300)
+    for experts in (0, 8):
+        cfg = LlamaConfig.tiny(vocab_size=300, moe_experts=experts)
         params = llama_init(jax.random.PRNGKey(7), cfg)
-        tokens = jax.random.randint(jax.random.PRNGKey(8), (2, 33), 0, 300)
-        x, y = tokens[:, :-1], tokens[:, 1:]
-        got = float(llama_loss(params, x, y, cfg, None))
-        want = sum(float(ref.loss(params, x[i], y[i],
-                                  **ref.kwargs_from(cfg)))
-                   for i in range(2)) / 2
-        assert got == pytest.approx(want, abs=1e-4), experts
+        kw = ref.kwargs_from(cfg)
+        logits, margins = ref.logits_and_margins(params, tokens, **kw)
+        assert (logits == ref.logits(params, tokens, **kw)).all()
+        assert (margins == ref.router_margins(params, tokens, **kw)).all()
+        assert margins.shape == (33,) and (margins >= 0).all()
+        # no router, nothing to flip
+        assert bool(jnp.isinf(margins).all()) == (experts == 0)
+    # one layer, first position: attention returns the token's own
+    # value, so the router's input can be written out by hand
+    cfg = LlamaConfig.tiny(vocab_size=300, moe_experts=8, n_layers=1)
+    params = llama_init(jax.random.PRNGKey(7), cfg)
+    layer = jax.tree.map(lambda w: w[0].astype(jnp.float32),
+                         params["layers"])
+    norm = lambda x, w: x * jax.lax.rsqrt(  # noqa: E731
+        jnp.mean(x * x) + cfg.norm_eps) * w
+    x = params["embedding"][tokens[0]].astype(jnp.float32)
+    v = jnp.repeat((norm(x, layer["attn_norm"]) @ layer["wv"]).reshape(
+        cfg.n_kv_heads, -1), cfg.n_heads // cfg.n_kv_heads, 0)
+    x = x + v.reshape(-1) @ layer["wo"]
+    ranked = jnp.sort(norm(x, layer["mlp_norm"]) @ layer["router"])
+    margin = ref.router_margins(params, tokens, **ref.kwargs_from(cfg))[0]
+    assert float(margin) == pytest.approx(float(ranked[-2] - ranked[-3]),
+                                          abs=1e-5)
+
+
+# -- the comparison for a model with a router ---------------------------
+
+def test_routed_report_on_numbers_written_out():
+    from benchmark import reference_check as rc
+
+    far, near = rc.ROUTER_MARGIN * 2, rc.ROUTER_MARGIN / 2
+    # a flipped token reads units and decides nothing, decided or not
+    ok = rc.routed_report([0.01] * 190 + [3.0, 2.0],
+                          [far] * 190 + [near, far])
+    assert ok["ok"] and ok["worst"] == 3.0 and ok["tokens"] == 192
+    assert ok["decided_share"] == pytest.approx(191 / 192)
+    assert ok["undecided_worst"] == 3.0 and ok["undecided_mean"] == 3.0
+    assert set(ok["limits"]) == {"decided_mean", "decided_over_share",
+                                 "decided_median",
+                                 "decided_share_at_least"}
+    # a fault in every token fails, however small the worst
+    every = rc.routed_report([2.5 * rc.LOGPROB_MEAN_TOL] * 192, [far] * 192)
+    assert not every["ok"] and every["worst"] < rc.LOGPROB_TOL
+    # a fault in a share of the decided tokens fails
+    share = rc.ROUTED_OVER_SHARE_MAX * 2
+    n = round(192 * share)
+    some = rc.routed_report([0.001] * (192 - n) + [rc.LOGPROB_TOL * 1.01] * n,
+                            [far] * 192)
+    assert not some["ok"]
+    assert some["decided_mean"] <= rc.LOGPROB_MEAN_TOL
+    # too few decided tokens to judge by: not ok, whatever they read
+    few = rc.routed_report([0.001] * 192, [near] * 190 + [far] * 2)
+    assert not few["ok"]
+    assert few["decided_share"] < rc.ROUTED_DECIDED_SHARE_MIN
+    assert not rc.routed_report([0.001] * 192, [near] * 192)["ok"]
+
+
+def test_dense_report_is_worst_and_mean():
+    from benchmark import reference_check as rc
+
+    report = rc.dense_report([0.01, 0.02, 0.03])
+    assert report["ok"] and report["tokens"] == 3
+    assert report["worst"] == 0.03
+    assert report["mean"] == pytest.approx(0.02)
+    assert not rc.dense_report([0.01] * 17 + [rc.LOGPROB_TOL * 1.1])["ok"]
+    assert not rc.dense_report([rc.LOGPROB_MEAN_TOL * 1.1] * 18)["ok"]
+
+
+def _routed_model(**kw):
+    """Where routing has near ties and a CPU can hold the run: 8
+    experts, top-2, a few hundred positions. 4.0 is the capacity
+    factor at which the program drops nothing."""
+    import jax.numpy as jnp
+
+    from ray_tpu.models.llama import LlamaConfig
+    base = dict(vocab_size=2048, dim=256, n_layers=4, n_heads=8,
+                n_kv_heads=2, hidden_dim=896, moe_experts=8, moe_top_k=2,
+                moe_capacity_factor=4.0, max_seq_len=512,
+                dtype=jnp.bfloat16, attention="reference", remat=False)
+    base.update(kw)
+    return LlamaConfig(**base)
+
+
+def _served(seed, model, wrong_weights=None):
+    """The program serves 3 x 64 tokens as check_serving has it do; the
+    reference scores them on the weights as the seed gives them, as a
+    top-2 model. -> (differences, margins)."""
+    import jax
+
+    from benchmark import reference_check as rc
+    from benchmark.reference import mistral as ref
+    from ray_tpu.llm.engine import ContinuousBatchingEngine, EngineConfig
+    from ray_tpu.models.llama import llama_init
+
+    params = jax.jit(llama_init, static_argnums=1)(
+        jax.random.PRNGKey(seed), model)
+    engine = ContinuousBatchingEngine(
+        EngineConfig(model=model, max_batch=8, max_seq=512, seed=seed),
+        params=wrong_weights(params) if wrong_weights else params)
+    try:
+        generated = rc.generate(engine, [100, 200, 300], 64, seed)
+    finally:
+        engine.close()
+    return rc.differences(generated, params, ref,
+                          _routed_model(dtype=model.dtype))
+
+
+def _w2_of_two_experts_swapped(params):
+    layers = dict(params["layers"])
+    w2 = layers["w2"]
+    layers["w2"] = w2.at[:, 0].set(w2[:, 1]).at[:, 1].set(w2[:, 0])
+    return {**params, "layers": layers}
+
+
+def _experts_in_8_bits(params):
+    """fp8 (4 exponent bits, 3 of mantissa) with a scale for each
+    output channel: the nearest precision under bf16 that the
+    comparison can tell from it. (int8 with such a scale, 127 even
+    steps, reads 1.4 to 1.9 times the sound program, on the chip as
+    here: no limit with room over a sound seed catches it.)"""
+    import jax
+    import jax.numpy as jnp
+    layers = dict(params["layers"])
+    for name in ("w1", "w3", "w2"):
+        w = layers[name].astype(jnp.float32)
+        scale = jnp.max(jnp.abs(w), axis=-2, keepdims=True) / 240.0
+        layers[name] = (jax.lax.reduce_precision(
+            w / scale, exponent_bits=4, mantissa_bits=3)
+            * scale).astype(layers[name].dtype)
+    return {**params, "layers": layers}
+
+
+def _gates_not_renormalised(x, router, k):
+    import jax
+    import jax.numpy as jnp
+    logits = x.astype(jnp.float32) @ router.astype(jnp.float32)
+    probs = jax.nn.softmax(logits, axis=-1)
+    vals, idx = jax.lax.top_k(probs, k)
+    gates = jnp.zeros_like(probs).at[
+        jnp.arange(x.shape[0])[:, None], idx].set(vals)
+    return gates, idx, jnp.zeros((), jnp.float32)
+
+
+@pytest.mark.parametrize("seed", [1, 2, 3])
+def test_routed_comparison_passes_the_drop_free_program(seed):
+    from benchmark import reference_check as rc
+
+    report = rc.routed_report(*_served(seed, _routed_model()))
+    assert report["ok"], report
+    assert report["tokens"] == 192
+    # near-tie routing is there: tokens the dense limit would fail
+    assert report["worst"] > rc.LOGPROB_TOL
+
+
+WRONG = {
+    # the program's default capacity, prompts padded to 128, 256, 512
+    "capacity_2_padded_bucket": (dict(moe_capacity_factor=2.0), None),
+    "top_1_for_top_2": (dict(moe_top_k=1), None),
+    "gates_not_renormalised": ({}, None),
+    "w2_of_two_experts_swapped": ({}, _w2_of_two_experts_swapped),
+    "experts_in_8_bits": ({}, _experts_in_8_bits),
+}
+
+
+@pytest.mark.parametrize("fault", sorted(WRONG))
+def test_routed_comparison_fails_a_wrong_program(fault, monkeypatch):
+    from benchmark import reference_check as rc
+    from ray_tpu.parallel import moe
+
+    if fault == "gates_not_renormalised":
+        monkeypatch.setattr(moe, "top_k_gating", _gates_not_renormalised)
+    overrides, wrong_weights = WRONG[fault]
+    report = rc.routed_report(
+        *_served(1, _routed_model(**overrides), wrong_weights))
+    assert not report["ok"], report
+
+
+def test_drop_free_float32_program_matches_every_token():
+    """Where a fault in one token in a hundred is caught: in float32
+    the two choose the same experts, and every token has to agree."""
+    import jax.numpy as jnp
+
+    diffs, margins = _served(1, _routed_model(dtype=jnp.float32))
+    assert len(diffs) == 192 and max(diffs) <= 1e-4
+    assert min(margins) < 0.01      # near ties were among them

@@ -7,6 +7,13 @@ only decides their order (which gap precedes which request, which
 prompt gets which answer length) and the prompts' bytes. So two seeds
 offer the same work at the same mean rate, and what differs between
 runs is the system, not the draw.
+
+The order is a plain shuffle unless the mix file says ``"order":
+{"strata": k}``: then each list is dealt in rounds of k, one value from
+every k-th part of its sorted grid a round. A window that is not
+drained needs it: under a plain shuffle one seed puts its long answers
+last, where the close cuts them, and completes 3% fewer tokens than
+the next seed on the same system.
 """
 
 from __future__ import annotations
@@ -37,6 +44,31 @@ def quantile_grid(spec: Dict[str, Any], n: int) -> List[float]:
     return [min(max(v, lo), hi) for v in values]
 
 
+def _in_rounds(values: List[float], rng: random.Random,
+               strata: int) -> List[float]:
+    """``values`` in the seed's order. One stratum: a plain shuffle.
+    k strata: the sorted values are cut into k slices of equal size
+    and dealt in rounds, each round one value of every slice in a
+    shuffled order, so any k consecutive requests carry about the same
+    work whatever the seed."""
+    if strata <= 1:
+        values = list(values)
+        rng.shuffle(values)
+        return values
+    values = sorted(values)
+    n = len(values)
+    slices = [values[n * i // strata:n * (i + 1) // strata]
+              for i in range(strata)]
+    for s in slices:
+        rng.shuffle(s)
+    out = []
+    for j in range(max(len(s) for s in slices)):
+        round_ = [s[j] for s in slices if j < len(s)]
+        rng.shuffle(round_)
+        out += round_
+    return out
+
+
 def open_loop_schedule(mix: Dict[str, Any], seed: int, seconds: float,
                        scale: Dict[str, float] = None
                        ) -> List[Dict[str, Any]]:
@@ -65,8 +97,9 @@ def open_loop_schedule(mix: Dict[str, Any], seed: int, seconds: float,
                for v in quantile_grid(mix["prompt_bytes"], n)]
     outputs = [max(1, round(v * scale.get("output", 1.0)))
                for v in quantile_grid(mix["output_tokens"], n)]
-    for values in (gaps, prompts, outputs):
-        rng.shuffle(values)
+    strata = int(mix.get("order", {}).get("strata", 1))
+    gaps, prompts, outputs = (_in_rounds(values, rng, strata)
+                              for values in (gaps, prompts, outputs))
     dues = [0.01 * (i + 1) for i in range(burst)]
     due = 0.0
     for gap in gaps:
