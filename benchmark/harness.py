@@ -1,6 +1,6 @@
 """What every runner shares: the cell as BENCHMARK.json and the data
-files describe it, the model configuration handed to the program, the
-percentile and open-loop arithmetic, and the result line.
+files describe it, the modules its files name (runner, model family,
+readers), the percentile and open-loop arithmetic, and the result line.
 
 Nothing here touches a JAX backend: the process that runs a cell never
 holds the chip (the worker or the replica the runtime spawns does).
@@ -57,34 +57,15 @@ def load_cell(workload: str, rehearse: bool = False) -> Dict[str, Any]:
     return cell
 
 
-def model_kwargs(config: Dict[str, Any], rehearse: bool = False
-                 ) -> Dict[str, Any]:
-    """The published keys, as LlamaConfig names them. ``rehearse``
-    swaps in LlamaConfig.tiny's sizes (the CPU rehearsal), keeping
-    what kind of model it is: grouped-query, dense or experts."""
-    if config.get("sliding_window") is not None:
-        raise BenchError("the program has no sliding-window attention")
-    kw = dict(
-        vocab_size=config["vocab_size"], dim=config["hidden_size"],
-        n_layers=config["num_hidden_layers"],
-        n_heads=config["num_attention_heads"],
-        n_kv_heads=config["num_key_value_heads"],
-        hidden_dim=config["intermediate_size"],
-        rope_theta=float(config["rope_theta"]),
-        norm_eps=float(config["rms_norm_eps"]),
-        moe_experts=int(config.get("num_local_experts", 0)),
-        moe_top_k=int(config.get("num_experts_per_tok", 2)),
-        attention="flash")
-    if rehearse:
-        kw.update(vocab_size=512, dim=64, n_layers=2, n_heads=4,
-                  n_kv_heads=2, hidden_dim=128, attention="reference",
-                  moe_experts=4 if kw["moe_experts"] else 0)
-    return kw
-
-
 def runner_for(kind: str):
     """The runner of a traffic file's ``kind``, found by name."""
     return importlib.import_module(f"benchmark.runners.{kind}")
+
+
+def program_for(name: str):
+    """The module of a model family, found by a configuration file's
+    ``program`` (what it gives: benchmark/programs/__init__.py)."""
+    return importlib.import_module(f"benchmark.programs.{name}")
 
 
 def reader_for(name: str):

@@ -24,7 +24,7 @@ phase or a dropped layer moves them by tenths to units. The mean loss
 of a batch averages the per-token differences out: at 4 layers and
 8192 tokens the two losses differed by 7e-6; the limit is 1e-3.
 
-ROUTED: a model with a router (``moe_experts > 0``) cannot be held to
+ROUTED: a model with a router (``routed``, programs/) cannot be held to
 the worst token. The reference chooses each token's experts from its
 float32 hidden states, the program from its bf16 ones; where a router's
 k-th and (k+1)-th logits lie closer than that rounding the two choose
@@ -91,6 +91,25 @@ ROUTER_MARGIN = 0.04             # router logits; under it a token is undecided
 ROUTED_DECIDED_SHARE_MIN = 0.3   # decided tokens, of all
 ROUTED_OVER_SHARE_MAX = 0.05     # decided tokens over LOGPROB_TOL, of decided
 ROUTED_MEDIAN_MAX = 0.026        # median |difference| of decided tokens
+
+
+# the check's sizes where a configuration file has no "check" key:
+# prompts for prefill buckets 128, 256 and 512, all on the flash kernel
+CHECK_PROMPTS = (100, 200, 300)
+CHECK_TOKENS = 6
+# a model with a router is judged by shares and means over its tokens
+# (routed_report): enough tokens that a share is one
+ROUTED_CHECK_TOKENS = 64
+
+
+def check_sizes(config: Dict[str, Any], routed: bool):
+    """-> (prompt lengths, tokens decoded after each) of the serving
+    check: the configuration file's ``"check": {"prompt_lens": [...],
+    "new_tokens": n}``, either key or both, else the defaults."""
+    check = config.get("check", {})
+    return (list(check.get("prompt_lens", CHECK_PROMPTS)),
+            int(check.get("new_tokens",
+                          ROUTED_CHECK_TOKENS if routed else CHECK_TOKENS)))
 
 
 def _reference(name: str):
@@ -199,8 +218,10 @@ def routed_report(diffs: List[float], margins: List[float]
 
 
 def check_serving(engine_config, reference: str, prompt_lens: List[int],
-                  new_tokens: int, seed: int) -> Dict[str, Any]:
-    """Runs in a worker that owns the chip (or, rehearsing, the CPU)."""
+                  new_tokens: int, seed: int, routed: bool
+                  ) -> Dict[str, Any]:
+    """Runs in a worker that owns the chip (or, rehearsing, the CPU).
+    ``routed`` is the program module's word on the model."""
     import gc
 
     from ray_tpu.accelerators import jax_backend
@@ -210,8 +231,8 @@ def check_serving(engine_config, reference: str, prompt_lens: List[int],
     diffs, margins = differences(
         generate(engine, prompt_lens, new_tokens, seed), engine.params,
         _reference(reference), engine_config.model)
-    report = (routed_report(diffs, margins)
-              if engine_config.model.moe_experts else dense_report(diffs))
+    report = (routed_report(diffs, margins) if routed
+              else dense_report(diffs))
     report["device"] = jax_backend.device_report()
     # the runtime may hand this worker, chip and all, to the replica:
     # give the engine's weights and cache back first

@@ -5,15 +5,17 @@
 
 The cell's configuration, traffic and metrics are data (BENCHMARK.json
 and the files under benchmark/); the traffic file's ``kind`` names the
-runner. The last line of stdout is the result. ``--trace 0`` reports
-the cell's end-to-end metrics, ``--trace 1`` its per-layer metrics.
+runner, the configuration file's ``program`` the module of its model
+family (benchmark/programs/). The last line of stdout is the result.
+``--trace 0`` reports the cell's end-to-end metrics, ``--trace 1`` its
+per-layer metrics.
 
-``--rehearse`` runs the same paths at LlamaConfig.tiny sizes on the
-CPU to check them: it says ``"correct": false`` and a device that is
-``cpu``, and writes no metric. Without it a run that finds no chip
-fails. ``--sweep 2,3,4`` (serving cells) offers each rate in turn to
-one replica and prints a row for each: how a traffic file's rate was
-found.
+``--rehearse`` runs the same paths on the CPU to check them, at the
+tiny sizes the program module keeps for its family: it says
+``"correct": false`` and a device that is ``cpu``, and writes no
+metric. Without it a run that finds no chip fails. ``--sweep 2,3,4``
+(serving cells) offers each rate in turn to one replica and prints a
+row for each: how a traffic file's rate was found.
 """
 
 from __future__ import annotations

@@ -6,10 +6,13 @@ This process starts the runtime, the proxy and (through the runtime)
 the replica that owns the chip; it never initializes a JAX backend.
 The load generator is a child process (benchmark/loadgen.py).
 
-Phases: reference check on the chip (a task that builds the same
-engine, see reference_check.py) -> deploy -> warm every prefill bucket
-the traffic uses and the decode program -> window -> (steady cells)
-drain -> stats and series -> shut down.
+Phases: build (the cell's EngineConfig, LLMConfig and schedules; no
+runtime yet) -> reference check on the chip (a task that builds the
+same engine, see reference_check.py) -> deploy -> warm every prefill
+bucket the traffic uses and the decode program -> window -> (steady
+cells) drain -> stats and series -> shut down -> the result. What is
+particular to the model family comes from the configuration file's
+program module (benchmark/programs/).
 """
 
 from __future__ import annotations
@@ -21,6 +24,7 @@ import socket
 import subprocess
 import sys
 import time
+import types
 import urllib.request
 from typing import Any, Dict, List, Tuple
 
@@ -28,15 +32,12 @@ from benchmark import harness, reference_check, traffic
 
 DEPLOY_TIMEOUT_S = 900.0
 REQUEST_TIMEOUT_S = 600.0
-# prompts of the reference check: prefill buckets 128, 256 and 512,
-# all on the flash kernel
-CHECK_PROMPTS = (100, 200, 300)
-CHECK_TOKENS = 6
-# a model with a router is judged by shares and means over its tokens
-# (reference_check.routed_report): enough tokens that a share is one
-ROUTED_CHECK_TOKENS = 64
 MAX_MEAN_LAG_S = 0.010
-_SERIES = re.compile(r"^(ray_tpu_engine_\w+?)(\{[^}]*\})? (\S+)$")
+_SERIES = re.compile(r"^(ray_tpu_\w+?)(\{[^}]*\})? (\S+)$")
+
+
+def _log(*a) -> None:
+    print("[serve_open_loop]", *a, flush=True)
 
 
 def _free_port() -> int:
@@ -50,9 +51,10 @@ def _get(url: str, timeout: float = REQUEST_TIMEOUT_S) -> Dict[str, Any]:
         return json.loads(r.read())
 
 
-def engine_series() -> Dict[str, float]:
-    """The ray_tpu_engine_* series as the driver's registry holds them
-    now, ``name{labels}`` -> value."""
+def program_series() -> Dict[str, float]:
+    """Every ray_tpu_* series as the driver's registry holds them now,
+    ``name{labels}`` -> value: the engine's, and what a model brings
+    outside it (a state cache's, a dispatch's), for a reader to find."""
     from ray_tpu.util import metrics
     out = {}
     for line in metrics.prometheus_text().splitlines():
@@ -67,7 +69,7 @@ def _scrape(base: str) -> Tuple[Dict[str, Any], Dict[str, float]]:
     they reach the driver's registry a moment later."""
     stats = _get(base + "/stats")
     time.sleep(0.5)
-    return stats, engine_series()
+    return stats, program_series()
 
 
 def _body(request: Dict[str, Any], mix: Dict[str, Any], no_eos) -> Dict[str, Any]:
@@ -114,59 +116,93 @@ def _window(port: int, requests, mix, no_eos,
                      for r in requests]})
 
 
-def run(cell: Dict[str, Any], args, t_start: float) -> Dict[str, Any]:
-    import ray_tpu
-    from ray_tpu import serve
+def build(cell: Dict[str, Any], args) -> types.SimpleNamespace:
+    """What a run of the cell is made of, before any runtime: the
+    EngineConfig and LLMConfig the replica gets, the reference check's
+    sizes, and the schedule(s) the seed deals."""
     from ray_tpu.llm.engine import EngineConfig
     from ray_tpu.llm.tokenizer import ByteTokenizer
-    from ray_tpu.models.llama import LlamaConfig
-    from ray_tpu.serve.config import HTTPOptions
-    from ray_tpu.serve.llm import LLMConfig, build_openai_app
+    from ray_tpu.serve.llm import LLMConfig
 
     config, mix = cell["config_file"], cell["traffic_file"]
     sizes = config["serving"]
     seed = args.seed % harness.SEED_MODULUS
-    model_kw = harness.model_kwargs(config, args.rehearse)
-    if args.rehearse:
-        import jax.numpy as jnp
-        model_kw.update(dtype=jnp.float32, remat=False)
-    model = LlamaConfig(max_seq_len=sizes["max_seq"], **model_kw)
-    engine = EngineConfig(model=model, max_batch=sizes["max_batch"],
-                          max_seq=sizes["max_seq"], seed=seed)
+    program = harness.program_for(config["program"])
+    engine = EngineConfig(
+        model=program.serving_model(config, sizes["max_seq"], args.rehearse),
+        max_batch=sizes["max_batch"], max_seq=sizes["max_seq"], seed=seed)
     llm = LLMConfig(model_id=cell["config"], engine=engine,
                     use_tpu=not args.rehearse,
                     tpu_chips_per_replica=cell["chips"],
                     max_ongoing_requests=sizes["max_ongoing_requests"])
+    routed = program.routed(config)
+    check_lens, check_tokens = reference_check.check_sizes(config, routed)
     scale = ({"prompt": 0.1, "output": 0.1} if args.rehearse else None)
     rates = args.sweep or [mix["rate_rps"]]
-    schedules = [traffic.open_loop_schedule(
-        {**mix, "rate_rps": rate}, args.seed, args.seconds, scale)
-        for rate in rates]
-    requests = schedules[0]
-    drain = bool(mix.get("drain", True)) or bool(args.sweep)
-    no_eos = {str(ByteTokenizer.eos_id): -100}
-    log = lambda *a: print("[serve_open_loop]", *a, flush=True)  # noqa: E731
+    return types.SimpleNamespace(
+        program=program, engine=engine, llm=llm, routed=routed,
+        check_lens=[min(n, sizes["max_seq"] // 2) for n in check_lens],
+        check_tokens=check_tokens, rates=rates,
+        schedules=[traffic.open_loop_schedule(
+            {**mix, "rate_rps": rate}, args.seed, args.seconds, scale)
+            for rate in rates],
+        drain=bool(mix.get("drain", True)) or bool(args.sweep),
+        no_eos={str(ByteTokenizer.eos_id): -100})
 
-    ray_tpu.init(**({"num_tpus": cell["chips"]} if args.rehearse else {}))
-    notes: Dict[str, Any] = {}
-    try:
-        # -- the program against the plain reference, on the chip -----
-        lens = [min(n, sizes["max_seq"] // 2) for n in CHECK_PROMPTS]
-        check = ray_tpu.get(
-            ray_tpu.remote(num_tpus=cell["chips"])(
-                reference_check.check_serving).remote(
-                engine, config["reference"], lens,
-                ROUTED_CHECK_TOKENS if model.moe_experts else CHECK_TOKENS,
-                seed),
-            timeout=DEPLOY_TIMEOUT_S)
-        log("reference check:", json.dumps(check))
-        where = check["device"]
-        if not args.rehearse and where["platform"] != "tpu":
-            raise harness.BenchError(f"the worker computes on {where}")
-        if len(where["device_ids"]) != cell["chips"]:
+
+def _reference_check(cell: Dict[str, Any], built, rehearse: bool
+                     ) -> Dict[str, Any]:
+    """The program against the plain reference, on the chip."""
+    import ray_tpu
+
+    check = ray_tpu.get(
+        ray_tpu.remote(num_tpus=cell["chips"])(
+            reference_check.check_serving).remote(
+            built.engine, cell["config_file"]["reference"],
+            built.check_lens, built.check_tokens, built.engine.seed,
+            built.routed),
+        timeout=DEPLOY_TIMEOUT_S)
+    _log("reference check:", json.dumps(check))
+    where = check["device"]
+    if not rehearse and where["platform"] != "tpu":
+        raise harness.BenchError(f"the worker computes on {where}")
+    if len(where["device_ids"]) != cell["chips"]:
+        raise harness.BenchError(
+            f"cell asks for {cell['chips']} chips, the worker sees "
+            f"{where['device_ids']}")
+    return check
+
+
+def _warm_up(port: int, built, mix: Dict[str, Any]) -> None:
+    """Each prefill bucket the schedules use, then a full decode batch."""
+    warm_lens = _buckets([len(r["prompt"]) + 1 for s in built.schedules
+                          for r in s], built.engine.max_seq)
+    warm = [{"due": 0.0, "prompt": "w" * (n - 1), "max_tokens": 4}
+            for n in warm_lens]
+    for w in warm:     # one at a time: each compiles its bucket
+        got = _window(port, [w], mix, built.no_eos, 1.0, True)
+        if got["records"][0]["error"]:
             raise harness.BenchError(
-                f"cell asks for {cell['chips']} chips, the worker sees "
-                f"{where['device_ids']}")
+                f"warm-up request failed: {got['records'][0]}")
+    got = _window(port, [dict(w, due=0.01 * i) for i, w in
+                         enumerate(warm * 3)], mix, built.no_eos, 1.0, True)
+    bad = [r for r in got["records"] if r["error"]]
+    if bad:
+        raise harness.BenchError(f"warm-up failed: {bad[:2]}")
+
+
+def run(cell: Dict[str, Any], args, t_start: float) -> Dict[str, Any]:
+    import ray_tpu
+    from ray_tpu import serve
+    from ray_tpu.serve.config import HTTPOptions
+    from ray_tpu.serve.llm import build_openai_app
+
+    mix = cell["traffic_file"]
+    built = build(cell, args)
+    ray_tpu.init(**({"num_tpus": cell["chips"]} if args.rehearse else {}))
+    seen: Dict[str, Any] = {}       # what _result judges and reduces
+    try:
+        check = _reference_check(cell, built, args.rehearse)
         # -- deploy ---------------------------------------------------
         port = _free_port()
         http = HTTPOptions(port=port)
@@ -174,50 +210,34 @@ def run(cell: Dict[str, Any], args, t_start: float) -> Dict[str, Any]:
         serve.start(http_options=http, proxy=True)
         if args.trace:
             from benchmark.runners import serve_trace
-            app = serve_trace.traced_app(llm)
+            app = serve_trace.traced_app(built.llm)
         else:
-            app = build_openai_app(config=llm)
+            app = build_openai_app(config=built.llm)
         serve.run(app, route_prefix="/v1", timeout_s=DEPLOY_TIMEOUT_S)
-        notes["ready_s"] = time.monotonic() - t_start
-        # -- warm up: each prefill bucket, then a full decode batch ----
-        warm_lens = _buckets([len(r["prompt"]) + 1 for s in schedules
-                              for r in s], sizes["max_seq"])
-        warm = [{"due": 0.0, "prompt": "w" * (n - 1), "max_tokens": 4}
-                for n in warm_lens]
-        for w in warm:     # one at a time: each compiles its bucket
-            got = _window(port, [w], mix, no_eos, 1.0, True)
-            if got["records"][0]["error"]:
-                raise harness.BenchError(
-                    f"warm-up request failed: {got['records'][0]}")
-        got = _window(port, [dict(w, due=0.01 * i) for i, w in
-                                   enumerate(warm * 3)], mix, no_eos,
-                      1.0, True)
-        bad = [r for r in got["records"] if r["error"]]
-        if bad:
-            raise harness.BenchError(f"warm-up failed: {bad[:2]}")
-        stats0, series0 = _scrape(base)
+        seen["ready_s"] = time.monotonic() - t_start
+        _warm_up(port, built, mix)
+        seen["stats_before"], seen["series_before"] = _scrape(base)
         t_scrape0 = time.monotonic()
         # -- the window(s) ---------------------------------------------
-        results = []
+        results = seen["results"] = []
         pending = None
-        for rate, schedule in zip(rates, schedules):
+        for rate, schedule in zip(built.rates, built.schedules):
             if args.trace:
                 pending = serve_trace.PendingTrace(
                     base, mix.get("trace_after_s", 5.0),
                     mix.get("trace_seconds", 3.0))
-            got = _window(port, schedule, mix, no_eos,
-                          args.seconds, drain)
+            got = _window(port, schedule, mix, built.no_eos,
+                          args.seconds, built.drain)
             got["rate_rps"] = rate
             results.append(got)
             if args.sweep:
-                log("sweep", json.dumps(_sweep_row(got, args.seconds)))
-        got = results[0]
-        notes["setup_s"] = got["t0_monotonic"] - t_start
-        t_scrape1 = time.monotonic()
-        stats1, series1 = _scrape(base)
-        traced = pending.collect() if pending else None
+                _log("sweep", json.dumps(_sweep_row(got, args.seconds)))
+        seen["setup_s"] = results[0]["t0_monotonic"] - t_start
+        seen["series_window_s"] = time.monotonic() - t_scrape0
+        seen["stats_after"], seen["series_after"] = _scrape(base)
+        seen["trace"] = pending.collect() if pending else None
     finally:
-        notes["logs"] = _log_tails() if args.dump else ""
+        logs = _log_tails() if args.dump else ""
         serve.shutdown()
         ray_tpu.shutdown()
 
@@ -225,10 +245,17 @@ def run(cell: Dict[str, Any], args, t_start: float) -> Dict[str, Any]:
         os.makedirs(args.dump, exist_ok=True)
         with open(os.path.join(args.dump, f"{cell['name']}.serve.json"),
                   "w") as f:
-            json.dump({"results": results, "stats": stats1,
-                       "series_before": series0, "series_after": series1,
-                       "trace": traced, "check": check,
-                       "logs": notes["logs"]}, f)
+            json.dump({**seen, "check": check, "logs": logs}, f)
+    return _result(cell, args, built, check, seen)
+
+
+def _result(cell: Dict[str, Any], args, built, check: Dict[str, Any],
+            seen: Dict[str, Any]) -> Dict[str, Any]:
+    """After the runtime has shut down: the first window's records
+    into what was measured, what was observed, and ``correct``."""
+    got, traced = seen["results"][0], seen["trace"]
+    stats0, stats1 = seen["stats_before"], seen["stats_after"]
+    requests, drain, where = built.schedules[0], built.drain, check["device"]
     sent = [(r, q["max_tokens"]) for r, q in zip(got["records"], requests)
             if r["sent"] is not None]
     unsent = len(got["records"]) - len(sent)
@@ -244,20 +271,20 @@ def run(cell: Dict[str, Any], args, t_start: float) -> Dict[str, Any]:
     if not records_done:
         raise harness.BenchError("no request finished inside the window")
     lat = harness.open_loop_latencies(records_done)
-    log(f"generator lag: worst {lat['lag_worst_s'] * 1e3:.2f} ms, mean "
-        f"{lat['lag_mean_s'] * 1e3:.3f} ms; unsent {unsent}; in flight at "
-        f"the close {got['in_flight_at_end']}; window ended "
-        f"{got['ended_s']:.2f} s"
-        + ("; THE GENERATOR WAS STARVED: discard this run"
-           if lat["lag_mean_s"] > MAX_MEAN_LAG_S else ""))
+    _log(f"generator lag: worst {lat['lag_worst_s'] * 1e3:.2f} ms, mean "
+         f"{lat['lag_mean_s'] * 1e3:.3f} ms; unsent {unsent}; in flight at "
+         f"the close {got['in_flight_at_end']}; window ended "
+         f"{got['ended_s']:.2f} s"
+         + ("; THE GENERATOR WAS STARVED: discard this run"
+            if lat["lag_mean_s"] > MAX_MEAN_LAG_S else ""))
     measured = {
-        "setup_s": notes["setup_s"],
+        "setup_s": seen["setup_s"],
         "ttft_p50_ms": harness.percentile(lat["ttft_s"], 0.50) * 1e3,
         "itl_p90_ms": harness.percentile(lat["gaps_s"], 0.90) * 1e3,
         "serve_tok_s": harness.tokens_inside(records, args.seconds)
         / args.seconds,
     }
-    log("also:", json.dumps({
+    _log("also:", json.dumps({
         "ttft_p50_ms": measured["ttft_p50_ms"],
         "ttft_p90_ms": harness.percentile(lat["ttft_s"], 0.9) * 1e3,
         "ttft_max_ms": max(lat["ttft_s"]) * 1e3,
@@ -266,10 +293,11 @@ def run(cell: Dict[str, Any], args, t_start: float) -> Dict[str, Any]:
         "itl_p95_ms": harness.percentile(lat["gaps_s"], 0.95) * 1e3,
         "serve_tok_s": measured["serve_tok_s"],
         "requests": len(records), "gaps": len(lat["gaps_s"]),
-        "ready_s": notes["ready_s"]}))
+        "ready_s": seen["ready_s"]}))
     dev0, dev1 = stats0["device"], stats1["device"]
     compiled = dev1["compile_seconds"] - dev0["compile_seconds"]
-    kernels_ok, kernel_note = _kernels_ok(stats1, args.rehearse)
+    kernels_ok, kernel_note = _kernels_ok(stats1, built.program,
+                                          args.rehearse)
     # How late the generator ran is printed above and decides nothing:
     # latency runs from the due time, so a late send is charged to the
     # system, and a send that comes late offers less load, so a starved
@@ -283,15 +311,16 @@ def run(cell: Dict[str, Any], args, t_start: float) -> Dict[str, Any]:
                and not stats1["flash_fallbacks"]
                and dev1["platform"] == where["platform"])
     if not correct:
-        log("NOT CORRECT:", json.dumps({
+        _log("NOT CORRECT:", json.dumps({
             "check": check["ok"], "wrong_counts": len(wrong),
             "kernels": kernel_note, "compiled_in_window_s": compiled,
             "replica_pids": [dev0["pid"], dev1["pid"]],
             "unsent": unsent, "fallbacks": stats1["flash_fallbacks"],
             "lag_worst_s": lat["lag_worst_s"]}))
     observed = {
-        "client": lat, "series_before": series0, "series_after": series1,
-        "series_window_s": t_scrape1 - t_scrape0, "trace": traced,
+        "client": lat, "series_before": seen["series_before"],
+        "series_after": seen["series_after"],
+        "series_window_s": seen["series_window_s"], "trace": traced,
         "cell": cell}
     device, breakdown = harness.device_and_breakdown(dev1, traced)
     return {"correct": correct and not args.rehearse,
@@ -317,17 +346,15 @@ def _log_tails() -> str:
     return "\n".join(out)
 
 
-def _kernels_ok(stats: Dict[str, Any], rehearse: bool):
-    """Every prefill program holds flash_fwd and rms_norm, every decode
-    program rms_norm (what attention="flash" asks for on a TPU)."""
+def _kernels_ok(stats: Dict[str, Any], program, rehearse: bool):
+    """Every program the engine lowered holds the Pallas kernels its
+    family's module asks of a program of that name."""
     if rehearse:
         return True, "rehearsal: the CPU holds no kernels"
     missing = []
     for name, found in stats["programs"].items():
-        wanted = (["flash_fwd", "rms_norm"] if name.startswith("prefill")
-                  else ["rms_norm"])
-        missing += [f"{name}:{w}"
-                    for w in harness.missing_kernels(found, wanted)]
+        missing += [f"{name}:{w}" for w in harness.missing_kernels(
+            found, program.kernels(name))]
     if not stats["programs"]:
         missing.append("no program reported")
     return not missing, missing

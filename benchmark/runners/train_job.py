@@ -1,6 +1,7 @@
 """Runner of the traffic kind ``train_job``: JaxTrainer -> one worker
-that owns the cell's chips -> the jitted adamw step on llama_loss over
-an fsdp mesh, fed by ray_tpu.data.
+that owns the cell's chips -> the jitted adamw step on the loss of the
+configuration's program module (benchmark/programs/) over an fsdp
+mesh, fed by ray_tpu.data.
 
 This process starts the runtime and the trainer and never initializes
 a JAX backend; ``_loop`` runs in the worker, which holds the chips,
@@ -21,26 +22,25 @@ from typing import Any, Dict
 from benchmark import harness, traffic
 
 
-def _programs(cfg, mesh, opt):
-    """init(key) -> (params, opt_state), the shardings of that state
-    under the fsdp rules, the loss, and the adamw step on it."""
+def _programs(family, mesh, opt):
+    """``family`` is a program module's training(). -> init(key) ->
+    (params, opt_state), the shardings of that state under the
+    family's rules, the loss, and the adamw step on it."""
     import jax
     import optax
 
-    from ray_tpu.models.llama import (llama_init, llama_loss,
-                                      llama_sharding_rules)
     from ray_tpu.parallel.sharding import infer_sharding
 
     def init(key):
-        params = llama_init(key, cfg)
+        params = family["init"](key)
         return params, opt.init(params)
 
     shardings = infer_sharding(
         jax.eval_shape(init, jax.random.PRNGKey(0)), mesh,
-        llama_sharding_rules("fsdp"))
+        family["sharding_rules"])
 
     def loss_fn(params, tokens, targets):
-        return llama_loss(params, tokens, targets, cfg, mesh)
+        return family["loss"](params, tokens, targets, mesh)
 
     def train_step(params, opt_state, tokens, targets):
         loss, grads = jax.value_and_grad(loss_fn)(params, tokens, targets)
@@ -63,16 +63,16 @@ def _loop(config: Dict[str, Any]) -> None:
     import ray_tpu.train as train
     from benchmark import reference_check, trace_reduce
     from ray_tpu.accelerators import jax_backend
-    from ray_tpu.models.llama import LlamaConfig
     from ray_tpu.ops import attention as att
     from ray_tpu.parallel.mesh import MeshSpec, make_mesh
 
     jax_backend.track_compile_time()
     sizes, seconds = config["sizes"], config["seconds"]
-    cfg = LlamaConfig(**config["model"])
+    family = harness.program_for(config["program"]).training(
+        config["config_file"], sizes, config["rehearse"])
     mesh = make_mesh(MeshSpec(fsdp=sizes["fsdp"]))
     init, shardings, loss_fn, train_step = _programs(
-        cfg, mesh, optax.adamw(sizes["learning_rate"]))
+        family, mesh, optax.adamw(sizes["learning_rate"]))
     # under jit straight into the target sharding, in the served type
     params, opt_state = jax.jit(init, out_shardings=shardings)(
         jax.random.PRNGKey(config["seed"]))
@@ -82,8 +82,8 @@ def _loop(config: Dict[str, Any]) -> None:
     first = next(batches)
     # the program's loss against the plain reference, same weights
     check = reference_check.check_training(
-        params, first["tokens"], first["targets"], cfg,
-        config["reference"], jax.jit(loss_fn))
+        params, first["tokens"], first["targets"], family["model"],
+        config["config_file"]["reference"], jax.jit(loss_fn))
     lowered = jax.jit(train_step, donate_argnums=(0, 1)).lower(
         params, opt_state, first["tokens"], first["targets"])
     kernels = jax_backend.pallas_kernels(lowered.as_text())
@@ -169,25 +169,22 @@ def run(cell: Dict[str, Any], args, t_start: float) -> Dict[str, Any]:
     config, job = cell["config_file"], cell["traffic_file"]
     sizes = config["training"]
     seed = args.seed % harness.SEED_MODULUS
-    model = harness.model_kwargs(config, args.rehearse)
-    model.update(max_seq_len=sizes["seq"], remat=sizes["remat"],
-                 ce_chunk_tokens=sizes["ce_chunk_tokens"])
-    if args.rehearse:
-        import jax.numpy as jnp
-        model.update(dtype=jnp.float32)
+    program = harness.program_for(config["program"])
+    vocab_size = program.vocab_size(config, args.rehearse)
     chips = cell["chips"]
     log = lambda *a: print("[train_job]", *a, flush=True)  # noqa: E731
     rows = sizes["batch"] * job["max_steps"]
     ray_tpu.init(**({"num_tpus": chips} if args.rehearse else {}))
     try:
         ds = rd.range(rows, parallelism=job["blocks"]).map_batches(
-            traffic.token_rows(args.seed, sizes["seq"], model["vocab_size"]))
+            traffic.token_rows(args.seed, sizes["seq"], vocab_size))
         result = JaxTrainer(
             _loop,
             train_loop_config={
-                "model": model, "sizes": sizes, "seed": seed,
+                "program": config["program"], "config_file": config,
+                "rehearse": args.rehearse, "sizes": sizes, "seed": seed,
                 "seconds": args.seconds, "t_start": t_start,
-                "trace": bool(args.trace), "reference": config["reference"],
+                "trace": bool(args.trace),
                 "trace_at_step": job["trace_at_step"],
                 "trace_steps": job["trace_steps"]},
             scaling_config=ScalingConfig(num_workers=1, use_tpu=True,
@@ -210,7 +207,7 @@ def run(cell: Dict[str, Any], args, t_start: float) -> Dict[str, Any]:
     measured = {"setup_s": s["setup_s"],
                 "train_tok_s": n * tokens_per_step / s["window_s"]}
     missing = [] if args.rehearse else harness.missing_kernels(
-        s["kernels"], ["flash_fwd", "flash_dq", "flash_dkv", "rms_norm"])
+        s["kernels"], program.kernels("train_step"))
     finite = all(x == x and abs(x) != float("inf") for x in s["losses"])
     correct = (s["check"]["ok"] and finite and not missing
                and not s["flash_fallbacks"]

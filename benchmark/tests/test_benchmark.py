@@ -230,9 +230,14 @@ def test_benchmark_json_names_files_and_characters():
             data = json.load(f)
         assert set(c["reduced"]) == set(data["reduced"])
         for key in ("source", "reduced", "assumed", "departures",
-                    "deployment", "reference"):
+                    "deployment", "reference", "program"):
             assert key in data, (c["name"], key)
         assert data["source"] == c["source"]
+        program = harness.program_for(data["program"])
+        for function in ("serving_model", "training", "vocab_size",
+                         "kernels", "routed"):
+            assert callable(getattr(program, function)), (c["name"],
+                                                          function)
     e2e = {m["name"]: m for m in bench["end_to_end"]}
     cells = {w["name"] for w in bench["workloads"]}
     assert "setup_s" in e2e
