@@ -518,7 +518,12 @@ def _lora_delta(h, a, b):
 
 
 def llama_init_cache(config: LlamaConfig, batch: int, max_seq: int):
-    """KV cache pair, each [L, B, S, KVH, HD] in the model dtype."""
+    """KV cache pair, each [L, B, S, KVH, HD] in the model dtype.
+
+    ``llama_decode_step`` reads the pair in this layout and writes one
+    row per layer and slot in place, so the program that calls it must
+    donate both (``donate_argnums``): a cache that is not donated is
+    copied whole, every step."""
     c = config
     shape = (c.n_layers, batch, max_seq, c.n_kv_heads, c.head_dim)
     return jnp.zeros(shape, c.dtype), jnp.zeros(shape, c.dtype)
@@ -571,6 +576,18 @@ def llama_decode_step(params, token, cache_k, cache_v, pos,
     cache_k/v: [L, B, S, KVH, HD]. Returns (logits [B, vocab] f32,
     cache_k, cache_v) with the new K/V written at `pos`.
 
+    The caches are attended over as they are stored and written in
+    place: they ride in the layer scan's carry, each layer scatters its
+    [B, KVH, HD] row at ``[l, arange(B), pos]`` and then reads its own
+    slice, and the query heads are grouped by the KV head they share
+    (head h belongs to KV head ``h // n_rep``), so no K or V is
+    expanded to ``n_heads`` and no layer is handed back whole. The
+    caller's program must donate both caches, or XLA copies them every
+    step. Every ``pos`` must lie in ``[0, S-1]`` (the engine parks idle
+    slots at 0 or at the last row): a scatter drops a row that is out of
+    bounds where ``dynamic_update_slice`` would clamp it, and inside
+    the bounds the two never differ.
+
     Multi-LoRA: ``lora_bank`` stacks adapters on a leading axis
     ({A_q: [N, L, D, r], ...}; index 0 all-zero = no adapter) and
     ``lora_idx`` [B] picks one per slot — the vLLM-style batched-gather
@@ -578,23 +595,25 @@ def llama_decode_step(params, token, cache_k, cache_v, pos,
     """
     c = config
     n_layers, b, s, kvh, hd = cache_k.shape
-    n_rep = c.n_heads // c.n_kv_heads
+    n_rep = c.n_heads // kvh
     x = params["embedding"][token][:, None, :].astype(c.dtype)  # [B,1,D]
     cos, sin = rope_frequencies(hd, s, c.rope_theta)
     pos_2d = pos[:, None]                                       # [B,1]
     # causal visibility: this token may attend to cache slots <= pos
     visible = jnp.arange(s)[None, :] <= pos_2d                  # [B,S]
+    slots = jnp.arange(b)
     if lora_bank is not None:
         # [N, L, ...] -> [L, N, ...] so the layer scan consumes them
         bank = {k2: jnp.swapaxes(v2, 0, 1)
                 for k2, v2 in lora_bank.items() if k2 != "scale"}
         lora_scale = lora_bank["scale"]
 
-    def body(x, layer):
+    def body(carry, layer):
+        x, cache_k, cache_v = carry
         if lora_bank is not None:
-            layer_params, ck, cv, a_q, b_q, a_v, b_v = layer
+            layer_params, l, a_q, b_q, a_v, b_v = layer
         else:
-            layer_params, ck, cv = layer                        # ck [B,S,KVH,HD]
+            layer_params, l = layer
         with jax.named_scope(SCOPE_ATTENTION):
             h = rms_norm(x, layer_params["attn_norm"], c.norm_eps)
             q = (h @ layer_params["wq"]).reshape(b, 1, c.n_heads, hd)
@@ -607,37 +626,36 @@ def llama_decode_step(params, token, cache_k, cache_v, pos,
                 v = v + (lora_scale * dv).reshape(b, 1, kvh, hd)
             q = apply_rope(q, cos, sin, positions=pos_2d)
             k = apply_rope(k, cos, sin, positions=pos_2d)
-            write = jax.vmap(
-                lambda cache, new, p: jax.lax.dynamic_update_slice(
-                    cache, new, (p, 0, 0)))
-            ck = write(ck, k, pos)
-            cv = write(cv, v, pos)
-            kk = jnp.repeat(ck, n_rep, axis=2) if n_rep > 1 else ck
-            vv = jnp.repeat(cv, n_rep, axis=2) if n_rep > 1 else cv
-            scores = jnp.einsum("bqhd,bshd->bhqs", q,
-                                kk).astype(jnp.float32)
+            cache_k = cache_k.at[l, slots, pos].set(k[:, 0])
+            cache_v = cache_v.at[l, slots, pos].set(v[:, 0])
+            ck = jax.lax.dynamic_index_in_dim(cache_k, l, keepdims=False)
+            cv = jax.lax.dynamic_index_in_dim(cache_v, l, keepdims=False)
+            # [B,S,KVH,HD] as stored; a group is the n_rep query heads
+            # of one KV head (n_rep == 1: groups of one)
+            scores = jnp.einsum("bgrd,bsgd->bgrs",
+                                q.reshape(b, kvh, n_rep, hd), ck,
+                                preferred_element_type=jnp.float32)
             scores = scores * (hd ** -0.5)
             scores = jnp.where(visible[:, None, None, :], scores, -1e30)
             weights = jax.nn.softmax(scores, axis=-1).astype(c.dtype)
-            attn = jnp.einsum("bhqs,bshd->bqhd", weights, vv)
+            attn = jnp.einsum("bgrs,bsgd->bgrd", weights, cv)
             x = x + (attn.reshape(b, 1, c.n_heads * hd)
                      @ layer_params["wo"])
         with jax.named_scope(SCOPE_FFN):
             h = rms_norm(x, layer_params["mlp_norm"], c.norm_eps)
             y, _aux = _ffn(layer_params, h, c)  # MoE-aware (decode too)
             x = x + y
-        return x, (ck, cv)
+        return (x, cache_k, cache_v), None
 
+    xs = (params["layers"], jnp.arange(n_layers))
     if lora_bank is not None:
-        xs = (params["layers"], cache_k, cache_v,
-              bank["A_q"], bank["B_q"], bank["A_v"], bank["B_v"])
-    else:
-        xs = (params["layers"], cache_k, cache_v)
-    x, (new_k, new_v) = jax.lax.scan(body, x, xs)
+        xs += (bank["A_q"], bank["B_q"], bank["A_v"], bank["B_v"])
+    (x, cache_k, cache_v), _ = jax.lax.scan(
+        body, (x, cache_k, cache_v), xs)
     with jax.named_scope(SCOPE_HEAD):
         x = rms_norm(x, params["final_norm"], c.norm_eps)
         logits = (x[:, 0] @ params["lm_head"]).astype(jnp.float32)
-    return logits, new_k, new_v
+    return logits, cache_k, cache_v
 
 
 def llama_verify_step(params, tokens, cache_k, cache_v, pos,

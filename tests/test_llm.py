@@ -54,6 +54,84 @@ def test_decode_matches_full_forward():
                                rtol=5e-2, atol=5e-2)
 
 
+@pytest.mark.parametrize("n_kv_heads", [4, 2, 1])
+def test_decode_steps_attend_over_the_cache_as_stored(n_kv_heads):
+    """Three consecutive decode steps in float32 against the full
+    forward on the grown sequences, for groups of 1, 2 and 4 query
+    heads a KV head: the second and third steps read rows the first
+    wrote. Slots parked at row 0 and at the last row sit beside live
+    ones, one of which ends on the last row. Rows no step wrote stay
+    bit-equal, and the engine's ``decode_lp`` program (one
+    ``logprobs=0`` request beside idle slots, as the benchmark's
+    reference check runs it) reports the forward's log-probabilities."""
+    import jax
+    import jax.numpy as jnp
+    from ray_tpu.models.llama import (
+        llama_decode_step, llama_forward, llama_init, llama_init_cache,
+        llama_prefill)
+    cfg = LlamaConfig.tiny(n_kv_heads=n_kv_heads, max_seq_len=64)
+    params = llama_init(jax.random.PRNGKey(0), cfg)
+    seq, steps = 16, 3
+    starts = [0, seq - 1, seq - steps, 5, 1]   # slots 0 and 1 are parked
+    parked = (0, 1)
+    rng = np.random.default_rng(n_kv_heads)
+    shape = llama_init_cache(cfg, len(starts), seq)[0].shape
+    # junk in every row: a row past a slot's position must not be read
+    ck = jnp.asarray(rng.standard_normal(shape), jnp.float32)
+    cv = jnp.asarray(rng.standard_normal(shape), jnp.float32)
+    # one right-padded batch: under a causal mask a row's prefix does
+    # not see what follows it, so one forward holds every grown
+    # sequence's logits and one prefill every prompt's K/V
+    grown = jnp.asarray(rng.integers(0, cfg.vocab_size,
+                                     size=(len(starts), seq)), jnp.int32)
+    full = np.asarray(llama_forward(params, grown, cfg))
+    _, ks, vs = llama_prefill(params, grown, cfg)
+    live = [slot for slot in range(len(starts)) if slot not in parked]
+    for slot in live:
+        n = starts[slot]
+        ck = ck.at[:, slot, :n].set(ks[:, slot, :n])
+        cv = cv.at[:, slot, :n].set(vs[:, slot, :n])
+    before_k, before_v = np.asarray(ck), np.asarray(cv)
+    step = jax.jit(
+        lambda tok, ck, cv, pos: llama_decode_step(params, tok, ck, cv,
+                                                   pos, cfg),
+        donate_argnums=(1, 2))
+    written = np.zeros(shape[1:3], bool)                      # [B, S]
+    slots = np.arange(len(starts))
+    for i in range(steps):
+        pos = np.asarray([n if slot in parked else n + i
+                          for slot, n in enumerate(starts)], np.int32)
+        logits, ck, cv = step(grown[slots, pos], ck, cv, jnp.asarray(pos))
+        written[slots, pos] = True
+        np.testing.assert_allclose(
+            np.asarray(logits)[live], full[live, pos[live]],
+            rtol=1e-4, atol=1e-4)
+    after_k, after_v = np.asarray(ck), np.asarray(cv)
+    assert written.sum() == 2 + 3 * steps
+    for after, before in ((after_k, before_k), (after_v, before_v)):
+        np.testing.assert_array_equal(after[:, ~written],
+                                      before[:, ~written])
+        assert (after[:, written] != before[:, written]).any(
+            axis=(-1, -2)).all()
+
+    engine = ContinuousBatchingEngine(EngineConfig(
+        model=cfg, max_batch=4, max_seq=64))
+    prompt = grown[3, :5].tolist()
+    req = engine.add_request(GenerationRequest(
+        prompt_ids=prompt, max_tokens=5, temperature=0.0, logprobs=0))
+    while not req.done:
+        engine.step()
+    assert "decode_lp" in engine.stats()["programs"]
+    ids = prompt + req.output_ids
+    lsm = jax.nn.log_softmax(llama_forward(
+        engine.params, jnp.asarray([ids], jnp.int32), cfg)[0], axis=-1)
+    want = [float(lsm[len(prompt) - 1 + j, t])
+            for j, t in enumerate(req.output_ids)]
+    got = [e["logprob"] for e in req.logprob_data]
+    assert [e["top"] for e in req.logprob_data] == [[]] * 5
+    np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-4)
+
+
 def test_engine_greedy_deterministic():
     engine = tiny_engine()
     out1 = engine.generate([[1, 2, 3]], max_tokens=8)

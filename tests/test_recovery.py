@@ -123,31 +123,32 @@ def test_node_death_drill_recovery_timeline(drill_cluster):
         # printable without raising
         assert "NODE_DEAD" in recovery.render(report)
 
-        # same incident through the out-of-process CLI surface
-        from ray_tpu.scripts.cli import _load_state
-        deadline = time.monotonic() + 15
-        while time.monotonic() < deadline:
-            snap = _load_state()
-            if snap and any(e["kind"] == "NODE_DEAD"
-                            for e in snap.get("events", [])):
-                break
-            time.sleep(0.2)
-        else:
-            pytest.fail("NODE_DEAD never reached the state snapshot")
-        out = subprocess.run(
-            [sys.executable, "-m", "ray_tpu.scripts.cli", "events",
-             "--kind", "NODE_DEAD"],
-            capture_output=True, text=True, timeout=60)
-        assert out.returncode == 0 and "NODE_DEAD" in out.stdout
+        # same incident through the out-of-process CLI surface. The
+        # CLI finds the session through one pointer file a machine,
+        # which every live runtime rewrites every 2 s: under xdist
+        # another worker's session can own it when the child reads,
+        # so ask until this session's snapshot is the one read.
+        def cli_until(module_args, seen):
+            deadline = time.monotonic() + 30
+            while True:
+                out = subprocess.run(
+                    [sys.executable, "-m", *module_args],
+                    capture_output=True, text=True, timeout=60)
+                if out.returncode == 0 and seen(out.stdout):
+                    return
+                if time.monotonic() > deadline:
+                    pytest.fail(f"{module_args}: NODE_DEAD never seen: "
+                                f"{out.returncode} {out.stdout[-300:]!r} "
+                                f"{out.stderr[-300:]!r}")
+                time.sleep(0.2)
+
+        cli_until(["ray_tpu.scripts.cli", "events", "--kind", "NODE_DEAD"],
+                  lambda stdout: "NODE_DEAD" in stdout)
         # ... and the standalone report CLI folds the same snapshot
-        out = subprocess.run(
-            [sys.executable, "-m", "ray_tpu.devtools.recovery",
-             "--json"],
-            capture_output=True, text=True, timeout=60)
-        assert out.returncode == 0
-        folded = json.loads(out.stdout)
-        assert any(i["root_kind"] == "NODE_DEAD"
-                   for i in folded["incidents"])
+        cli_until(["ray_tpu.devtools.recovery", "--json"],
+                  lambda stdout: any(
+                      i["root_kind"] == "NODE_DEAD"
+                      for i in json.loads(stdout)["incidents"]))
     finally:
         proc.send_signal(signal.SIGKILL)  # kills stopped processes too
         proc.wait(timeout=10)

@@ -5,6 +5,8 @@ compiler). Catches what only the TPU compiler says — scoped-VMEM
 overflow, "Mosaic kernels cannot be automatically partitioned" — before
 any chip time is spent. Skipped where the topology cannot be had."""
 
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -135,32 +137,68 @@ def test_fsdp4_train_step_compiles_with_per_shard_kernels(v5e):
     assert bytes_per_chip < HBM_BYTES
 
 
+def _abstract_params(mesh, cfg):
+    params = jax.eval_shape(lambda k: llama_init(k, cfg),
+                            jax.random.PRNGKey(0))
+    return _abstract(params, jax.tree.map(
+        lambda _: NamedSharding(mesh, P()), params))
+
+
+def _lower_decode_step(mesh, cfg, params, *, batch, seq):
+    """``llama_decode_step`` with both caches donated, as the engine's
+    decode program calls it."""
+    cache = jax.eval_shape(lambda: llama_init_cache(cfg, batch, seq))
+    cache_k, cache_v = _abstract(cache, jax.tree.map(
+        lambda _: NamedSharding(mesh, P()), cache))
+    ints = _on(mesh, P(), (batch,), jnp.int32)
+    return jax.jit(
+        lambda p, tok, ck, cv, pos: llama_decode_step(p, tok, ck, cv,
+                                                      pos, cfg),
+        donate_argnums=(2, 3)).lower(params, ints, cache_k, cache_v, ints)
+
+
 @pytest.mark.slow
 def test_serving_programs_compile(v5e):
     """The engine's prefill (buckets 128 and 1024) and decode programs
     at the smoke's serving size: 16 layers, batch 8, seq 1024."""
     cfg = LlamaConfig.llama2_7b(n_layers=16, max_seq_len=1024)
     mesh = _mesh(v5e, 1)
-    params = jax.eval_shape(lambda k: llama_init(k, cfg),
-                            jax.random.PRNGKey(0))
-    params = _abstract(params, jax.tree.map(
-        lambda _: NamedSharding(mesh, P()), params))
+    params = _abstract_params(mesh, cfg)
     for bucket in (128, 1024):
         lowered = jax.jit(lambda p, t: llama_prefill(p, t, cfg)).lower(
             params, _on(mesh, P(), (1, bucket), jnp.int32))
         assert [k.split("(")[0] for k in _kernels(lowered)] == [
             "flash_fwd", "rms_norm"]
         lowered.compile()
-    cache = jax.eval_shape(lambda: llama_init_cache(cfg, 8, 1024))
-    cache_k, cache_v = _abstract(cache, jax.tree.map(
-        lambda _: NamedSharding(mesh, P()), cache))
-    ints = _on(mesh, P(), (8,), jnp.int32)
-    lowered = jax.jit(
-        lambda p, tok, ck, cv, pos: llama_decode_step(p, tok, ck, cv,
-                                                      pos, cfg),
-        donate_argnums=(2, 3)).lower(params, ints, cache_k, cache_v, ints)
+    lowered = _lower_decode_step(mesh, cfg, params, batch=8, seq=1024)
     assert [k.split("(")[0] for k in _kernels(lowered)] == ["rms_norm"]
     memory = lowered.compile().memory_analysis()
     assert (memory.argument_size_in_bytes
             + memory.temp_size_in_bytes) < HBM_BYTES
     assert attention.kernel_fallbacks == []
+
+
+def test_decode_step_attends_over_the_cache_in_place(v5e):
+    """The decode program of the two serving cells (Mistral-7B widths,
+    16 layers, batch 32, seq 1024, caches donated) holds no copy of the
+    cache: K and V are not expanded to 32 heads, no layer is handed
+    back through a fresh buffer, and both caches alias their donated
+    inputs. The mechanism always engages, so the compiled program is
+    the counter that says it did."""
+    cfg = LlamaConfig(vocab_size=32768, dim=4096, n_layers=16, n_heads=32,
+                      n_kv_heads=8, hidden_dim=14336, max_seq_len=1024,
+                      rope_theta=1e6)
+    mesh = _mesh(v5e, 1)
+    params = _abstract_params(mesh, cfg)
+    lowered = _lower_decode_step(mesh, cfg, params, batch=32, seq=1024)
+    assert [k.split("(")[0] for k in _kernels(lowered)] == ["rms_norm"]
+    compiled = lowered.compile()
+    memory = compiled.memory_analysis()
+    cache_bytes = 2 * 16 * 32 * 1024 * 8 * 128 * 2
+    assert memory.alias_size_in_bytes == cache_bytes
+    assert memory.temp_size_in_bytes < 256 * 2**20
+    text = compiled.as_text()
+    assert "[32,1024,8,4,128]" not in text
+    whole_cache = re.escape("bf16[16,32,1024,8,128]")
+    assert not re.search(
+        rf"= {whole_cache}\S* (copy|custom-call)\(", text)
