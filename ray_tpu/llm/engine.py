@@ -79,9 +79,18 @@ ENGINE_STEP_HOST_SECONDS = _metrics.Histogram(
     "Engine step wall time less the time the stepper waited for the "
     "device in it (its blocking read-backs), by phase",
     boundaries=_STEP_BOUNDS, tag_keys=("phase",))
+ENGINE_STEP_UPLOAD_SECONDS = _metrics.Histogram(
+    "ray_tpu_engine_step_upload_seconds",
+    "Time the stepper spent sending a step's inputs to the device "
+    "(0.0 for a step that sent nothing), by phase",
+    boundaries=_STEP_BOUNDS, tag_keys=("phase",))
 ENGINE_TOKENS = _metrics.Counter(
     "ray_tpu_engine_tokens_generated_total",
     "Tokens emitted by the engine")
+ENGINE_STATE_UPLOADS = _metrics.Counter(
+    "ray_tpu_engine_state_uploads_total",
+    "Dense decode steps that sent the per-slot state from the host: "
+    "the others took it from the decode step before them")
 ENGINE_TOKENS_PER_S = _metrics.Gauge(
     "ray_tpu_engine_tokens_per_second",
     "Decode throughput over the last metrics flush window")
@@ -117,10 +126,11 @@ class _MetricsBuffer(_metrics.LocalBuffer):
         self._stop = threading.Event()
 
     def note_step(self, phase: str, dt: float, host_dt: float,
-                  tokens: int) -> None:
+                  upload_dt: float, tokens: int) -> None:
         tags = {"phase": phase}
         self.observe(ENGINE_STEP_SECONDS, dt, tags)
         self.observe(ENGINE_STEP_HOST_SECONDS, host_dt, tags)
+        self.observe(ENGINE_STEP_UPLOAD_SECONDS, upload_dt, tags)
         if tokens:
             self.inc(ENGINE_TOKENS, float(tokens))
         if self._thread is None and not self._stop.is_set():
@@ -359,6 +369,13 @@ class GenerationRequest:
                 pass  # stream consumer is gone; tokens just drop
 
 
+# Rows of the dense decode step's state, one int32 [7, B] array that
+# lives on the device (temperature rides along bit-cast). A step changes
+# TOKEN, POS and STEP (the sampler's counter, the same in every column);
+# the rest only admission and endings do.
+_TOKEN, _POS, _TEMP, _TOPK, _LORA, _LIVE, _STEP = range(7)
+
+
 class _Slot:
     def __init__(self, index: int):
         self.index = index
@@ -388,6 +405,8 @@ class ContinuousBatchingEngine:
         import jax.numpy as jnp
 
         jax_backend.track_compile_time()
+        self._jax = jax
+        self._jnp = jnp
         self.config = config
         c = config.model
         # name -> (jitted, abstract args, static kwargs) of the first
@@ -413,8 +432,17 @@ class ContinuousBatchingEngine:
             if "w1_q8" not in params["layers"]:
                 params = quantize_llama_ffn(params, c)
         self.params = params
-        self.cache_k, self.cache_v = llama_init_cache(
-            c, config.max_batch, config.max_seq)
+        self._base_key = jax.random.PRNGKey(config.seed)
+        # The dense step's state goes from one decode program to the
+        # next, and a program's results are committed to their device
+        # when any of its arguments is. A state sent from the host has
+        # to reach the program as one it returned does (jit keys its
+        # programs on that too, and would compile ``decode`` twice), so
+        # everything a step sends is put committed, where the params
+        # are, and the caches with it: ``insert`` sees them before and
+        # after the first decode step.
+        self._on_device = self._sharding_beside(params)
+        self.cache_k, self.cache_v = self._fresh_cache(c)
         # per-slot logit_bias rows, device-resident so the per-step
         # cost is one [B, V] add — rows are (re)set at admission, so
         # stale rows from finished requests are never read
@@ -452,8 +480,7 @@ class ContinuousBatchingEngine:
                 draft_params = init(
                     jax.random.PRNGKey(config.seed + 1), dc)
             self.draft_params = draft_params
-            self.draft_cache_k, self.draft_cache_v = llama_init_cache(
-                dc, config.max_batch, config.max_seq)
+            self.draft_cache_k, self.draft_cache_v = self._fresh_cache(dc)
         scratch = 0
         if self._spec:
             scratch = max(scratch, config.spec_tokens)
@@ -479,16 +506,30 @@ class ContinuousBatchingEngine:
         self._prefilled_waiting: List[tuple] = []
         self._lock = locktrace.traced_lock("llm.engine")
         self.total_generated = 0
-        self._base_key = jax.random.PRNGKey(config.seed)
         self._step_counter = 0
+        # The dense step's per-slot state as the last decode program
+        # returned it, the slots it holds live, and whether a slot has
+        # changed hands since (a request admitted, ended, found
+        # cancelled; another step program run). A dense step that finds
+        # the flag set, or that is to run other slots than the state
+        # holds live (beside chunked prefill the dense step takes the
+        # adapter and logprobs requests only, and all of them again
+        # afterwards), builds the state from the slots and sends it,
+        # once. Otherwise it sends nothing.
+        self._state = None
+        self._state_slots: tuple = ()
+        self._state_stale = True
+        self.decode_steps = 0     # dense decode programs launched
+        self.state_uploads = 0    # of them, with a state from the host
         self._mbuf = _MetricsBuffer(self)
         self._admitted_last_step = 0
         # step() calls so far: the number a flight-recorder
         # engine_step event and its child spans share
         self._steps = 0
         # seconds of the current step the stepper spent waiting for
-        # the device (see _readback)
+        # the device (see _readback) and sending to it (see _upload)
         self._blocked_s = 0.0
+        self._upload_s = 0.0
         # multi-LoRA bank: slot 0 is the all-zero base adapter, so
         # "no adapter" needs no conditional in the decode program
         self._adapters: Dict[str, int] = {}
@@ -536,25 +577,34 @@ class ContinuousBatchingEngine:
             sampled = jnp.where(topk > 0, topk_tok, full)
             return jnp.where(temp <= 0.0, greedy, sampled)
 
-        def decode(params, cache_k, cache_v, tokens, pos, temp, topk,
-                   base_key, step, lora_bank, lora_idx, bias,
-                   want_lp=False):
+        def decode(params, cache_k, cache_v, state, base_key,
+                   lora_bank, bias, want_lp=False):
+            """One token for every live slot. ``state`` ([7, B] int32,
+            rows _TOKEN.._STEP) comes back as the next step's: a live
+            slot's sampled token and its position one further, a
+            parked slot's as they were (token 0 at ``_dense_park``),
+            the counter one up, so it equals what the host would gather
+            for the next step."""
+            tokens, pos, live = state[_TOKEN], state[_POS], state[_LIVE]
+            temp = jax.lax.bitcast_convert_type(state[_TEMP], jnp.float32)
             logits, ck, cv = llama_decode_step(
                 params, tokens, cache_k, cache_v, pos, c,
-                lora_bank=lora_bank, lora_idx=lora_idx)
-            key = jax.random.fold_in(base_key, step)
-            tok = sample_tokens(logits, temp, topk, key, bias)
+                lora_bank=lora_bank, lora_idx=state[_LORA])
+            key = jax.random.fold_in(base_key, state[_STEP, 0])
+            tok = sample_tokens(logits, temp, state[_TOPK], key, bias)
+            state = state.at[_TOKEN].set(tok * live).at[_POS].add(
+                live).at[_STEP].add(1)
             if not want_lp:
                 # static arg: the no-logprobs program carries none of
                 # the log_softmax/top_k work or output buffers
-                return tok, None, None, None, ck, cv
+                return state, None, None, None, ck, cv
             # logprobs of the biased (un-temperature-scaled) logits;
             # [B] chosen + [B, lp_k] top alternatives — tiny transfers
             lsm = jax.nn.log_softmax(
                 (logits + bias).astype(jnp.float32), axis=-1)
             chosen = jnp.take_along_axis(lsm, tok[:, None], 1)[:, 0]
             top_vals, top_ids = jax.lax.top_k(lsm, lp_k)
-            return tok, chosen, top_vals, top_ids, ck, cv
+            return state, chosen, top_vals, top_ids, ck, cv
 
         def prefill(params, tokens, lora):
             return llama_prefill(params, tokens, c, lora=lora)
@@ -702,12 +752,13 @@ class ContinuousBatchingEngine:
                     body, (token0, ck, cv), jnp.arange(n_draft + 1))
                 return drafts[:n_draft], ck, cv   # drafts: [G-1, B]
 
-            def draft_sync(dparams, ck, cv, tokens, pos):
+            def draft_sync(dparams, ck, cv, state):
                 """Dense-path companion: write the fed tokens' K/V into
                 the draft cache (output discarded) so dense fallback
-                rounds don't leave gaps that desync the draft."""
+                rounds don't leave gaps that desync the draft. Reads
+                the state the dense step was given."""
                 _logits, ck, cv = llama_decode_step(
-                    dparams, tokens, ck, cv, pos, dc)
+                    dparams, state[_TOKEN], ck, cv, state[_POS], dc)
                 return ck, cv
 
             def verify(tparams, ck, cv, chunk, pos, temp, topk,
@@ -727,9 +778,6 @@ class ContinuousBatchingEngine:
             self._verify = jax.jit(verify, donate_argnums=(1, 2))
             self._draft_prefill = jax.jit(
                 lambda p, t: llama_prefill(p, t, dc))
-
-        self._jax = jax
-        self._jnp = jnp
 
     # ------------------------------------------------------------------
     def register_adapter(self, name: str, lora_params) -> None:
@@ -902,7 +950,6 @@ class ContinuousBatchingEngine:
     def _admit_prefilled(self) -> None:
         """Adopt disaggregated requests: their KV arrives ready-made
         from a prefill engine; just insert into a free slot."""
-        jnp = self._jnp
         while True:
             with self._lock:
                 if not self._prefilled_waiting:
@@ -913,11 +960,13 @@ class ContinuousBatchingEngine:
                 request, ks, vs, plen, tok = self._prefilled_waiting.pop(0)
                 slot = free[0]
                 slot.request = request
+            self._state_stale = True  # graftlint: disable=GL001  # stepper-thread-only
             self._note_admitted(request)
             self._install_bias(request, slot.index)
+            ks, vs = self._upload(ks, vs)
             self.cache_k, self.cache_v = self._insert(
-                self.cache_k, self.cache_v, jnp.asarray(ks),
-                jnp.asarray(vs), slot.index)
+                self.cache_k, self.cache_v, ks, vs, slot.index)
+            del ks, vs
             if self._spec:
                 # disagg ships only the TARGET KV; rebuild the draft's
                 # prefix locally (draft prefill is cheap). The draft
@@ -956,16 +1005,44 @@ class ContinuousBatchingEngine:
         the flight recorder (category serve), tagged with the step."""
         return _flight.span("serve", name, step=self._steps, **args)
 
+    def _sharding_beside(self, params):
+        """Where the engine keeps what it sends and feeds back: the one
+        device the params are on (the default device for params that
+        are on none yet), or replicated over their mesh when they are
+        sharded over several."""
+        jax = self._jax
+        sharding = getattr(jax.tree_util.tree_leaves(params)[0],
+                           "sharding", None)
+        if sharding is None:
+            return jax.sharding.SingleDeviceSharding(jax.devices()[0])
+        if len(sharding.device_set) == 1:
+            return jax.sharding.SingleDeviceSharding(
+                next(iter(sharding.device_set)))
+        return jax.sharding.NamedSharding(
+            sharding.mesh, jax.sharding.PartitionSpec())
+
+    def _fresh_cache(self, model: LlamaConfig):
+        """An empty KV cache pair, committed to the engine's device
+        (see ``_on_device``)."""
+        return self._jax.device_put(
+            llama_init_cache(model, self.config.max_batch,
+                             self.config.max_seq), self._on_device)
+
     def _upload(self, *arrays) -> list:
-        """Host arrays of one step onto the device. Callers ``del``
-        the results once the program that reads them is launched, as
-        call-site temporaries would go: a device array dropped after
-        the step's read-back is released on the stepper thread while
-        the device sits idle (0.4 ms each on a v5e); dropped earlier,
-        its release is paid inside the next step's uploads, a little
-        cheaper in sum."""
+        """Host arrays of one step onto the device, committed there
+        (see ``_on_device``); the time it takes is the step's upload
+        time. Callers ``del`` the results once the program that reads
+        them is launched, as call-site temporaries would go: a device
+        array dropped after the step's read-back is released on the
+        stepper thread while the device sits idle (0.4 ms each on a
+        v5e); dropped earlier, its release is paid inside the next
+        step's uploads, a little cheaper in sum."""
+        t0 = time.perf_counter()
         with self._span("engine.upload"):
-            return [self._jnp.asarray(a) for a in arrays]
+            out = [self._jax.device_put(a, self._on_device)
+                   for a in arrays]
+        self._upload_s += time.perf_counter() - t0  # graftlint: disable=GL001  # stepper-thread-only
+        return out
 
     def _readback(self, *arrays) -> list:
         """Device results as numpy arrays. These reads block the
@@ -1240,6 +1317,7 @@ class ContinuousBatchingEngine:
                       ids: List[int]) -> None:
         """One admitted prompt: its bias row, its prefill (or, chunked,
         the bookkeeping that lets step() run it), its first token."""
+        self._state_stale = True  # graftlint: disable=GL001  # stepper-thread-only
         self._install_bias(request, slot.index)
         C = self.config.chunked_prefill_tokens
         if C > 0 and request.adapter is None \
@@ -1283,6 +1361,7 @@ class ContinuousBatchingEngine:
             # cancelled from another thread mid-step: discard the
             # token and release the slot
             slot.request = None
+            self._state_stale = True  # graftlint: disable=GL001  # stepper-thread-only
             return
         request.output_ids.append(token)
         self.total_generated += 1
@@ -1324,6 +1403,7 @@ class ContinuousBatchingEngine:
         if request.done:
             request.push_stream(None)
             slot.request = None
+            self._state_stale = True  # graftlint: disable=GL001  # stepper-thread-only
 
     def _gather_batch(self, active, pos_fill: int = 0):
         """Host-side per-slot input arrays for the jitted decode
@@ -1346,6 +1426,19 @@ class ContinuousBatchingEngine:
                 lora_idx[slot.index] = self._adapter_index(request)
         return tokens, pos, temp, topk, lora_idx
 
+    def _gather_state(self, active) -> np.ndarray:
+        """The dense step's packed state ([7, B] int32, rows
+        _TOKEN.._STEP) as the slots and the sampler's counter have it:
+        what ``decode`` is given after a slot changed hands, and what
+        it hands on otherwise."""
+        tokens, pos, temp, topk, lora_idx = self._gather_batch(
+            active, pos_fill=self._dense_park)
+        live = np.zeros_like(tokens)
+        live[[slot.index for slot in active]] = 1
+        return np.stack([tokens, pos, temp.view(np.int32), topk,
+                         lora_idx, live,
+                         np.full_like(tokens, self._step_counter)])
+
     def _spec_step(self, active) -> int:
         """One speculation round: G-1 batched draft decodes + ONE
         target verify over the [B, G] chunk; each greedy slot emits
@@ -1359,7 +1452,10 @@ class ContinuousBatchingEngine:
             active, pos_fill=park)
         tokens_j, pos_j, temp_j, topk_j = self._upload(
             tokens, pos, temp, topk)
-        self._step_counter += 1  # graftlint: disable=GL001  # stepper-thread-only
+        # stepper-thread-only; this program advances positions the
+        # dense step's device state does not see
+        self._step_counter += 1  # graftlint: disable=GL001
+        self._state_stale = True  # graftlint: disable=GL001
         with self._span("engine.launch"):
             # draft proposals d_1..d_{G-1}: one fused dispatch
             drafts_dev, self.draft_cache_k, self.draft_cache_v = \
@@ -1406,7 +1502,10 @@ class ContinuousBatchingEngine:
         outputs match single-step decoding exactly."""
         tokens, pos, temp, topk, lora_idx = self._gather_batch(
             active, pos_fill=self.config.max_seq - K)
-        self._step_counter += 1  # graftlint: disable=GL001  # stepper-thread-only
+        # stepper-thread-only; this program advances positions the
+        # dense step's device state does not see
+        self._step_counter += 1  # graftlint: disable=GL001
+        self._state_stale = True  # graftlint: disable=GL001
         tokens_j, pos_j, temp_j, topk_j, lora_j = self._upload(
             tokens, pos, temp, topk, lora_idx)
         with self._span("engine.launch"):
@@ -1453,7 +1552,10 @@ class ContinuousBatchingEngine:
             chunk[slot.index] = row
             pos[slot.index] = p
             last_idx[slot.index] = len(part) - 1
-        self._step_counter += 1  # graftlint: disable=GL001  # stepper-thread-only
+        # stepper-thread-only; this program advances positions the
+        # dense step's device state does not see
+        self._step_counter += 1  # graftlint: disable=GL001
+        self._state_stale = True  # graftlint: disable=GL001
         chunk_j, pos_j, last_j, temp_j, topk_j = self._upload(
             chunk, pos, last_idx, temp, topk)
         with self._span("engine.launch"):
@@ -1492,6 +1594,7 @@ class ContinuousBatchingEngine:
         tokens_before = self.total_generated
         self._admitted_last_step = 0
         self._blocked_s = 0.0
+        self._upload_s = 0.0
         self._steps += 1  # graftlint: disable=GL001  # stepper-thread-only
         handled = self._step_impl()
         dt = time.perf_counter() - t0
@@ -1506,7 +1609,7 @@ class ContinuousBatchingEngine:
                        {"step": self._steps, "phase": phase,
                         "slots": handled, "tokens": emitted})
         self._mbuf.note_step(phase, dt, max(0.0, dt - self._blocked_s),
-                             emitted)
+                             self._upload_s, emitted)
         return handled
 
     def record_stage(self, stage: str, seconds: float) -> None:
@@ -1587,20 +1690,26 @@ class ContinuousBatchingEngine:
             # tokens, which a fused K-step scan cannot do — dense
             # fallback while any such request is active
             return self._multi_step(active, K) + handled
-        tokens, pos, temp, topk, lora_idx = self._gather_batch(
-            active, pos_fill=self._dense_park)
-        self._step_counter += 1  # graftlint: disable=GL001  # stepper-thread-only
+        # stepper-thread-only: the RNG counter and the state's fields
+        self._step_counter += 1  # graftlint: disable=GL001
+        self.decode_steps += 1  # graftlint: disable=GL001
         want_lp = any(s.request.logprobs is not None for s in active)
-        tokens_j, pos_j, temp_j, topk_j, lora_j = self._upload(
-            tokens, pos, temp, topk, lora_idx)
+        state = self._state
+        live = tuple(s.index for s in active)
+        if self._state_stale or live != self._state_slots:
+            # a slot changed hands: the slots' own record of tokens and
+            # positions (kept up by the emit loop below) is the truth
+            (state,) = self._upload(self._gather_state(active))
+            self._state_stale = False  # graftlint: disable=GL001
+            self._state_slots = live  # graftlint: disable=GL001
+            self.state_uploads += 1  # graftlint: disable=GL001
+            self._mbuf.inc(ENGINE_STATE_UPLOADS)
         with self._span("engine.launch"):
-            sampled, chosen_lp, top_vals, top_ids, self.cache_k, \
+            self._state, chosen_lp, top_vals, top_ids, self.cache_k, \
                 self.cache_v = self._call_program(
                     "decode_lp" if want_lp else "decode", self._decode,
-                    self.params, self.cache_k, self.cache_v,
-                    tokens_j, pos_j, temp_j, topk_j,
-                    self._base_key, self._step_counter,
-                    self.lora_bank, lora_j, self._bias,
+                    self.params, self.cache_k, self.cache_v, state,
+                    self._base_key, self.lora_bank, self._bias,
                     want_lp=want_lp)
             if self._spec:
                 # keep the draft cache in lockstep through dense
@@ -1609,10 +1718,12 @@ class ContinuousBatchingEngine:
                 self.draft_cache_k, self.draft_cache_v = \
                     self._draft_sync(
                         self.draft_params, self.draft_cache_k,
-                        self.draft_cache_v, tokens_j, pos_j)
-        # dropped while the device runs (see _upload)
-        del tokens_j, pos_j, temp_j, topk_j, lora_j
-        (sampled,) = self._readback(sampled)
+                        self.draft_cache_v, state)
+        # the state this step was given, dropped while the device runs
+        # (see _upload)
+        del state
+        (sampled,) = self._readback(self._state)
+        sampled = sampled[_TOKEN]
         if want_lp:
             # only logprob requests pay the extra device-to-host syncs
             chosen_lp, top_vals, top_ids = self._readback(
@@ -1673,12 +1784,12 @@ class ContinuousBatchingEngine:
             slot.prefill_pos = 0
             slot.bias_stale = False
             slot.pending_lp = None
-        self.cache_k, self.cache_v = llama_init_cache(
-            self.config.model, self.config.max_batch, self.config.max_seq)
+        self._state = None
+        self._state_stale = True
+        self.cache_k, self.cache_v = self._fresh_cache(self.config.model)
         if self._spec:
-            self.draft_cache_k, self.draft_cache_v = llama_init_cache(
-                self.config.draft_model, self.config.max_batch,
-                self.config.max_seq)
+            self.draft_cache_k, self.draft_cache_v = self._fresh_cache(
+                self.config.draft_model)
         if self._prefix_cache is not None:
             # a failed step may have consumed donated buffers that
             # cache entries alias through sharing — drop them all
@@ -1758,6 +1869,10 @@ class ContinuousBatchingEngine:
                                   and s.prefilling),
                 "max_batch": self.config.max_batch,
                 "total_generated": self.total_generated,
+                # dense decode programs launched, and how many of them
+                # were sent their per-slot state from the host
+                "decode_steps": self.decode_steps,
+                "state_uploads": self.state_uploads,
                 # which device served, what it compiled, and whether
                 # flash attention stepped aside for any shape
                 "device": jax_backend.device_report(),

@@ -25,6 +25,8 @@ STAGES = "ray_tpu_engine_request_stage_seconds"
 TTFT = "ray_tpu_engine_ttft_seconds"
 STEP = "ray_tpu_engine_step_seconds"
 STEP_HOST = "ray_tpu_engine_step_host_seconds"
+STEP_UPLOAD = "ray_tpu_engine_step_upload_seconds"
+STATE_UPLOADS = "ray_tpu_engine_state_uploads_total"
 
 
 def _engine_config(max_batch=2, **kw):
@@ -225,6 +227,75 @@ def test_request_stages_add_up_and_host_time_is_inside_step_time():
     assert steps == engine._steps
 
 
+# -- (c2) how often the dense step's state stays on the device ------------
+
+def _counter(name):
+    for line in prometheus_text().splitlines():
+        if line.startswith(name + " "):
+            return float(line.split()[-1])
+    return 0.0
+
+
+def test_state_is_sent_once_for_each_change_of_the_slots():
+    """Decode steps with no admission and no ending send nothing; an
+    admission, an ending, a cancel and a fail_all each cost exactly one
+    state at the next dense step (a cancel is seen by the stepper one
+    step after it is made: that step still takes the device's state).
+    ``stats()`` and the two series say the same."""
+    engine = ContinuousBatchingEngine(_engine_config(max_batch=4))
+    engine.flush_metrics()
+    uploads0 = _counter(STATE_UPLOADS)
+    upload_hist0 = {phase: _hist(STEP_UPLOAD, phase=phase)
+                    for phase in ("decode", "prefill")}
+
+    def steps(n):
+        """n steps; how many states they sent."""
+        was = engine.state_uploads
+        for _ in range(n):
+            engine.step()
+        return engine.state_uploads - was
+
+    def add(max_tokens):
+        return engine.add_request(GenerationRequest(
+            prompt_ids=[1, 2, 3, max_tokens], max_tokens=max_tokens))
+
+    long_a, long_b = add(60), add(60)
+    assert steps(1) == 1          # two admissions, one state
+    assert steps(6) == 0          # k steps, nothing changed hands
+    short = add(4)
+    assert steps(1) == 1          # an admission
+    assert steps(1) == 0
+    assert steps(1) == 0 and short.done     # its fourth token: an ending
+    assert steps(1) == 1
+    assert steps(3) == 0
+    canceller = threading.Thread(target=engine.cancel, args=(long_a,))
+    canceller.start()
+    canceller.join()
+    assert steps(1) == 0          # the stepper finds it cancelled
+    assert steps(1) == 1
+    assert steps(3) == 0 and not long_b.done
+    engine.fail_all("test")
+    assert long_b.done and steps(1) == 0 and engine._state is None
+    again = add(8)
+    assert steps(1) == 1          # fail_all and the admission: one state
+    assert steps(5) == 0 and not again.done
+    stats = engine.stats()
+    assert stats["state_uploads"] == engine.state_uploads == 5
+    assert stats["decode_steps"] == engine.decode_steps == 25
+    assert _counter(STATE_UPLOADS) - uploads0 == 5
+    # the upload time is observed once a step, 0.0 where nothing was
+    # sent, so its count is the step count and its sum is the time of
+    # the few steps that sent
+    observed = 0
+    for phase in ("decode", "prefill"):
+        total, count = _hist(STEP_UPLOAD, phase=phase)
+        # prefill: three admitting steps sent prompts; decode: the two
+        # steps after an ending sent a state
+        assert total - upload_hist0[phase][0] > 0.0
+        observed += count - upload_hist0[phase][1]
+    assert observed == engine._steps == 26
+
+
 # -- (d) one step's spans in the flight recorder -------------------------
 
 @pytest.fixture
@@ -253,6 +324,21 @@ def test_step_spans_lie_inside_their_step_and_carry_its_number(recorder):
     for _seq, t0, dur, _cat, name, args in children:
         _, p0, pdur, _, _, _ = steps[args["step"]]
         assert p0 <= t0 and t0 + dur <= p0 + pdur, (name, args)
+    # a step that sends a state gathers and uploads it; one that takes
+    # the state the step before it left on the device opens neither
+    # span (here: every step that admitted nothing, as each follows an
+    # admission and nothing ends before the last)
+    sent = {n: {ev[4] for ev in children if ev[5]["step"] == n}
+            for n in steps}
+    assert engine.state_uploads == 2 and engine.decode_steps == 4
+    quiet = [n for n, ev in steps.items() if ev[5]["phase"] == "decode"]
+    assert len(quiet) == 2
+    for n, opened in sent.items():
+        if n in quiet:
+            assert opened == {"engine.launch", "engine.readback",
+                              "engine.emit"}, (n, opened)
+        else:
+            assert {"engine.gather", "engine.upload"} <= opened
     # a prefill span names the request it served, and the phases of
     # that prefill lie inside it
     prefills = [ev for ev in children if ev[4] == "engine.prefill"]

@@ -132,6 +132,295 @@ def test_decode_steps_attend_over_the_cache_as_stored(n_kv_heads):
     np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-4)
 
 
+# -- the dense step's per-slot state stays on the device -----------------
+
+def _staggered_specs(temperature):
+    """Seven requests through three slots: different lengths, two
+    admitted together, one that waits for a slot, one cancelled from
+    another thread, one that ends on a stop token, later ones that take
+    over freed slots."""
+    rng = np.random.default_rng(33)
+
+    def prompt(n):
+        return rng.integers(3, 250, size=n).tolist()
+
+    specs = [
+        dict(at=0, prompt=prompt(5), max_tokens=12),
+        dict(at=0, prompt=prompt(9), max_tokens=5),
+        dict(at=2, prompt=prompt(7), max_tokens=20, cancel_at=6),
+        dict(at=3, prompt=prompt(4), max_tokens=9),
+        dict(at=7, prompt=prompt(6), max_tokens=15, stop_at=3),
+        dict(at=9, prompt=prompt(11), max_tokens=3),
+        dict(at=12, prompt=prompt(3), max_tokens=7),
+    ]
+    for spec, top_k in zip(specs, [0, 5, 0, 3, 0, 8, 0]):
+        spec["temperature"] = temperature
+        spec["top_k"] = top_k if temperature > 0 else 0
+    return specs
+
+
+def _request_of(spec):
+    return GenerationRequest(
+        prompt_ids=list(spec["prompt"]), max_tokens=spec["max_tokens"],
+        temperature=spec["temperature"], top_k=spec["top_k"],
+        stop_ids=tuple(spec.get("stop_ids", ())))
+
+
+def _run_schedule(engine, specs, before_step=None):
+    """engine.step() by hand: spec ``at`` is the step before which the
+    request is added, ``cancel_at`` the step after which another thread
+    cancels it. Returns (output_ids, finish_reason) per spec."""
+    import threading
+    requests = [None] * len(specs)
+    for i in range(200):
+        for n, spec in enumerate(specs):
+            if spec["at"] == i:
+                requests[n] = engine.add_request(_request_of(spec))
+        if before_step is not None:
+            before_step(engine)
+        engine.step()
+        for n, spec in enumerate(specs):
+            if spec.get("cancel_at") == i:
+                thread = threading.Thread(target=engine.cancel,
+                                          args=(requests[n],))
+                thread.start()
+                thread.join()
+        if all(r is not None and r.done for r in requests):
+            break
+    return [(r.output_ids, r.finish_reason) for r in requests]
+
+
+# what the parent commit (1e1f5b6, the five arrays sent every step)
+# emitted for _staggered_specs(0.8) on an engine of seed 5
+_SAMPLED_ON_THE_PARENT = [
+    ([139, 250, 33, 96, 181, 8, 232, 45, 56, 250, 232, 220], "length"),
+    ([210, 175, 210, 47, 72], "length"),
+    ([46, 245, 90, 16, 74, 88], "abort"),
+    ([65, 232, 226, 224, 89, 89, 89, 16, 153], "length"),
+    ([215, 88, 161, 199], "stop"),
+    ([38, 214, 40], "length"),
+    ([65, 152, 234, 37, 129, 72, 234], "length"),
+]
+
+
+@pytest.mark.parametrize("temperature,every_state_from_host", [
+    (0.0, False), (0.8, False), (0.8, True)])
+def test_staggered_traffic_emits_what_each_request_gets_alone(
+        temperature, every_state_from_host):
+    """Greedy: token for token what each request gets when generated
+    alone. Sampled with a seed: what the parent commit emitted (a
+    token's key is fold_in(base_key, step_counter) split by slot, so
+    the schedule is part of the seed), also when every step is made to
+    send its state as the parent did. Whenever a step takes the state
+    the step before it left on the device, that state equals what the
+    host would have gathered from the slots."""
+    fed_back = []
+
+    def before_step(engine):
+        if every_state_from_host:
+            engine._state_stale = True
+        elif not engine._state_stale:
+            active = [s for s in engine.slots if s.request is not None]
+            engine._step_counter += 1       # as the step is about to
+            want = engine._gather_state(active)
+            engine._step_counter -= 1
+            np.testing.assert_array_equal(np.asarray(engine._state), want)
+            fed_back.append(engine._steps)
+
+    specs = _staggered_specs(temperature)
+    if temperature > 0:
+        specs[4]["stop_ids"] = (199,)
+    else:
+        # the stop token: the fourth the request emits without one
+        learn = dict(specs[4], at=0)
+        (ids, _), = _run_schedule(tiny_engine(max_batch=3, seed=5),
+                                  [learn])
+        specs[4]["stop_ids"] = (ids[specs[4]["stop_at"]],)
+    engine = tiny_engine(max_batch=3, seed=5)
+    got = _run_schedule(engine, specs, before_step)
+    assert [why for _, why in got] == [
+        "length", "length", "abort", "length", "stop", "length", "length"]
+    assert len(got[2][0]) == 6 and len(got[4][0]) == 4
+    if temperature > 0:
+        assert got == _SAMPLED_ON_THE_PARENT
+    else:
+        for spec, (ids, why) in zip(specs, got):
+            (alone, _), = _run_schedule(tiny_engine(max_batch=3, seed=5),
+                                        [dict(spec, at=0, cancel_at=None)])
+            assert ids == alone[:len(ids)], spec
+            assert len(ids) == len(alone) or why == "abort"
+    stats = engine.stats()
+    assert stats["decode_steps"] == engine.decode_steps >= 15
+    if every_state_from_host:
+        assert stats["state_uploads"] == stats["decode_steps"]
+    else:
+        # 7 admissions in 6 steps (two share one), 7 endings; a step
+        # that found the state good may still admit and send one
+        assert len(fed_back) >= \
+            stats["decode_steps"] - stats["state_uploads"] >= 4
+        assert stats["state_uploads"] <= 13
+    assert engine._decode._cache_size() == 1
+
+
+@pytest.mark.parametrize("scratch", [False, True])
+def test_parked_slots_stay_parked_over_fed_back_steps(scratch):
+    """50 decode steps, the first with a state from the host and 49 fed
+    back on the device: the parked slots stay at ``_dense_park`` (row 0,
+    or the last row when the engine keeps a scratch region) with token
+    0, the live one advances by one a step, and no step writes a row of
+    the cache outside the live slot's own new row and the parked
+    slots' park row."""
+    from ray_tpu.llm.engine import _LIVE, _POS, _TOKEN
+    engine = tiny_engine(max_batch=4, max_seq=64,
+                         **({"multi_step": 2} if scratch else {}))
+    park = engine._dense_park
+    assert park == (63 if scratch else 0)
+    # logprobs keep a multi_step engine on the dense step
+    request = engine.add_request(GenerationRequest(
+        prompt_ids=[5, 6, 7], max_tokens=52, logprobs=0))
+    engine.step()
+    assert engine.state_uploads == 1
+    slot = next(s for s in engine.slots if s.request is request)
+    parked = [s.index for s in engine.slots if s.request is None]
+    assert len(parked) == 3
+    before_k = np.asarray(engine.cache_k)
+    before_v = np.asarray(engine.cache_v)
+    pos0 = slot.pos
+    for i in range(1, 50):
+        engine.step()
+        state = np.asarray(engine._state)
+        assert (state[_POS, parked] == park).all()
+        assert not state[_TOKEN, parked].any()
+        assert not state[_LIVE, parked].any()
+        assert state[_POS, slot.index] == pos0 + i == slot.pos
+        assert state[_TOKEN, slot.index] == request.output_ids[-1]
+    assert engine.state_uploads == 1 and engine.decode_steps == 50
+    assert not request.done
+    written = np.zeros(before_k.shape[1:3], bool)            # [B, S]
+    written[slot.index, pos0:pos0 + 49] = True
+    written[parked, park] = True
+    for after, before in ((np.asarray(engine.cache_k), before_k),
+                          (np.asarray(engine.cache_v), before_v)):
+        np.testing.assert_array_equal(after[:, ~written],
+                                      before[:, ~written])
+        assert (after[:, slot.index, pos0:pos0 + 49]
+                != before[:, slot.index, pos0:pos0 + 49]).any(
+                    axis=(-1, -2)).all()
+
+
+@pytest.mark.parametrize("want_lp", [False, True])
+@pytest.mark.parametrize("params_on", [
+    "no device", "the default device", "another device", "a mesh"])
+def test_decode_is_compiled_once_whichever_way_its_state_comes(
+        params_on, want_lp):
+    """Steps that took the state from the host (after an admission and
+    after an ending) and steps that took it from the device share one
+    compiled program: the state is committed either way, as a program's
+    results are, and to where the params are: params that are committed
+    themselves (a checkpoint put on a device, also another than the
+    default one, or sharded over a mesh) change neither that nor the
+    tokens."""
+    import jax
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec
+    config = EngineConfig(
+        model=LlamaConfig.tiny(max_seq_len=64, attention="reference",
+                               remat=False), max_batch=3, max_seq=64)
+
+    def run(engine):
+        lp = 0 if want_lp else None
+        first = engine.add_request(GenerationRequest(
+            prompt_ids=[1, 2, 3], max_tokens=6, logprobs=lp))
+        for _ in range(3):
+            engine.step()
+        second = engine.add_request(GenerationRequest(
+            prompt_ids=[4, 5, 6], max_tokens=9, logprobs=lp))
+        while not (first.done and second.done):
+            engine.step()
+        return first.output_ids, second.output_ids
+
+    engine = ContinuousBatchingEngine(config)
+    devices = {jax.devices()[0]}
+    if params_on != "no device":
+        want = run(engine)
+        if params_on == "a mesh":
+            mesh = Mesh(np.array(jax.devices()[2:4]), ("tp",))
+            devices = set(mesh.devices.flat)
+
+            def put(x):     # last axis split where it can be
+                split = x.ndim >= 2 and x.shape[-1] % 2 == 0
+                return jax.device_put(x, NamedSharding(
+                    mesh, PartitionSpec(*[None] * (x.ndim - 1), "tp")
+                    if split else PartitionSpec()))
+            params = jax.tree_util.tree_map(put, engine.params)
+        else:
+            device = jax.devices()[params_on == "another device"]
+            devices = {device}
+            params = jax.device_put(engine.params, device)
+        engine = ContinuousBatchingEngine(config, params=params)
+        assert run(engine) == want
+    else:
+        run(engine)
+    assert 2 <= engine.state_uploads < engine.decode_steps
+    assert engine._state.sharding.device_set == devices
+    assert engine.cache_k.sharding.device_set == devices
+    assert engine._decode._cache_size() == 1
+    if params_on != "a mesh":
+        assert engine._insert._cache_size() == 1
+    assert list(engine.stats()["programs"]) == [
+        "prefill_4", "decode_lp" if want_lp else "decode"]
+
+
+@pytest.mark.parametrize("dense_by", ["logprobs", "adapter"])
+def test_dense_state_follows_the_slots_it_was_built_for(dense_by):
+    """Chunked prefill: a logprobs (or LoRA) request decodes on the dense
+    step beside the chunk program while a plain prompt of three chunks
+    prefills, so the dense step's state covers that one slot. When the
+    prefill is over the dense step takes every slot, with no admission
+    or ending in between: it has to notice that its state was built
+    for another set of slots. Both requests emit what they emit alone."""
+    import jax
+    from ray_tpu.models.llama import lora_init
+    config = EngineConfig(
+        model=LlamaConfig.tiny(max_seq_len=64, attention="reference",
+                               remat=False),
+        max_batch=3, max_seq=64, chunked_prefill_tokens=4,
+        max_loras=1, lora_rank=4)
+    special = {"logprobs": 0} if dense_by == "logprobs" \
+        else {"adapter": "ada"}
+    specs = [dict(prompt_ids=[1, 2, 3], max_tokens=24, **special),
+             dict(prompt_ids=list(range(5, 16)), max_tokens=10)]
+
+    def engine_with_adapter():
+        engine = ContinuousBatchingEngine(config)
+        lora = lora_init(jax.random.PRNGKey(3), config.model, rank=4)
+        lora["B_q"] = jax.random.normal(
+            jax.random.PRNGKey(4), lora["B_q"].shape,
+            dtype=config.model.dtype) * 0.5
+        engine.register_adapter("ada", lora)
+        return engine
+
+    alone = []
+    for spec in specs:
+        engine = engine_with_adapter()
+        request = engine.add_request(GenerationRequest(**spec))
+        while not request.done:
+            engine.step()
+        alone.append(request.output_ids)
+    engine = engine_with_adapter()
+    first = engine.add_request(GenerationRequest(**specs[0]))
+    for _ in range(4):
+        engine.step()
+    assert first.output_ids and not first.done
+    second = engine.add_request(GenerationRequest(**specs[1]))
+    uploads = []
+    while not (first.done and second.done):
+        engine.step()
+        uploads.append(engine.state_uploads)
+    assert [first.output_ids, second.output_ids] == alone
+    # and the state is fed back again once the set of slots is settled
+    assert len(set(uploads)) < len(uploads)
+
+
 def test_engine_greedy_deterministic():
     engine = tiny_engine()
     out1 = engine.generate([[1, 2, 3]], max_tokens=8)

@@ -202,3 +202,43 @@ def test_decode_step_attends_over_the_cache_in_place(v5e):
     whole_cache = re.escape("bf16[16,32,1024,8,128]")
     assert not re.search(
         rf"= {whole_cache}\S* (copy|custom-call)\(", text)
+
+
+@pytest.mark.parametrize("want_lp", [False, True])
+def test_engine_decode_program_feeds_its_state_back(v5e, monkeypatch,
+                                                    want_lp):
+    """The engine's own ``decode`` / ``decode_lp`` program at the two
+    serving cells' sizes, its per-slot inputs and the sampler's counter
+    one packed [7, 32] int32 state that it also returns: both caches
+    still alias their donated inputs, the temporaries stay under 256
+    MiB (the sampler's and, with logprobs, the log-softmax's [32, 32768]
+    rows), ``rms_norm`` is the one kernel, and the state comes back
+    with the shape and type it went in with, so the next step can take
+    it as it is."""
+    from ray_tpu.llm import engine as engine_mod
+    cfg = LlamaConfig(vocab_size=32768, dim=4096, n_layers=16, n_heads=32,
+                      n_kv_heads=8, hidden_dim=14336, max_seq_len=1024,
+                      rope_theta=1e6)
+    mesh = _mesh(v5e, 1)
+    params = _abstract_params(mesh, cfg)
+    cache = jax.eval_shape(lambda: llama_init_cache(cfg, 32, 1024))
+    cache_k, cache_v = _abstract(cache, jax.tree.map(
+        lambda _: NamedSharding(mesh, P()), cache))
+    # an engine around shapes: no weights and no cache are made here
+    monkeypatch.setattr(engine_mod.ContinuousBatchingEngine, "_fresh_cache",
+                        lambda self, model: (cache_k, cache_v))
+    engine = engine_mod.ContinuousBatchingEngine(
+        engine_mod.EngineConfig(model=cfg, max_batch=32, max_seq=1024),
+        params=params)
+    state = _on(mesh, P(), (7, 32), jnp.int32)
+    lowered = engine._decode.lower(
+        params, cache_k, cache_v, state, _on(mesh, P(), (2,), jnp.uint32),
+        None, _on(mesh, P(), (32, 32768), jnp.float32), want_lp=want_lp)
+    assert [k.split("(")[0] for k in _kernels(lowered)] == ["rms_norm"]
+    compiled = lowered.compile()
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes == 2 * 16 * 32 * 1024 * 8 * 128 * 2
+    assert memory.temp_size_in_bytes < 256 * 2**20
+    out_state = jax.tree.leaves(lowered.out_info)[0]
+    assert (out_state.shape, out_state.dtype) == ((7, 32), jnp.int32)
+    assert "[32,1024,8,4,128]" not in compiled.as_text()
