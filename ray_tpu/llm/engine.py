@@ -4,9 +4,25 @@ Reference: the reference serves LLMs by wrapping vLLM
 (python/ray/llm/_internal/serve/engines/vllm/vllm_engine.py —
 continuous batching, paged KV). TPU-native redesign (JetStream-style):
 
-- The KV cache is ONE static-shape array pair [L, B, S, KVH, HD] in
-  HBM: XLA-friendly, no paging indirection — slot b of the batch
-  dimension is the "page table", assigned to one request at a time.
+- The serving cache is a few static-shape arrays in HBM, each with the
+  slot on axis 1: XLA-friendly, no paging indirection — slot b of the
+  batch dimension is the "page table", assigned to one request at a
+  time. What the arrays are is the model family's business
+  (models/family.py): for the Llama family one pair of keys and values
+  [L, B, S, KVH, HD]; for the Jamba family such a pair for its few
+  attention layers beside the recurrent state of its Mamba layers
+  ([M, B, N, d_inner] float32 and the convolution's last inputs). The
+  engine holds the family's pytree as the list of its leaves, hands a
+  slot over by writing a batch-1 entry over every leaf (donated, in
+  place), and never looks inside.
+- A family whose cache holds recurrent state runs the dense path only
+  (admission, bucketed prefill told the prompt's true length, the
+  whole-batch decode step, which moves every slot's state, a parked
+  slot's too: that is junk the next admission replaces whole). Prefix
+  caching, chunked prefill, speculative and multi-step decoding,
+  LoRA banks and disaggregated prefill assume rows that can be written
+  again; the engine refuses them for such a family, by name, instead of
+  corrupting a state.
 - Decode is a single jitted step for the WHOLE batch every iteration;
   requests join (prefill into a free slot) and leave (EOS/length)
   between steps without recompiling — that is the continuous batching.
@@ -41,10 +57,11 @@ from typing import Any, Dict, List, Optional
 import numpy as np
 
 from ray_tpu.accelerators import jax_backend
+from ray_tpu.models.family import family_of, insert_slot
 from ray_tpu.models.llama import (
-    LlamaConfig, llama_decode_step, llama_init, llama_init_cache,
-    llama_prefill, llama_verify_step)
+    LlamaConfig, llama_decode_step, llama_prefill, llama_verify_step)
 from ray_tpu.ops import attention as _attention_op
+from ray_tpu.ops import selective_scan as _scan_op
 from ray_tpu.util import flight_recorder as _flight
 from ray_tpu.util import metrics as _metrics
 
@@ -91,6 +108,16 @@ ENGINE_STATE_UPLOADS = _metrics.Counter(
     "ray_tpu_engine_state_uploads_total",
     "Dense decode steps that sent the per-slot state from the host: "
     "the others took it from the decode step before them")
+ENGINE_PREFILL_TOKENS = _metrics.Counter(
+    "ray_tpu_engine_prefill_tokens_total",
+    "Positions the prefill programs computed, by kind: real (a "
+    "prompt's own tokens) or pad (what its bucket added)",
+    tag_keys=("kind",))
+ENGINE_CACHE_BYTES = _metrics.Gauge(
+    "ray_tpu_engine_cache_bytes",
+    "Bytes of the serving cache, by kind: kv (rows of keys and values) "
+    "or recurrent (state a decode step consumes and replaces)",
+    tag_keys=("kind",))
 ENGINE_TOKENS_PER_S = _metrics.Gauge(
     "ray_tpu_engine_tokens_per_second",
     "Decode throughput over the last metrics flush window")
@@ -195,8 +222,10 @@ class EngineSaturatedError(RuntimeError):
 
 @dataclass
 class EngineConfig:
-    # default vocab covers the ByteTokenizer's 258 ids (256 bytes + BOS/EOS)
-    model: LlamaConfig = field(
+    # default vocab covers the ByteTokenizer's 258 ids (256 bytes + BOS/EOS).
+    # A LlamaConfig or a JambaConfig: the engine asks the configuration's
+    # family (models/family.py) for the model's functions.
+    model: Any = field(
         default_factory=lambda: LlamaConfig.tiny(vocab_size=258))
     max_batch: int = 8
     max_seq: int = 512
@@ -409,6 +438,9 @@ class ContinuousBatchingEngine:
         self._jnp = jnp
         self.config = config
         c = config.model
+        fam = self._family = family_of(c)
+        if fam.recurrent:
+            self._refuse_for_recurrent(config)
         # name -> (jitted, abstract args, static kwargs) of the first
         # call of each hot program, and the Pallas kernels its lowered
         # text holds (filled by stats())
@@ -418,7 +450,10 @@ class ContinuousBatchingEngine:
         # under jit: eagerly, llama_init holds each stacked weight in
         # float32 twice before casting, which took a 16-layer 7B-width
         # engine to 12.75 GiB of a v5e's 15.75 at construction.
-        init = jax.jit(llama_init, static_argnums=1)
+        def init(key, model):
+            return jax.jit(family_of(model).init, static_argnums=1)(
+                key, model)
+
         if params is None:
             params = init(jax.random.PRNGKey(config.seed), c)
         if config.quantization is not None:
@@ -442,7 +477,15 @@ class ContinuousBatchingEngine:
         # are, and the caches with it: ``insert`` sees them before and
         # after the first decode step.
         self._on_device = self._sharding_beside(params)
-        self.cache_k, self.cache_v = self._fresh_cache(c)
+        # The serving cache: the family's pytree (every leaf with the
+        # slot on axis 1), kept as the list of its leaves so that a
+        # program's results go back into it by position. For the Llama
+        # family that is [cache_k, cache_v].
+        cache_shape = jax.eval_shape(
+            lambda: fam.init_cache(c, config.max_batch, config.max_seq))
+        self._cache_def = cache_def = jax.tree.structure(cache_shape)
+        self.cache_bytes = fam.cache_bytes(cache_shape)
+        self.cache = self._fresh_cache(c)
         # per-slot logit_bias rows, device-resident so the per-step
         # cost is one [B, V] add — rows are (re)set at admission, so
         # stale rows from finished requests are never read
@@ -519,9 +562,13 @@ class ContinuousBatchingEngine:
         self._state = None
         self._state_slots: tuple = ()
         self._state_stale = True
+        self.prefill_tokens = {"real": 0, "pad": 0}
         self.decode_steps = 0     # dense decode programs launched
         self.state_uploads = 0    # of them, with a state from the host
         self._mbuf = _MetricsBuffer(self)
+        for kind, nbytes in self.cache_bytes.items():
+            self._mbuf.set(ENGINE_CACHE_BYTES, float(nbytes),
+                           {"kind": kind})
         self._admitted_last_step = 0
         # step() calls so far: the number a flight-recorder
         # engine_step event and its child spans share
@@ -577,8 +624,8 @@ class ContinuousBatchingEngine:
             sampled = jnp.where(topk > 0, topk_tok, full)
             return jnp.where(temp <= 0.0, greedy, sampled)
 
-        def decode(params, cache_k, cache_v, state, base_key,
-                   lora_bank, bias, want_lp=False):
+        def decode(params, cache, state, base_key, lora_bank, bias,
+                   want_lp=False):
             """One token for every live slot. ``state`` ([7, B] int32,
             rows _TOKEN.._STEP) comes back as the next step's: a live
             slot's sampled token and its position one further, a
@@ -587,9 +634,10 @@ class ContinuousBatchingEngine:
             for the next step."""
             tokens, pos, live = state[_TOKEN], state[_POS], state[_LIVE]
             temp = jax.lax.bitcast_convert_type(state[_TEMP], jnp.float32)
-            logits, ck, cv = llama_decode_step(
-                params, tokens, cache_k, cache_v, pos, c,
-                lora_bank=lora_bank, lora_idx=state[_LORA])
+            logits, cache = fam.decode_step(
+                params, tokens, jax.tree.unflatten(cache_def, cache), pos,
+                c, lora_bank, state[_LORA])
+            cache = jax.tree.leaves(cache)
             key = jax.random.fold_in(base_key, state[_STEP, 0])
             tok = sample_tokens(logits, temp, state[_TOPK], key, bias)
             state = state.at[_TOKEN].set(tok * live).at[_POS].add(
@@ -597,17 +645,18 @@ class ContinuousBatchingEngine:
             if not want_lp:
                 # static arg: the no-logprobs program carries none of
                 # the log_softmax/top_k work or output buffers
-                return state, None, None, None, ck, cv
+                return (state, None, None, None, *cache)
             # logprobs of the biased (un-temperature-scaled) logits;
             # [B] chosen + [B, lp_k] top alternatives — tiny transfers
             lsm = jax.nn.log_softmax(
                 (logits + bias).astype(jnp.float32), axis=-1)
             chosen = jnp.take_along_axis(lsm, tok[:, None], 1)[:, 0]
             top_vals, top_ids = jax.lax.top_k(lsm, lp_k)
-            return state, chosen, top_vals, top_ids, ck, cv
+            return (state, chosen, top_vals, top_ids, *cache)
 
-        def prefill(params, tokens, lora):
-            return llama_prefill(params, tokens, c, lora=lora)
+        def prefill(params, tokens, length, lora):
+            logits, entry = fam.prefill(params, tokens, length, c, lora)
+            return (logits, *jax.tree.leaves(entry))
 
         def sample_one(logits, temp, topk, key, bias_row,
                        want_lp=False):
@@ -623,21 +672,18 @@ class ContinuousBatchingEngine:
             top_vals, top_ids = jax.lax.top_k(lsm, lp_k)
             return tok, chosen, top_vals, top_ids
 
-        def insert(cache_k, cache_v, ks, vs, slot):
-            # in-place (donated) slot write — no whole-cache copy.
-            # ks/vs: [L, 1, bucket, KVH, HD] from a batch-1 prefill.
-            ck = jax.lax.dynamic_update_slice(
-                cache_k, ks, (0, slot, 0, 0, 0))
-            cv = jax.lax.dynamic_update_slice(
-                cache_v, vs, (0, slot, 0, 0, 0))
-            return ck, cv
+        def insert(cache, entry, slot):
+            # in-place (donated) slot write, leaf by leaf: no whole-cache
+            # copy. An entry's leaves come from a batch-1 prefill (for
+            # the Llama family ks / vs of [L, 1, bucket, KVH, HD]).
+            return insert_slot(cache, entry, slot)
 
-        self._decode = jax.jit(decode, donate_argnums=(1, 2),
+        self._decode = jax.jit(decode, donate_argnums=(1,),
                                static_argnames=("want_lp",))
         self._prefill = jax.jit(prefill)
         self._sample_one = jax.jit(sample_one,
                                    static_argnames=("want_lp",))
-        self._insert = jax.jit(insert, donate_argnums=(0, 1))
+        self._insert = jax.jit(insert, donate_argnums=(0,))
 
         if config.enable_prefix_caching:
             import collections
@@ -780,6 +826,16 @@ class ContinuousBatchingEngine:
                 lambda p, t: llama_prefill(p, t, dc))
 
     # ------------------------------------------------------------------
+    @property
+    def cache_k(self):
+        """The Llama family's keys: the first leaf of ``cache``."""
+        return self.cache[0]
+
+    @property
+    def cache_v(self):
+        """The Llama family's values: the second leaf of ``cache``."""
+        return self.cache[1]
+
     def register_adapter(self, name: str, lora_params) -> None:
         """Install a LoRA adapter into the bank under ``name``
         (reference: vLLM add_lora / serve model multiplexing). The
@@ -864,6 +920,7 @@ class ContinuousBatchingEngine:
         automaton from the start state when it adopts the request, so
         prefill/decode stay consistent without shipping opaque state.
         """
+        self._refuse_disagg("prefill_only")
         limit = self._pos_limit
         ids = list(prompt_ids)[-limit:]
         if adapter is not None and adapter not in self._adapters:
@@ -875,7 +932,7 @@ class ContinuousBatchingEngine:
                                      guided=guided)
             self._validate_guided(fake)
             bias_row = self._bias_row(fake)
-        ks, vs, token, _lp = self._run_prefill(
+        (ks, vs), token, _lp = self._run_prefill(
             ids, adapter, temperature, top_k, bias_row=bias_row)
         return (np.asarray(ks), np.asarray(vs), len(ids), token)
 
@@ -884,6 +941,7 @@ class ContinuousBatchingEngine:
         """DECODE side of disaggregation: adopt a request whose prefill
         ran elsewhere — the KV block is inserted into a free slot at the
         next admit, skipping local prefill entirely."""
+        self._refuse_disagg("add_prefilled")
         if request.logprobs is not None:
             raise ValueError(
                 "logprobs are not supported on the disaggregated "
@@ -920,6 +978,11 @@ class ContinuousBatchingEngine:
         if len(request.prompt_ids) > limit:
             request.prompt_ids = request.prompt_ids[-limit:]
         if request.adapter is not None:
+            if self._family.recurrent:
+                raise ValueError(
+                    "adapter: LoRA is implemented for the Llama family's "
+                    "projections, not for a "
+                    f"{type(self.config.model).__name__}")
             self._adapter_index(request)  # fail fast on unknown names
         if request.top_k > self.config.max_top_k:
             # the sampler's static width bounds per-request top-k; make
@@ -963,10 +1026,9 @@ class ContinuousBatchingEngine:
             self._state_stale = True  # graftlint: disable=GL001  # stepper-thread-only
             self._note_admitted(request)
             self._install_bias(request, slot.index)
-            ks, vs = self._upload(ks, vs)
-            self.cache_k, self.cache_v = self._insert(
-                self.cache_k, self.cache_v, ks, vs, slot.index)
-            del ks, vs
+            entry = self._upload(ks, vs)
+            self.cache = self._insert(self.cache, entry, slot.index)
+            del entry
             if self._spec:
                 # disagg ships only the TARGET KV; rebuild the draft's
                 # prefix locally (draft prefill is cheap). The draft
@@ -1021,12 +1083,14 @@ class ContinuousBatchingEngine:
         return jax.sharding.NamedSharding(
             sharding.mesh, jax.sharding.PartitionSpec())
 
-    def _fresh_cache(self, model: LlamaConfig):
-        """An empty KV cache pair, committed to the engine's device
-        (see ``_on_device``)."""
-        return self._jax.device_put(
-            llama_init_cache(model, self.config.max_batch,
-                             self.config.max_seq), self._on_device)
+    def _fresh_cache(self, model) -> list:
+        """The leaves of an empty serving cache of ``model``'s family,
+        committed to the engine's device (see ``_on_device``)."""
+        jax = self._jax
+        return jax.device_put(
+            jax.tree.leaves(family_of(model).init_cache(
+                model, self.config.max_batch, self.config.max_seq)),
+            self._on_device)
 
     def _upload(self, *arrays) -> list:
         """Host arrays of one step onto the device, committed there
@@ -1055,6 +1119,49 @@ class ContinuousBatchingEngine:
             out = [np.asarray(a) for a in arrays]
         self._blocked_s += time.perf_counter() - t0  # graftlint: disable=GL001  # stepper-thread-only
         return out
+
+    def _note_prefill_tokens(self, real: int, pad: int) -> None:
+        """What one prefill program computed: the prompt's own
+        positions and the ones its bucket added (wasted work)."""
+        with self._lock:
+            self.prefill_tokens["real"] += real
+            self.prefill_tokens["pad"] += pad
+        self._mbuf.inc(ENGINE_PREFILL_TOKENS, float(real), {"kind": "real"})
+        self._mbuf.inc(ENGINE_PREFILL_TOKENS, float(pad), {"kind": "pad"})
+
+    @staticmethod
+    def _refuse_for_recurrent(config: EngineConfig) -> None:
+        """A family whose cache holds recurrent state runs the dense
+        path only. The other step programs and the prefix cache
+        rewrite, keep or ship rows of keys and values, and a state has
+        none: run over one they would corrupt it, so the engine says so
+        at construction."""
+        family = type(config.model).__name__
+        rows = (f"needs cache rows that can be written again or kept "
+                f"apart; the cache of a {family} holds recurrent state "
+                "that a decode step consumes")
+        llama_only = (f"is implemented for the Llama family's "
+                      f"projections, not for a {family}")
+        for option, asked, why in (
+                ("draft_model", config.draft_model is not None, rows),
+                ("multi_step", config.multi_step > 1, rows),
+                ("enable_prefix_caching", config.enable_prefix_caching,
+                 rows),
+                ("chunked_prefill_tokens",
+                 config.chunked_prefill_tokens > 0, rows),
+                ("max_loras", config.max_loras > 0, llama_only),
+                ("quantization", config.quantization is not None,
+                 llama_only)):
+            if asked:
+                raise ValueError(f"{option} {why}")
+
+    def _refuse_disagg(self, what: str) -> None:
+        if self._family.recurrent:
+            raise ValueError(
+                f"{what} ships a prompt's rows of keys and values; the "
+                f"cache of a {type(self.config.model).__name__} holds "
+                "recurrent state beside them, which the disaggregated "
+                "path does not carry")
 
     def _call_program(self, name: str, jitted, *args, **static):
         """Run a jitted program, keeping the abstract signature of its
@@ -1093,10 +1200,13 @@ class ContinuousBatchingEngine:
             lora = self._adapter_prefill.get(adapter) if adapter else None
             (tokens_dev,) = self._upload(padded)
             with self._span("engine.launch"):
-                logits, ks, vs = self._call_program(
+                logits, *entry = self._call_program(
                     f"prefill_{padded.shape[1]}", self._prefill,
-                    self.params, tokens_dev, lora)
-                last_logits = logits[0, len(ids) - 1]
+                    self.params, tokens_dev, np.int32(len(ids)), lora)
+                # a family that is told the length may return that
+                # position's row alone
+                last_logits = logits[0, min(len(ids), logits.shape[1]) - 1]
+            self._note_prefill_tokens(len(ids), padded.shape[1] - len(ids))
         else:
             # suffix-only prefill: ONE fused program pads the cached
             # prefix KV to the target bucket and scores the suffix
@@ -1118,10 +1228,12 @@ class ContinuousBatchingEngine:
             chunk_dev, start_dev = self._upload(
                 chunk, np.asarray([plen_p], dtype=np.int32))
             with self._span("engine.launch"):
-                logits, ks, vs = self._suffix_prefill(
+                logits, *entry = self._suffix_prefill(
                     self.params, cks, cvs, chunk_dev, start_dev,
                     bucket=bucket)
                 last_logits = logits[0, len(suffix) - 1]
+            self._note_prefill_tokens(len(suffix),
+                                      chunk_len - len(suffix))
         # stepper-thread-only RNG state
         self._step_counter += 1  # graftlint: disable=GL001
         bias_dev = (self._zero_bias_row if bias_row is None
@@ -1133,7 +1245,7 @@ class ContinuousBatchingEngine:
                                          self._step_counter),
                 bias_dev, want_lp=want_logprobs)
         if use_cache:
-            self._store_prefix(ids, ks, vs)
+            self._store_prefix(ids, *entry)
         if want_logprobs:
             token, chosen, top_vals, top_ids = self._readback(
                 token, chosen, top_vals, top_ids)
@@ -1141,7 +1253,7 @@ class ContinuousBatchingEngine:
         else:
             (token,) = self._readback(token)
             first_lp = None
-        return ks, vs, int(token), first_lp
+        return entry, int(token), first_lp
 
     def _validate_logit_bias(self, logit_bias) -> None:
         """Reject out-of-vocab ids on the CALLER's thread — every
@@ -1290,7 +1402,7 @@ class ContinuousBatchingEngine:
         _logits, ks, vs = self._draft_prefill(
             self.draft_params, jnp.asarray(self._pad_bucket(ids)))
         self.draft_cache_k, self.draft_cache_v = self._insert(
-            self.draft_cache_k, self.draft_cache_v, ks, vs, slot_index)
+            [self.draft_cache_k, self.draft_cache_v], [ks, vs], slot_index)
 
     def _admit(self) -> None:
         """Prefill waiting requests into free slots."""
@@ -1338,15 +1450,16 @@ class ContinuousBatchingEngine:
         if request.logit_bias or self._has_dynamic_bias(request):
             with self._span("engine.bias"):
                 bias_row = self._bias_row(request)
-        ks, vs, token, first_lp = self._run_prefill(
+        entry, token, first_lp = self._run_prefill(
             ids, request.adapter, request.temperature,
             request.top_k, bias_row=bias_row,
             want_logprobs=request.logprobs is not None)
         if request.logprobs is not None:
             slot.pending_lp = first_lp
         with self._span("engine.launch"):
-            self.cache_k, self.cache_v = self._insert(
-                self.cache_k, self.cache_v, ks, vs, slot.index)
+            with self._span("engine.insert"):
+                self.cache = self._insert(self.cache, entry, slot.index)
+            del entry
             if self._spec:
                 self._draft_prefill_slot(ids, slot.index)
                 slot.draft_ready = True
@@ -1464,8 +1577,8 @@ class ContinuousBatchingEngine:
             # one target forward scores the whole chunk
             chunk = jnp.concatenate(
                 [tokens_j[:, None], drafts_dev.T], axis=1)   # [B, G]
-            greedy, first_sampled, self.cache_k, self.cache_v = \
-                self._verify(self.params, self.cache_k, self.cache_v,
+            greedy, first_sampled, *self.cache = \
+                self._verify(self.params, *self.cache,
                              chunk, pos_j, temp_j, topk_j,
                              self._base_key, self._step_counter,
                              self._bias)
@@ -1509,8 +1622,8 @@ class ContinuousBatchingEngine:
         tokens_j, pos_j, temp_j, topk_j, lora_j = self._upload(
             tokens, pos, temp, topk, lora_idx)
         with self._span("engine.launch"):
-            toks, self.cache_k, self.cache_v = self._decode_multi(
-                self.params, self.cache_k, self.cache_v,
+            toks, *self.cache = self._decode_multi(
+                self.params, *self.cache,
                 tokens_j, pos_j, temp_j, topk_j,
                 self._base_key, self._step_counter,
                 self.lora_bank, lora_j, self._bias)
@@ -1559,8 +1672,8 @@ class ContinuousBatchingEngine:
         chunk_j, pos_j, last_j, temp_j, topk_j = self._upload(
             chunk, pos, last_idx, temp, topk)
         with self._span("engine.launch"):
-            tok, self.cache_k, self.cache_v = self._chunk_prefill(
-                self.params, self.cache_k, self.cache_v,
+            tok, *self.cache = self._chunk_prefill(
+                self.params, *self.cache,
                 chunk_j, pos_j, last_j, temp_j, topk_j,
                 self._base_key, self._step_counter, self._bias)
         del chunk_j, pos_j, last_j, temp_j, topk_j       # see _upload
@@ -1705,10 +1818,10 @@ class ContinuousBatchingEngine:
             self.state_uploads += 1  # graftlint: disable=GL001
             self._mbuf.inc(ENGINE_STATE_UPLOADS)
         with self._span("engine.launch"):
-            self._state, chosen_lp, top_vals, top_ids, self.cache_k, \
-                self.cache_v = self._call_program(
+            self._state, chosen_lp, top_vals, top_ids, *self.cache = \
+                self._call_program(
                     "decode_lp" if want_lp else "decode", self._decode,
-                    self.params, self.cache_k, self.cache_v, state,
+                    self.params, self.cache, state,
                     self._base_key, self.lora_bank, self._bias,
                     want_lp=want_lp)
             if self._spec:
@@ -1786,7 +1899,7 @@ class ContinuousBatchingEngine:
             slot.pending_lp = None
         self._state = None
         self._state_stale = True
-        self.cache_k, self.cache_v = self._fresh_cache(self.config.model)
+        self.cache = self._fresh_cache(self.config.model)
         if self._spec:
             self.draft_cache_k, self.draft_cache_v = self._fresh_cache(
                 self.config.draft_model)
@@ -1829,11 +1942,10 @@ class ContinuousBatchingEngine:
             raise ValueError("cannot embed an empty prompt")
         if self._embed_fn is None:
             c = self.config.model
-            from ray_tpu.models.llama import llama_forward
+            hidden = self._family.hidden
 
             def emb(params, tokens, n):
-                h = llama_forward(params, tokens, c,
-                                  return_hidden=True)       # [1, S, D]
+                h = hidden(params, tokens, c)               # [1, S, D]
                 mask = (jnp.arange(tokens.shape[1])
                         < n)[None, :, None].astype(h.dtype)
                 pooled = (jnp.sum(h * mask, axis=1)
@@ -1878,6 +1990,11 @@ class ContinuousBatchingEngine:
                 "device": jax_backend.device_report(),
                 "programs": dict(self._program_kernels),
                 "flash_fallbacks": list(_attention_op.kernel_fallbacks),
+                "scan_fallbacks": list(_scan_op.kernel_fallbacks),
+                # positions the prefill programs computed, a prompt's
+                # own and what its bucket added; the cache by kind
+                "prefill_tokens": dict(self.prefill_tokens),
+                "cache_bytes": dict(self.cache_bytes),
             }
             if self._prefix_cache is not None:
                 out["prefix_cache_entries"] = len(self._prefix_cache)

@@ -15,6 +15,7 @@ from ray_tpu.models.dit import (
     dit_sample,
     dit_sharding_rules,
 )
+from ray_tpu.models.jamba import JambaConfig, jamba_forward, jamba_init
 from ray_tpu.models.mlp import MLPConfig, mlp_forward, mlp_init
 from ray_tpu.models.vit import (
     CLIPConfig,

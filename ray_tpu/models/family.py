@@ -1,0 +1,124 @@
+"""What the serving engine asks of a language-model family.
+
+The engine's dense path (admission, bucketed prefill, the whole-batch
+decode step, the sampler) knows no model: it asks the family of
+``EngineConfig.model`` for the functions below and handles the serving
+cache as an opaque pytree whose every leaf has the slot on axis 1. A
+slot is handed over by writing a batch-1 entry of that pytree over the
+slot's part of every leaf (``insert_slot``).
+
+``recurrent`` says whether the cache holds state that a step consumes
+(a state-space mixer's). Such a cache has no rows that can be written
+again or kept apart, which the engine's other step programs and its
+prefix cache assume: they stay with families whose cache is rows of
+keys and values, and the engine refuses them for a recurrent one.
+"""
+
+from __future__ import annotations
+
+import functools
+from dataclasses import dataclass
+from typing import Any, Callable, Dict
+
+import jax
+
+
+@dataclass(frozen=True)
+class ModelFamily:
+    # (rng, config) -> params
+    init: Callable
+    # (params, tokens [B, S], config) -> final-norm hidden [B, S, dim]
+    hidden: Callable
+    # (config, batch, max_seq) -> cache
+    init_cache: Callable
+    # (params, tokens [1, bucket], length, config, lora) -> (logits
+    # [1, n, vocab] float32, entry). ``length`` is the prompt's true
+    # length, traced; a family that has no use for it ignores it and
+    # its programs do not hold it. n is the bucket, or 1 where the
+    # family returns the row of position length - 1 alone.
+    prefill: Callable
+    # (params, token [B], cache, pos [B], config, lora_bank, lora_idx)
+    # -> (logits [B, vocab] float32, cache); the caller donates cache
+    decode_step: Callable
+    # cache -> bytes by kind, {"kv": ..., "recurrent": ...}
+    cache_bytes: Callable
+    recurrent: bool
+
+
+def insert_slot(cache, entry, slot):
+    """``entry`` (a cache of one slot; its leaves may be shorter than
+    the cache's on the later axes, as a bucket is shorter than max_seq)
+    written over slot ``slot`` of ``cache``, leaf by leaf. Under jit
+    with ``cache`` donated this is in place."""
+    def write(c, e):
+        return jax.lax.dynamic_update_slice(
+            c, e.astype(c.dtype), (0, slot) + (0,) * (c.ndim - 2))
+    return jax.tree.map(write, cache, entry)
+
+
+def _nbytes(leaves) -> int:
+    return int(sum(x.size * x.dtype.itemsize for x in leaves))
+
+
+@functools.cache
+def _llama() -> ModelFamily:
+    from ray_tpu.models import llama
+
+    def prefill(params, tokens, length, config, lora):
+        logits, ks, vs = llama.llama_prefill(params, tokens, config,
+                                             lora=lora)
+        return logits, (ks, vs)
+
+    def decode_step(params, token, cache, pos, config, lora_bank,
+                    lora_idx):
+        logits, ck, cv = llama.llama_decode_step(
+            params, token, *cache, pos, config, lora_bank=lora_bank,
+            lora_idx=lora_idx)
+        return logits, (ck, cv)
+
+    return ModelFamily(
+        init=llama.llama_init,
+        hidden=lambda params, tokens, config: llama.llama_forward(
+            params, tokens, config, return_hidden=True),
+        init_cache=llama.llama_init_cache, prefill=prefill,
+        decode_step=decode_step,
+        cache_bytes=lambda cache: {"kv": _nbytes(cache), "recurrent": 0},
+        recurrent=False)
+
+
+@functools.cache
+def _jamba() -> ModelFamily:
+    from ray_tpu.models import jamba
+
+    def prefill(params, tokens, length, config, lora):
+        return jamba.jamba_prefill(params, tokens, length, config)
+
+    def decode_step(params, token, cache, pos, config, lora_bank,
+                    lora_idx):
+        return jamba.jamba_decode_step(params, token, cache, pos, config)
+
+    return ModelFamily(
+        init=jamba.jamba_init,
+        hidden=lambda params, tokens, config: jamba.jamba_forward(
+            params, tokens, config, return_hidden=True),
+        init_cache=jamba.jamba_init_cache, prefill=prefill,
+        decode_step=decode_step,
+        cache_bytes=lambda cache: {
+            "kv": _nbytes([cache["k"], cache["v"]]),
+            "recurrent": _nbytes([cache["ssm"], cache["conv"]])},
+        recurrent=True)
+
+
+_FAMILIES: Dict[str, Callable[[], ModelFamily]] = {
+    "LlamaConfig": _llama, "JambaConfig": _jamba}
+
+
+def family_of(config: Any) -> ModelFamily:
+    """The family of a model configuration, by the configuration's
+    class (LlamaConfig, JambaConfig)."""
+    try:
+        return _FAMILIES[type(config).__name__]()
+    except KeyError:
+        raise TypeError(
+            f"no model family for a {type(config).__name__} "
+            f"(have {sorted(_FAMILIES)})") from None

@@ -29,7 +29,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from ray_tpu.accelerators import jax_backend
-from ray_tpu.ops.attention import flash_attention
+from ray_tpu.ops.attention import decode_attention, flash_attention
 from ray_tpu.ops.rmsnorm import rms_norm
 from ray_tpu.ops.rope import apply_rope, rope_frequencies
 from ray_tpu.parallel.sharding import ShardingRules
@@ -630,15 +630,8 @@ def llama_decode_step(params, token, cache_k, cache_v, pos,
             cache_v = cache_v.at[l, slots, pos].set(v[:, 0])
             ck = jax.lax.dynamic_index_in_dim(cache_k, l, keepdims=False)
             cv = jax.lax.dynamic_index_in_dim(cache_v, l, keepdims=False)
-            # [B,S,KVH,HD] as stored; a group is the n_rep query heads
-            # of one KV head (n_rep == 1: groups of one)
-            scores = jnp.einsum("bgrd,bsgd->bgrs",
-                                q.reshape(b, kvh, n_rep, hd), ck,
-                                preferred_element_type=jnp.float32)
-            scores = scores * (hd ** -0.5)
-            scores = jnp.where(visible[:, None, None, :], scores, -1e30)
-            weights = jax.nn.softmax(scores, axis=-1).astype(c.dtype)
-            attn = jnp.einsum("bgrs,bsgd->bgrd", weights, cv)
+            attn = decode_attention(q.reshape(b, kvh, n_rep, hd), ck, cv,
+                                    visible, c.dtype)
             x = x + (attn.reshape(b, 1, c.n_heads * hd)
                      @ layer_params["wo"])
         with jax.named_scope(SCOPE_FFN):

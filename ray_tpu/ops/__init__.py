@@ -1,7 +1,9 @@
 """TPU compute kernels (Pallas) with jnp references.
 
 The hot ops of the transformer stack: fused attention (flash),
-fused RMSNorm, rotary embeddings, weight-only int8 matmul. Each op
+fused RMSNorm, rotary embeddings, weight-only int8 matmul, the
+selective scan of a Mamba-1 mixer (ops/selective_scan.py, forward
+only; imported as a module, its function bears the module's name). Each op
 exposes a reference implementation used for tests/CPU and a Pallas
 TPU kernel selected automatically on TPU backends."""
 

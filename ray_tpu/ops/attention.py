@@ -473,3 +473,18 @@ def flash_attention(q, k, v, causal: bool = True, mesh=None):
         lambda q_, k_, v_: _flash(q_, k_, v_, causal), mesh=mesh,
         in_specs=(spec, spec, spec), out_specs=spec,
         check_vma=False)(q, k, v)
+
+
+def decode_attention(q, k, v, visible, dtype):
+    """One query position for every slot against the cache as it is
+    stored. q: [B, KVH, n_rep, HD], the query heads grouped by the KV
+    head they share (n_rep == 1: groups of one); k, v: [B, S, KVH, HD];
+    visible: [B, S] bool, the rows a slot may attend. -> [B, KVH,
+    n_rep, HD]. No K or V is expanded to the query's heads. Plain XLA:
+    the decode programs of every model family share it."""
+    scores = jnp.einsum("bgrd,bsgd->bgrs", q, k,
+                        preferred_element_type=jnp.float32)
+    scores = scores * (q.shape[-1] ** -0.5)
+    scores = jnp.where(visible[:, None, None, :], scores, -1e30)
+    weights = jax.nn.softmax(scores, axis=-1).astype(dtype)
+    return jnp.einsum("bgrs,bsgd->bgrd", weights, v)

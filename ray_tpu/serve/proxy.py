@@ -241,12 +241,22 @@ def _make_handler(state: _ProxyState):
     return Handler
 
 
+class _ProxyServer(ThreadingHTTPServer):
+    # socketserver listens with a backlog of 5. A burst of connections
+    # (a client catching up after a stall, sessions opening together)
+    # overflows it, the kernel drops the SYNs, and each client retries
+    # after 1, 3, 7, 15, 31 s: on the chip's machine 58 requests sent at
+    # once waited that long for a server that had capacity, and two
+    # were never answered (PERF.md, PR 34).
+    request_queue_size = 1024
+
+
 class HttpProxy:
     def __init__(self, controller, host: str = "127.0.0.1",
                  port: int = 8000):
         self.state = _ProxyState(controller)
-        self.server = ThreadingHTTPServer((host, port),
-                                          _make_handler(self.state))
+        self.server = _ProxyServer((host, port),
+                                   _make_handler(self.state))
         self.port = self.server.server_address[1]
         self._thread = threading.Thread(target=self.server.serve_forever,
                                         daemon=True)

@@ -319,8 +319,8 @@ def test_step_spans_lie_inside_their_step_and_carry_its_number(recorder):
     children = [ev for ev in events if ev[4].startswith("engine.")]
     names = {ev[4] for ev in children}
     assert names == {"engine.prefill", "engine.bias", "engine.gather",
-                     "engine.upload", "engine.launch", "engine.readback",
-                     "engine.emit"}
+                     "engine.upload", "engine.launch", "engine.insert",
+                     "engine.readback", "engine.emit"}
     for _seq, t0, dur, _cat, name, args in children:
         _, p0, pdur, _, _, _ = steps[args["step"]]
         assert p0 <= t0 and t0 + dur <= p0 + pdur, (name, args)

@@ -485,3 +485,26 @@ def test_prefix_aware_routing_affinity(ray_start_shared):
         handle.remote({"prompt": "zzz different"}).result(timeout_s=60)
     finally:
         serve.shutdown()
+
+
+def test_proxy_listens_with_room_for_a_burst_of_connections():
+    """64 clients connect at once while nobody accepts (the accept
+    loop is not even started): every handshake completes out of the
+    listen backlog. socketserver's default of 5 let the kernel drop the
+    rest, and a dropped SYN is retried after 1, 3, 7, 15, 31 s."""
+    import socket
+    from http.server import BaseHTTPRequestHandler
+    from ray_tpu.serve.proxy import _ProxyServer
+    server = _ProxyServer(("127.0.0.1", 0), BaseHTTPRequestHandler)
+    clients = []
+    try:
+        for _ in range(64):
+            client = socket.socket()
+            client.settimeout(0.5)
+            client.connect(server.server_address)
+            clients.append(client)
+    finally:
+        for client in clients:
+            client.close()
+        server.server_close()
+    assert len(clients) == 64

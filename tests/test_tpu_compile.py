@@ -232,7 +232,7 @@ def test_engine_decode_program_feeds_its_state_back(v5e, monkeypatch,
         params=params)
     state = _on(mesh, P(), (7, 32), jnp.int32)
     lowered = engine._decode.lower(
-        params, cache_k, cache_v, state, _on(mesh, P(), (2,), jnp.uint32),
+        params, [cache_k, cache_v], state, _on(mesh, P(), (2,), jnp.uint32),
         None, _on(mesh, P(), (32, 32768), jnp.float32), want_lp=want_lp)
     assert [k.split("(")[0] for k in _kernels(lowered)] == ["rms_norm"]
     compiled = lowered.compile()
@@ -242,3 +242,57 @@ def test_engine_decode_program_feeds_its_state_back(v5e, monkeypatch,
     out_state = jax.tree.leaves(lowered.out_info)[0]
     assert (out_state.shape, out_state.dtype) == ((7, 32), jnp.int32)
     assert "[32,1024,8,4,128]" not in compiled.as_text()
+
+
+def test_jamba_serving_programs_compile_at_published_widths(v5e):
+    """The Jamba cell's engine programs as the chip gets them
+    (AI21-Jamba2-3B whole: 26 Mamba layers and 2 of attention, batch
+    32, seq 1024): the decode program through the engine's family seam
+    holds ``rms_norm`` alone, aliases the whole cache of two kinds
+    (donated, so no state and no row is copied) and keeps its
+    temporaries small; a prefill program holds its bucket's scan kernel
+    beside flash attention, and everything fits one chip."""
+    from ray_tpu.llm import engine as engine_mod
+    from ray_tpu.models.jamba import (JambaConfig, jamba_init,
+                                      jamba_init_cache)
+    from ray_tpu.ops import selective_scan
+    cfg = JambaConfig(max_seq_len=1024)
+    mesh = _mesh(v5e, 1)
+
+    def on_chip(tree):
+        return _abstract(tree, jax.tree.map(
+            lambda _: NamedSharding(mesh, P()), tree))
+
+    params = on_chip(jax.eval_shape(
+        lambda key: jamba_init(key, cfg), jax.random.PRNGKey(0)))
+    cache = jax.tree.leaves(on_chip(jax.eval_shape(
+        lambda: jamba_init_cache(cfg, 32, 1024))))
+    with pytest.MonkeyPatch.context() as patch:
+        # an engine around shapes: no weights and no cache are made here
+        patch.setattr(engine_mod.ContinuousBatchingEngine, "_fresh_cache",
+                      lambda self, model: cache)
+        engine = engine_mod.ContinuousBatchingEngine(
+            engine_mod.EngineConfig(model=cfg, max_batch=32, max_seq=1024),
+            params=params)
+    lowered = engine._decode.lower(
+        params, cache, _on(mesh, P(), (7, 32), jnp.int32),
+        _on(mesh, P(), (2,), jnp.uint32), None,
+        _on(mesh, P(), (32, 65536), jnp.float32), want_lp=False)
+    assert [k.split("(")[0] for k in _kernels(lowered)] == ["rms_norm"]
+    memory = lowered.compile().memory_analysis()
+    # the K/V rows of 2 layers and the state of 26: all of it in place
+    kv = 2 * 2 * 32 * 1024 * 128 * 2
+    state = 26 * 32 * 5120 * (16 * 4 + 3 * 2)
+    assert kv + state <= memory.alias_size_in_bytes <= 1.2 * (kv + state)
+    assert memory.temp_size_in_bytes < 256 * 2**20
+    assert (memory.argument_size_in_bytes
+            + memory.temp_size_in_bytes) < HBM_BYTES // 2
+    lowered = engine._prefill.lower(
+        params, _on(mesh, P(), (1, 256), jnp.int32),
+        _on(mesh, P(), (), jnp.int32), None)
+    assert [k.split("(")[0] for k in _kernels(lowered)] == [
+        "flash_fwd", "rms_norm", "selective_scan_256"]
+    memory = lowered.compile().memory_analysis()
+    assert memory.temp_size_in_bytes < 512 * 2**20
+    assert selective_scan.kernel_fallbacks == []
+    assert attention.kernel_fallbacks == []
