@@ -31,10 +31,19 @@ from ray_tpu.llm.guided import (
     tool_call_constraint)
 from ray_tpu.llm.tokenizer import get_tokenizer
 from ray_tpu.serve.proxy import RECEIVED_KEY
+from ray_tpu.util import metrics as _metrics
 
 
 # cap on per-replica compiled guided-decoding constraints (LRU)
 _MAX_CONSTRAINTS = 32
+
+STREAM_TOKENS = _metrics.Counter(
+    "ray_tpu_serve_llm_stream_tokens_total",
+    "Tokens streamed per-token (stream_token_deltas), by kind: "
+    "immediate (the chunk left when the token was sampled) or held "
+    "(the decoded text ended inside a character, so the chunk waited "
+    "for the next token or the end of the stream)",
+    tag_keys=("kind",))
 
 
 @dataclass
@@ -95,39 +104,50 @@ def stream_text_deltas(tokenizer, request):
         yield final[len(emitted):]
 
 
-def stream_token_deltas(tokenizer, request):
+def stream_token_deltas(tokenizer, request, counted=None):
     """Like :func:`stream_text_deltas`, but yields exactly ONE delta per
     non-stop generated token — the contract the OpenAI SSE surface
     advertises ("per-token chunks"). When a token lands mid-way through
     a multi-byte character the decoded tail is U+FFFD; the text-delta
     variant silently merges it into the next token's delta, shifting
-    chunk counts. Here the incomplete token yields ``""`` and the text
-    catches up on a later token, via one-token lookahead so the final
-    token's delta can absorb any held-back tail."""
+    chunk counts. Here a token's delta is yielded as soon as the token
+    is on the queue, unless the text then ends in U+FFFD: that token
+    (and only it) is held until its successor arrives, yields ``""``
+    then, and the text catches up on the token that completes the
+    character, or on the held one when the stream ends inside it.
+
+    ``counted(immediate, held)`` is called once, when the generator
+    ends or is closed: how many tokens' deltas left at once and how
+    many waited."""
     out_ids: List[int] = []
-    emitted = ""
-    pending = False
-    while True:
-        token = request.stream_queue.get()
-        if token is None:
-            break
-        if token in request.stop_ids:
-            continue
-        if pending:
-            text = tokenizer.decode(out_ids)
-            if text.endswith("�"):
+    text = emitted = ""
+    held = False  # the newest token's delta has not been yielded yet
+    n_held = 0
+    try:
+        while True:
+            token = request.stream_queue.get()
+            if token is None:
+                break
+            if token in request.stop_ids:
+                continue
+            if held:
                 yield ""
+            out_ids.append(token)
+            text = tokenizer.decode(out_ids)
+            held = text.endswith("\ufffd")
+            if held:
+                n_held += 1
             else:
                 delta = text[len(emitted):]
                 emitted = text
                 yield delta
-        out_ids.append(token)
-        pending = True
-    if request.error is not None:
-        raise RuntimeError(request.error)
-    if pending:
-        final = tokenizer.decode(out_ids)
-        yield final[len(emitted):]
+        if request.error is not None:
+            raise RuntimeError(request.error)
+        if held:
+            yield text[len(emitted):]
+    finally:
+        if counted is not None:
+            counted(len(out_ids) - n_held, n_held)
 
 
 class LLMServer:
@@ -832,7 +852,8 @@ class LLMServer:
             # reads output_ids after the stream drains
             request_sink["request"] = request
             request_sink["prompt_tokens"] = len(_ids)
-        deltas = stream_token_deltas(self.tokenizer, request)
+        deltas = stream_token_deltas(self.tokenizer, request,
+                                     self._count_streamed)
         if not stop:
             yield from deltas
             return
@@ -854,6 +875,14 @@ class LLMServer:
                 emitted = safe
         if len(text) > emitted:
             yield text[emitted:]
+
+    def _count_streamed(self, immediate: int, held: int) -> None:
+        """One finished stream's tokens into the engine's metrics
+        buffer, beside the request stages: its flush thread ships
+        them, never a replica's request thread one token at a time."""
+        buffer = self.engine._mbuf
+        buffer.inc(STREAM_TOKENS, float(immediate), {"kind": "immediate"})
+        buffer.inc(STREAM_TOKENS, float(held), {"kind": "held"})
 
     # -- OpenAI-compatible surface (routed by path) --------------------
     def __call__(self, request: Dict[str, Any]) -> Dict[str, Any]:
