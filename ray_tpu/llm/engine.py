@@ -52,7 +52,7 @@ import weakref
 from ray_tpu.devtools import locktrace
 import time
 from dataclasses import dataclass, field
-from typing import Any, Dict, List, Optional
+from typing import Any, Dict, List, NamedTuple, Optional
 
 import numpy as np
 
@@ -113,6 +113,14 @@ ENGINE_PREFILL_TOKENS = _metrics.Counter(
     "Positions the prefill programs computed, by kind: real (a "
     "prompt's own tokens) or pad (what its bucket added)",
     tag_keys=("kind",))
+ENGINE_ADMIT_LAUNCH_SECONDS = _metrics.Histogram(
+    "ray_tpu_engine_admit_launch_seconds",
+    "Time from the stepper's pop of a waiting request to the return of "
+    "its prefill program's dispatch: how long the host kept the device "
+    "from this prompt's prefill. overlapped=1 where the prompt admitted "
+    "before it was still being prefilled, so the time cost the device "
+    "nothing",
+    boundaries=_STEP_BOUNDS, tag_keys=("overlapped",))
 ENGINE_CACHE_BYTES = _metrics.Gauge(
     "ray_tpu_engine_cache_bytes",
     "Bytes of the serving cache, by kind: kv (rows of keys and values) "
@@ -427,6 +435,23 @@ class _Slot:
         self.pending_lp = None
 
 
+class _Launched(NamedTuple):
+    """What ``_run_prefill`` left queued on the device for one prompt."""
+    entry: list       # the prompt's cache entry, leaf by leaf
+    bias: Any         # the request's [V] row (the shared zero row if none)
+    sampled: tuple    # (token, chosen_lp, top_vals, top_ids) of
+    #                   sample_one; without logprobs the last three None
+    t_launched: float  # perf_counter() when the prefill's dispatch returned
+
+
+class _Admission(NamedTuple):
+    """A prompt in its slot with every program of its admission queued
+    and its first token not read yet."""
+    slot: _Slot
+    sampled: tuple    # as in _Launched
+    span: Any         # its engine.prefill span, open until the token is out
+
+
 class ContinuousBatchingEngine:
     def __init__(self, config: EngineConfig, params=None,
                  draft_params=None):
@@ -489,8 +514,7 @@ class ContinuousBatchingEngine:
         # per-slot logit_bias rows, device-resident so the per-step
         # cost is one [B, V] add — rows are (re)set at admission, so
         # stale rows from finished requests are never read
-        self._bias = jnp.zeros((config.max_batch, c.vocab_size),
-                               jnp.float32)
+        self._bias, self._zero_bias_row = self._fresh_bias()
 
         def set_bias_row(bias, row, idx):
             return jax.lax.dynamic_update_slice(
@@ -500,7 +524,6 @@ class ContinuousBatchingEngine:
         # dynamic starts, so ONE compile covers every slot (a static
         # idx would compile per slot index)
         self._set_bias = jax.jit(set_bias_row, donate_argnums=(0,))
-        self._zero_bias_row = jnp.zeros((c.vocab_size,), jnp.float32)
         # Scratch region: every batched dispatch writes K/V rows for
         # ALL slots, so slots not participating park their writes in
         # the cache tail. Those rows must never hold live history —
@@ -565,6 +588,10 @@ class ContinuousBatchingEngine:
         self.prefill_tokens = {"real": 0, "pad": 0}
         self.decode_steps = 0     # dense decode programs launched
         self.state_uploads = 0    # of them, with a state from the host
+        # prompts admitted through a prefill of their own, and how many
+        # of them were launched under the prefill of the one before
+        self.admissions = 0
+        self.admissions_overlapped = 0
         self._mbuf = _MetricsBuffer(self)
         for kind, nbytes in self.cache_bytes.items():
             self._mbuf.set(ENGINE_CACHE_BYTES, float(nbytes),
@@ -925,15 +952,14 @@ class ContinuousBatchingEngine:
         ids = list(prompt_ids)[-limit:]
         if adapter is not None and adapter not in self._adapters:
             raise ValueError(f"unknown LoRA adapter {adapter!r}")
-        bias_row = None
-        if logit_bias or guided is not None:
-            self._validate_logit_bias(logit_bias)
-            fake = GenerationRequest(prompt_ids=[], logit_bias=logit_bias,
-                                     guided=guided)
-            self._validate_guided(fake)
-            bias_row = self._bias_row(fake)
-        (ks, vs), token, _lp = self._run_prefill(
-            ids, adapter, temperature, top_k, bias_row=bias_row)
+        self._validate_logit_bias(logit_bias)
+        fake = GenerationRequest(prompt_ids=[], logit_bias=logit_bias,
+                                 guided=guided)
+        self._validate_guided(fake)
+        launched = self._run_prefill(ids, adapter, temperature, top_k,
+                                     biased_as=fake)
+        token, _lp = self._read_first_token(launched.sampled)
+        ks, vs = launched.entry
         return (np.asarray(ks), np.asarray(vs), len(ids), token)
 
     def add_prefilled(self, request: GenerationRequest, ks, vs,
@@ -1092,6 +1118,18 @@ class ContinuousBatchingEngine:
                 model, self.config.max_batch, self.config.max_seq)),
             self._on_device)
 
+    def _fresh_bias(self) -> list:
+        """The slots' [B, V] bias rows and the [V] row of a request that
+        biases nothing, zeroed and committed like an uploaded row (see
+        ``_on_device``): a biased and an unbiased admission run the same
+        ``set_bias_row`` and ``sample_one``."""
+        jnp = self._jnp
+        c = self.config
+        vocab = c.model.vocab_size
+        return self._jax.device_put(
+            [jnp.zeros((c.max_batch, vocab), jnp.float32),
+             jnp.zeros((vocab,), jnp.float32)], self._on_device)
+
     def _upload(self, *arrays) -> list:
         """Host arrays of one step onto the device, committed there
         (see ``_on_device``); the time it takes is the step's upload
@@ -1179,11 +1217,21 @@ class ContinuousBatchingEngine:
 
     def _run_prefill(self, ids: List[int], adapter: Optional[str],
                      temperature: float, top_k: int,
-                     bias_row=None, want_logprobs: bool = False):
-        """Shared prefill: bucket/pad the prompt, run the jitted
-        prefill, sample the first token. Both the colocated admit path
-        and prefill_only (disaggregation) call this — one copy, so the
-        exact-parity guarantee between the two modes can't drift."""
+                     biased_as: Optional[GenerationRequest] = None,
+                     want_logprobs: bool = False) -> _Launched:
+        """Shared prefill: bucket/pad the prompt, launch the jitted
+        prefill, then queue the first token's sampling behind it. Both
+        the colocated admit path and prefill_only (disaggregation) call
+        this — one copy, so the exact-parity guarantee between the two
+        modes can't drift.
+
+        The device starts on the prompt before the host does anything
+        the prefill does not need: ``biased_as``'s [V] row is built and
+        sent while the prefill runs, once, and the one device array
+        samples the first token here and is the slot's row for the
+        caller to install. Nothing here waits for the device: the
+        caller reads the token with ``_read_first_token`` when it has
+        queued what else it has for the device."""
         use_cache = self._prefix_cache is not None and adapter is None
         hit = self._match_prefix(ids) if use_cache else None
         if hit is not None:
@@ -1203,9 +1251,9 @@ class ContinuousBatchingEngine:
                 logits, *entry = self._call_program(
                     f"prefill_{padded.shape[1]}", self._prefill,
                     self.params, tokens_dev, np.int32(len(ids)), lora)
-                # a family that is told the length may return that
-                # position's row alone
-                last_logits = logits[0, min(len(ids), logits.shape[1]) - 1]
+            # a family that is told the length may return that
+            # position's row alone
+            last = min(len(ids), logits.shape[1]) - 1
             self._note_prefill_tokens(len(ids), padded.shape[1] - len(ids))
         else:
             # suffix-only prefill: ONE fused program pads the cached
@@ -1231,29 +1279,34 @@ class ContinuousBatchingEngine:
                 logits, *entry = self._suffix_prefill(
                     self.params, cks, cvs, chunk_dev, start_dev,
                     bucket=bucket)
-                last_logits = logits[0, len(suffix) - 1]
+            last = len(suffix) - 1
             self._note_prefill_tokens(len(suffix),
                                       chunk_len - len(suffix))
+        t_launched = time.perf_counter()
+        bias_dev = self._bias_on_device(biased_as)
         # stepper-thread-only RNG state
         self._step_counter += 1  # graftlint: disable=GL001
-        bias_dev = (self._zero_bias_row if bias_row is None
-                    else self._upload(bias_row)[0])
         with self._span("engine.launch"):
-            token, chosen, top_vals, top_ids = self._sample_one(
-                last_logits, float(temperature), int(top_k),
+            sampled = self._sample_one(
+                logits[0, last], float(temperature), int(top_k),
                 self._jax.random.fold_in(self._base_key,
                                          self._step_counter),
                 bias_dev, want_lp=want_logprobs)
         if use_cache:
             self._store_prefix(ids, *entry)
-        if want_logprobs:
-            token, chosen, top_vals, top_ids = self._readback(
-                token, chosen, top_vals, top_ids)
-            first_lp = (float(chosen), top_vals, top_ids)
-        else:
+        return _Launched(entry, bias_dev, sampled, t_launched)
+
+    def _read_first_token(self, sampled: tuple):
+        """Wait for what ``_run_prefill`` sampled: (token, its
+        logprobs or None). Only a logprobs request reads more than the
+        token."""
+        token, chosen, top_vals, top_ids = sampled
+        if chosen is None:
             (token,) = self._readback(token)
-            first_lp = None
-        return entry, int(token), first_lp
+            return int(token), None
+        token, chosen, top_vals, top_ids = self._readback(
+            token, chosen, top_vals, top_ids)
+        return int(token), (float(chosen), top_vals, top_ids)
 
     def _validate_logit_bias(self, logit_bias) -> None:
         """Reject out-of-vocab ids on the CALLER's thread — every
@@ -1321,17 +1374,24 @@ class ContinuousBatchingEngine:
             row = row + penalty
         return row
 
+    def _bias_on_device(self, request: Optional[GenerationRequest]):
+        """``request``'s [V] row on the device, built and sent here;
+        the shared zero row, with no host build or copy, for a request
+        that biases nothing (or none)."""
+        if request is None or not (request.logit_bias
+                                   or self._has_dynamic_bias(request)):
+            return self._zero_bias_row
+        with self._span("engine.bias"):
+            (row,) = self._upload(self._bias_row(request))
+        return row
+
     def _install_bias(self, request: GenerationRequest,
-                      slot_index: int) -> None:
-        if request.logit_bias or self._has_dynamic_bias(request):
-            with self._span("engine.bias"):
-                self._bias = self._set_bias(
-                    self._bias, self._jnp.asarray(self._bias_row(request)),
-                    self._jnp.asarray(slot_index))
-            return
-        # no per-request host build/copy
-        self._bias = self._set_bias(self._bias, self._zero_bias_row,
-                                    self._jnp.asarray(slot_index))
+                      slot_index: int, row=None) -> None:
+        """Make ``row`` (by default the request's, built now) the
+        slot's row for the decode steps to come."""
+        if row is None:
+            row = self._bias_on_device(request)
+        self._bias = self._set_bias(self._bias, row, np.int32(slot_index))
 
     def _bucket_len(self, n: int) -> int:
         bucket = 1
@@ -1405,32 +1465,49 @@ class ContinuousBatchingEngine:
             [self.draft_cache_k, self.draft_cache_v], [ks, vs], slot_index)
 
     def _admit(self) -> None:
-        """Prefill waiting requests into free slots."""
+        """Prefill waiting requests into free slots, two deep: the next
+        waiting prompt that has a free slot is queued on the device
+        before the host waits for the first token of the one before it,
+        so the device goes from one prompt's programs to the next's.
+        First tokens are read and emitted in admission order. Deeper
+        would buy nothing (one prefill outlasts the host's part of the
+        next admission several times) and hold more prefill outputs."""
         self._admit_prefilled()
+        ahead: Optional[_Admission] = None
         while True:
             with self._lock:
-                if not self.waiting:
-                    return
-                free = self._free_slots()
-                if not free:
-                    return
-                request = self.waiting.pop(0)
-                slot = free[0]
-                slot.request = request
-            self._admitted_last_step += 1  # graftlint: disable=GL001  # stepper-thread-only
-            self._note_admitted(request)
-            ids = request.prompt_ids
-            with self._span("engine.prefill", req=request.request_id,
-                            prompt_len=len(ids),
-                            bucket=self._bucket_len(len(ids))):
-                self._prefill_into(slot, request, ids)
+                free = self._free_slots() if self.waiting else None
+                if free:
+                    request = self.waiting.pop(0)
+                    slot = free[0]
+                    slot.request = request
+            if not free and ahead is None:
+                return
+            queued = None
+            if free:
+                self._admitted_last_step += 1  # graftlint: disable=GL001  # stepper-thread-only
+                self._note_admitted(request)
+                queued = self._prefill_into(slot, request,
+                                            overlapped=ahead is not None)
+            if ahead is not None:
+                # with or without a prompt queued behind it; then the
+                # loop looks again, for a prompt that arrived meanwhile
+                # or the slot that its ending freed
+                self._first_token_out(ahead)
+            ahead = queued
 
     def _prefill_into(self, slot: _Slot, request: GenerationRequest,
-                      ids: List[int]) -> None:
-        """One admitted prompt: its bias row, its prefill (or, chunked,
-        the bookkeeping that lets step() run it), its first token."""
+                      overlapped: bool) -> Optional[_Admission]:
+        """Queue everything the device will run for one admitted
+        prompt: its prefill first, then its bias row, its first token's
+        sampling and the slot hand-over. Returns what ``_first_token_out``
+        finishes; None for a chunked admission, which queues nothing
+        (step() runs its chunks) and only books the prompt in."""
+        ids = request.prompt_ids
         self._state_stale = True  # graftlint: disable=GL001  # stepper-thread-only
-        self._install_bias(request, slot.index)
+        span = self._span("engine.prefill", req=request.request_id,
+                          prompt_len=len(ids),
+                          bucket=self._bucket_len(len(ids)))
         C = self.config.chunked_prefill_tokens
         if C > 0 and request.adapter is None \
                 and request.logprobs is None:
@@ -1440,33 +1517,48 @@ class ContinuousBatchingEngine:
             # the prompt to _pos_limit = max_seq-1-scratch with
             # scratch >= C. LoRA requests lack a chunk-program
             # path and take the blocking prefill below.
+            with span:
+                self._install_bias(request, slot.index)
             slot.prefilling = True
             slot.prefill_ids = list(ids)
             slot.prefill_pos = 0
             slot.pos = 0
             slot.next_token = 0
-            return
-        bias_row = None
-        if request.logit_bias or self._has_dynamic_bias(request):
-            with self._span("engine.bias"):
-                bias_row = self._bias_row(request)
-        entry, token, first_lp = self._run_prefill(
-            ids, request.adapter, request.temperature,
-            request.top_k, bias_row=bias_row,
-            want_logprobs=request.logprobs is not None)
-        if request.logprobs is not None:
-            slot.pending_lp = first_lp
+            return None
+        # open until the first token is out: under a second admission
+        # the spans of the two overlap, as their work does
+        span.__enter__()
+        launched = self._run_prefill(
+            ids, request.adapter, request.temperature, request.top_k,
+            biased_as=request, want_logprobs=request.logprobs is not None)
+        self.admissions += 1  # graftlint: disable=GL001  # stepper-thread-only
+        self.admissions_overlapped += overlapped  # graftlint: disable=GL001
+        self._mbuf.observe(
+            ENGINE_ADMIT_LAUNCH_SECONDS,
+            max(0.0, launched.t_launched - request.t_admit),
+            {"overlapped": "1" if overlapped else "0"})
         with self._span("engine.launch"):
+            self._install_bias(request, slot.index, launched.bias)
             with self._span("engine.insert"):
-                self.cache = self._insert(self.cache, entry, slot.index)
-            del entry
+                self.cache = self._insert(self.cache, launched.entry,
+                                          slot.index)
             if self._spec:
                 self._draft_prefill_slot(ids, slot.index)
                 slot.draft_ready = True
-        slot.next_token = token
         slot.pos = len(ids)
-        with self._span("engine.emit"):
-            self._emit(slot, slot.next_token)
+        return _Admission(slot, launched.sampled, span)
+
+    def _first_token_out(self, admission: _Admission) -> None:
+        """Wait for an admitted prompt's first token and emit it; the
+        slot hand-over queued behind the sampling runs meanwhile."""
+        slot, sampled, span = admission
+        try:
+            slot.next_token, slot.pending_lp = self._read_first_token(
+                sampled)
+            with self._span("engine.emit"):
+                self._emit(slot, slot.next_token)
+        finally:
+            span.__exit__(None, None, None)
 
     def _emit(self, slot: _Slot, token: int) -> None:
         request = slot.request
@@ -1985,6 +2077,11 @@ class ContinuousBatchingEngine:
                 # were sent their per-slot state from the host
                 "decode_steps": self.decode_steps,
                 "state_uploads": self.state_uploads,
+                # prompts admitted through a prefill of their own, and
+                # how many of them were queued on the device before the
+                # host waited for the one before
+                "admissions": self.admissions,
+                "admissions_overlapped": self.admissions_overlapped,
                 # which device served, what it compiled, and whether
                 # flash attention stepped aside for any shape
                 "device": jax_backend.device_report(),

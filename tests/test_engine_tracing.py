@@ -351,10 +351,16 @@ def test_step_spans_lie_inside_their_step_and_carry_its_number(recorder):
     inside = [ev[4] for ev in children
               if ev is not first and first[1] <= ev[1]
               and ev[1] + ev[2] <= first[1] + first[2]]
-    assert inside.count("engine.bias") == 2     # the only biased request
+    # the only biased request: its row is built and sent once, behind
+    # the launch of its prefill (the first launch of its span)
+    assert inside.count("engine.bias") == 1
     assert {"engine.upload", "engine.launch", "engine.readback",
             "engine.emit"} <= set(inside)
-    assert sum(ev[4] == "engine.bias" for ev in children) == 2
+    assert sum(ev[4] == "engine.bias" for ev in children) == 1
+    bias = next(ev for ev in children if ev[4] == "engine.bias")
+    launch = min(ev[1] for ev in children if ev[4] == "engine.launch"
+                 and ev[1] >= first[1])
+    assert launch < bias[1]
     # the wait in the queue, per request, ends where its prefill starts
     queued = {ev[5]["req"]: ev for ev in events
               if ev[4] == "request_queue"}

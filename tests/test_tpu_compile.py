@@ -227,6 +227,8 @@ def test_engine_decode_program_feeds_its_state_back(v5e, monkeypatch,
     # an engine around shapes: no weights and no cache are made here
     monkeypatch.setattr(engine_mod.ContinuousBatchingEngine, "_fresh_cache",
                         lambda self, model: (cache_k, cache_v))
+    monkeypatch.setattr(engine_mod.ContinuousBatchingEngine, "_fresh_bias",
+                        lambda self: (None, None))
     engine = engine_mod.ContinuousBatchingEngine(
         engine_mod.EngineConfig(model=cfg, max_batch=32, max_seq=1024),
         params=params)
@@ -271,6 +273,8 @@ def test_jamba_serving_programs_compile_at_published_widths(v5e):
         # an engine around shapes: no weights and no cache are made here
         patch.setattr(engine_mod.ContinuousBatchingEngine, "_fresh_cache",
                       lambda self, model: cache)
+        patch.setattr(engine_mod.ContinuousBatchingEngine, "_fresh_bias",
+                      lambda self: (None, None))
         engine = engine_mod.ContinuousBatchingEngine(
             engine_mod.EngineConfig(model=cfg, max_batch=32, max_seq=1024),
             params=params)
