@@ -121,6 +121,23 @@ ENGINE_ADMIT_LAUNCH_SECONDS = _metrics.Histogram(
     "before it was still being prefilled, so the time cost the device "
     "nothing",
     boundaries=_STEP_BOUNDS, tag_keys=("overlapped",))
+ENGINE_STEPPER_SECONDS = _metrics.Counter(
+    "ray_tpu_engine_stepper_seconds_total",
+    "Wall seconds of the stepper thread's life by what it was in: the "
+    "phases exclude each other and sum to the time its loop has run",
+    tag_keys=("phase",))
+ENGINE_STEPPER_CPU_SECONDS = _metrics.Counter(
+    "ray_tpu_engine_stepper_cpu_seconds_total",
+    "Seconds the stepper thread spent on a CPU, in the stretches in "
+    "which it read that clock, by phase, python standing for the five "
+    "of plain Python (admit, bias, gather, emit, other)",
+    tag_keys=("phase",))
+ENGINE_STEPPER_CPU_WALL_SECONDS = _metrics.Counter(
+    "ray_tpu_engine_stepper_cpu_wall_seconds_total",
+    "Wall seconds of those stretches, by the same phases: less the CPU "
+    "seconds, what the thread waited there; in python, for the "
+    "interpreter or a CPU",
+    tag_keys=("phase",))
 ENGINE_CACHE_BYTES = _metrics.Gauge(
     "ray_tpu_engine_cache_bytes",
     "Bytes of the serving cache, by kind: kv (rows of keys and values) "
@@ -135,6 +152,116 @@ ENGINE_OCCUPANCY = _metrics.Gauge(
 ENGINE_WAITING = _metrics.Gauge(
     "ray_tpu_engine_waiting_requests",
     "Requests queued for a free decode slot")
+
+
+# The stepper's phases and the span that means each: what has no span
+# of its own (has_work, the step() wrapper, building ``active``) is
+# ``other``, engine.step's own phase. The thread's CPU clock is a
+# system call (6-25 us, in ticks of 10 ms, on the benchmark's machine):
+# it is read only where the stepper enters or leaves a phase in which
+# it may sleep by intent (the five of plain Python share ``python``),
+# and only in one stretch in four between two waits for the device.
+STEPPER_PHASES = ("wait", "admit", "bias", "gather", "upload", "launch",
+                  "blocked", "emit", "other")
+STEPPER_CPU_PHASES = ("wait", "python", "upload", "launch", "blocked")
+_CPU_PHASE = (0, 1, 1, 1, 2, 3, 4, 1, 1)
+# a list of the account, its counter and the phases that index both
+_STEPPER_FAMILIES = (
+    ("wall", ENGINE_STEPPER_SECONDS, STEPPER_PHASES),
+    ("cpu", ENGINE_STEPPER_CPU_SECONDS, STEPPER_CPU_PHASES),
+    ("cpu_wall", ENGINE_STEPPER_CPU_WALL_SECONDS, STEPPER_CPU_PHASES))
+_WAIT, _ADMIT, _BIAS, _GATHER, _UPLOAD, _LAUNCH, _BLOCKED, _EMIT, _OTHER = \
+    range(len(STEPPER_PHASES))
+_SPAN_PHASE = {
+    "engine.wait": _WAIT, "engine.prefill": _ADMIT, "engine.bias": _BIAS,
+    "engine.gather": _GATHER, "engine.upload": _UPLOAD,
+    "engine.launch": _LAUNCH, "engine.insert": _LAUNCH,
+    "engine.readback": _BLOCKED, "engine.emit": _EMIT,
+    "engine.step": _OTHER}
+
+
+class _StepperAccount:
+    """Where the stepper thread's time went. ``wall``: seconds by phase,
+    exclusive, so they sum to the time since ``bind`` first ran,
+    exactly. ``cpu``: the thread's CPU seconds by CPU phase over the
+    stretches in which that clock is read, and ``cpu_wall`` the wall
+    seconds of the same stretches. Only the stepper writes (a switch
+    charges what passed to the phase it leaves); the metrics buffer's
+    flush copies the lists."""
+
+    __slots__ = ("clock", "cpu_clock", "cpu_every", "wall", "cpu",
+                 "cpu_wall", "phase", "thread", "prefills", "t", "_c",
+                 "_tc", "_reading", "_blocks")
+
+    def __init__(self):
+        self.clock, self.cpu_clock = time.perf_counter, time.thread_time
+        self.cpu_every = 4
+        self.wall = [0.0] * len(STEPPER_PHASES)
+        self.cpu = [0.0] * len(STEPPER_CPU_PHASES)
+        self.cpu_wall = [0.0] * len(STEPPER_CPU_PHASES)
+        self.phase = _OTHER
+        self.thread: Optional[int] = None
+        self.prefills = 0   # engine.prefill spans open: two may overlap
+        self.t = 0.0        # ``clock`` at the last switch
+        self._c = self._tc = 0.0   # both clocks at the last CPU reading
+        self._reading, self._blocks = False, 0
+
+    def bind(self) -> None:
+        """The caller is the stepper from here on: its loop's first
+        turn starts the clock. A thread that takes the loop over reads
+        a CPU clock of its own."""
+        ident = threading.get_ident()
+        if ident != self.thread:
+            if self.thread is None:
+                self.t = self.clock()
+            self.thread, self._reading = ident, False
+
+    def switch(self, phase: int) -> None:
+        t, before = self.clock(), self.phase
+        self.wall[before] += t - self.t
+        self.t, self.phase = t, phase
+        kind = _CPU_PHASE[before]
+        if kind != _CPU_PHASE[phase]:
+            if self._reading:
+                c = self.cpu_clock()
+                self.cpu[kind] += c - self._c
+                self.cpu_wall[kind] += t - self._tc
+                self._c, self._tc = c, t
+            if phase == _BLOCKED:
+                # the device has work, so a reading here delays nothing:
+                # a stretch of readings ends, every fourth time one starts
+                self._blocks += 1
+                self._reading = not self._blocks % self.cpu_every
+                if self._reading:
+                    self._c, self._tc = self.cpu_clock(), t
+
+
+class _StepperSpan(_flight.span):
+    """An engine span opened by the stepper: entering sets its phase,
+    leaving restores the one before. engine.prefill spans are opened
+    under engine.step only and overlap since PR 40, so leaving one
+    restores ``admit`` while another is open and ``other`` after."""
+
+    __slots__ = ("_account", "_phase", "_before")
+
+    def __init__(self, account: _StepperAccount, name: str, **args):
+        super().__init__("serve", name, **args)
+        self._account, self._phase = account, _SPAN_PHASE[name]
+
+    def __enter__(self) -> "_StepperSpan":
+        account = self._account
+        self._before = account.phase
+        account.prefills += self._phase == _ADMIT
+        account.switch(self._phase)
+        return super().__enter__()
+
+    def __exit__(self, *exc_info) -> None:
+        super().__exit__(*exc_info)
+        account, before = self._account, self._before
+        if self._phase == _ADMIT:
+            account.prefills -= 1
+            before = _ADMIT if account.prefills else _OTHER
+        account.switch(before)
 
 
 class _MetricsBuffer(_metrics.LocalBuffer):
@@ -154,6 +281,9 @@ class _MetricsBuffer(_metrics.LocalBuffer):
         self._engine = weakref.ref(engine)
         self._last_flush = time.perf_counter()
         self._flushed_tokens = 0
+        # the stepper's account, list by list, as the last flush read it
+        self.stepper_seconds = [[0.0] * len(phases)
+                                for _, _, phases in _STEPPER_FAMILIES]
         # flushers: the buffer's thread and stats()/flush_metrics()
         # callers on request threads; never the stepper
         self._flush_lock = locktrace.traced_lock("llm.engine.flush")
@@ -200,6 +330,15 @@ class _MetricsBuffer(_metrics.LocalBuffer):
                 self.set(ENGINE_OCCUPANCY, float(sum(
                     1 for s in engine.slots if s.request is not None)))
                 self.set(ENGINE_WAITING, float(len(engine.waiting)))
+                # the stepper never brings its account: its growth
+                # since the last flush is read here (a copy of a list
+                # is one step of the interpreter)
+                for (kind, counter, phases), sent in zip(
+                        _STEPPER_FAMILIES, self.stepper_seconds):
+                    read = list(getattr(engine._account, kind))
+                    for phase, now_s, sent_s in zip(phases, read, sent):
+                        self.inc(counter, now_s - sent_s, {"phase": phase})
+                    sent[:] = read
             try:
                 return super().flush()
             except Exception:  # graftlint: disable=GL004
@@ -597,13 +736,10 @@ class ContinuousBatchingEngine:
             self._mbuf.set(ENGINE_CACHE_BYTES, float(nbytes),
                            {"kind": kind})
         self._admitted_last_step = 0
-        # step() calls so far: the number a flight-recorder
-        # engine_step event and its child spans share
+        # step() calls so far: the number an engine.step span and its
+        # child spans share
         self._steps = 0
-        # seconds of the current step the stepper spent waiting for
-        # the device (see _readback) and sending to it (see _upload)
-        self._blocked_s = 0.0
-        self._upload_s = 0.0
+        self._account = _StepperAccount()
         # multi-LoRA bank: slot 0 is the all-zero base adapter, so
         # "no adapter" needs no conditional in the decode program
         self._adapters: Dict[str, int] = {}
@@ -1090,8 +1226,19 @@ class ContinuousBatchingEngine:
 
     def _span(self, name: str, **args) -> "_flight.span":
         """A phase of the current step, on the profiler's clock and in
-        the flight recorder (category serve), tagged with the step."""
-        return _flight.span("serve", name, step=self._steps, **args)
+        the flight recorder (category serve), tagged with the step;
+        on the stepper thread also a phase of its account, and the one
+        place that switches it (prefill_only runs on request threads)."""
+        if threading.get_ident() != self._account.thread:
+            return _flight.span("serve", name, step=self._steps, **args)
+        return _StepperSpan(self._account, name, step=self._steps, **args)
+
+    def idling(self) -> "_flight.span":
+        """For the loop that drives step(), around its wait for work:
+        the stepper's time between steps is in the account and on the
+        trace as engine.wait."""
+        self._account.bind()
+        return self._span("engine.wait")
 
     def _sharding_beside(self, params):
         """Where the engine keeps what it sends and feeds back: the one
@@ -1139,12 +1286,9 @@ class ContinuousBatchingEngine:
         stepper thread while the device sits idle (0.4 ms each on a
         v5e); dropped earlier, its release is paid inside the next
         step's uploads, a little cheaper in sum."""
-        t0 = time.perf_counter()
         with self._span("engine.upload"):
-            out = [self._jax.device_put(a, self._on_device)
-                   for a in arrays]
-        self._upload_s += time.perf_counter() - t0  # graftlint: disable=GL001  # stepper-thread-only
-        return out
+            return [self._jax.device_put(a, self._on_device)
+                    for a in arrays]
 
     def _readback(self, *arrays) -> list:
         """Device results as numpy arrays. These reads block the
@@ -1152,11 +1296,8 @@ class ContinuousBatchingEngine:
         so every step program's and the prefill's go through here and
         the time waited is counted once: a step's host time is its
         wall time less this."""
-        t0 = time.perf_counter()
         with self._span("engine.readback"):
-            out = [np.asarray(a) for a in arrays]
-        self._blocked_s += time.perf_counter() - t0  # graftlint: disable=GL001  # stepper-thread-only
-        return out
+            return [np.asarray(a) for a in arrays]
 
     def _note_prefill_tokens(self, real: int, pad: int) -> None:
         """What one prefill program computed: the prompt's own
@@ -1789,32 +1930,31 @@ class ContinuousBatchingEngine:
         """Admit + one whole-batch decode step (sampling fused on
         device — only [B] token ids come back). Returns #active slots.
 
-        Instrumented wrapper: step wall time and host time (phase-
-        tagged prefill vs decode) and tokens accumulate in the local
-        buffer, which its own thread flushes: nothing here reaches the
-        control plane."""
-        t0 = time.perf_counter()
-        rec = _flight.RECORDER
-        t0_ns = rec.clock() if rec is not None else 0
+        Instrumented wrapper: step wall time, host and upload time
+        (phase-tagged prefill vs decode; all three are what the
+        stepper's account grew by across the engine.step span) and
+        tokens accumulate in the local buffer, which its own thread
+        flushes: nothing here reaches the control plane."""
+        account = self._account
+        account.bind()
         tokens_before = self.total_generated
         self._admitted_last_step = 0
-        self._blocked_s = 0.0
-        self._upload_s = 0.0
         self._steps += 1  # graftlint: disable=GL001  # stepper-thread-only
-        handled = self._step_impl()
-        dt = time.perf_counter() - t0
-        emitted = self.total_generated - tokens_before
-        phase = ("prefill" if self._admitted_last_step
-                 or any(s.request is not None and s.prefilling
-                        for s in self.slots)
-                 else "decode")
-        if rec is not None and handled:
-            rec.record("serve", "engine_step", t0_ns,
-                       rec.clock() - t0_ns,
-                       {"step": self._steps, "phase": phase,
-                        "slots": handled, "tokens": emitted})
-        self._mbuf.note_step(phase, dt, max(0.0, dt - self._blocked_s),
-                             self._upload_s, emitted)
+        with self._span("engine.step") as span:
+            t0 = account.t
+            blocked = account.wall[_BLOCKED]
+            upload = account.wall[_UPLOAD]
+            handled = self._step_impl()
+            emitted = self.total_generated - tokens_before
+            phase = ("prefill" if self._admitted_last_step
+                     or any(s.request is not None and s.prefilling
+                            for s in self.slots)
+                     else "decode")
+            span.note(phase=phase, slots=handled, tokens=emitted)
+        dt = account.t - t0
+        self._mbuf.note_step(
+            phase, dt, max(0.0, dt - (account.wall[_BLOCKED] - blocked)),
+            account.wall[_UPLOAD] - upload, emitted)
         return handled
 
     def record_stage(self, stage: str, seconds: float) -> None:
@@ -1991,6 +2131,7 @@ class ContinuousBatchingEngine:
             slot.pending_lp = None
         self._state = None
         self._state_stale = True
+        self._account.prefills = 0   # a step that raised left them open
         self.cache = self._fresh_cache(self.config.model)
         if self._spec:
             self.draft_cache_k, self.draft_cache_v = self._fresh_cache(
@@ -2092,6 +2233,14 @@ class ContinuousBatchingEngine:
                 # own and what its bucket added; the cache by kind
                 "prefill_tokens": dict(self.prefill_tokens),
                 "cache_bytes": dict(self.cache_bytes),
+                # the stepper's account as the flush above read it and
+                # sent it, and when (this process's perf_counter):
+                # between two reads ``wall`` grows by the time that passed
+                "stepper_seconds": {
+                    kind: dict(zip(phases, seconds))
+                    for (kind, _, phases), seconds in zip(
+                        _STEPPER_FAMILIES, self._mbuf.stepper_seconds)},
+                "stepper_read_at": self._mbuf._last_flush,
             }
             if self._prefix_cache is not None:
                 out["prefix_cache_entries"] = len(self._prefix_cache)

@@ -194,7 +194,8 @@ class LLMServer:
                 if self.engine.has_work():
                     self.engine.step()
                 else:
-                    self._wake.wait(0.002)
+                    with self.engine.idling():
+                        self._wake.wait(0.002)
                     self._wake.clear()
             except Exception as e:  # noqa: BLE001 — keep serving
                 # fail in-flight requests instead of hanging them; the

@@ -223,6 +223,11 @@ class span:
                             if _trace_annotation else None)
         self._t0: Optional[int] = None
 
+    def note(self, **args) -> None:
+        """Journal args known only once the interval is under way (the
+        profiler's annotation keeps those it was built with)."""
+        self._args.update(args)
+
     def __enter__(self) -> "span":
         rec = RECORDER
         self._t0 = rec.clock() if rec is not None else None

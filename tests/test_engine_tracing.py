@@ -314,9 +314,16 @@ def test_step_spans_lie_inside_their_step_and_carry_its_number(recorder):
     while any(not r.done for r in requests):
         engine.step()
     events = [ev for ev in recorder.snapshot() if ev[3] == "serve"]
-    steps = {ev[5]["step"]: ev for ev in events if ev[4] == "engine_step"}
+    steps = {ev[5]["step"]: ev for ev in events if ev[4] == "engine.step"}
     assert sorted(steps) == list(range(1, engine._steps + 1))
-    children = [ev for ev in events if ev[4].startswith("engine.")]
+    assert sum(ev[5]["tokens"] for ev in steps.values()) == 9
+    assert all(ev[5]["slots"] >= 1 for ev in steps.values())
+    # a loop's wait between steps is a span beside them, in no step
+    with engine.idling():
+        pass
+    assert [ev[4] for ev in recorder.snapshot()][-1] == "engine.wait"
+    children = [ev for ev in events if ev[4].startswith("engine.")
+                and ev[4] != "engine.step"]
     names = {ev[4] for ev in children}
     assert names == {"engine.prefill", "engine.bias", "engine.gather",
                      "engine.upload", "engine.launch", "engine.insert",
@@ -369,6 +376,239 @@ def test_step_spans_lie_inside_their_step_and_carry_its_number(recorder):
         q = queued[ev[5]["req"]]
         assert q[5]["step"] == ev[5]["step"]
         assert abs(q[1] + q[2] - ev[1]) < 5e6   # ns
+
+
+# -- (d2) the stepper's account of its own time ---------------------------
+
+STEPPER = "ray_tpu_engine_stepper_seconds_total"
+STEPPER_CPU = "ray_tpu_engine_stepper_cpu_seconds_total"
+STEPPER_CPU_WALL = "ray_tpu_engine_stepper_cpu_wall_seconds_total"
+# the phases of plain Python, which share one account of CPU seconds
+PYTHON = ("admit", "bias", "gather", "emit", "other")
+
+
+def _phase_series(name):
+    """{phase: value} of one of the account's counter families."""
+    out = {}
+    for line in prometheus_text().splitlines():
+        if line.startswith(name + "{phase="):
+            out[line.split('"')[1]] = float(line.split()[-1])
+    return out
+
+
+def _scripted(engine, cpu_every):
+    """Clocks that a script advances: the k-th read of the wall clock
+    comes k ms after the one before, and the thread has been on a CPU
+    for a quarter of the wall time whenever anyone asks. Returns the
+    count of reads of each."""
+    account = engine._account
+    reads = {"wall": 0, "cpu": 0}
+
+    def wall():
+        reads["wall"] += 1
+        return 100.0 + sum(range(reads["wall"])) * 1e-3
+
+    def cpu():
+        reads["cpu"] += 1
+        return 7.0 + 0.25 * sum(range(reads["wall"])) * 1e-3
+
+    account.clock, account.cpu_clock = wall, cpu
+    account.cpu_every = cpu_every
+    return reads
+
+
+def test_scripted_spans_charge_each_interval_to_one_phase():
+    """The spans of a step as the engine opens them: nested (bias over
+    its upload, launch over insert), two engine.prefill spans that
+    overlap, a wait. Every interval between two switches lands in
+    exactly one phase and the wall seconds sum to the last switch less
+    the first. The thread's CPU clock is read from the first wait for
+    the device on, and only where the stepper enters or leaves a phase
+    that is not plain Python; the CPU seconds and the wall seconds of
+    what it read fall to the same phase."""
+    from ray_tpu.llm.engine import STEPPER_CPU_PHASES, STEPPER_PHASES
+    engine = ContinuousBatchingEngine(_engine_config())
+    account = engine._account
+    reads = _scripted(engine, cpu_every=1)
+    script = []          # the phase each interval belongs to, in order
+
+    def enter(name, phase):
+        span = engine._span(name)
+        span.__enter__()
+        script.append(phase)
+        return span
+
+    def leave(span, back_in):
+        span.__exit__(None, None, None)
+        script.append(back_in)
+
+    account.bind()                        # the loop's first turn
+    script.append("other")
+    step = enter("engine.step", "other")
+    first = enter("engine.prefill", "admit")
+    leave(enter("engine.upload", "upload"), "admit")
+    launch = enter("engine.launch", "launch")
+    leave(enter("engine.insert", "launch"), "launch")
+    leave(launch, "admit")
+    second = enter("engine.prefill", "admit")     # under the first
+    bias = enter("engine.bias", "bias")
+    leave(enter("engine.upload", "upload"), "bias")
+    leave(bias, "admit")
+    cpu_from = len(script)        # the first wait for the device
+    leave(enter("engine.readback", "blocked"), "admit")
+    leave(enter("engine.emit", "emit"), "admit")
+    leave(first, "admit")                 # the second is still open
+    leave(enter("engine.readback", "blocked"), "admit")
+    leave(second, "other")
+    leave(enter("engine.gather", "gather"), "other")
+    leave(enter("engine.upload", "upload"), "other")
+    leave(enter("engine.launch", "launch"), "other")
+    leave(step, "other")
+    leave(enter("engine.wait", "wait"), "other")
+    # a request thread's span (prefill_only) is no phase of the stepper
+    before = dict(reads)
+    other = threading.Thread(
+        target=lambda: leave(enter("engine.upload", None), None))
+    other.start()
+    other.join()
+    assert reads == before and script[-2:] == [None, None]
+    del script[-2:]
+    script.pop()          # the phase after the last switch is open
+    assert reads["wall"] == len(script) + 1
+    expected = dict.fromkeys(STEPPER_PHASES, 0.0)
+    read = dict.fromkeys(STEPPER_CPU_PHASES, 0.0)
+    for k, phase in enumerate(script, start=1):
+        expected[phase] += k * 1e-3
+        if k > cpu_from:
+            read["python" if phase in PYTHON else phase] += k * 1e-3
+    wall = dict(zip(STEPPER_PHASES, account.wall))
+    for phase in STEPPER_PHASES:
+        assert abs(wall[phase] - expected[phase]) < 1e-12, phase
+        assert wall[phase] > 0.0, phase      # the script visits all nine
+    assert abs(sum(account.wall) - (account.t - 100.0)) < 1e-9
+    assert abs(sum(account.wall) - sum(range(len(script) + 1)) * 1e-3) < 1e-9
+    cpu = dict(zip(STEPPER_CPU_PHASES, account.cpu))
+    cpu_wall = dict(zip(STEPPER_CPU_PHASES, account.cpu_wall))
+    for phase in STEPPER_CPU_PHASES:
+        assert abs(cpu_wall[phase] - read[phase]) < 1e-12, phase
+        assert abs(cpu[phase] - 0.25 * read[phase]) < 1e-12, phase
+        assert 0.0 < cpu[phase] <= cpu_wall[phase]
+    # between two phases of plain Python the CPU clock is left alone.
+    # The first wait for the device starts the readings (one on its way
+    # in, one out), the second closes a stretch and starts the next
+    # (two in, one out); the upload, the launch and the wait after them
+    # read on their way in and out
+    assert reads["cpu"] == 2 + 3 + 3 * 2 < reads["wall"]
+    assert account.prefills == 0
+    assert STEPPER_PHASES[account.phase] == "other"
+
+
+def test_cpu_clock_is_read_in_one_stretch_in_four():
+    """Eight plain steps under scripted clocks: the CPU clock is read
+    between the fourth wait for the device and the fifth and between
+    the eighth and the ninth, at the four places a step leaves plain
+    Python or comes back to it, and nowhere else."""
+    from ray_tpu.llm.engine import STEPPER_CPU_PHASES
+    engine = ContinuousBatchingEngine(_engine_config())
+    account = engine._account
+    reads = _scripted(engine, cpu_every=4)
+    account.bind()
+    seen = []
+    for _ in range(9):
+        with engine._span("engine.step"):
+            with engine._span("engine.launch"):
+                pass
+            with engine._span("engine.readback"):
+                seen.append(reads["cpu"])
+            with engine._span("engine.emit"):
+                pass
+    # reads so far when each wait for the device began: the one that
+    # opens a stretch, four in it (the last closes it), none outside
+    assert seen == [0, 0, 0, 1, 5, 5, 5, 6, 10]
+    cpu_wall = dict(zip(STEPPER_CPU_PHASES, account.cpu_wall))
+    assert cpu_wall["wait"] == cpu_wall["upload"] == 0.0
+    # a step is 8 switches: the stretches are the intervals 29-36 and
+    # 61-68 of the script (ms each)
+    assert abs(sum(account.cpu_wall)
+               - (sum(range(29, 37)) + sum(range(61, 69))) * 1e-3) < 1e-9
+    assert abs(sum(account.cpu) - 0.25 * sum(account.cpu_wall)) < 1e-12
+
+
+def test_account_reaches_its_counter_families_and_stats():
+    """Admissions, decode steps and an idle wait on a tiny engine: all
+    nine phases appear in the wall family, the five CPU phases in the
+    other two and all of them in stats(); the three agree, and the step
+    histograms were fed from the account."""
+    from ray_tpu.llm.engine import STEPPER_CPU_PHASES, STEPPER_PHASES
+    engine = ContinuousBatchingEngine(_engine_config())
+    switches = []        # the wall clock, as the account read it
+
+    def clock():
+        switches.append(time.perf_counter())
+        return switches[-1]
+
+    engine._account.clock = clock
+    engine._account.cpu_every = 1
+    engine.flush_metrics()
+    families = {"wall": (STEPPER, STEPPER_PHASES),
+                "cpu": (STEPPER_CPU, STEPPER_CPU_PHASES),
+                "cpu_wall": (STEPPER_CPU_WALL, STEPPER_CPU_PHASES)}
+    before = {name: _phase_series(name) for name, _ in families.values()}
+    hist0 = {(name, phase): _hist(name, phase=phase)[0]
+             for name in (STEP, STEP_HOST, STEP_UPLOAD)
+             for phase in ("decode", "prefill")}
+    with engine.idling():
+        pass
+    early, early_switch = engine.stats(), switches[-1]
+    requests = [engine.add_request(GenerationRequest(
+        prompt_ids=[1, 2, 3, i], max_tokens=5,
+        logit_bias={9: -50.0} if i == 1 else None)) for i in range(4)]
+    while any(not r.done for r in requests):
+        engine.step()
+    with engine.idling():
+        pass
+    late = engine.stats()
+    stats = late["stepper_seconds"]
+    # a read holds the loop's life up to the last switch before it, so
+    # between two reads the account grows by the time that passed, give
+    # or take the phase that was open at each
+    for read, last_switch in ((early, early_switch), (late, switches[-1])):
+        life = sum(read["stepper_seconds"]["wall"].values())
+        assert abs(life - (last_switch - switches[0])) < 1e-9
+        assert read["stepper_read_at"] >= last_switch
+    assert sorted(stats) == sorted(families)
+    for kind, (name, phases) in families.items():
+        after = _phase_series(name)
+        assert list(stats[kind]) == list(phases)
+        assert sorted(after) == sorted(phases)
+        for phase in phases:
+            grew = after[phase] - before[name].get(phase, 0.0)
+            assert abs(grew - stats[kind][phase]) < 1e-9, (name, phase)
+            assert stats[kind][phase] >= 0.0
+    assert len(stats["wall"]) == 9 and len(stats["cpu"]) == 5
+    account = engine._account
+    # stats() gave what the flush read, and nothing ran since
+    assert list(stats["wall"].values()) == account.wall
+    assert all(seconds > 0.0 for seconds in stats["wall"].values()), stats
+    assert all(seconds > 0.0 for seconds in stats["cpu_wall"].values())
+    # the CPU clock was read from the first wait for the device on
+    assert 0.0 < sum(stats["cpu_wall"].values()) < sum(account.wall)
+    assert stats["cpu_wall"]["blocked"] == stats["wall"]["blocked"]
+
+    def fed(name):
+        return sum(_hist(name, phase=phase)[0] - hist0[(name, phase)]
+                   for phase in ("decode", "prefill"))
+
+    # host time is step time less the account's blocked seconds, and
+    # the upload histogram took the account's upload seconds
+    assert abs(fed(STEP) - fed(STEP_HOST) - stats["wall"]["blocked"]) < 1e-9
+    assert abs(fed(STEP_UPLOAD) - stats["wall"]["upload"]) < 1e-9
+    # the steps are the account less the waits and the loop around them
+    in_steps = sum(account.wall) - stats["wall"]["wait"]
+    assert fed(STEP) <= in_steps + 1e-9
+    # a second flush with nothing new adds nothing
+    engine.flush_metrics()
+    assert _phase_series(STEPPER_CPU_WALL) == after
 
 
 def test_span_without_recorder_or_profiler_is_inert():
