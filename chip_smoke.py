@@ -63,12 +63,14 @@ class PhaseError(RuntimeError):
 # ---------------------------------------------------------------------
 
 def check_kernels(attn_shapes: Sequence[tuple], rms_shapes: Sequence[tuple],
-                  int8_shape: tuple, expect_kernels: bool = True
-                  ) -> Dict[str, Any]:
+                  int8_shape: tuple, decode_shape: tuple,
+                  expect_kernels: bool = True) -> Dict[str, Any]:
     """Each pallas_call site against its reference. ``attn_shapes``:
     (batch, seq, heads, head_dim, with_grads); ``rms_shapes``: x shapes;
-    ``int8_shape``: (rows, k, n). With ``expect_kernels`` every checked
-    program must hold its kernel in the lowered text."""
+    ``int8_shape``: (rows, k, n); ``decode_shape``: a serving cache's
+    (layers, slots, rows, kv_heads, head_dim) and the query heads a KV
+    head. With ``expect_kernels`` every checked program must hold its
+    kernel in the lowered text."""
     import jax
     import jax.numpy as jnp
 
@@ -148,16 +150,32 @@ def check_kernels(attn_shapes: Sequence[tuple], rms_shapes: Sequence[tuple],
     close(tag, fn(x, w8, scale),
           x.astype(jnp.float32) @ (w8.astype(jnp.float32) * scale[None, :]))
 
+    *cache_shape, n_rep = decode_shape
+    layers, slots, cache_rows, kv_heads, head_dim = cache_shape
+    keys = jax.random.split(jax.random.PRNGKey(SEED + 3), 3)
+    q = jax.random.normal(keys[0], (slots, kv_heads, n_rep, head_dim),
+                          jnp.bfloat16)
+    ck, cv = (jax.random.normal(kk, cache_shape, jnp.bfloat16)
+              for kk in keys[1:])
+    # a parked slot, the cache's last row, and the rest in between
+    pos = (jnp.arange(slots, dtype=jnp.int32) * 131) % cache_rows
+    pos = pos.at[-1].set(cache_rows - 1)
+    tag = f"decode_attention{list(decode_shape)}"
+    fn = jax.jit(lambda q, ck, cv, pos: att.decode_attention(
+        q, ck, cv, layers - 1, pos, jnp.bfloat16))
+    held(tag, fn, (q, ck, cv, pos), ["decode_attention"])
+    close(tag, fn(q, ck, cv, pos), att._decode_attention_reference(
+        q, ck, cv, layers - 1, pos, jnp.bfloat16))
+
     return {"checks": results, "device": jax_backend.device_report(),
             "flash_fallbacks": list(att.kernel_fallbacks)}
 
 
-def phase_kernels(attn_shapes, rms_shapes, int8_shape):
+def phase_kernels(*shapes):
     import ray_tpu
 
     task = ray_tpu.remote(num_tpus=1)(check_kernels)
-    return ray_tpu.get(task.remote(attn_shapes, rms_shapes, int8_shape),
-                       timeout=BUDGET_S)
+    return ray_tpu.get(task.remote(*shapes), timeout=BUDGET_S)
 
 
 # ---------------------------------------------------------------------
@@ -508,7 +526,10 @@ def main() -> None:
             [(4, seq, 4096), (1, 512, 4096), (8, 1, 4096)],
             # on no later phase: Llama-2-7B's FFN width (11008) divides
             # neither of its block sizes
-            (8, 4096, 4096))
+            (8, 4096, 4096),
+            # the serve phase's cache, two layers of it, one query head
+            # a KV head
+            (2, 8, 1024, 32, 128, 1))
         run("1_train_1chip", phase_train,
             LlamaConfig.llama2_7b(n_layers=4, max_seq_len=seq,
                                   ce_chunk_tokens=4096),
@@ -519,7 +540,7 @@ def main() -> None:
             max_batch=8, max_seq=1024, seed=SEED)
         serve_kw = dict(max_tokens=32,
                         prefill_kernels=["flash_fwd", "rms_norm"],
-                        decode_kernels=["rms_norm"])
+                        decode_kernels=["decode_attention", "rms_norm"])
         # with the BOS token these land in prefill buckets 256 and 512
         lens = [128, 160, 200, 255, 300, 384, 448, 511]
         run("2_serve_1chip", phase_serve,

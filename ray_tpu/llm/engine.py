@@ -113,6 +113,13 @@ ENGINE_PREFILL_TOKENS = _metrics.Counter(
     "Positions the prefill programs computed, by kind: real (a "
     "prompt's own tokens) or pad (what its bucket added)",
     tag_keys=("kind",))
+ENGINE_DECODE_KV_ROWS = _metrics.Counter(
+    "ray_tpu_engine_decode_kv_rows_total",
+    "Rows of one layer's KV cache a dense decode step's attention "
+    "covered (read: the blocks up to each live slot's position and a "
+    "parked slot's park row) and the rest of its slots x max_seq rows "
+    "(skipped); all read where the decode kernel does not engage",
+    tag_keys=("kind",))
 ENGINE_ADMIT_LAUNCH_SECONDS = _metrics.Histogram(
     "ray_tpu_engine_admit_launch_seconds",
     "Time from the stepper's pop of a waiting request to the return of "
@@ -727,6 +734,12 @@ class ContinuousBatchingEngine:
         self.prefill_tokens = {"real": 0, "pad": 0}
         self.decode_steps = 0     # dense decode programs launched
         self.state_uploads = 0    # of them, with a state from the host
+        # rows of a layer's KV cache those programs' attention covered
+        # and did not: the decode kernel reads whole blocks up to a
+        # slot's position (ops/attention.py), the XLA form every row
+        self._kv_block = _attention_op.decode_block_rows(
+            config.max_seq, c.n_kv_heads, c.head_dim) or config.max_seq
+        self.decode_kv_rows = {"read": 0, "skipped": 0}
         # prompts admitted through a prefill of their own, and how many
         # of them were launched under the prefill of the one before
         self.admissions = 0
@@ -1298,6 +1311,21 @@ class ContinuousBatchingEngine:
         wall time less this."""
         with self._span("engine.readback"):
             return [np.asarray(a) for a in arrays]
+
+    def _note_kv_rows(self, active) -> None:
+        """The cache rows the dense step just launched covers, from the
+        positions it was given: counted while the device runs it."""
+        block = self._kv_block
+        parked = self.config.max_batch - len(active)
+        read = block * (sum(s.pos // block for s in active) + len(active)
+                        + parked * (self._dense_park // block + 1))
+        skipped = self.config.max_batch * self.config.max_seq - read
+        # stepper-thread-only
+        self.decode_kv_rows["read"] += read  # graftlint: disable=GL001
+        self.decode_kv_rows["skipped"] += skipped  # graftlint: disable=GL001
+        self._mbuf.inc(ENGINE_DECODE_KV_ROWS, float(read), {"kind": "read"})
+        self._mbuf.inc(ENGINE_DECODE_KV_ROWS, float(skipped),
+                       {"kind": "skipped"})
 
     def _note_prefill_tokens(self, real: int, pad: int) -> None:
         """What one prefill program computed: the prompt's own
@@ -2067,6 +2095,7 @@ class ContinuousBatchingEngine:
         # the state this step was given, dropped while the device runs
         # (see _upload)
         del state
+        self._note_kv_rows(active)
         (sampled,) = self._readback(self._state)
         sampled = sampled[_TOKEN]
         if want_lp:
@@ -2218,6 +2247,10 @@ class ContinuousBatchingEngine:
                 # were sent their per-slot state from the host
                 "decode_steps": self.decode_steps,
                 "state_uploads": self.state_uploads,
+                # rows of a layer's KV cache those steps' attention
+                # covered, and the rest of slots x max_seq
+                "decode_kv_rows_read": self.decode_kv_rows["read"],
+                "decode_kv_rows_skipped": self.decode_kv_rows["skipped"],
                 # prompts admitted through a prefill of their own, and
                 # how many of them were queued on the device before the
                 # host waited for the one before

@@ -414,15 +414,17 @@ def jamba_decode_step(params, token, cache, pos, config: JambaConfig):
 
     Every slot's recurrent state is updated, a parked slot's too: what
     it holds then is junk that the next admission replaces whole. The
-    cache rides in the layer loops' carry and is written in place; the
-    caller's program must donate it."""
+    cache rides in the layer loops' carry and is written in place; an
+    attention layer writes its row and hands the stacked K and V, its
+    index and ``pos`` to ``decode_attention`` (on a TPU the kernel
+    reads of each slot only the blocks up to ``pos``). The caller's
+    program must donate the cache and run on one device, and every
+    ``pos`` must lie in ``[0, S-1]``."""
     c = config
     b = token.shape[0]
     hd, kvh, di = c.head_dim, c.n_kv_heads, c.d_inner
     n_rep = c.n_heads // kvh
-    s = cache["k"].shape[2]
     x = params["embedding"][token].astype(jnp.float32)          # [B, D]
-    visible = jnp.arange(s)[None, :] <= pos[:, None]            # [B, S]
     slots = jnp.arange(b)
     k_cache, v_cache = cache["k"], cache["v"]
     ssm, conv = cache["ssm"], cache["conv"]
@@ -472,8 +474,7 @@ def jamba_decode_step(params, token, cache, pos, config: JambaConfig):
                     (h @ p["wk"]).reshape(b, kvh, hd))
                 v_cache = v_cache.at[a, slots, pos].set(
                     (h @ p["wv"]).reshape(b, kvh, hd))
-                out = decode_attention(q, k_cache[a], v_cache[a], visible,
-                                       c.dtype)
+                out = decode_attention(q, k_cache, v_cache, a, pos, c.dtype)
                 x = x + _mm(out.reshape(b, c.n_heads * hd), p["wo"])
             x = _mlp(p, x, c)
     logits = _head(params, x, c)

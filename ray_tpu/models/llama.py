@@ -578,15 +578,19 @@ def llama_decode_step(params, token, cache_k, cache_v, pos,
 
     The caches are attended over as they are stored and written in
     place: they ride in the layer scan's carry, each layer scatters its
-    [B, KVH, HD] row at ``[l, arange(B), pos]`` and then reads its own
-    slice, and the query heads are grouped by the KV head they share
-    (head h belongs to KV head ``h // n_rep``), so no K or V is
-    expanded to ``n_heads`` and no layer is handed back whole. The
+    [B, KVH, HD] row at ``[l, arange(B), pos]`` and then hands the
+    STACKED caches, ``l`` and ``pos`` to ``decode_attention``, and the
+    query heads are grouped by the KV head they share (head h belongs
+    to KV head ``h // n_rep``), so no K or V is expanded to
+    ``n_heads``, no layer is sliced out or handed back whole, and on a
+    TPU with heads of 128 the kernel reads of each slot only the
+    blocks up to its ``pos`` (a slot parked at 0: one block). The
     caller's program must donate both caches, or XLA copies them every
-    step. Every ``pos`` must lie in ``[0, S-1]`` (the engine parks idle
-    slots at 0 or at the last row): a scatter drops a row that is out of
-    bounds where ``dynamic_update_slice`` would clamp it, and inside
-    the bounds the two never differ.
+    step, and run on one device (the kernels cannot be partitioned).
+    Every ``pos`` must lie in ``[0, S-1]`` (the engine parks idle slots
+    at 0 or at the last row): a scatter drops a row that is out of
+    bounds where ``dynamic_update_slice`` would clamp it, and the
+    kernel would read blocks past the cache's end.
 
     Multi-LoRA: ``lora_bank`` stacks adapters on a leading axis
     ({A_q: [N, L, D, r], ...}; index 0 all-zero = no adapter) and
@@ -599,8 +603,6 @@ def llama_decode_step(params, token, cache_k, cache_v, pos,
     x = params["embedding"][token][:, None, :].astype(c.dtype)  # [B,1,D]
     cos, sin = rope_frequencies(hd, s, c.rope_theta)
     pos_2d = pos[:, None]                                       # [B,1]
-    # causal visibility: this token may attend to cache slots <= pos
-    visible = jnp.arange(s)[None, :] <= pos_2d                  # [B,S]
     slots = jnp.arange(b)
     if lora_bank is not None:
         # [N, L, ...] -> [L, N, ...] so the layer scan consumes them
@@ -628,10 +630,8 @@ def llama_decode_step(params, token, cache_k, cache_v, pos,
             k = apply_rope(k, cos, sin, positions=pos_2d)
             cache_k = cache_k.at[l, slots, pos].set(k[:, 0])
             cache_v = cache_v.at[l, slots, pos].set(v[:, 0])
-            ck = jax.lax.dynamic_index_in_dim(cache_k, l, keepdims=False)
-            cv = jax.lax.dynamic_index_in_dim(cache_v, l, keepdims=False)
-            attn = decode_attention(q.reshape(b, kvh, n_rep, hd), ck, cv,
-                                    visible, c.dtype)
+            attn = decode_attention(q.reshape(b, kvh, n_rep, hd), cache_k,
+                                    cache_v, l, pos, c.dtype)
             x = x + (attn.reshape(b, 1, c.n_heads * hd)
                      @ layer_params["wo"])
         with jax.named_scope(SCOPE_FFN):

@@ -23,6 +23,13 @@ equivalent the blueprint commits to.
 
 Layout: [batch, seq, heads, head_dim] (GQA supported by repeating K/V
 heads upstream in the model).
+
+Decode (``decode_attention``): one query position a slot against the
+serving cache as it is stored, [layers, slots, rows, kv_heads,
+head_dim]. One kernel takes the stacked cache in HBM, the layer index
+and the slots' positions as scalar prefetch, and fetches of each slot
+only the blocks that hold a visible row; the XLA form it replaced
+stays as the reference and the path of uncovered shapes.
 """
 
 from __future__ import annotations
@@ -32,6 +39,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from ray_tpu.accelerators import jax_backend
@@ -475,16 +483,199 @@ def flash_attention(q, k, v, causal: bool = True, mesh=None):
         check_vma=False)(q, k, v)
 
 
-def decode_attention(q, k, v, visible, dtype):
-    """One query position for every slot against the cache as it is
-    stored. q: [B, KVH, n_rep, HD], the query heads grouped by the KV
-    head they share (n_rep == 1: groups of one); k, v: [B, S, KVH, HD];
-    visible: [B, S] bool, the rows a slot may attend. -> [B, KVH,
-    n_rep, HD]. No K or V is expanded to the query's heads. Plain XLA:
-    the decode programs of every model family share it."""
+# ---------------------------------------------------------------------------
+# Decode attention: one query position a slot, over the cache as stored
+# ---------------------------------------------------------------------------
+
+def _decode_attention_reference(q, cache_k, cache_v, layer, pos, dtype):
+    """The plain XLA form of ``decode_attention``: the layer sliced out
+    of the stacked cache, every one of its S rows scored and the rows
+    above ``pos`` masked. What the kernel is tested against, and the
+    path of shapes and devices it does not cover."""
+    k = jax.lax.dynamic_index_in_dim(cache_k, layer, keepdims=False)
+    v = jax.lax.dynamic_index_in_dim(cache_v, layer, keepdims=False)
+    visible = jnp.arange(k.shape[1])[None, :] <= pos[:, None]    # [B, S]
     scores = jnp.einsum("bgrd,bsgd->bgrs", q, k,
                         preferred_element_type=jnp.float32)
     scores = scores * (q.shape[-1] ** -0.5)
-    scores = jnp.where(visible[:, None, None, :], scores, -1e30)
+    scores = jnp.where(visible[:, None, None, :], scores, NEG_INF)
     weights = jax.nn.softmax(scores, axis=-1).astype(dtype)
     return jnp.einsum("bgrs,bsgd->bgrd", weights, v)
+
+
+def decode_block_rows(s: int, kvh: int, hd: int) -> Optional[int]:
+    """Positions one block of the decode kernel holds for a cache of
+    ``s`` rows, ``kvh`` KV heads of ``hd``: a slot at position ``p``
+    has rows ``[0, (p // block + 1) * block)`` read and no other. None
+    where the kernel does not cover the shape or the backend, and the
+    reference reads all ``s`` rows. About 256 KiB of bf16 a block for 8
+    KV heads (1024 cache rows of 128 lanes); never under 128 positions,
+    so one KV head gets 512."""
+    if not (_INTERPRET or jax_backend.on_tpu()):
+        return None
+    block = min(s, max(128, 512 // kvh))
+    if hd % 128 or s % block or (block * kvh) % 128:
+        return None
+    return block
+
+
+def _decode_kernel(layer_ref, pos_ref, q_ref, at_ref, k_hbm, v_hbm, o_ref,
+                   k_buf, v_buf, sems, seen_ref, *, block: int, kvh: int,
+                   sm_scale: float):
+    """One grid step a slot. The slot's live blocks (those that hold a
+    row <= pos) come from the stacked cache in HBM by DMA, two buffers
+    deep, the next slot's first block started under this slot's last;
+    ``seen_ref`` counts the blocks so far, whose parity is the buffer.
+    A cache row is (position, KV head), heads fastest: all the query
+    heads meet all of a block's rows in one product, and ``at_ref``
+    ([heads, rows] int32) holds a row's position for the query heads
+    of its KV head and a number past any position for the others."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    b = pl.program_id(0)
+    n_slots = pl.num_programs(0)
+    layer = layer_ref[0]
+    rows = block * kvh
+    pos = pos_ref[b]
+    n_live = pos // block + 1
+
+    def copies(slot, j, buf):
+        start = pl.multiple_of(j * rows, rows)
+        return [pltpu.make_async_copy(
+            hbm.at[layer, slot, pl.ds(start, rows)], vmem.at[buf],
+            sems.at[i, buf])
+            for i, (hbm, vmem) in enumerate(((k_hbm, k_buf),
+                                             (v_hbm, v_buf)))]
+
+    def start(slot, j, buf):
+        for copy in copies(slot, j, buf):
+            copy.start()
+
+    @pl.when(b == 0)
+    def _first():
+        seen_ref[0] = 0
+        start(0, 0, 0)
+
+    seen = seen_ref[0]
+    q = q_ref[0]                                          # [H, HD]
+    at = at_ref[...]                                      # [H, rows]
+    heads, hd = q.shape
+
+    def body(j, carry):
+        m, l, acc = carry
+        buf = (seen + j) % 2
+
+        @pl.when(j + 1 < n_live)
+        def _next_block():
+            start(b, j + 1, 1 - buf)
+
+        @pl.when(jnp.logical_and(j + 1 == n_live, b + 1 < n_slots))
+        def _next_slot():
+            start(b + 1, 0, 1 - buf)
+
+        for copy in copies(b, j, buf):
+            copy.wait()
+        k = k_buf[buf]                                    # [rows, HD]
+        v = v_buf[buf]
+        s = jax.lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * sm_scale
+        s = jnp.where(at <= pos - j * block, s, NEG_INF)  # [H, rows]
+        m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+        alpha = jnp.exp(m - m_new)
+        p = jnp.exp(s - m_new)
+        l = alpha * l + jnp.sum(p, axis=-1, keepdims=True)
+        acc = alpha * acc + jax.lax.dot_general(
+            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        return m_new, l, acc
+
+    _, l, acc = jax.lax.fori_loop(
+        0, n_live, body,
+        (jnp.full((heads, 1), NEG_INF, jnp.float32),
+         jnp.zeros((heads, 1), jnp.float32),
+         jnp.zeros((heads, hd), jnp.float32)))
+    seen_ref[0] = seen + n_live
+    o_ref[0] = (acc / l).astype(o_ref.dtype)
+
+
+def _decode_pallas(q, cache_k, cache_v, layer, pos, dtype, block: int):
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    b, kvh, n_rep, hd = q.shape
+    n_layers, _, s = cache_k.shape[:3]
+    n_heads = kvh * n_rep
+    # whole sublane tiles of query heads; a padded head is a zero query
+    # of the last KV head, computed and dropped
+    heads = -(-n_heads // 16) * 16
+    rows = block * kvh
+    qh = jnp.pad(q.reshape(b, n_heads, hd),
+                 ((0, 0), (0, heads - n_heads), (0, 0)))
+    head_kv = np.minimum(np.arange(heads) // n_rep, kvh - 1)[:, None]
+    row = np.arange(rows)[None, :]
+    at = np.where(row % kvh == head_kv, row // kvh,
+                  np.iinfo(np.int32).max).astype(np.int32)
+    out = pl.pallas_call(
+        functools.partial(_decode_kernel, block=block, kvh=kvh,
+                          sm_scale=hd ** -0.5),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(b,),
+            in_specs=[
+                pl.BlockSpec((1, heads, hd), lambda i, *_: (i, 0, 0)),
+                pl.BlockSpec((heads, rows), lambda i, *_: (0, 0)),
+                pl.BlockSpec(memory_space=pl.ANY),
+                pl.BlockSpec(memory_space=pl.ANY),
+            ],
+            out_specs=pl.BlockSpec((1, heads, hd), lambda i, *_: (i, 0, 0)),
+            scratch_shapes=[
+                pltpu.VMEM((2, rows, hd), cache_k.dtype),
+                pltpu.VMEM((2, rows, hd), cache_v.dtype),
+                pltpu.SemaphoreType.DMA((2, 2)),
+                pltpu.SMEM((1,), jnp.int32),
+            ]),
+        out_shape=jax.ShapeDtypeStruct((b, heads, hd), dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=_INTERPRET,
+        name="decode_attention",
+    )(jnp.asarray(layer, jnp.int32).reshape(1), pos.astype(jnp.int32),
+      qh, at,
+      # rows of (position, KV head): the stored bytes in their order
+      cache_k.reshape(n_layers, b, s * kvh, hd),
+      cache_v.reshape(n_layers, b, s * kvh, hd))
+    return out[:, :n_heads].reshape(b, kvh, n_rep, hd)
+
+
+def decode_attention(q, cache_k, cache_v, layer, pos, dtype):
+    """One query position for every slot against the cache as it is
+    stored. q: [B, KVH, n_rep, HD], the query heads grouped by the KV
+    head they share (n_rep == 1: groups of one); cache_k, cache_v: the
+    STACKED caches [L, B, S, KVH, HD] with this step's row already
+    written; ``layer`` (an int, traced or not) the one to attend; pos:
+    [B] int32, slot ``b`` sees rows ``t <= pos[b]``. -> [B, KVH, n_rep,
+    HD] in ``dtype``. No K or V is expanded to the query's heads and no
+    layer is sliced out of the stack.
+
+    On a TPU (and in the tests' interpret mode), where ``HD % 128 ==
+    0``, S divides into ``decode_block_rows`` and q has the caches'
+    dtype, one Pallas kernel reads layer ``layer`` in place, and of each
+    slot only the blocks that hold a visible row: the decode programs
+    of every model family share it. It needs every ``pos`` in ``[0, S -
+    1]``, the program's caches donated (or XLA copies them for every
+    caller, kernel or not) and the program on one device (a Mosaic
+    kernel cannot be partitioned, as ``rms_norm`` beside it cannot).
+    Everything else takes ``_decode_attention_reference``, on a TPU
+    with an entry in ``kernel_fallbacks``."""
+    _, kvh, _, hd = q.shape
+    block = decode_block_rows(cache_k.shape[2], kvh, hd)
+    if block is None or not (q.dtype == cache_k.dtype == cache_v.dtype):
+        if jax_backend.on_tpu():
+            kernel_fallbacks.append(
+                f"decode q{list(q.shape)} {q.dtype} "
+                f"cache{list(cache_k.shape)} {cache_k.dtype}")
+        return _decode_attention_reference(q, cache_k, cache_v, layer, pos,
+                                           dtype)
+    return _decode_pallas(q, cache_k, cache_v, layer, pos, dtype, block)

@@ -171,7 +171,8 @@ def test_serving_programs_compile(v5e):
             "flash_fwd", "rms_norm"]
         lowered.compile()
     lowered = _lower_decode_step(mesh, cfg, params, batch=8, seq=1024)
-    assert [k.split("(")[0] for k in _kernels(lowered)] == ["rms_norm"]
+    assert [k.split("(")[0] for k in _kernels(lowered)] == [
+        "decode_attention", "rms_norm"]
     memory = lowered.compile().memory_analysis()
     assert (memory.argument_size_in_bytes
             + memory.temp_size_in_bytes) < HBM_BYTES
@@ -181,24 +182,34 @@ def test_serving_programs_compile(v5e):
 def test_decode_step_attends_over_the_cache_in_place(v5e):
     """The decode program of the two serving cells (Mistral-7B widths,
     16 layers, batch 32, seq 1024, caches donated) holds no copy of the
-    cache: K and V are not expanded to 32 heads, no layer is handed
-    back through a fresh buffer, and both caches alias their donated
-    inputs. The mechanism always engages, so the compiled program is
-    the counter that says it did."""
+    cache: the ``decode_attention`` kernel takes the stacked caches as
+    they are (a bitcast to rows of 128 lanes), K and V are not expanded
+    to 32 heads, no layer is sliced out or handed back through a fresh
+    buffer, the temporaries stay under 64 MiB and both caches alias
+    their donated inputs."""
     cfg = LlamaConfig(vocab_size=32768, dim=4096, n_layers=16, n_heads=32,
                       n_kv_heads=8, hidden_dim=14336, max_seq_len=1024,
                       rope_theta=1e6)
     mesh = _mesh(v5e, 1)
     params = _abstract_params(mesh, cfg)
     lowered = _lower_decode_step(mesh, cfg, params, batch=32, seq=1024)
-    assert [k.split("(")[0] for k in _kernels(lowered)] == ["rms_norm"]
+    # the kernel takes the stacked caches as rows of (position, KV head)
+    stacked = "tensor<16x32x8192x128xbf16>"
+    assert _kernels(lowered) == [
+        "decode_attention(tensor<1xi32>, tensor<32xi32>, "
+        "tensor<32x32x128xbf16>, tensor<32x1024xi32>, "
+        f"{stacked}, {stacked})",
+        "rms_norm(tensor<32x4096xbf16>, tensor<4096xbf16>)"]
     compiled = lowered.compile()
     memory = compiled.memory_analysis()
     cache_bytes = 2 * 16 * 32 * 1024 * 8 * 128 * 2
     assert memory.alias_size_in_bytes == cache_bytes
-    assert memory.temp_size_in_bytes < 256 * 2**20
+    assert memory.temp_size_in_bytes < 64 * 2**20
     text = compiled.as_text()
     assert "[32,1024,8,4,128]" not in text
+    # no layer sliced out of the stack, no score over all 1024 rows
+    assert "[1,32,1024,8,128]" not in text
+    assert "[32,8,4,1024]" not in text
     whole_cache = re.escape("bf16[16,32,1024,8,128]")
     assert not re.search(
         rf"= {whole_cache}\S* (copy|custom-call)\(", text)
@@ -210,9 +221,10 @@ def test_engine_decode_program_feeds_its_state_back(v5e, monkeypatch,
     """The engine's own ``decode`` / ``decode_lp`` program at the two
     serving cells' sizes, its per-slot inputs and the sampler's counter
     one packed [7, 32] int32 state that it also returns: both caches
-    still alias their donated inputs, the temporaries stay under 256
+    still alias their donated inputs, the temporaries stay under 64
     MiB (the sampler's and, with logprobs, the log-softmax's [32, 32768]
-    rows), ``rms_norm`` is the one kernel, and the state comes back
+    rows; no copy of a cache), ``decode_attention`` and ``rms_norm``
+    are the kernels, and the state comes back
     with the shape and type it went in with, so the next step can take
     it as it is."""
     from ray_tpu.llm import engine as engine_mod
@@ -236,11 +248,12 @@ def test_engine_decode_program_feeds_its_state_back(v5e, monkeypatch,
     lowered = engine._decode.lower(
         params, [cache_k, cache_v], state, _on(mesh, P(), (2,), jnp.uint32),
         None, _on(mesh, P(), (32, 32768), jnp.float32), want_lp=want_lp)
-    assert [k.split("(")[0] for k in _kernels(lowered)] == ["rms_norm"]
+    assert [k.split("(")[0] for k in _kernels(lowered)] == [
+        "decode_attention", "rms_norm"]
     compiled = lowered.compile()
     memory = compiled.memory_analysis()
     assert memory.alias_size_in_bytes == 2 * 16 * 32 * 1024 * 8 * 128 * 2
-    assert memory.temp_size_in_bytes < 256 * 2**20
+    assert memory.temp_size_in_bytes < 64 * 2**20
     out_state = jax.tree.leaves(lowered.out_info)[0]
     assert (out_state.shape, out_state.dtype) == ((7, 32), jnp.int32)
     assert "[32,1024,8,4,128]" not in compiled.as_text()
@@ -250,9 +263,10 @@ def test_jamba_serving_programs_compile_at_published_widths(v5e):
     """The Jamba cell's engine programs as the chip gets them
     (AI21-Jamba2-3B whole: 26 Mamba layers and 2 of attention, batch
     32, seq 1024): the decode program through the engine's family seam
-    holds ``rms_norm`` alone, aliases the whole cache of two kinds
-    (donated, so no state and no row is copied) and keeps its
-    temporaries small; a prefill program holds its bucket's scan kernel
+    holds ``decode_attention`` beside ``rms_norm``, aliases the whole
+    cache of two kinds (donated, so no state and no row is copied) and
+    keeps its temporaries under 64 MiB; a prefill program holds its
+    bucket's scan kernel
     beside flash attention, and everything fits one chip."""
     from ray_tpu.llm import engine as engine_mod
     from ray_tpu.models.jamba import (JambaConfig, jamba_init,
@@ -282,13 +296,14 @@ def test_jamba_serving_programs_compile_at_published_widths(v5e):
         params, cache, _on(mesh, P(), (7, 32), jnp.int32),
         _on(mesh, P(), (2,), jnp.uint32), None,
         _on(mesh, P(), (32, 65536), jnp.float32), want_lp=False)
-    assert [k.split("(")[0] for k in _kernels(lowered)] == ["rms_norm"]
+    assert [k.split("(")[0] for k in _kernels(lowered)] == [
+        "decode_attention", "rms_norm"]
     memory = lowered.compile().memory_analysis()
     # the K/V rows of 2 layers and the state of 26: all of it in place
     kv = 2 * 2 * 32 * 1024 * 128 * 2
     state = 26 * 32 * 5120 * (16 * 4 + 3 * 2)
     assert kv + state <= memory.alias_size_in_bytes <= 1.2 * (kv + state)
-    assert memory.temp_size_in_bytes < 256 * 2**20
+    assert memory.temp_size_in_bytes < 64 * 2**20
     assert (memory.argument_size_in_bytes
             + memory.temp_size_in_bytes) < HBM_BYTES // 2
     lowered = engine._prefill.lower(

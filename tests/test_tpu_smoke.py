@@ -64,12 +64,14 @@ def test_kernel_checks_compare_against_references(monkeypatch):
     from ray_tpu.ops import attention, quant_matmul, rmsnorm
     for mod in (attention, quant_matmul, rmsnorm):
         monkeypatch.setattr(mod, "_INTERPRET", True)
-    shapes = ([(1, 256, 2, 128, True)], [(2, 64, 256)], (4, 1024, 512))
+    shapes = ([(1, 256, 2, 128, True)], [(2, 64, 256)], (4, 1024, 512),
+              (2, 3, 256, 2, 128, 2))
     report = chip_smoke.check_kernels(*shapes, expect_kernels=False)
     checks = report["checks"]
     for name in ("flash[1,256,2,128]", "flash[1,256,2,128].dq",
                  "flash[1,256,2,128].dk", "flash[1,256,2,128].dv",
-                 "rms_norm[2, 64, 256]", "int8_matmul[4,1024,512]"):
+                 "rms_norm[2, 64, 256]", "int8_matmul[4,1024,512]",
+                 "decode_attention[2, 3, 256, 2, 128, 2]"):
         assert 0 <= checks[name] <= chip_smoke.KERNEL_TOL, (name, checks)
     # interpreter mode lowers to plain HLO: a caller that expects the
     # kernels in the program is told they are not there
