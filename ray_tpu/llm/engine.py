@@ -120,6 +120,27 @@ ENGINE_DECODE_KV_ROWS = _metrics.Counter(
     "parked slot's park row) and the rest of its slots x max_seq rows "
     "(skipped); all read where the decode kernel does not engage",
     tag_keys=("kind",))
+ENGINE_EXPERT_PICKS = _metrics.Counter(
+    "ray_tpu_engine_expert_picks_total",
+    "Experts picked by the router for live rows (a prompt's own "
+    "positions, a live slot's decode step; parked slots and padding "
+    "not counted), by where the expert lives: held (this device "
+    "computed it) or absent (another rank of its expert-parallel group "
+    "does)",
+    tag_keys=("where",))
+ENGINE_EXPERT_SLOTS = _metrics.Counter(
+    "ray_tpu_engine_expert_slots_total",
+    "Held experts over the layers of the dense decode steps, by state: "
+    "hit (at least one live row picked it) or idle (none did, its "
+    "weights were read for nothing)",
+    tag_keys=("state",))
+# the expert layers' device counts (parallel.moe.EXPERT_COUNTS) that
+# are series: name -> (counter, tags)
+_EXPERT_COUNT_SERIES = {
+    "picks_held": (ENGINE_EXPERT_PICKS, {"where": "held"}),
+    "picks_absent": (ENGINE_EXPERT_PICKS, {"where": "absent"}),
+    "slots_hit": (ENGINE_EXPERT_SLOTS, {"state": "hit"}),
+    "slots_idle": (ENGINE_EXPERT_SLOTS, {"state": "idle"})}
 ENGINE_ADMIT_LAUNCH_SECONDS = _metrics.Histogram(
     "ray_tpu_engine_admit_launch_seconds",
     "Time from the stepper's pop of a waiting request to the return of "
@@ -291,6 +312,11 @@ class _MetricsBuffer(_metrics.LocalBuffer):
         # the stepper's account, list by list, as the last flush read it
         self.stepper_seconds = [[0.0] * len(phases)
                                 for _, _, phases in _STEPPER_FAMILIES]
+        # the expert layers' device counts as the last flush read them
+        # (uint32 on the device: a difference is taken modulo 2**32),
+        # and their sums since the engine began, by name
+        self.expert_read = [0] * len(engine._family.expert_counts)
+        self.expert_totals = dict.fromkeys(engine._family.expert_counts, 0)
         # flushers: the buffer's thread and stats()/flush_metrics()
         # callers on request threads; never the stepper
         self._flush_lock = locktrace.traced_lock("llm.engine.flush")
@@ -346,10 +372,28 @@ class _MetricsBuffer(_metrics.LocalBuffer):
                     for phase, now_s, sent_s in zip(phases, read, sent):
                         self.inc(counter, now_s - sent_s, {"phase": phase})
                     sent[:] = read
+                self._read_expert_counts(engine)
             try:
                 return super().flush()
             except Exception:  # graftlint: disable=GL004
                 return []  # observability is best-effort
+
+    def _read_expert_counts(self, engine) -> None:
+        """What the family's expert layers counted on the device since
+        the last flush. The array is the one the newest program
+        returned: reading it waits for that program, here on the
+        flusher's thread, never on the stepper's."""
+        names = engine._family.expert_counts
+        if not names:
+            return
+        read = [int(v) for v in np.asarray(engine._expert_counts)]
+        for name, now, sent in zip(names, read, self.expert_read):
+            grown = (now - sent) % 2**32
+            self.expert_totals[name] += grown
+            if grown and name in _EXPERT_COUNT_SERIES:
+                counter, tags = _EXPERT_COUNT_SERIES[name]
+                self.inc(counter, float(grown), tags)
+        self.expert_read = read
 
     def close(self) -> None:
         """Stop the flush thread after one last flush."""
@@ -744,6 +788,12 @@ class ContinuousBatchingEngine:
         # of them were launched under the prefill of the one before
         self.admissions = 0
         self.admissions_overlapped = 0
+        # what the family's expert layers count on the device (picks
+        # and slots): a small array every prefill and decode program
+        # takes and returns one further, NOT donated, so that the
+        # metrics flush can read whichever it finds; None for a family
+        # with no routed experts
+        self._expert_counts = self._fresh_expert_counts()
         self._mbuf = _MetricsBuffer(self)
         for kind, nbytes in self.cache_bytes.items():
             self._mbuf.set(ENGINE_CACHE_BYTES, float(nbytes),
@@ -801,7 +851,7 @@ class ContinuousBatchingEngine:
             return jnp.where(temp <= 0.0, greedy, sampled)
 
         def decode(params, cache, state, base_key, lora_bank, bias,
-                   want_lp=False):
+                   counts=None, want_lp=False):
             """One token for every live slot. ``state`` ([7, B] int32,
             rows _TOKEN.._STEP) comes back as the next step's: a live
             slot's sampled token and its position one further, a
@@ -810,9 +860,11 @@ class ContinuousBatchingEngine:
             for the next step."""
             tokens, pos, live = state[_TOKEN], state[_POS], state[_LIVE]
             temp = jax.lax.bitcast_convert_type(state[_TEMP], jnp.float32)
-            logits, cache = fam.decode_step(
+            logits, cache, counted = fam.decode_step(
                 params, tokens, jax.tree.unflatten(cache_def, cache), pos,
-                c, lora_bank, state[_LORA])
+                live, c, lora_bank, state[_LORA])
+            if counted is not None:
+                counts = counts + counted
             cache = jax.tree.leaves(cache)
             key = jax.random.fold_in(base_key, state[_STEP, 0])
             tok = sample_tokens(logits, temp, state[_TOPK], key, bias)
@@ -821,18 +873,21 @@ class ContinuousBatchingEngine:
             if not want_lp:
                 # static arg: the no-logprobs program carries none of
                 # the log_softmax/top_k work or output buffers
-                return (state, None, None, None, *cache)
+                return (state, None, None, None, counts, *cache)
             # logprobs of the biased (un-temperature-scaled) logits;
             # [B] chosen + [B, lp_k] top alternatives — tiny transfers
             lsm = jax.nn.log_softmax(
                 (logits + bias).astype(jnp.float32), axis=-1)
             chosen = jnp.take_along_axis(lsm, tok[:, None], 1)[:, 0]
             top_vals, top_ids = jax.lax.top_k(lsm, lp_k)
-            return (state, chosen, top_vals, top_ids, *cache)
+            return (state, chosen, top_vals, top_ids, counts, *cache)
 
-        def prefill(params, tokens, length, lora):
-            logits, entry = fam.prefill(params, tokens, length, c, lora)
-            return (logits, *jax.tree.leaves(entry))
+        def prefill(params, tokens, length, lora, counts=None):
+            logits, entry, counted = fam.prefill(params, tokens, length, c,
+                                                 lora)
+            if counted is not None:
+                counts = counts + counted
+            return (logits, counts, *jax.tree.leaves(entry))
 
         def sample_one(logits, temp, topk, key, bias_row,
                        want_lp=False):
@@ -1290,6 +1345,14 @@ class ContinuousBatchingEngine:
             [jnp.zeros((c.max_batch, vocab), jnp.float32),
              jnp.zeros((vocab,), jnp.float32)], self._on_device)
 
+    def _fresh_expert_counts(self):
+        names = self._family.expert_counts
+        if not names:
+            return None
+        return self._jax.device_put(
+            self._jnp.zeros((len(names),), self._jnp.uint32),
+            self._on_device)
+
     def _upload(self, *arrays) -> list:
         """Host arrays of one step onto the device, committed there
         (see ``_on_device``); the time it takes is the step's upload
@@ -1417,9 +1480,10 @@ class ContinuousBatchingEngine:
             lora = self._adapter_prefill.get(adapter) if adapter else None
             (tokens_dev,) = self._upload(padded)
             with self._span("engine.launch"):
-                logits, *entry = self._call_program(
+                logits, self._expert_counts, *entry = self._call_program(
                     f"prefill_{padded.shape[1]}", self._prefill,
-                    self.params, tokens_dev, np.int32(len(ids)), lora)
+                    self.params, tokens_dev, np.int32(len(ids)), lora,
+                    self._expert_counts)
             # a family that is told the length may return that
             # position's row alone
             last = min(len(ids), logits.shape[1]) - 1
@@ -2078,12 +2142,12 @@ class ContinuousBatchingEngine:
             self.state_uploads += 1  # graftlint: disable=GL001
             self._mbuf.inc(ENGINE_STATE_UPLOADS)
         with self._span("engine.launch"):
-            self._state, chosen_lp, top_vals, top_ids, *self.cache = \
-                self._call_program(
-                    "decode_lp" if want_lp else "decode", self._decode,
-                    self.params, self.cache, state,
-                    self._base_key, self.lora_bank, self._bias,
-                    want_lp=want_lp)
+            (self._state, chosen_lp, top_vals, top_ids,
+             self._expert_counts, *self.cache) = self._call_program(
+                "decode_lp" if want_lp else "decode", self._decode,
+                self.params, self.cache, state,
+                self._base_key, self.lora_bank, self._bias,
+                self._expert_counts, want_lp=want_lp)
             if self._spec:
                 # keep the draft cache in lockstep through dense
                 # rounds, or the next _spec_step would condition on KV
@@ -2275,6 +2339,21 @@ class ContinuousBatchingEngine:
                         _STEPPER_FAMILIES, self._mbuf.stepper_seconds)},
                 "stepper_read_at": self._mbuf._last_flush,
             }
+            if self._family.expert_counts:
+                # what the expert layers counted on the device, as the
+                # flush above read it: the router's picks for live rows
+                # by where the expert lives, the held experts of the
+                # decode steps' layers by whether a live row used them,
+                # and the held picks that the layer did not compute
+                # (it has no capacity: 0 unless its sort and its groups
+                # disagree)
+                n = self._mbuf.expert_totals
+                out["expert_picks"] = {"held": n["picks_held"],
+                                       "absent": n["picks_absent"]}
+                out["expert_slots"] = {"hit": n["slots_hit"],
+                                       "idle": n["slots_idle"]}
+                out["dropped_rows"] = (n["picks_held"]
+                                       - n["picks_computed"])
             if self._prefix_cache is not None:
                 out["prefix_cache_entries"] = len(self._prefix_cache)
                 out["prefix_hits"] = self.prefix_hits

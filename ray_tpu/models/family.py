@@ -32,17 +32,25 @@ class ModelFamily:
     # (config, batch, max_seq) -> cache
     init_cache: Callable
     # (params, tokens [1, bucket], length, config, lora) -> (logits
-    # [1, n, vocab] float32, entry). ``length`` is the prompt's true
-    # length, traced; a family that has no use for it ignores it and
-    # its programs do not hold it. n is the bucket, or 1 where the
+    # [1, n, vocab] float32, entry, counts). ``length`` is the prompt's
+    # true length, traced; a family that has no use for it ignores it
+    # and its programs do not hold it. n is the bucket, or 1 where the
     # family returns the row of position length - 1 alone.
     prefill: Callable
-    # (params, token [B], cache, pos [B], config, lora_bank, lora_idx)
-    # -> (logits [B, vocab] float32, cache); the caller donates cache
+    # (params, token [B], cache, pos [B], live [B], config, lora_bank,
+    # lora_idx) -> (logits [B, vocab] float32, cache, counts); the
+    # caller donates cache. ``live`` says which slots hold a request
+    # (the others are parked: computed, and counted by nobody).
     decode_step: Callable
     # cache -> bytes by kind, {"kv": ..., "recurrent": ...}
     cache_bytes: Callable
     recurrent: bool
+    # parallel.moe.EXPERT_COUNTS where the family's programs count their
+    # expert layers' picks ON THE DEVICE: the ``counts`` above are then
+    # [n] uint32 of that prompt or step, and the engine adds them up in
+    # an array its programs hand on and reads it where metrics flush.
+    # (): the family has no routed experts, ``counts`` is None.
+    expert_counts: tuple = ()
 
 
 def insert_slot(cache, entry, slot):
@@ -67,14 +75,14 @@ def _llama() -> ModelFamily:
     def prefill(params, tokens, length, config, lora):
         logits, ks, vs = llama.llama_prefill(params, tokens, config,
                                              lora=lora)
-        return logits, (ks, vs)
+        return logits, (ks, vs), None
 
-    def decode_step(params, token, cache, pos, config, lora_bank,
+    def decode_step(params, token, cache, pos, live, config, lora_bank,
                     lora_idx):
         logits, ck, cv = llama.llama_decode_step(
             params, token, *cache, pos, config, lora_bank=lora_bank,
             lora_idx=lora_idx)
-        return logits, (ck, cv)
+        return logits, (ck, cv), None
 
     return ModelFamily(
         init=llama.llama_init,
@@ -91,11 +99,12 @@ def _jamba() -> ModelFamily:
     from ray_tpu.models import jamba
 
     def prefill(params, tokens, length, config, lora):
-        return jamba.jamba_prefill(params, tokens, length, config)
+        return (*jamba.jamba_prefill(params, tokens, length, config), None)
 
-    def decode_step(params, token, cache, pos, config, lora_bank,
+    def decode_step(params, token, cache, pos, live, config, lora_bank,
                     lora_idx):
-        return jamba.jamba_decode_step(params, token, cache, pos, config)
+        return (*jamba.jamba_decode_step(params, token, cache, pos, config),
+                None)
 
     return ModelFamily(
         init=jamba.jamba_init,
@@ -109,13 +118,38 @@ def _jamba() -> ModelFamily:
         recurrent=True)
 
 
+@functools.cache
+def _granite() -> ModelFamily:
+    from ray_tpu.models import granite
+
+    def prefill(params, tokens, length, config, lora):
+        return granite.granite_prefill(params, tokens, length, config)
+
+    def decode_step(params, token, cache, pos, live, config, lora_bank,
+                    lora_idx):
+        return granite.granite_decode_step(params, token, cache, pos, live,
+                                           config)
+
+    return ModelFamily(
+        init=granite.granite_init,
+        hidden=lambda params, tokens, config: granite.granite_forward(
+            params, tokens, config, return_hidden=True),
+        init_cache=granite.granite_init_cache, prefill=prefill,
+        decode_step=decode_step,
+        cache_bytes=lambda cache: {
+            "kv": _nbytes([cache["k"], cache["v"]]),
+            "recurrent": _nbytes([cache["ssm"], cache["conv"]])},
+        recurrent=True, expert_counts=granite.EXPERT_COUNTS)
+
+
 _FAMILIES: Dict[str, Callable[[], ModelFamily]] = {
-    "LlamaConfig": _llama, "JambaConfig": _jamba}
+    "LlamaConfig": _llama, "JambaConfig": _jamba,
+    "GraniteConfig": _granite}
 
 
 def family_of(config: Any) -> ModelFamily:
     """The family of a model configuration, by the configuration's
-    class (LlamaConfig, JambaConfig)."""
+    class (LlamaConfig, JambaConfig, GraniteConfig)."""
     try:
         return _FAMILIES[type(config).__name__]()
     except KeyError:

@@ -40,11 +40,11 @@ Design for the TPU:
 - ``A_log`` is kept as [N, d_inner], the transpose of the published
   [d_inner, N], for the same tiling reason.
 - Precision: weights and matmul inputs are bf16, accumulation float32,
-  and what lies between two matmuls stays float32 (``_mm``). 28 layers
-  of two sublayers in series add up what every rounding in between
-  costs; a matmul of few rows (a decode step of up to ``_SPLIT_ROWS``
-  slots) also carries its input's low half through, for 3% of the
-  step, so decode rounds less than prefill (see ``_mm``).
+  and what lies between two matmuls stays float32 (``ops.matmul.mm``).
+  28 layers of two sublayers in series add up what every rounding in
+  between costs; a matmul of few rows (a decode step of up to
+  ``ops.matmul.SPLIT_ROWS`` slots) also carries its input's low half
+  through, for 3% of the step, so decode rounds less than prefill.
 
 Serving only: the scan has no backward (ops/selective_scan.py).
 """
@@ -52,12 +52,14 @@ Serving only: the scan has no backward (ops/selective_scan.py).
 from __future__ import annotations
 
 from dataclasses import dataclass
-from typing import Any, Dict, List, Tuple
+from typing import Any, Dict, Tuple
 
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.ops.attention import decode_attention, flash_attention
+from ray_tpu.models.hybrid import (attn_decode, attn_sequence,
+                                   layer as _layer, runs)
+from ray_tpu.ops.matmul import mm as _mm
 from ray_tpu.ops.rmsnorm import rms_norm
 from ray_tpu.ops.selective_scan import selective_scan
 
@@ -67,7 +69,6 @@ SCOPE_CONV = "mamba.conv"
 SCOPE_SCAN = "mamba.scan"        # prefill: the recurrence over a prompt
 SCOPE_UPDATE = "mamba.update"    # decode: one step of it for every slot
 SCOPE_OUT_PROJ = "mamba.out_proj"
-SCOPE_ATTN = "attn"
 SCOPE_MLP = "mlp"
 SCOPE_HEAD = "head"
 
@@ -117,15 +118,7 @@ class JambaConfig:
     def runs(self) -> Tuple[Tuple[str, int, int], ...]:
         """The trunk as (kind, first index within its kind's stack,
         count) for each run of consecutive layers of one kind."""
-        out: List[Tuple[str, int, int]] = []
-        seen = {"attn": 0, "mamba": 0}
-        for kind in self.layer_kinds:
-            if out and out[-1][0] == kind:
-                out[-1] = (kind, out[-1][1], out[-1][2] + 1)
-            else:
-                out.append((kind, seen[kind], 1))
-            seen[kind] += 1
-        return tuple(out)
+        return runs(self.layer_kinds)
 
     @staticmethod
     def tiny(**kw) -> "JambaConfig":
@@ -201,49 +194,6 @@ def jamba_init(rng, config: JambaConfig) -> Dict[str, Any]:
             "mamba": mamba, "attn": attn, "final_norm": ones(c.dim)}
 
 
-def _layer(stack, index):
-    """One layer's weights out of a stack, by a traced or static index."""
-    return jax.tree.map(
-        lambda w: jax.lax.dynamic_index_in_dim(w, index, keepdims=False),
-        stack)
-
-
-# ``_mm`` carries the low half of its input through when it has at most
-# this many rows: a replica's 32 slots. It is not free: one decode step
-# alone takes 10.74 ms with it and 10.39 without at 32 slots, 12.97 /
-# 12.30 at 64, 18.26 / 16.51 at 128 (one v5e chip, PERF.md, PR 34), so an
-# engine of more slots rounds its input as a prefill does.
-_SPLIT_ROWS = 32
-
-
-def _mm(x, w):
-    """x [rows, K] @ w on the matrix unit, the result left in the
-    accumulator's float32. Whatever lies between two matmuls (the
-    residual stream, gates, the convolution, norms) stays float32: it
-    is [tokens, features], next to nothing beside the weights a step
-    reads, and every rounding saved is noise the 28 x 2 sublayers in
-    series do not add up (see PERF.md, PR 34).
-
-    Many rows (a prefill): x is rounded to the weights' bf16. Few rows
-    (a decode step, which reading the weights bounds): the rounding is
-    taken back. x = hi + lo, both bf16, go through ONE matmul as [hi;
-    lo] and the two halves of the result are added, so the weights are
-    read once. It took the engine's distance from the float32
-    reference over 192 tokens from 0.075-0.129 at worst (limit 0.15)
-    and 0.022-0.029 on average to 0.027-0.091 and 0.008-0.012, for 3%
-    of a decode step. ``hi`` comes from ``reduce_precision``, an
-    operation XLA keeps: a float32 -> bf16 -> float32 pair of converts
-    it may drop (excess precision), and ``lo`` would be 0."""
-    rows = x.shape[0]
-    if x.dtype == w.dtype or rows > _SPLIT_ROWS:
-        return jnp.dot(x.astype(w.dtype), w,
-                       preferred_element_type=jnp.float32)
-    hi = jax.lax.reduce_precision(x, exponent_bits=8, mantissa_bits=7)
-    out = jnp.dot(jnp.concatenate([hi, x - hi], axis=0).astype(w.dtype), w,
-                  preferred_element_type=jnp.float32)
-    return out[:rows] + out[rows:]
-
-
 def _mlp(p, x, c: JambaConfig):
     with jax.named_scope(SCOPE_MLP):
         h = rms_norm(x, p["ff_norm"], c.norm_eps)
@@ -303,27 +253,6 @@ def _mamba_sequence(p, x, length, c: JambaConfig):
     return x, ssm, conv
 
 
-def _attn_sequence(p, x, c: JambaConfig):
-    """One attention layer over one sequence. x [L, dim] -> (x, k, v
-    [L, KVH, HD]). Causal, no position encoding."""
-    seq, hd = x.shape[0], c.head_dim
-    with jax.named_scope(SCOPE_ATTN):
-        h = rms_norm(x, p["in_norm"], c.norm_eps).astype(c.dtype)
-        q = (h @ p["wq"]).reshape(1, seq, c.n_heads, hd)
-        k = (h @ p["wk"]).reshape(1, seq, c.n_kv_heads, hd)
-        v = (h @ p["wv"]).reshape(1, seq, c.n_kv_heads, hd)
-        n_rep = c.n_heads // c.n_kv_heads
-        kk = jnp.repeat(k, n_rep, axis=2) if n_rep > 1 else k
-        vv = jnp.repeat(v, n_rep, axis=2) if n_rep > 1 else v
-        if c.attention == "flash":
-            out = flash_attention(q, kk, vv, True)
-        else:
-            from ray_tpu.ops.attention import _attention_reference
-            out = _attention_reference(q, kk, vv, True)
-        x = x + _mm(out.reshape(seq, c.n_heads * hd), p["wo"])
-    return x, k[0], v[0]
-
-
 def _trunk(params, tokens, length, c: JambaConfig):
     """tokens [L] int32 -> (hidden [L, dim] before the final norm,
     the sequence's cache entry as jamba_init_cache lays it out, with a
@@ -334,7 +263,7 @@ def _trunk(params, tokens, length, c: JambaConfig):
         if kind == "attn":
             for a in range(first, first + count):
                 p = _layer(params["attn"], a)
-                x, k, v = _attn_sequence(p, x, c)
+                x, k, v = attn_sequence(p, x, c)
                 x = _mlp(p, x, c)
                 ks.append(k)
                 vs.append(v)
@@ -421,11 +350,8 @@ def jamba_decode_step(params, token, cache, pos, config: JambaConfig):
     program must donate the cache and run on one device, and every
     ``pos`` must lie in ``[0, S-1]``."""
     c = config
-    b = token.shape[0]
-    hd, kvh, di = c.head_dim, c.n_kv_heads, c.d_inner
-    n_rep = c.n_heads // kvh
+    di = c.d_inner
     x = params["embedding"][token].astype(jnp.float32)          # [B, D]
-    slots = jnp.arange(b)
     k_cache, v_cache = cache["k"], cache["v"]
     ssm, conv = cache["ssm"], cache["conv"]
 
@@ -467,15 +393,8 @@ def jamba_decode_step(params, token, cache, pos, config: JambaConfig):
             continue
         for a in range(first, first + count):
             p = _layer(params["attn"], a)
-            with jax.named_scope(SCOPE_ATTN):
-                h = rms_norm(x, p["in_norm"], c.norm_eps).astype(c.dtype)
-                q = (h @ p["wq"]).reshape(b, kvh, n_rep, hd)
-                k_cache = k_cache.at[a, slots, pos].set(
-                    (h @ p["wk"]).reshape(b, kvh, hd))
-                v_cache = v_cache.at[a, slots, pos].set(
-                    (h @ p["wv"]).reshape(b, kvh, hd))
-                out = decode_attention(q, k_cache, v_cache, a, pos, c.dtype)
-                x = x + _mm(out.reshape(b, c.n_heads * hd), p["wo"])
+            x, k_cache, v_cache = attn_decode(p, x, k_cache, v_cache, a,
+                                              pos, c)
             x = _mlp(p, x, c)
     logits = _head(params, x, c)
     return logits, {"k": k_cache, "v": v_cache, "ssm": ssm, "conv": conv}

@@ -14,15 +14,42 @@ Components:
   renormalized weights and a Switch-style load-balancing aux loss.
 - ``moe_dispatch``/``moe_combine``: capacity-bounded one-hot routing.
 - ``moe_ffn``: the full layer — gate → dispatch → per-expert SwiGLU
-  FFN (batched over the expert axis) → combine.
+  FFN (batched over the expert axis) → combine. The trainer's form.
+- ``held_experts_ffn``: the serving form, one device of an expert-
+  parallel group: every row routed over ALL the experts, the experts
+  this device holds computed, nothing dropped (no capacity, no factor).
+  ``gated_ffn`` is the shared expert beside it.
 """
 
 from __future__ import annotations
 
-from typing import Any, Dict, Tuple
+from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+
+from ray_tpu.ops.matmul import SPLIT_ROWS, few_rows, mm
+
+# jax.named_scope names of held_experts_ffn's two parts
+SCOPE_ROUTER = "moe.router"
+SCOPE_EXPERTS = "moe.experts"
+# what held_experts_ffn counts of its live rows, in this order
+EXPERT_COUNTS = ("picks_held", "picks_absent", "picks_computed",
+                 "slots_hit", "slots_idle")
+
+
+def _gates(logits: jax.Array, k: int
+           ) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """Router logits [T, E] -> (gates [T, E], topk_idx [T, k], probs
+    [T, E]): a softmax over all experts, the k largest, renormalized
+    over those; gates are zero outside them."""
+    probs = jax.nn.softmax(logits, axis=-1)
+    topk_vals, topk_idx = jax.lax.top_k(probs, k)                # [T, k]
+    topk_vals = topk_vals / jnp.maximum(
+        topk_vals.sum(axis=-1, keepdims=True), 1e-9)
+    gates = jnp.zeros_like(probs).at[
+        jnp.arange(logits.shape[0])[:, None], topk_idx].set(topk_vals)
+    return gates, topk_idx, probs
 
 
 def top_k_gating(x: jax.Array, router: jax.Array, k: int
@@ -34,14 +61,9 @@ def top_k_gating(x: jax.Array, router: jax.Array, k: int
     is the Switch load-balancing term E * sum_e(frac_tokens_e *
     mean_prob_e), minimized at uniform routing.
     """
-    logits = x.astype(jnp.float32) @ router.astype(jnp.float32)  # [T, E]
-    probs = jax.nn.softmax(logits, axis=-1)
-    topk_vals, topk_idx = jax.lax.top_k(probs, k)                # [T, k]
-    topk_vals = topk_vals / jnp.maximum(
-        topk_vals.sum(axis=-1, keepdims=True), 1e-9)
+    gates, topk_idx, probs = _gates(
+        jnp.dot(x.astype(jnp.float32), router.astype(jnp.float32)), k)
     num_experts = router.shape[-1]
-    gates = jnp.zeros_like(probs).at[
-        jnp.arange(x.shape[0])[:, None], topk_idx].set(topk_vals)
     # load-balancing aux (Switch Transformer eq. 4-6)
     top1 = jax.nn.one_hot(topk_idx[:, 0], num_experts)
     frac_tokens = top1.mean(axis=0)
@@ -117,3 +139,117 @@ def moe_ffn(x: jax.Array, router: jax.Array, w1: jax.Array,
     # [T,E,C] x [E,C,D] -> [T,D]: the all-to-all back (experts -> tokens)
     y = jnp.einsum("tec,ecd->td", combine, expert_out)
     return y.reshape(b, s, d), aux
+
+
+def gated_ffn(x: jax.Array, w_in: jax.Array, w_out: jax.Array) -> jax.Array:
+    """A gated feed-forward whose two input projections are one matrix:
+    ``[a | b] = x w_in`` ([D, 2I], the gated half first), ``(silu(a) *
+    b) w_out``. x [T, D] -> [T, D] float32. A shared expert is this,
+    beside the routed ones and counted once."""
+    ab = mm(x, w_in)
+    half = ab.shape[-1] // 2
+    return mm(jax.nn.silu(ab[..., :half]) * ab[..., half:], w_out)
+
+
+def held_experts_ffn(x: jax.Array, router: jax.Array, w_in: jax.Array,
+                     w_out: jax.Array, first: int, *, layer, top_k: int,
+                     live: Optional[jax.Array] = None
+                     ) -> Tuple[jax.Array, jax.Array]:
+    """The part of a routed layer that the experts THIS device holds
+    add: one rank of an expert-parallel group.
+
+    ``x`` [T, D] rows; ``router`` [D, E] over all E experts; ``w_in``
+    [L, H, D, 2I] and ``w_out`` [L, H, I, D], the stacked weights of the
+    held experts ``first .. first + H - 1`` (each a ``gated_ffn``) of L
+    layers, of which this is layer ``layer`` (an index, traced or not):
+    a caller that walks a stack of layers hands the stack over and no
+    layer's experts are sliced out into a copy (453 + 226 MB a layer at
+    granite-4.0-h-small's widths, 2.07 ms of every prefill's layer on a
+    v5e: PERF.md, PR 45). Every row picks its ``top_k`` of all E by the
+    router (its product at the highest precision: a TPU rounds float32
+    inputs to bf16 by default, and a rounded logit changes a pick),
+    gates renormalised over the picks; a pick of a held expert adds
+    ``gate * expert(row)``, a pick of an absent one adds nothing HERE
+    (the rank that holds it adds it; the ranks' parts sum to the whole
+    layer). Nothing is dropped: there is no capacity and no factor,
+    every pick of a held expert is computed whatever the router's skew.
+
+    By the static row count, as ``ops.matmul.few_rows`` decides:
+    - few rows (a decode step): every held expert on every row, weighted
+      by the gate, which is zero where the row did not pick it. Exact,
+      and bound by reading the experts' weights either way.
+    - many rows (a prefill): the (row, pick) pairs sorted by expert,
+      the held experts' first, and one grouped matmul (``ragged_dot``)
+      over their rows; the absent experts' pairs are past the last
+      group and masked out. The groups are all L x H experts of the
+      stack, every other layer's of size zero.
+
+    -> (y [T, D] float32, EXPERT_COUNTS [5] uint32 of the ``live`` rows
+    ([T] bool, default every row): their picks that fell on held
+    experts, those that fell on absent ones, the held picks whose
+    product was computed (every one in the first regime; in the second
+    those whose place in the sorted order lies inside the groups), how
+    many held experts got a live row and how many got none)."""
+    rows, dtype = x.shape[0], w_in.dtype
+    n_experts, (held, inner, _) = router.shape[-1], w_out.shape[-3:]
+    live = jnp.ones((rows,), bool) if live is None else live.astype(bool)
+    with jax.named_scope(SCOPE_ROUTER):
+        gates, idx, _ = _gates(
+            jnp.dot(x.astype(jnp.float32), router.astype(jnp.float32),
+                    precision=jax.lax.Precision.HIGHEST), top_k)
+        # expert e as (e - first) mod E, which is < H exactly for the
+        # held; a row picks an expert at most once
+        rel = (idx - first) % n_experts                       # [T, k]
+        picked = jnp.any(rel[:, :, None] == jnp.arange(held), axis=1)
+        picked_live = picked & live[:, None]
+        n_held = jnp.sum(picked_live)
+        n_hit = jnp.sum(jnp.any(picked_live, axis=0))
+
+    def counts(n_computed):
+        return jnp.stack([n_held, jnp.sum(live) * top_k - n_held,
+                          n_computed, n_hit, held - n_hit]
+                         ).astype(jnp.uint32)
+
+    with jax.named_scope(SCOPE_EXPERTS):
+        if rows <= SPLIT_ROWS:
+            # a slice XLA reads through, inside the product
+            w_in, w_out = (jax.lax.dynamic_index_in_dim(
+                w, layer, keepdims=False) for w in (w_in, w_out))
+            both, merge = few_rows(x, dtype)
+            ab = merge(jnp.einsum("td,edf->etf", both, w_in,
+                                  preferred_element_type=jnp.float32))
+            mine = gates[:, first:first + held]   # 0 where not picked
+            act = (jax.nn.silu(ab[..., :inner]) * ab[..., inner:]
+                   * mine.T[:, :, None])                      # [H, T, I]
+            both, merge = few_rows(act, dtype)
+            return merge(jnp.einsum(
+                "eti,eid->td", both, w_out,
+                preferred_element_type=jnp.float32)), counts(n_held)
+        # the pairs by expert, the held experts' first
+        order = jnp.argsort(rel.reshape(-1))
+        n_layers = w_in.shape[0]
+        group_sizes = jax.lax.dynamic_update_slice_in_dim(
+            jnp.zeros((n_layers * held,), jnp.int32),
+            jnp.sum(picked, axis=0, dtype=jnp.int32), layer * held, 0)
+        xs = x.astype(dtype)[order // top_k]                  # [T * k, D]
+        ab = jax.lax.ragged_dot(
+            xs, w_in.reshape((n_layers * held,) + w_in.shape[2:]),
+            group_sizes, preferred_element_type=jnp.float32)
+        act = jax.nn.silu(ab[:, :inner]) * ab[:, inner:]
+        out = jax.lax.ragged_dot(
+            act.astype(dtype),
+            w_out.reshape((n_layers * held,) + w_out.shape[2:]),
+            group_sizes, preferred_element_type=jnp.float32)
+        # back in (row, pick) order by a gather (a scatter-add would
+        # serialise on a TPU), then the gates; a pair of an absent
+        # expert lies past the last group, where the product wrote
+        # nothing
+        place = jnp.argsort(order)
+        out = out[place].reshape(rows, top_k, -1)
+        gate = jnp.take_along_axis(gates, idx, axis=1)        # [T, k]
+        mine = rel < held
+        computed = (place < jnp.sum(group_sizes)).reshape(rows, top_k)
+        return jnp.sum(jnp.where(mine[:, :, None],
+                                 out * gate[:, :, None], 0.0),
+                       axis=1), counts(
+                           jnp.sum(mine & computed & live[:, None]))

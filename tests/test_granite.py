@@ -1,0 +1,319 @@
+"""The Granite-4.0-H family (Mamba-2 mixers beside attention layers, a
+routed and a shared feed-forward in every layer, four multipliers), as
+one expert-parallel rank holds it: the model, the chunked recurrence,
+the expert layer and the engine's dense path with its device counts,
+held to the plain reference (benchmark/reference/granite.py) in float32
+at tiny sizes: hidden 64, 8 Mamba heads of 16 x 16 states, chunks of
+16, four layers of which the third is attention, 8 experts of which
+the first 4 are held, top-3, vocabulary 512."""
+
+import dataclasses
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from benchmark.reference import granite as reference
+from ray_tpu.llm.engine import (ContinuousBatchingEngine, EngineConfig,
+                                GenerationRequest)
+from ray_tpu.models.family import family_of
+from ray_tpu.models.granite import (GraniteConfig, granite_forward,
+                                    granite_init, granite_init_cache,
+                                    granite_prefill, ssd_chunked)
+from ray_tpu.models.llama import LlamaConfig
+
+CFG = GraniteConfig.tiny(dtype=jnp.float32)
+TOL = 1e-4
+
+
+@pytest.fixture(scope="module")
+def params():
+    return jax.jit(granite_init, static_argnums=1)(jax.random.PRNGKey(0),
+                                                   CFG)
+
+
+def _engine(params, **kw):
+    return ContinuousBatchingEngine(
+        EngineConfig(model=CFG, max_batch=3, max_seq=128, **kw),
+        params=params)
+
+
+def _prompt(n, seed=0):
+    return np.random.default_rng(seed).integers(0, 512, n).tolist()
+
+
+def _reference_logprobs(params, ids, n_out, cfg=CFG):
+    """The reference's log-probability of each of the last ``n_out``
+    tokens of ``ids``, from one full forward pass."""
+    seq = jnp.asarray(ids, jnp.int32)
+    logp = jax.nn.log_softmax(reference.logits(
+        params, seq[:-1], **reference.kwargs_from(cfg)), -1)
+    at = np.arange(len(ids) - 1 - n_out, len(ids) - 1)
+    return np.asarray(logp[at, seq[at + 1]])
+
+
+def test_config_keeps_the_published_pattern_and_shares():
+    assert CFG.layer_kinds == ("mamba", "mamba", "attn", "mamba")
+    assert CFG.runs == (("mamba", 0, 2), ("attn", 0, 1), ("mamba", 2, 1))
+    full = GraniteConfig()
+    assert [i for i, t in enumerate(full.layer_types)
+            if t == "attention"] == [5, 15, 25, 35]
+    assert (full.n_mamba_layers, full.n_attn_layers) == (36, 4)
+    assert (full.d_inner, full.conv_dim) == (8192, 8448)
+    assert full.q_scale == pytest.approx(128 ** 0.5 / 128)
+    assert family_of(CFG).recurrent
+    with pytest.raises(ValueError, match="experts_held"):
+        GraniteConfig.tiny(experts_held=(6, 4))
+
+
+def test_forward_matches_the_reference(params):
+    tokens = jax.random.randint(jax.random.PRNGKey(1), (2, 37), 0, 512)
+    got = jax.jit(lambda p, t: granite_forward(p, t, CFG))(params, tokens)
+    for i in range(2):
+        want = reference.logits(params, tokens[i],
+                                **reference.kwargs_from(CFG))
+        assert float(jnp.abs(got[i] - want).max()) < TOL
+
+
+@pytest.mark.parametrize("length", [5, 16, 37, 64, 100])
+def test_engine_prefill_then_decode_matches_the_reference(params, length):
+    """A bucketed prefill told the prompt's true length, then whole-
+    batch decode steps with two parked slots: every token's
+    log-probability against the reference's one full pass. 16 and 64
+    end on a chunk's boundary (chunks of 16), 5, 37 and 100 do not; 5
+    is shorter than a chunk."""
+    engine = _engine(params)
+    ids = _prompt(length, seed=length)
+    request = engine.add_request(GenerationRequest(
+        prompt_ids=ids, max_tokens=20, logprobs=0))
+    while engine.has_work():
+        engine.step()
+    assert request.error is None and len(request.output_ids) == 20
+    got = [e["logprob"] for e in request.logprob_data]
+    want = _reference_logprobs(params, ids + request.output_ids, 20)
+    assert np.abs(np.asarray(got) - want).max() < TOL
+    assert engine._decode._cache_size() == 1
+    assert engine.stats()["dropped_rows"] == 0
+
+
+def test_padding_leaves_the_state_of_the_true_last_token(params):
+    """The same prompt through two buckets: the cache entry (recurrent
+    state, convolution inputs, the K/V rows of the prompt), the logits
+    and the expert counts do not see the padding."""
+    ids = _prompt(21, seed=3)
+    outs = []
+    for bucket in (32, 64):
+        padded = np.zeros((1, bucket), np.int32)
+        padded[0, :21] = ids
+        outs.append(jax.jit(lambda p, t, n: granite_prefill(p, t, n, CFG))(
+            params, padded, np.int32(21)))
+    (logits_a, a, counts_a), (logits_b, b, counts_b) = outs
+    assert float(jnp.abs(logits_a - logits_b).max()) < 1e-5
+    for leaf in ("ssm", "conv"):
+        assert float(jnp.abs(a[leaf] - b[leaf]).max()) < 1e-5
+    for leaf in ("k", "v"):
+        assert float(jnp.abs(a[leaf][:, :, :21]
+                             - b[leaf][:, :, :21]).max()) < 1e-5
+    assert float(jnp.abs(a["ssm"]).max()) > 0
+    # 21 positions x 4 layers x 3 picks, wherever the padding ends
+    assert counts_a.tolist() == counts_b.tolist()
+    assert int(counts_a[0] + counts_a[1]) == 21 * 4 * 3
+    # every held pick computed; a prefill counts no expert slots
+    assert int(counts_a[2]) == int(counts_a[0])
+    assert counts_a[3:].tolist() == [0, 0]
+
+
+@pytest.mark.parametrize("seq,length,chunk", [
+    (8, 5, 16), (64, 64, 16), (64, 37, 16), (48, 48, 32), (40, 33, 16)])
+def test_chunked_recurrence_matches_the_step_by_step_one(seq, length,
+                                                         chunk):
+    """``ssd_chunked`` against the recurrence one position at a time,
+    with positions past ``length`` given ``dt = 0``: their outputs are
+    junk nobody reads, the state is that of position length - 1."""
+    heads, p, n = 4, 8, 16
+    keys = jax.random.split(jax.random.PRNGKey(seq + length), 5)
+    xs = jax.random.normal(keys[0], (seq, heads, p))
+    dt = jax.nn.softplus(jax.random.normal(keys[1], (seq, heads)) - 2)
+    dt = jnp.where(jnp.arange(seq)[:, None] < length, dt, 0.0)
+    a = -jnp.exp(jax.random.uniform(keys[2], (heads,), minval=0.0,
+                                    maxval=2.5))
+    b = jax.random.normal(keys[3], (seq, n))
+    c = jax.random.normal(keys[4], (seq, n))
+
+    def step(h, inp):
+        x_t, dt_t, b_t, c_t = inp
+        h = (jnp.exp(dt_t * a)[:, None, None] * h
+             + (dt_t[:, None] * x_t)[:, :, None] * b_t[None, None, :])
+        return h, jnp.sum(h * c_t[None, None, :], axis=-1)
+
+    with jax.default_matmul_precision("highest"):
+        want_h, want_y = jax.lax.scan(
+            step, jnp.zeros((heads, p, n)), (xs, dt, b, c))
+        y, h = jax.jit(ssd_chunked, static_argnums=5)(xs, dt, a, b, c,
+                                                      chunk)
+        h_short, _ = jax.lax.scan(
+            step, jnp.zeros((heads, p, n)),
+            (xs[:length], dt[:length], b[:length], c[:length]))
+    assert float(jnp.abs(y[:length] - want_y[:length]).max()) < TOL
+    assert float(jnp.abs(h - want_h).max()) < TOL
+    assert float(jnp.abs(h - h_short).max()) < TOL
+    assert float(jnp.abs(h).max()) > 1e-3
+
+
+@pytest.mark.parametrize("field,value", [
+    ("embedding_multiplier", 5.0), ("attention_multiplier", 0.25),
+    ("residual_multiplier", 0.8), ("logits_scaling", 4.0)])
+def test_each_multiplier_moves_the_output_as_the_reference_says(
+        params, field, value):
+    """A multiplier that the program ignored would leave its logits
+    where they were; each moves them, to where the reference's go. The
+    attention multiplier is the score scale: it is not ``head_dim **
+    -0.5`` (0.25 at these sizes) by default, and is given that here."""
+    cfg = dataclasses.replace(CFG, **{field: value})
+    tokens = jax.random.randint(jax.random.PRNGKey(2), (1, 24), 0, 512)
+    base = jax.jit(lambda p, t: granite_forward(p, t, CFG))(params, tokens)
+    got = jax.jit(lambda p, t: granite_forward(p, t, cfg))(params, tokens)
+    want = reference.logits(params, tokens[0],
+                            **reference.kwargs_from(cfg))
+    assert float(jnp.abs(got[0] - want).max()) < TOL
+    assert float(jnp.abs(got - base).max()) > 1e-2
+
+
+def test_requests_admitted_at_different_steps_equal_their_solo_outputs(
+        params):
+    """Two requests of unequal length share the batch from different
+    steps on; a third takes the slot the first one left. Parked slots'
+    states are moved by every step and replaced whole at admission."""
+    prompts = [_prompt(9, 1), _prompt(40, 2), _prompt(17, 3)]
+    lengths = [6, 14, 8]
+    solo = []
+    for ids, n in zip(prompts, lengths):
+        engine = _engine(params)
+        solo.append(engine.generate([ids], max_tokens=n)[0])
+    engine = _engine(params)
+    first = engine.add_request(GenerationRequest(
+        prompt_ids=prompts[0], max_tokens=lengths[0]))
+    for _ in range(3):
+        engine.step()
+    second = engine.add_request(GenerationRequest(
+        prompt_ids=prompts[1], max_tokens=lengths[1]))
+    while not first.done:
+        engine.step()
+    third = engine.add_request(GenerationRequest(
+        prompt_ids=prompts[2], max_tokens=lengths[2]))
+    engine.step()
+    assert engine.slots[0].request is third
+    while engine.has_work():
+        engine.step()
+    assert [first.output_ids, second.output_ids, third.output_ids] == solo
+    assert engine._decode._cache_size() == 1
+
+
+_DRAFT = LlamaConfig.tiny(vocab_size=512)
+
+
+@pytest.mark.parametrize("option,kwargs", [
+    ("draft_model", {"draft_model": _DRAFT}),
+    ("multi_step", {"multi_step": 2}),
+    ("enable_prefix_caching", {"enable_prefix_caching": True}),
+    ("chunked_prefill_tokens", {"chunked_prefill_tokens": 16}),
+    ("max_loras", {"max_loras": 2}),
+    ("quantization", {"quantization": "int8"}),
+    ("adapter", None), ("prefill_only", None), ("add_prefilled", None)])
+def test_engine_refuses_what_a_recurrent_cache_cannot_honour(
+        params, option, kwargs):
+    """Each by name, at construction or, for what a request or a call
+    asks, there: the refusals PR 34 wrote apply to this family as they
+    stand."""
+    if kwargs is not None:
+        with pytest.raises(ValueError, match=option):
+            _engine(params, **kwargs)
+        return
+    engine = _engine(params)
+    with pytest.raises(ValueError, match=option):
+        if option == "adapter":
+            engine.add_request(GenerationRequest(
+                prompt_ids=[1, 2, 3], adapter="tuned"))
+        elif option == "prefill_only":
+            engine.prefill_only([1, 2, 3])
+        else:
+            engine.add_prefilled(
+                GenerationRequest(prompt_ids=[1, 2, 3]),
+                np.zeros((1, 1, 4, 2, 16), np.float32),
+                np.zeros((1, 1, 4, 2, 16), np.float32), 3, 7)
+    assert not engine.has_work()
+
+
+def test_stats_and_series_tell_the_cache_the_picks_and_the_hit_experts(
+        params):
+    """The device counts reach ``stats()`` and the series through the
+    metrics flush: a live row's picks by where the expert lives (parked
+    slots and padding not counted), and for every layer of every dense
+    decode step the held experts that a live row used and those none
+    did. Nothing is dropped."""
+    from ray_tpu.util import metrics
+    engine = _engine(params)
+    engine.generate([_prompt(5), _prompt(37)], max_tokens=3)
+    stats = engine.stats()
+    cache = granite_init_cache(CFG, 3, 128)
+    assert stats["cache_bytes"] == {
+        "kv": cache["k"].nbytes + cache["v"].nbytes,
+        "recurrent": cache["ssm"].nbytes + cache["conv"].nbytes}
+    assert stats["prefill_tokens"] == {"real": 42, "pad": 3 + 27}
+    assert stats["flash_fallbacks"] == []
+    assert sorted(stats["programs"]) == ["decode", "prefill_64",
+                                         "prefill_8"]
+    # 42 prompt positions and 2 decode steps of 2 live rows, over 4
+    # layers of 3 picks a row
+    picks = stats["expert_picks"]
+    assert picks["held"] + picks["absent"] == (42 + 2 * 2) * 4 * 3
+    assert 0 < picks["held"] < picks["held"] + picks["absent"]
+    # 2 decode steps x 4 layers x 4 held experts; 2 live rows of 3
+    # picks cannot hit more than... all four of them
+    slots = stats["expert_slots"]
+    assert slots["hit"] + slots["idle"] == stats["decode_steps"] * 4 * 4
+    assert stats["decode_steps"] == 2 and slots["hit"] > 0
+    # the held picks whose product the layer computed are all of them
+    assert engine._mbuf.expert_totals["picks_computed"] == picks["held"]
+    assert stats["dropped_rows"] == 0
+    # a second read adds nothing the device has not counted since
+    assert engine.stats()["expert_picks"] == picks
+    text = metrics.prometheus_text()
+    for series in ('ray_tpu_engine_expert_picks_total{where="held"}',
+                   'ray_tpu_engine_expert_picks_total{where="absent"}',
+                   'ray_tpu_engine_expert_slots_total{state="hit"}',
+                   'ray_tpu_engine_cache_bytes{kind="recurrent"}',
+                   'ray_tpu_engine_cache_bytes{kind="kv"}'):
+        assert series in text
+    engine.close()
+
+
+def test_the_stepper_never_reads_the_expert_counts(params, monkeypatch):
+    """The counts come to the host with the metrics flush (its thread,
+    ``stats()``), not on a step's path: with the flush held off, steps
+    run and the totals stay where the last flush left them."""
+    engine = _engine(params)
+    monkeypatch.setattr(engine._mbuf, "flush_interval_s", 3600.0)
+    engine.generate([_prompt(9)], max_tokens=4)
+    assert set(engine._mbuf.expert_totals.values()) == {0}
+    assert int(np.asarray(engine._expert_counts).sum()) > 0
+    stats = engine.stats()
+    assert sum(stats["expert_picks"].values()) == (9 + 3) * 4 * 3
+    engine.close()
+
+
+def test_embed_and_fail_all_go_through_the_family(params):
+    engine = _engine(params)
+    vector = engine.embed(_prompt(11))
+    assert vector.shape == (CFG.dim,) and np.isfinite(vector).all()
+    request = engine.add_request(GenerationRequest(
+        prompt_ids=_prompt(7), max_tokens=50))
+    engine.step()
+    engine.fail_all("boom")
+    assert request.error == "boom"
+    assert [leaf.shape for leaf in engine.cache] == [
+        leaf.shape for leaf in jax.tree.leaves(
+            granite_init_cache(CFG, 3, 128))]
+    again = engine.generate([_prompt(7)], max_tokens=4)
+    assert again == _engine(params).generate([_prompt(7)], max_tokens=4)

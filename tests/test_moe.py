@@ -18,7 +18,8 @@ from ray_tpu.models.llama import (
     llama_sharding_rules,
 )
 from ray_tpu.parallel.mesh import MeshSpec, make_mesh
-from ray_tpu.parallel.moe import moe_dispatch, moe_ffn, top_k_gating
+from ray_tpu.parallel.moe import (gated_ffn, held_experts_ffn, moe_dispatch,
+                                  moe_ffn, top_k_gating)
 from ray_tpu.parallel.sharding import shard_pytree
 
 
@@ -134,3 +135,135 @@ def test_llama_moe_expert_parallel_matches_replicated(cpu_mesh8):
 
     ep_loss = float(loss_fn(sharded, tok_sharded, tgt_sharded))
     assert ep_loss == pytest.approx(baseline, rel=1e-4)
+
+
+# --- the serving form: the experts one device of an expert-parallel
+# group holds (held_experts_ffn), float32, 8 experts, top-3 -----------
+
+_D, _E, _I, _K = 16, 8, 12, 3
+
+
+def _expert_layer(seed=0):
+    keys = jax.random.split(jax.random.PRNGKey(seed), 5)
+    return dict(
+        router=jax.random.normal(keys[0], (_D, _E)),
+        w_in=jax.random.normal(keys[1], (_E, _D, 2 * _I)) * 0.3,
+        w_out=jax.random.normal(keys[2], (_E, _I, _D)) * 0.3,
+        w_in_s=jax.random.normal(keys[3], (_D, 4 * _I)) * 0.3,
+        w_out_s=jax.random.normal(keys[4], (2 * _I, _D)) * 0.3)
+
+
+def _uncut_layer(x, layer, router=None):
+    """The whole layer, plainly: every expert on every row, weighted by
+    the gate (zero where not picked), and the shared expert."""
+    router = layer["router"] if router is None else router
+    gates, _, _ = top_k_gating(x, router, _K)
+    routed = sum(
+        gates[:, e:e + 1] * gated_ffn(x, layer["w_in"][e],
+                                      layer["w_out"][e])
+        for e in range(_E))
+    return routed + gated_ffn(x, layer["w_in_s"], layer["w_out_s"])
+
+
+def _share(x, layer, first, count, router=None, live=None):
+    router = layer["router"] if router is None else router
+    # a stack of one layer, and its index
+    return jax.jit(lambda x: held_experts_ffn(
+        x, router, layer["w_in"][None, first:first + count],
+        layer["w_out"][None, first:first + count], first, layer=0,
+        top_k=_K, live=live))(x)
+
+
+@pytest.mark.parametrize("rows", [8, 100])
+def test_the_shares_parts_and_the_shared_expert_add_up_to_the_layer(rows):
+    """Two ranks of an expert-parallel pair (experts 0-3 and 4-7) route
+    over all 8 and compute their own: their parts, plus the shared
+    expert counted once, are the uncut layer; few rows (a decode step's
+    form) and many (a prefill's grouped matmul)."""
+    layer = _expert_layer()
+    x = jax.random.normal(jax.random.PRNGKey(7), (rows, _D))
+    with jax.default_matmul_precision("highest"):
+        want = _uncut_layer(x, layer)
+        low, counts_low = _share(x, layer, 0, 4)
+        high, counts_high = _share(x, layer, 4, 4)
+        shared = gated_ffn(x, layer["w_in_s"], layer["w_out_s"])
+    np.testing.assert_allclose(np.asarray(low + high + shared),
+                               np.asarray(want), rtol=1e-4, atol=1e-5)
+    assert float(jnp.abs(low).max()) > 0 and float(jnp.abs(high).max()) > 0
+    # every pick is on one rank or the other: held here, absent there
+    assert int(counts_low[0]) == int(counts_high[1])
+    assert int(counts_low[0] + counts_low[1]) == rows * _K
+    # and every held pick was computed
+    assert int(counts_low[2]) == int(counts_low[0])
+    assert int(counts_high[2]) == int(counts_high[0])
+
+
+@pytest.mark.parametrize("rows", [8, 100])
+def test_a_skewed_router_loses_no_row(rows):
+    """A router under which EVERY row picks expert 5 first (and 6, 7
+    after it): a capacity of 2.0 x k x T / E rows an expert would drop
+    five rows in eight; this layer has none and computes them all."""
+    layer = _expert_layer(1)
+    # x's first feature is 1, so the router's first row is a bias
+    x = jax.random.normal(jax.random.PRNGKey(3), (rows, _D)) * 0.01
+    x = x.at[:, 0].set(1.0)
+    router = jnp.zeros((_D, _E)).at[0].set(
+        jnp.array([0., 0., 0., 0., 0., 9., 6., 3.]))
+    with jax.default_matmul_precision("highest"):
+        _, idx, _ = top_k_gating(x, router, _K)
+        want = _uncut_layer(x, layer, router) \
+            - gated_ffn(x, layer["w_in_s"], layer["w_out_s"])
+        got, counts = _share(x, layer, 4, 4, router)
+        none, counts_none = _share(x, layer, 0, 4, router)
+    assert np.asarray(idx).tolist() == [[5, 6, 7]] * rows
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-4, atol=1e-5)
+    # every row's output is there: none was dropped
+    assert float(jnp.abs(got).sum(axis=1).min()) > 0
+    # EXPERT_COUNTS: held, absent, computed, hit, idle
+    assert counts.tolist() == [rows * _K, 0, rows * _K, 3, 1]
+    assert counts_none.tolist() == [0, rows * _K, 0, 0, 4]
+    assert float(jnp.abs(none).max()) == 0.0
+
+
+def test_the_two_regimes_agree_on_the_same_input():
+    """The few-rows form (every held expert on every row) and the many-
+    rows form (sorted pairs, grouped matmul) are one function: a batch
+    of 100 rows through the grouped matmul equals its rows through the
+    few-rows form 20 at a time; ``live`` masks the counts and nothing
+    else."""
+    layer = _expert_layer(2)
+    x = jax.random.normal(jax.random.PRNGKey(11), (100, _D))
+    live = jnp.arange(100) < 60
+    with jax.default_matmul_precision("highest"):
+        many, counts = _share(x, layer, 0, 4, live=live)
+        few = [_share(x[i:i + 20], layer, 0, 4, live=live[i:i + 20])
+               for i in range(0, 100, 20)]
+    np.testing.assert_allclose(
+        np.asarray(many), np.concatenate([np.asarray(y) for y, _ in few]),
+        rtol=1e-4, atol=1e-5)
+    assert int(counts[0]) == sum(int(c[0]) for _, c in few)
+    assert int(counts[0] + counts[1]) == 60 * _K
+    assert int(counts[2]) == int(counts[0])
+
+
+@pytest.mark.parametrize("rows", [8, 100])
+def test_a_stack_of_layers_and_an_index_is_the_layer_itself(rows):
+    """The experts' weights as whole stacks [L, H, ...] of three layers
+    and a traced index (a caller that walks a stack of layers, so that
+    no layer's experts are sliced out into a copy) give what a stack of
+    that layer alone gives, in both regimes."""
+    layers = [_expert_layer(seed) for seed in (3, 4, 5)]
+    stack_in = jnp.stack([l["w_in"][:4] for l in layers])
+    stack_out = jnp.stack([l["w_out"][:4] for l in layers])
+    x = jax.random.normal(jax.random.PRNGKey(13), (rows, _D))
+    stacked = jax.jit(lambda x, i: held_experts_ffn(
+        x, layers[1]["router"], stack_in, stack_out, 0, top_k=_K, layer=i))
+    with jax.default_matmul_precision("highest"):
+        want, want_counts = _share(x, layers[1], 0, 4)
+        got, counts = stacked(x, jnp.int32(1))
+        other, _ = stacked(x, jnp.int32(2))
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-4, atol=1e-5)
+    assert counts.tolist() == want_counts.tolist()
+    assert float(jnp.abs(other - got).max()) > 1e-3

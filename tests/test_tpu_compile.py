@@ -315,3 +315,76 @@ def test_jamba_serving_programs_compile_at_published_widths(v5e):
     assert memory.temp_size_in_bytes < 512 * 2**20
     assert selective_scan.kernel_fallbacks == []
     assert attention.kernel_fallbacks == []
+
+
+def test_granite_serving_programs_compile_at_published_widths(v5e):
+    """The Granite cell's engine programs as the chip gets them
+    (granite-4.0-h-small's first period of ten layers, 36 of 72 experts
+    and half the vocabulary held, batch 32, seq 2560): the decode
+    program through the engine's family seam holds ``decode_attention``
+    beside ``rms_norm``, aliases the whole cache of two kinds (donated:
+    no state and no row is copied), hands its device counts on without
+    donating them and keeps its temporaries under 64 MiB; a prefill
+    program holds flash attention and no other Pallas kernel (the
+    grouped matmul is XLA's own ``ragged_dot``), and everything fits
+    one chip."""
+    from ray_tpu.llm import engine as engine_mod
+    from ray_tpu.models.granite import (GraniteConfig, granite_init,
+                                        granite_init_cache)
+    cfg = GraniteConfig(
+        vocab_size=50176, layer_types=GraniteConfig().layer_types[:10],
+        experts_held=(0, 36), max_seq_len=2560)
+    mesh = _mesh(v5e, 1)
+
+    def on_chip(tree):
+        return _abstract(tree, jax.tree.map(
+            lambda _: NamedSharding(mesh, P()), tree))
+
+    params = on_chip(jax.eval_shape(
+        lambda key: granite_init(key, cfg), jax.random.PRNGKey(0)))
+    weights = sum(x.size * x.dtype.itemsize
+                  for x in jax.tree.leaves(params))
+    assert 9.4e9 < weights < 9.6e9
+    cache = jax.tree.leaves(on_chip(jax.eval_shape(
+        lambda: granite_init_cache(cfg, 32, 2560))))
+    counts = _on(mesh, P(), (5,), jnp.uint32)
+    with pytest.MonkeyPatch.context() as patch:
+        # an engine around shapes: no weights and no cache are made here
+        patch.setattr(engine_mod.ContinuousBatchingEngine, "_fresh_cache",
+                      lambda self, model: cache)
+        patch.setattr(engine_mod.ContinuousBatchingEngine, "_fresh_bias",
+                      lambda self: (None, None))
+        patch.setattr(engine_mod.ContinuousBatchingEngine,
+                      "_fresh_expert_counts", lambda self: None)
+        engine = engine_mod.ContinuousBatchingEngine(
+            engine_mod.EngineConfig(model=cfg, max_batch=32, max_seq=2560),
+            params=params)
+    lowered = engine._decode.lower(
+        params, cache, _on(mesh, P(), (7, 32), jnp.int32),
+        _on(mesh, P(), (2,), jnp.uint32), None,
+        _on(mesh, P(), (32, 50176), jnp.float32), counts, want_lp=False)
+    # rms_norm twice: over the model's width and over d_inner
+    assert sorted({k.split("(")[0] for k in _kernels(lowered)}) == [
+        "decode_attention", "rms_norm"]
+    memory = lowered.compile().memory_analysis()
+    # the K/V rows of 1 layer and the state of 9: all of it in place
+    kv = 2 * 32 * 2560 * 8 * 128 * 2
+    state = 9 * 32 * (128 * 64 * 128 * 4 + 3 * 8448 * 2)
+    assert kv + state <= memory.alias_size_in_bytes <= 1.2 * (kv + state)
+    assert memory.temp_size_in_bytes < 64 * 2**20
+    assert (memory.argument_size_in_bytes
+            + memory.temp_size_in_bytes) < 0.75 * HBM_BYTES
+    for bucket in (256, 1024):
+        lowered = engine._prefill.lower(
+            params, _on(mesh, P(), (1, bucket), jnp.int32),
+            _on(mesh, P(), (), jnp.int32), None, counts)
+        assert sorted({k.split("(")[0] for k in _kernels(lowered)}) == [
+            "flash_fwd", "rms_norm"]
+        compiled = lowered.compile()
+        assert compiled.memory_analysis().temp_size_in_bytes < 1536 * 2**20
+        # no layer's experts are sliced out of their stack into a copy
+        # (453 MB a layer): the grouped matmul reads the stack itself
+        made = re.findall(r"= bf16\[36,4096,1536\]\S* (\S+?)\(",
+                          compiled.as_text())
+        assert set(made) <= {"bitcast", "parameter"}, set(made)
+    assert attention.kernel_fallbacks == []
