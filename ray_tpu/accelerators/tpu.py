@@ -22,9 +22,12 @@ hardware, per SURVEY.md §7 "Testing without TPUs".
 
 from __future__ import annotations
 
+import errno
 import glob
 import os
 import re
+import sys
+import time
 from typing import Dict, List, Optional
 
 # GKE-injected env vars (and the test fake interface).
@@ -82,21 +85,68 @@ class TpuAcceleratorManager:
 
     # --- chip detection -------------------------------------------------
     @staticmethod
+    def _device_nodes() -> List[str]:
+        """The chips' device nodes in chip order: /dev/accel*, else
+        /dev/vfio's numbered groups (reference: tpu.py:225-245)."""
+        nodes = sorted(glob.glob("/dev/accel*"))
+        if nodes:
+            return nodes
+        try:
+            entries = os.listdir("/dev/vfio")
+        except FileNotFoundError:
+            return []
+        return [f"/dev/vfio/{g}"
+                for g in sorted((e for e in entries if e.isdigit()), key=int)]
+
+    @staticmethod
     def num_chips_on_node() -> int:
-        """Detect local chips: /dev/accel*, then /dev/vfio numeric
-        entries (reference: tpu.py:225-245). RTPU_TPU_NUM_CHIPS
+        """Detect local chips by their device nodes. RTPU_TPU_NUM_CHIPS
         overrides for tests/simulation."""
         override = os.environ.get("RTPU_TPU_NUM_CHIPS")
         if override is not None:
             return int(override)
-        accel = glob.glob("/dev/accel*")
-        if accel:
-            return len(accel)
-        try:
-            entries = os.listdir("/dev/vfio")
-        except FileNotFoundError:
-            return 0
-        return sum(1 for e in entries if e.isdigit())
+        return len(TpuAcceleratorManager._device_nodes())
+
+    # --- hand-over of chips between processes ---------------------------
+    @staticmethod
+    def chip_device_paths(chips: List[int]) -> List[str]:
+        """The device nodes of chips by index (an index this host has
+        no node for names nothing)."""
+        nodes = TpuAcceleratorManager._device_nodes()
+        return [nodes[i] for i in chips if 0 <= i < len(nodes)]
+
+    @staticmethod
+    def wait_for_chips(chips: List[int], timeout_s: float = 120.0,
+                       poll_s: float = 0.25) -> float:
+        """Wait until the device nodes of ``chips`` open. A process that
+        held them and was killed, or is exiting, keeps them until the
+        kernel has taken them back, which takes seconds after it has
+        stopped answering; the TPU runtime does not wait and fails with
+        "Device or resource busy". Returns the seconds waited (said on
+        stderr when any); raises TimeoutError naming the busy nodes."""
+        paths = TpuAcceleratorManager.chip_device_paths(chips)
+        start = time.monotonic()
+        while True:
+            busy = []
+            for path in paths:
+                try:
+                    os.close(os.open(path, os.O_RDWR))
+                except OSError as exc:
+                    # any other error is the runtime's to report
+                    if exc.errno == errno.EBUSY:
+                        busy.append(path)
+            waited = time.monotonic() - start
+            if not busy:
+                if waited >= poll_s:
+                    print(f"ray_tpu: waited {waited:.1f} s for chips "
+                          f"{chips} to come free", file=sys.stderr,
+                          flush=True)
+                return waited
+            if waited >= timeout_s:
+                raise TimeoutError(
+                    f"chips {chips} still held by another process after "
+                    f"{waited:.0f} s: {', '.join(busy)} busy")
+            time.sleep(poll_s)
 
     # --- worker visibility ----------------------------------------------
     @staticmethod

@@ -13,6 +13,7 @@ reference's Cluster (reference: python/ray/cluster_utils.py:135).
 
 from __future__ import annotations
 
+import logging
 import os
 import socket
 import subprocess
@@ -31,6 +32,11 @@ from ray_tpu.core.object_store import SharedMemoryStore
 from ray_tpu.core.protocol import MessageConnection
 from ray_tpu.core.task_spec import TaskSpec
 from ray_tpu.devtools import threadguard
+
+logger = logging.getLogger(__name__)
+
+# how long stop() waits for a worker that was told to go to be gone
+_REAP_TIMEOUT_S = 60.0
 
 # Worker states
 STARTING = "STARTING"
@@ -104,6 +110,10 @@ class Node:
             create=True)
         self._lock = threading.RLock()
         self._workers: Dict[WorkerID, WorkerHandle] = {}
+        # workers whose connection closed before their process was
+        # gone (a killed chip owner takes seconds): stop() waits for
+        # these too
+        self._dying: List[WorkerHandle] = []
         # Separate pools per worker profile: "cpu" workers start with the
         # accelerator runtime masked out (fast startup, no chip
         # contention); "tpu:<k>" workers own k specific chips from the
@@ -229,6 +239,10 @@ class Node:
                "--node-id", self.node_id.hex(),
                "--worker-id", worker_id.hex(),
                "--store-name", self.store_name]
+        if chips:
+            # the worker waits for these, should a dying process of an
+            # earlier runtime still hold them (tpu.wait_for_chips)
+            cmd += ["--chips", ",".join(str(c) for c in chips)]
         if image_uri:
             # Containerized worker (reference: _private/runtime_env/
             # image_uri.py:24 — podman-run with host net/IPC so the
@@ -764,6 +778,9 @@ class Node:
             except ValueError:
                 pass
             self._workers.pop(worker.worker_id, None)
+            self._dying = [w for w in self._dying if w.proc.poll() is None]
+            if worker.proc is not None and worker.proc.poll() is None:
+                self._dying.append(worker)
             # Return this worker's chips; TPU specs may be queued
             # waiting for exactly these.
             if worker.chips:
@@ -809,6 +826,11 @@ class Node:
             return sum(len(q) for q in self._idle.values())
 
     def kill_worker(self, worker_id: WorkerID) -> None:
+        """Does not wait for the process (callers are on the IO loop):
+        its chips go back to the pool when its connection closes, the
+        worker that gets them next waits for the device nodes
+        (accelerators/tpu.py::wait_for_chips), and stop() waits for
+        the process if it is still dying then."""
         with self._lock:
             worker = self._workers.get(worker_id)
         if worker is not None:
@@ -833,10 +855,11 @@ class Node:
     def stop(self) -> None:
         self._stopped.set()
         with self._lock:
-            workers = list(self._workers.values())
+            workers = list(self._workers.values()) + self._dying
         for worker in workers:
             worker.send({"kind": "SHUTDOWN"})
-        deadline = time.time() + 2.0
+        sent = time.time()
+        deadline = sent + 2.0
         for worker in workers:
             if worker.proc is None:
                 continue
@@ -845,6 +868,29 @@ class Node:
                 worker.proc.wait(timeout=remaining)
             except subprocess.TimeoutExpired:
                 worker.proc.kill()
+        # A worker that owned chips is gone only when the kernel has
+        # taken them back (seconds after it stopped answering: 14 for
+        # the four chips of a v5e host), killed or not. Whoever starts
+        # next on this machine must find them free, so stop() returns
+        # after that.
+        reap_by = time.time() + _REAP_TIMEOUT_S
+        for worker in workers:
+            proc = worker.proc
+            if proc is None or proc.poll() is not None:
+                continue
+            try:
+                proc.wait(timeout=max(0.05, reap_by - time.time()))
+            except subprocess.TimeoutExpired:
+                logger.error(
+                    "worker %s (pid %d, %s) still there %.0f s after "
+                    "SHUTDOWN", worker.worker_id.hex()[:8], proc.pid,
+                    worker.profile, time.time() - sent)
+                continue
+            took = time.time() - sent
+            if took > 5.0:
+                logger.warning(
+                    "worker %s (%s) was gone %.1f s after SHUTDOWN",
+                    worker.worker_id.hex()[:8], worker.profile, took)
         self._listener_handle.close(wait=True)
         for worker in workers:
             if worker.conn is not None:

@@ -623,7 +623,7 @@ def _split_returns(result: Any, num_returns: int) -> List[Any]:
 
 
 def worker_main(socket_path: str, node_id_hex: str, worker_id_hex: str,
-                store_name: str) -> None:
+                store_name: str, chips: Optional[List[int]] = None) -> None:
     import faulthandler
     import signal
     faulthandler.register(signal.SIGUSR1)  # kill -USR1 <pid> dumps stacks
@@ -634,6 +634,16 @@ def worker_main(socket_path: str, node_id_hex: str, worker_id_hex: str,
     node_id = NodeID.from_hex(node_id_hex)
     worker_id = WorkerID.from_hex(worker_id_hex)
     rt = WorkerRuntime(conn, store, node_id, worker_id)
+    if chips:
+        # before anything can start the TPU runtime: a process that
+        # held these chips may still be on its way out. If they never
+        # come free, every task handed to this worker fails saying so.
+        from ray_tpu.accelerators.tpu import TpuAcceleratorManager
+        try:
+            TpuAcceleratorManager.wait_for_chips(chips)
+        except TimeoutError as exc:
+            print(f"ray_tpu: {exc}", file=sys.stderr, flush=True)
+            rt.setup_error = exc
 
     from ray_tpu.core import runtime as runtime_mod
     runtime_mod.set_runtime(rt)
@@ -920,6 +930,8 @@ def main():
     parser.add_argument("--node-id", required=True)
     parser.add_argument("--worker-id", required=True)
     parser.add_argument("--store-name", required=True)
+    # the chips this worker owns (node.py::_spawn_worker)
+    parser.add_argument("--chips", default="")
     args = parser.parse_args()
     # pip/conda runtime envs must take effect before this process
     # touches its node connection: build (or reuse) the cached
@@ -952,7 +964,8 @@ def main():
                     python,
                     [python, "-m", "ray_tpu.core.worker"] + sys.argv[1:],
                     dict(os.environ))
-    worker_main(args.socket, args.node_id, args.worker_id, args.store_name)
+    worker_main(args.socket, args.node_id, args.worker_id, args.store_name,
+                chips=[int(c) for c in args.chips.split(",") if c])
 
 
 if __name__ == "__main__":

@@ -45,6 +45,7 @@ decode batch can use different adapters.
 
 from __future__ import annotations
 
+import functools
 import itertools
 import threading
 import weakref
@@ -108,6 +109,13 @@ ENGINE_STATE_UPLOADS = _metrics.Counter(
     "ray_tpu_engine_state_uploads_total",
     "Dense decode steps that sent the per-slot state from the host: "
     "the others took it from the decode step before them")
+ENGINE_SAMPLER_STEPS = _metrics.Counter(
+    "ray_tpu_engine_sampler_steps_total",
+    "Decode programs launched, by the sampler's branch their live slots "
+    "engage: topk (a slot samples among its top k), full (a slot samples "
+    "over the whole vocabulary; a step may engage both) or greedy "
+    "(neither: every live slot takes the arg-max)",
+    tag_keys=("path",))
 ENGINE_PREFILL_TOKENS = _metrics.Counter(
     "ray_tpu_engine_prefill_tokens_total",
     "Positions the prefill programs computed, by kind: real (a "
@@ -603,6 +611,62 @@ class GenerationRequest:
 _TOKEN, _POS, _TEMP, _TOPK, _LORA, _LIVE, _STEP = range(7)
 
 
+def _sample_tokens(logits, temp, topk, key, bias=None, live=None, *,
+                   max_k: int):
+    """On-device sampling: greedy / temperature / top-k per slot,
+    [B, V] logits -> [B] int32 — only the token ids cross to the host.
+    ``bias`` [B, V] is the per-slot logit_bias; ``live`` [B] marks the
+    slots whose token is read (all of them where it is None).
+
+    The arg-max is always computed; the rest runs only where a live
+    slot asks for it: the sort and the draw among its ``max_k`` values
+    if one samples with ``topk > 0``, the draw over the whole
+    vocabulary if one samples with ``topk == 0``. A slot that samples
+    draws what it would with every branch taken (same keys, same
+    order); a slot that does not, or is not live, gets its arg-max."""
+    import jax
+    import jax.numpy as jnp
+
+    with jax.named_scope("sampler"):
+        n_b = logits.shape[0]
+        if bias is not None:
+            logits = logits + bias
+        greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        sampling = temp > 0.0
+        if live is not None:
+            sampling &= live > 0
+        among_top = topk > 0
+
+        def unasked():
+            return greedy
+
+        def draw():
+            scaled = logits / jnp.maximum(temp, 1e-6)[:, None]
+            keys = jax.random.split(key, n_b)
+
+            def full():
+                return jax.vmap(jax.random.categorical)(
+                    keys, scaled).astype(jnp.int32)
+
+            def top():
+                vals, idx = jax.lax.top_k(scaled, max_k)
+                mask = (jnp.arange(max_k)[None, :]
+                        < jnp.clip(topk, 1, max_k)[:, None])
+                vals = jnp.where(mask, vals, -jnp.inf)
+                choice = jax.vmap(jax.random.categorical)(keys, vals)
+                return jnp.take_along_axis(
+                    idx, choice[:, None], axis=1)[:, 0].astype(jnp.int32)
+
+            sampled = jnp.where(
+                among_top,
+                jax.lax.cond(jnp.any(sampling & among_top), top, unasked),
+                jax.lax.cond(jnp.any(sampling & ~among_top), full,
+                             unasked))
+            return jnp.where(sampling, sampled, greedy)
+
+        return jax.lax.cond(jnp.any(sampling), draw, unasked)
+
+
 class _Slot:
     def __init__(self, index: int):
         self.index = index
@@ -784,6 +848,10 @@ class ContinuousBatchingEngine:
         self._kv_block = _attention_op.decode_block_rows(
             config.max_seq, c.n_kv_heads, c.head_dim) or config.max_seq
         self.decode_kv_rows = {"read": 0, "skipped": 0}
+        # decode programs launched, by the sampler's branches their live
+        # slots engaged, and the branches of the slots last gathered
+        self.sampler_steps = {"greedy": 0, "topk": 0, "full": 0}
+        self._sampler_paths: tuple = ("greedy",)
         # prompts admitted through a prefill of their own, and how many
         # of them were launched under the prefill of the one before
         self.admissions = 0
@@ -827,28 +895,7 @@ class ContinuousBatchingEngine:
         lp_k = min(20, c.vocab_size)  # static top-logprobs width
         self._lp_k = lp_k
 
-        @jax.named_scope("sampler")
-        def sample_tokens(logits, temp, topk, key, bias=None):
-            """On-device sampling: greedy / temperature / top-k per
-            slot, [B, V] logits -> [B] int32 — only the token ids cross
-            to the host. ``bias`` [B, V] is the per-slot logit_bias."""
-            n_b = logits.shape[0]
-            if bias is not None:
-                logits = logits + bias
-            greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-            scaled = logits / jnp.maximum(temp, 1e-6)[:, None]
-            keys = jax.random.split(key, n_b)
-            full = jax.vmap(jax.random.categorical)(
-                keys, scaled).astype(jnp.int32)
-            vals, idx = jax.lax.top_k(scaled, max_k)
-            mask = (jnp.arange(max_k)[None, :]
-                    < jnp.clip(topk, 1, max_k)[:, None])
-            vals = jnp.where(mask, vals, -jnp.inf)
-            choice = jax.vmap(jax.random.categorical)(keys, vals)
-            topk_tok = jnp.take_along_axis(
-                idx, choice[:, None], axis=1)[:, 0].astype(jnp.int32)
-            sampled = jnp.where(topk > 0, topk_tok, full)
-            return jnp.where(temp <= 0.0, greedy, sampled)
+        sample_tokens = functools.partial(_sample_tokens, max_k=max_k)
 
         def decode(params, cache, state, base_key, lora_bank, bias,
                    counts=None, want_lp=False):
@@ -867,7 +914,7 @@ class ContinuousBatchingEngine:
                 counts = counts + counted
             cache = jax.tree.leaves(cache)
             key = jax.random.fold_in(base_key, state[_STEP, 0])
-            tok = sample_tokens(logits, temp, state[_TOPK], key, bias)
+            tok = sample_tokens(logits, temp, state[_TOPK], key, bias, live)
             state = state.at[_TOKEN].set(tok * live).at[_POS].add(
                 live).at[_STEP].add(1)
             if not want_lp:
@@ -1855,6 +1902,7 @@ class ContinuousBatchingEngine:
             temp = np.zeros(n, dtype=np.float32)
             topk = np.zeros(n, dtype=np.int32)
             lora_idx = np.zeros(n, dtype=np.int32)
+            paths = set()
             for slot in active:
                 request = slot.request
                 tokens[slot.index] = slot.next_token
@@ -1862,7 +1910,19 @@ class ContinuousBatchingEngine:
                 temp[slot.index] = request.temperature
                 topk[slot.index] = request.top_k
                 lora_idx[slot.index] = self._adapter_index(request)
+                if request.temperature > 0.0:
+                    paths.add("topk" if request.top_k > 0 else "full")
+            # stepper-thread-only
+            self._sampler_paths = tuple(paths) or ("greedy",)  # graftlint: disable=GL001
         return tokens, pos, temp, topk, lora_idx
+
+    def _note_sampler_step(self) -> None:
+        """Count the program being launched under each branch of the
+        sampler that the slots last gathered engage (the dense step's
+        state holds them until a slot changes hands)."""
+        for path in self._sampler_paths:
+            self.sampler_steps[path] += 1  # graftlint: disable=GL001  # stepper-thread-only
+            self._mbuf.inc(ENGINE_SAMPLER_STEPS, 1.0, {"path": path})
 
     def _gather_state(self, active) -> np.ndarray:
         """The dense step's packed state ([7, B] int32, rows
@@ -1894,6 +1954,7 @@ class ContinuousBatchingEngine:
         # dense step's device state does not see
         self._step_counter += 1  # graftlint: disable=GL001
         self._state_stale = True  # graftlint: disable=GL001
+        self._note_sampler_step()
         with self._span("engine.launch"):
             # draft proposals d_1..d_{G-1}: one fused dispatch
             drafts_dev, self.draft_cache_k, self.draft_cache_v = \
@@ -1946,6 +2007,7 @@ class ContinuousBatchingEngine:
         self._state_stale = True  # graftlint: disable=GL001
         tokens_j, pos_j, temp_j, topk_j, lora_j = self._upload(
             tokens, pos, temp, topk, lora_idx)
+        self._note_sampler_step()
         with self._span("engine.launch"):
             toks, *self.cache = self._decode_multi(
                 self.params, *self.cache,
@@ -1996,6 +2058,7 @@ class ContinuousBatchingEngine:
         self._state_stale = True  # graftlint: disable=GL001
         chunk_j, pos_j, last_j, temp_j, topk_j = self._upload(
             chunk, pos, last_idx, temp, topk)
+        self._note_sampler_step()
         with self._span("engine.launch"):
             tok, *self.cache = self._chunk_prefill(
                 self.params, *self.cache,
@@ -2141,6 +2204,7 @@ class ContinuousBatchingEngine:
             self._state_slots = live  # graftlint: disable=GL001
             self.state_uploads += 1  # graftlint: disable=GL001
             self._mbuf.inc(ENGINE_STATE_UPLOADS)
+        self._note_sampler_step()
         with self._span("engine.launch"):
             (self._state, chosen_lp, top_vals, top_ids,
              self._expert_counts, *self.cache) = self._call_program(
@@ -2315,6 +2379,9 @@ class ContinuousBatchingEngine:
                 # covered, and the rest of slots x max_seq
                 "decode_kv_rows_read": self.decode_kv_rows["read"],
                 "decode_kv_rows_skipped": self.decode_kv_rows["skipped"],
+                # decode programs by the sampler's branch their live
+                # slots engaged (greedy: none, an arg-max alone)
+                "sampler_steps": dict(self.sampler_steps),
                 # prompts admitted through a prefill of their own, and
                 # how many of them were queued on the device before the
                 # host waited for the one before

@@ -62,6 +62,34 @@ def _kernels(lowered):
     return jax_backend.pallas_kernels(lowered.as_text())
 
 
+def _reachable(text, name):
+    """The HLO text of computation ``name`` and of all it calls."""
+    bodies = dict(re.findall(r"^(%[\w.\-]+) \(.*?\{\n(.*?)^\}", text,
+                             re.M | re.S))
+    seen, todo = {}, [name]
+    while todo:
+        name = todo.pop()
+        if name in bodies and name not in seen:
+            seen[name] = bodies[name]
+            todo += re.findall(r"%[\w.\-]+", bodies[name])
+    return "\n".join(seen.values())
+
+
+def _assert_sampler_branches(compiled):
+    """The compiled decode program holds the sampler as a conditional:
+    its cheap branch (no live slot samples) computes nothing at all,
+    the other holds the top-k (a sort, or the compiler's ``TopK`` over
+    the widest vocabulary)."""
+    text = compiled.as_text()
+    (cheap, dear), = re.findall(
+        r" conditional\(.*branch_computations=\{(%[\w.\-]+), (%[\w.\-]+)\}"
+        r".*op_name=\"jit\(decode\)/sampler/cond\"", text)
+    cheap, dear = _reachable(text, cheap), _reachable(text, dear)
+    assert " sort(" in dear or 'custom_call_target="TopK"' in dear
+    for costly in (" sort(", "custom-call(", " while(", " fusion("):
+        assert costly not in cheap, costly
+
+
 def test_rms_block_rows_fit_vmem(v5e):
     mesh = _mesh(v5e, 1)
     for d, rows in ((2560, 512), (4096, 256), (8192, 128)):
@@ -257,6 +285,7 @@ def test_engine_decode_program_feeds_its_state_back(v5e, monkeypatch,
     out_state = jax.tree.leaves(lowered.out_info)[0]
     assert (out_state.shape, out_state.dtype) == ((7, 32), jnp.int32)
     assert "[32,1024,8,4,128]" not in compiled.as_text()
+    _assert_sampler_branches(compiled)
 
 
 def test_jamba_serving_programs_compile_at_published_widths(v5e):
@@ -298,7 +327,9 @@ def test_jamba_serving_programs_compile_at_published_widths(v5e):
         _on(mesh, P(), (32, 65536), jnp.float32), want_lp=False)
     assert [k.split("(")[0] for k in _kernels(lowered)] == [
         "decode_attention", "rms_norm"]
-    memory = lowered.compile().memory_analysis()
+    compiled = lowered.compile()
+    _assert_sampler_branches(compiled)
+    memory = compiled.memory_analysis()
     # the K/V rows of 2 layers and the state of 26: all of it in place
     kv = 2 * 2 * 32 * 1024 * 128 * 2
     state = 26 * 32 * 5120 * (16 * 4 + 3 * 2)
@@ -366,7 +397,9 @@ def test_granite_serving_programs_compile_at_published_widths(v5e):
     # rms_norm twice: over the model's width and over d_inner
     assert sorted({k.split("(")[0] for k in _kernels(lowered)}) == [
         "decode_attention", "rms_norm"]
-    memory = lowered.compile().memory_analysis()
+    compiled = lowered.compile()
+    _assert_sampler_branches(compiled)
+    memory = compiled.memory_analysis()
     # the K/V rows of 1 layer and the state of 9: all of it in place
     kv = 2 * 32 * 2560 * 8 * 128 * 2
     state = 9 * 32 * (128 * 64 * 128 * 4 + 3 * 8448 * 2)
