@@ -6,6 +6,15 @@ Llama-2-7B fine-tune ≥35% MFU on v5p). Design choices for TPU:
 - Layers are *stacked* (leading n_layers axis) and iterated with
   `lax.scan`: one compiled block regardless of depth, fast compiles,
   and `jax.checkpoint` per block gives layer-granular rematerialization.
+  With ``remat=True`` a block keeps its input and its narrow residuals
+  (`REMAT_SAVED`) and recomputes only the FFN's wide tensors: per
+  layer ``tokens * (2*dim + 2*n_heads*head_dim + 2*n_kv_heads*head_dim)``
+  elements in the model dtype plus ``4 * tokens * n_heads`` bytes of
+  float32 row sums (289 MiB a layer at 8192 tokens of Mistral-7B's
+  widths, of which 64 MiB are the input that full recomputation kept
+  too), and the compiled step's temporaries grow by up to twice the
+  difference. A job that sat at the memory's edge under full
+  recomputation has to cut its batch.
 - All matmuls stay [tokens, features] × [features, out] — large, MXU-
   shaped, bfloat16 by default with float32 accumulation.
 - Attention pluggable: "flash" (Pallas kernel, ray_tpu/ops/attention.py),
@@ -26,10 +35,12 @@ from typing import Any, Dict, Optional
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import PartitionSpec as P
 
 from ray_tpu.accelerators import jax_backend
-from ray_tpu.ops.attention import decode_attention, flash_attention
+from ray_tpu.ops.attention import (SAVED_LSE, SAVED_OUT, decode_attention,
+                                   flash_attention)
 from ray_tpu.ops.rmsnorm import rms_norm
 from ray_tpu.ops.rope import apply_rope, rope_frequencies
 from ray_tpu.parallel.sharding import ShardingRules
@@ -48,6 +59,9 @@ class LlamaConfig:
     norm_eps: float = 1e-5
     dtype: Any = jnp.bfloat16
     attention: str = "flash"  # flash | reference | ring | ulysses
+    # Recompute each block's wide tensors (the FFN's gate, up and their
+    # product, the norms) in the backward pass and keep only the narrow
+    # ones (REMAT_SAVED: the module docstring has what they cost).
     remat: bool = True
     # Chunked cross-entropy: tokens per chunk (0/None = dense loss).
     # Avoids materializing [B, S, vocab] fp32 logits — at large batch
@@ -201,6 +215,17 @@ SCOPE_FFN = "ffn"
 SCOPE_HEAD = "head"             # final norm and output projection
 SCOPE_LOSS = "cross_entropy"
 
+# What a rematerialized block keeps for its backward pass besides its
+# input: the tensors no wider than the model dimension. q and k are
+# named after rope and k and v BEFORE the grouped-query repeat (at
+# n_kv_heads; the repeat is a copy and is recomputed), the attention
+# output and the flash kernel's row sums where they are produced
+# (ops/attention.py names the kernel's own; `_attention` names the
+# output of the other implementations), and the stream after the
+# attention projection. Everything inside `_ffn` is recomputed.
+REMAT_SAVED = ("attn_q", "attn_k", "attn_v", SAVED_OUT, SAVED_LSE,
+               "attn_proj")
+
 
 def _attention(q, k, v, config: LlamaConfig, mesh):
     """Dispatch to the configured attention implementation."""
@@ -208,16 +233,19 @@ def _attention(q, k, v, config: LlamaConfig, mesh):
     if n_rep > 1:
         k = jnp.repeat(k, n_rep, axis=2)
         v = jnp.repeat(v, n_rep, axis=2)
+    if config.attention == "flash":
+        # names its own output, once, in the layout its backward reads
+        return flash_attention(q, k, v, True, mesh)
     if config.attention == "ring":
         from ray_tpu.parallel.ring_attention import ring_attention
-        return ring_attention(q, k, v, mesh, causal=True)
-    if config.attention == "ulysses":
+        out = ring_attention(q, k, v, mesh, causal=True)
+    elif config.attention == "ulysses":
         from ray_tpu.parallel.ring_attention import ulysses_attention
-        return ulysses_attention(q, k, v, mesh, causal=True)
-    if config.attention == "flash":
-        return flash_attention(q, k, v, True, mesh)
-    from ray_tpu.ops.attention import _attention_reference
-    return _attention_reference(q, k, v, True)
+        out = ulysses_attention(q, k, v, mesh, causal=True)
+    else:
+        from ray_tpu.ops.attention import _attention_reference
+        out = _attention_reference(q, k, v, True)
+    return checkpoint_name(out, SAVED_OUT)
 
 
 def _int8_mm(x2d, w8, scale):
@@ -301,10 +329,13 @@ def _block(layer_params, x, cos, sin, config: LlamaConfig, mesh,
         q = q.reshape(b, s, c.n_heads, hd)
         k = k.reshape(b, s, c.n_kv_heads, hd)
         v = v.reshape(b, s, c.n_kv_heads, hd)
-        q = apply_rope(q, cos, sin)
-        k = apply_rope(k, cos, sin)
+        q = checkpoint_name(apply_rope(q, cos, sin), "attn_q")
+        k = checkpoint_name(apply_rope(k, cos, sin), "attn_k")
+        v = checkpoint_name(v, "attn_v")
         attn = _attention(q, k, v, c, mesh)
-        x = x + attn.reshape(b, s, c.n_heads * hd) @ layer_params["wo"]
+        x = checkpoint_name(
+            x + attn.reshape(b, s, c.n_heads * hd) @ layer_params["wo"],
+            "attn_proj")
     with jax.named_scope(SCOPE_FFN):
         h = rms_norm(x, layer_params["mlp_norm"], c.norm_eps, mesh)
         y, aux = _ffn(layer_params, h, c)
@@ -326,7 +357,9 @@ def llama_forward(params, tokens, config: LlamaConfig, mesh=None,
 
     block = functools.partial(_block, config=c, mesh=mesh)
     if c.remat:
-        block = jax.checkpoint(block)
+        block = jax.checkpoint(
+            block, policy=jax.checkpoint_policies.save_only_these_names(
+                *REMAT_SAVED))
 
     def scan_body(carry, layer_params):
         x, aux_sum = carry

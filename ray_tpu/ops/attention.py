@@ -40,6 +40,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import PartitionSpec as P
 
 from ray_tpu.accelerators import jax_backend
@@ -60,6 +61,13 @@ _INTERPRET = False
 # "q[b,sq,h,d] k[sk]" entry per trace. Callers that must not run the
 # slow path (chip_smoke, engine.stats()) read it.
 kernel_fallbacks: list = []
+# jax.ad_checkpoint names of the two residuals that only the forward
+# kernel produces. A caller's jax.checkpoint policy that lists them
+# (models/llama.py REMAT_SAVED) keeps both, and the backward pass then
+# does not run the forward kernel a second time; with no such policy
+# the names do nothing.
+SAVED_OUT = "attn_out"   # kernel layout [B, H, S, D], as _bwd wants it
+SAVED_LSE = "attn_lse"   # [B, H, S, 1] float32
 
 
 def _block_size(pref: int, dim: int) -> Optional[int]:
@@ -429,11 +437,14 @@ def _flash(q, k, v, causal: bool):
 def _fwd(q, k, v, causal):
     plan = _kernel_plan(q, k)
     if plan is None:
-        return _flash(q, k, v, causal), (q, k, v, None, None)
+        out = checkpoint_name(_flash(q, k, v, causal), SAVED_OUT)
+        return out, (q, k, v, None, None)
     qt = q.transpose(0, 2, 1, 3)
     kt = k.transpose(0, 2, 1, 3)
     vt = v.transpose(0, 2, 1, 3)
     out, lse = _flash_forward(qt, kt, vt, causal, *plan)
+    out = checkpoint_name(out, SAVED_OUT)
+    lse = checkpoint_name(lse, SAVED_LSE)
     return out.transpose(0, 2, 1, 3), (q, k, v, out, lse)
 
 

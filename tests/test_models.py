@@ -1,8 +1,11 @@
 """Llama model tests: forward/loss/grad, sharded-vs-unsharded parity."""
 
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ray_tpu.models.llama import (
@@ -12,6 +15,7 @@ from ray_tpu.models.llama import (
     llama_loss,
     llama_sharding_rules,
 )
+from ray_tpu.ops import attention
 from ray_tpu.parallel.mesh import MeshSpec, make_mesh
 from ray_tpu.parallel.sharding import shard_pytree
 
@@ -116,3 +120,89 @@ def test_chunked_cross_entropy_matches_dense():
         flat_c = ravel_pytree(chunked_grads)[0]
         assert jnp.allclose(flat_d, flat_c, rtol=5e-3, atol=5e-4), (
             "grad mismatch", float(jnp.abs(flat_d - flat_c).max()))
+
+
+@pytest.fixture
+def flash_interpreted(monkeypatch):
+    # the Pallas kernels themselves, in interpreter mode on the CPU
+    monkeypatch.setattr(attention, "_INTERPRET", True)
+
+
+def _flash_sized(preset=LlamaConfig.tiny, **kw):
+    """The smallest shapes the flash kernels cover (heads of 128, a
+    sequence of 128), grouped-query, FFN wider than the model."""
+    return preset(dim=256, n_heads=2, n_kv_heads=1, hidden_dim=384,
+                  **kw)
+
+
+@pytest.mark.parametrize("ce_chunk_tokens", [0, 64])
+@pytest.mark.parametrize("preset", [LlamaConfig.tiny, LlamaConfig.tiny_moe],
+                         ids=["dense", "moe"])
+@pytest.mark.parametrize("attn", ["reference", "flash"])
+def test_remat_gives_the_loss_and_gradients_of_no_remat(
+        flash_interpreted, attn, preset, ce_chunk_tokens):
+    """Keeping a residual or recomputing it is the same program text on
+    the same inputs: exactly equal in float32."""
+    cfg = _flash_sized(preset, attention=attn, remat=True,
+                       ce_chunk_tokens=ce_chunk_tokens)
+    params = llama_init(jax.random.PRNGKey(0), cfg)
+    tokens, targets = _data(cfg, batch=2, seq=128)
+
+    def loss_and_grads(c):
+        return jax.value_and_grad(
+            lambda p: llama_loss(p, tokens, targets, c))(params)
+
+    loss, grads = loss_and_grads(cfg)
+    want_loss, want_grads = loss_and_grads(
+        dataclasses.replace(cfg, remat=False))
+    assert float(loss) == float(want_loss)
+    for (path, got), want in zip(
+            jax.tree_util.tree_leaves_with_path(grads),
+            jax.tree.leaves(want_grads)):
+        np.testing.assert_array_equal(
+            np.asarray(got), np.asarray(want),
+            err_msg=jax.tree_util.keystr(path))
+
+
+def test_remat_keeps_the_narrow_residuals_and_one_flash_forward(
+        flash_interpreted):
+    """What the checkpointed block hands its backward pass: its input,
+    q, k and v at their own head counts (k and v NOT repeated to
+    n_heads), the kernel's output and row sums, the stream after the
+    attention projection; nothing as wide as the FFN, and so no second
+    forward kernel in the backward scan."""
+    from jax._src.ad_checkpoint import saved_residuals
+
+    cfg = _flash_sized(attention="flash", remat=True, n_layers=3)
+    params = llama_init(jax.random.PRNGKey(0), cfg)
+    tokens, targets = _data(cfg, batch=2, seq=128)
+
+    def loss(p):
+        return llama_loss(p, tokens, targets, cfg)
+
+    computed = [aval.shape for aval, why in saved_residuals(loss, params)
+                if "from the argument" not in why]
+    assert not any(shape[-1:] == (cfg.hidden_dim,) for shape in computed)
+    # the layer scan stacks what each block keeps: [n_layers, ...]
+    kept = sorted(shape[1:] for shape in computed
+                  if shape[:1] == (cfg.n_layers,))
+    b, s, hd = 2, 128, cfg.head_dim
+    assert kept == sorted([
+        (b, s, cfg.dim),                    # the block's input
+        (b, s, cfg.n_heads, hd),            # q, after rope
+        (b, s, cfg.n_kv_heads, hd),         # k, after rope
+        (b, s, cfg.n_kv_heads, hd),         # v
+        (b, cfg.n_heads, s, hd),            # flash_fwd's out
+        (b, cfg.n_heads, s, 1),             # flash_fwd's lse
+        (b, s, cfg.dim),                    # x + attn @ wo
+    ])
+
+    def calls(c):
+        text = str(jax.make_jaxpr(jax.grad(
+            lambda p: llama_loss(p, tokens, targets, c)))(params))
+        return [text.count(f"name={k}")
+                for k in ("flash_fwd", "flash_dq", "flash_dkv")]
+
+    # one forward scan body, one backward scan body
+    assert calls(cfg) == [1, 1, 1]
+    assert calls(dataclasses.replace(cfg, remat=False)) == [1, 1, 1]

@@ -126,7 +126,12 @@ def test_flash_and_int8_kernels_compile(v5e):
 
 def _compile_train_step(devices, *, chips, n_layers, batch, seq=2048):
     """chip_smoke's trainer step, from abstract state sharded as its
-    loop shards it. Returns (kernels, bytes per chip)."""
+    loop shards it. Returns (kernels, bytes per chip), having checked
+    that the lowered step calls each flash kernel ONCE: the layer scan's
+    forward body holds flash_fwd and its backward body flash_dq and
+    flash_dkv, because remat keeps flash_fwd's output and row sums
+    (llama.REMAT_SAVED). A second flash_fwd means a name no longer
+    reaches the checkpoint's policy."""
     cfg = LlamaConfig.llama2_7b(n_layers=n_layers, max_seq_len=seq,
                                 ce_chunk_tokens=4096)
     mesh = _mesh(devices, chips)
@@ -137,7 +142,14 @@ def _compile_train_step(devices, *, chips, n_layers, batch, seq=2048):
     tokens = _on(mesh, P(("data", "fsdp")), (batch, seq), jnp.int32)
     lowered = jax.jit(train_step, donate_argnums=(0, 1)).lower(
         params, opt_state, tokens, tokens)
+    text = lowered.as_text()
+    assert [text.count(f'kernel_name = "{k}"') for k in
+            ("flash_fwd", "flash_dq", "flash_dkv")] == [1, 1, 1]
     memory = lowered.compile().memory_analysis()
+    gib = 2.0**30
+    print(f"train step on {chips} chip(s), {n_layers} layers, {batch} x "
+          f"{seq}: arguments {memory.argument_size_in_bytes / gib:.2f} + "
+          f"temporaries {memory.temp_size_in_bytes / gib:.2f} GiB a chip")
     return _kernels(lowered), (memory.argument_size_in_bytes
                                + memory.temp_size_in_bytes)
 
