@@ -144,22 +144,6 @@ class LlamaConfig:
         return (self.vocab_size * self.dim * 2     # embedding + lm_head
                 + self.n_layers * per_layer + self.dim)
 
-    def active_params_per_token(self) -> int:
-        """Parameters actually touched per token: for MoE, only top_k of
-        the moe_experts expert FFNs are active."""
-        total = self.num_params()
-        if self.moe_experts:
-            inactive = ((self.moe_experts - min(self.moe_top_k,
-                                                self.moe_experts))
-                        * 3 * self.dim * self.hidden_dim * self.n_layers)
-            total -= inactive
-        return total
-
-    def flops_per_token(self) -> float:
-        """Approx training FLOPs/token (6 * active params; counting all
-        experts would overstate MoE MFU by E/top_k)."""
-        return 6.0 * self.active_params_per_token()
-
 
 def llama_init(rng, config: LlamaConfig) -> Dict[str, Any]:
     """Initialize the parameter pytree (layers stacked on axis 0)."""

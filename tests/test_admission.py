@@ -6,7 +6,7 @@ the handle path, sheds excluded from latency histograms).
 """
 
 import json
-import math
+import os
 import threading
 import time
 import urllib.error
@@ -14,7 +14,6 @@ import urllib.request
 
 import pytest
 
-import ray_tpu
 from ray_tpu import serve
 from ray_tpu.serve.admission import (
     AdmissionController, BackpressureError, Shed, _Ewma,
@@ -386,11 +385,12 @@ def test_caps_are_per_deployment(serve_instance):
     blocker.result(timeout_s=30)
 
 
-def test_http_503_with_retry_after(serve_instance):
+def test_http_503_with_retry_after(serve_instance, tmp_path):
     @serve.deployment(max_ongoing_requests=1, max_queued_requests=0)
     class SlowHttp:
         def __call__(self, req):
-            time.sleep(float(req.get("sleep", 0)))
+            while "until" in req and not os.path.exists(req["until"]):
+                time.sleep(0.01)
             return {"ok": True}
 
     serve.start(proxy=True, http_options=serve.HTTPOptions(port=0))
@@ -405,11 +405,23 @@ def test_http_503_with_retry_after(serve_instance):
         with urllib.request.urlopen(req, timeout=timeout) as resp:
             return json.loads(resp.read())
 
+    # Posted in sequence without waiting, as a client does. The router
+    # frees a slot AFTER the answer is written, so now and then a post
+    # that follows an answer is shed (ROADMAP D9c, the product's to
+    # cure): this test then fails, here or on its last line.
     assert post({}) == {"ok": True}  # warm-up configures admission
-    blocker = threading.Thread(target=post, args=({"sleep": 1.5},))
+    release = tmp_path / "release"
+    blocker = threading.Thread(target=post,
+                               args=({"until": str(release)},))
     blocker.start()
     try:
-        time.sleep(0.4)
+        # the event the 503 needs is the slot's own count
+        slot = get_admission_controller("SlowHttp")
+        deadline = time.monotonic() + 30
+        while slot.snapshot()["inflight"] != 1:
+            assert blocker.is_alive(), "the blocker was shed (D9c)"
+            assert time.monotonic() < deadline, "the blocker never ran"
+            time.sleep(0.005)
         with pytest.raises(urllib.error.HTTPError) as ei:
             post({})
         err = ei.value
@@ -421,5 +433,6 @@ def test_http_503_with_retry_after(serve_instance):
         assert body["reason"] == "queue_full"
         assert body["retry_after_s"] > 0
     finally:
+        release.touch()
         blocker.join(timeout=30)
     assert post({}) == {"ok": True}  # recovered after the blocker

@@ -18,6 +18,7 @@ import time
 import numpy as np
 import pytest
 
+import hostratio
 import ray_tpu
 from ray_tpu.devtools import collsan
 
@@ -561,40 +562,26 @@ def test_recreated_group_matches_fresh_group_bitwise(ray_start_regular):
 # --- overhead guards (satellite 5) ---------------------------------------
 
 def test_disabled_hot_path_overhead_guard(ray_start_regular):
-    """Interleaved best-of-3 A/B of the world-1 allreduce stamp path;
+    """A/B of the world-1 allreduce stamp path (a stamp is ~2us);
     mirrors ``perf.py --collsan`` and the BENCH_core.json acceptance
     bound (enabled/disabled < 2.0)."""
-    import gc
-
     from ray_tpu.parallel import collective
     collective.init_collective_group(1, 0, "csan-ovh")
     x = np.ones(65536, dtype=np.float32)
+
+    def allreduces(n=300):
+        t0 = time.perf_counter()
+        for _ in range(n):
+            collective.allreduce(x, "sum", "csan-ovh")
+        return time.perf_counter() - t0
+
     try:
         saved = collsan.LEDGER
-        for _ in range(50):
-            collective.allreduce(x, "sum", "csan-ovh")
-        rounds = 300
-        best = {False: None, True: None}
-        for _ in range(5):
-            for enabled in (False, True):
-                if enabled:
-                    collsan.enable("test:ovh")  # fresh, empty ledger
-                else:
-                    collsan.disable()
-                # level the GC field: under pytest the heap carries
-                # every previous test's objects and a collection
-                # landing inside one timed segment but not the other
-                # would swamp the ~2µs stamp being measured
-                gc.collect()
-                t0 = time.perf_counter()
-                for _ in range(rounds):
-                    collective.allreduce(x, "sum", "csan-ovh")
-                dt = time.perf_counter() - t0
-                if best[enabled] is None or dt < best[enabled]:
-                    best[enabled] = dt
-        ratio = best[True] / best[False]
-        assert ratio < 2.0, (
-            f"collsan-enabled allreduce {ratio:.2f}x the disabled path")
+        allreduces(50)
+        hostratio.judge_switched(
+            "collsan on / off", 2.0, collsan.disable,
+            lambda: collsan.enable("test:ovh"),  # fresh, empty ledger
+            timed=allreduces, rounds=5)
     finally:
         collsan.LEDGER = saved
         collective._groups.pop("csan-ovh", None)

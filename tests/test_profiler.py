@@ -3,11 +3,11 @@ speedscope export, submit-path phase chains, profdiff round-trip,
 percentile None-contract, and the overhead ratio guards."""
 
 import json
-import threading
 import time
 
 import pytest
 
+import hostratio
 from ray_tpu.devtools import profdiff, profiler
 from ray_tpu.util import flight_recorder as fr
 from ray_tpu.util import metrics as metrics_mod
@@ -225,15 +225,11 @@ def test_histogram_percentile_none_when_unobserved():
 
 @pytest.mark.watchdog(120)
 def test_phase_chain_records_all_phases(ray_start_regular):
-    import ray_tpu
     from ray_tpu.core import task_phase
     from ray_tpu.core.config import get_config
     from ray_tpu.devtools import whereis
 
-    @ray_tpu.remote(num_cpus=0)
-    def nop():
-        return None
-
+    loop = hostratio.task_loop()
     cfg = get_config()
     saved = (fr.RECORDER, cfg.task_phase_sample_n)
     task_phase.reset()
@@ -241,7 +237,7 @@ def test_phase_chain_records_all_phases(ray_start_regular):
         cfg.task_phase_sample_n = 1          # sample every task
         fr.enable("driver:phase-test", capacity=4096)
         lo = fr.clock_ns()
-        ray_tpu.get([nop.remote() for _ in range(50)])
+        loop(50)
         hi = fr.clock_ns()
         report = whereis.task_path_attribution(
             fr.merged_journals(), window_ns=(lo, hi))
@@ -265,18 +261,13 @@ def test_phase_chain_records_all_phases(ray_start_regular):
 def test_phase_sampling_gate_is_cheap_when_untracked(ray_start_regular):
     """With the recorder off, sample_begin returns 0 and _TRACKED stays
     empty — the unsampled hot path must leave no chains behind."""
-    import ray_tpu
     from ray_tpu.core import task_phase
-
-    @ray_tpu.remote(num_cpus=0)
-    def nop():
-        return None
 
     saved = fr.RECORDER
     try:
         fr.disable()
         task_phase.reset()
-        ray_tpu.get([nop.remote() for _ in range(200)])
+        hostratio.task_loop()(200)
         assert task_phase._TRACKED == {}
         assert task_phase.sample_begin() == 0
     finally:
@@ -287,75 +278,52 @@ def test_phase_sampling_gate_is_cheap_when_untracked(ray_start_regular):
 
 @pytest.mark.watchdog(300)
 def test_profiler_overhead_disabled_ratio(ray_start_regular):
-    """With every observatory gate off, interleaved runs of the same
-    loop must agree within 5% — the disabled path is two loads and a
-    compare, so any drift here is a gate that grew a body."""
-    import ray_tpu
+    """The A/A control of the ratio guards: both arms run the SAME loop
+    with every observatory gate off, so no change to the tree can move
+    this ratio. It reads `hostratio`'s own noise on this box, at three
+    rounds (the other guards take two), and holds it to 5%: a guard
+    whose limit is nearer than that to what it measures says nothing."""
     from ray_tpu.core import task_phase
 
-    @ray_tpu.remote(num_cpus=0)
-    def nop():
-        return None
-
-    ray_tpu.get([nop.remote() for _ in range(500)])   # warmup
-
-    def run_loop(n=1500):
-        t0 = time.perf_counter()
-        ray_tpu.get([nop.remote() for _ in range(n)])
-        return time.perf_counter() - t0
-
-    saved = (fr.RECORDER, profiler.PROFILER)
-    try:
+    def all_off():
         fr.disable()
         profiler.disable()
         task_phase.reset()
-        timings = {"a": [], "b": []}
-        for arm in ("a", "b", "a", "b", "a", "b"):
-            timings[arm].append(run_loop())
-        ratio = min(timings["b"]) / min(timings["a"])
+
+    saved = (fr.RECORDER, profiler.PROFILER)
+    try:
+        hostratio.judge_switched("all off / all off", 1.05, all_off,
+                                 all_off, rounds=3)
     finally:
         fr.RECORDER, profiler.PROFILER = saved
-    assert ratio < 1.05, f"disabled-path drift ratio {ratio:.3f} >= 1.05"
 
 
 @pytest.mark.watchdog(300)
 def test_profiler_overhead_enabled_ratio(ray_start_regular):
     """Full observatory on — sampler at 101 Hz + recorder + 1-in-64
-    phase sampling — vs everything off, interleaved best-of: the
-    enabled loop must stay under 1.5x."""
-    import ray_tpu
+    phase sampling — vs everything off: the enabled loop must stay
+    under 1.5x."""
     from ray_tpu.core import task_phase
     from ray_tpu.core.config import get_config
 
-    @ray_tpu.remote(num_cpus=0)
-    def nop():
-        return None
-
-    ray_tpu.get([nop.remote() for _ in range(500)])   # warmup
-
-    def run_loop(n=1500):
-        t0 = time.perf_counter()
-        ray_tpu.get([nop.remote() for _ in range(n)])
-        return time.perf_counter() - t0
-
     cfg = get_config()
     saved = (fr.RECORDER, profiler.PROFILER, cfg.task_phase_sample_n)
+
+    def off():
+        cfg.task_phase_sample_n = saved[2]
+        fr.disable()
+        profiler.disable()
+        task_phase.reset()
+
+    def on():
+        cfg.task_phase_sample_n = 64
+        fr.enable("driver:overhead")
+        profiler.enable("driver:overhead", hz=101)
+        task_phase.reset()
+
     try:
-        timings = {}
-        for mode in ("off", "on", "off", "on"):    # interleave: best-of
-            if mode == "on":
-                cfg.task_phase_sample_n = 64
-                fr.enable("driver:overhead")
-                profiler.enable("driver:overhead", hz=101)
-            else:
-                cfg.task_phase_sample_n = saved[2]
-                fr.disable()
-                profiler.disable()
-            task_phase.reset()
-            timings.setdefault(mode, []).append(run_loop())
-        ratio = min(timings["on"]) / min(timings["off"])
+        hostratio.judge_switched("observatory on / off", 1.5, off, on)
     finally:
         profiler.disable()
         fr.RECORDER, profiler.PROFILER, cfg.task_phase_sample_n = saved
         task_phase.reset()
-    assert ratio < 1.5, f"observatory overhead ratio {ratio:.2f} >= 1.5"

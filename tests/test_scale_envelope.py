@@ -15,6 +15,7 @@ import time
 import numpy as np
 import pytest
 
+import hostratio
 import ray_tpu
 
 
@@ -25,21 +26,9 @@ def envelope_head():
     ray_tpu.shutdown()
 
 
-def _calibration_rate(n: int = 200_000) -> float:
-    t0 = time.perf_counter()
-    d = {}
-    out = []
-    for i in range(n):
-        d[i & 1023] = i
-        out.append((i, i + 1))
-        if len(out) > 1024:
-            out.clear()
-    return n / (time.perf_counter() - t0)
-
-
 def test_envelope_64_nodes_1k_actors_pgs(envelope_head):
     rt = envelope_head
-    calib = _calibration_rate()
+    calib = 1 / hostratio.calibration_op_seconds(200_000)
 
     # --- 64 nodes join the control plane (ledger + GCS) -------------
     # Stub registrations model what REMOTE nodes cost the head: a

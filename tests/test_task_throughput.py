@@ -2,16 +2,16 @@
 release/benchmarks/README.md — 10k+ tasks/s, 1M queued per node without
 collapse; owner-push + lease-cache design normal_task_submitter.cc:499).
 
-Absolute rates swing wildly with box load (the CI box is 1-core and
-shared), so the guards are RATIOS against a same-run calibration: a
-fixed pure-Python workload measures how fast this box runs Python right
-now, and task throughput must stay within a constant factor of it.
-Load slows both sides proportionally, so the ratio is stable where an
-absolute floor either flakes or goes blunt — quiet-box ratios are ~2.4x
-above these thresholds (PERF.md records the honest numbers;
-`python -m ray_tpu.scripts.perf` reproduces them, including an opt-in
-1M drain via --backlog 1000000). test_throughput_guard_has_teeth proves
-the thresholds catch a ~2x per-task regression.
+Absolute rates swing wildly with box load, so the guards are RATIOS
+against a same-run calibration: a fixed pure-Python workload measures
+how fast this box runs Python right now, and task throughput must stay
+within a constant factor of it (`python -m ray_tpu.scripts.perf`
+reproduces the rates, including an opt-in 1M drain via --backlog
+1000000). The calibration is one thread, read once, and a task crosses
+processes, so busy neighbours slow the task side more and a slow
+calibration passes an attempt: `hostratio.judge` says what three
+attempts are worth here. test_throughput_guard_has_teeth proves the
+thresholds catch a ~2x per-task regression in every attempt.
 """
 
 import socket
@@ -20,7 +20,7 @@ import time
 
 import pytest
 
-import ray_tpu
+import hostratio
 from ray_tpu.devtools import refsan as _refsan
 
 # A runtime sanitizer adds per-task-bookkeeping cost on only ONE side
@@ -40,67 +40,47 @@ CALIB_SUBMIT_RATIO = 0.0020
 CALIB_E2E_RATIO = 0.0008
 
 
-def _calibration_rate(n: int = 300_000) -> float:
-    """Fixed pure-Python workload (dict stores + tuple allocs + list
-    append/clear — the flavor of per-task bookkeeping) measuring the
-    box's current effective Python speed."""
-    t0 = time.perf_counter()
-    d = {}
-    out = []
-    for i in range(n):
-        d[i & 1023] = i
-        out.append((i, i + 1))
-        if len(out) > 1024:
-            out.clear()
-    return n / (time.perf_counter() - t0)
-
-
-def _rates(n: int) -> tuple:
-    """(submit rate, honest end-to-end rate) for n queued no-op tasks.
-    End-to-end = submit start -> last completion; completions overlap
-    submission, so no phase-sliced 'drain rate' (which would overstate
-    throughput by excluding early completions' time)."""
-    @ray_tpu.remote(num_cpus=0)
-    def nop():
-        return None
-
-    ray_tpu.get([nop.remote() for _ in range(500)])  # prime pool/caches
-    t0 = time.perf_counter()
-    refs = [nop.remote() for _ in range(n)]
-    t1 = time.perf_counter()
-    ray_tpu.get(refs)
-    t2 = time.perf_counter()
-    return n / (t1 - t0), n / (t2 - t0)
+def _against_calibration(loop, n: int) -> list:
+    """`hostratio.judge` readings for n queued no-op tasks: seconds a
+    task to submit, and end to end, over seconds a calibration op (a
+    rate over R x calibration is a cost under 1 / R). End to end =
+    submit start -> last completion; completions overlap submission, so
+    no phase-sliced 'drain rate' (which would overstate throughput by
+    excluding early completions' time). ONE reading of each, as the
+    limits were set: ROADMAP D9 says where the tree stands against
+    them."""
+    calib = hostratio.calibration_op_seconds()
+    submit, e2e = (s / n / calib for s in loop(n))
+    return [("submit s/task over s/calibration op", submit,
+             1 / CALIB_SUBMIT_RATIO),
+            ("end-to-end s/task over s/calibration op", e2e,
+             1 / CALIB_E2E_RATIO)]
 
 
 def test_deep_backlog_does_not_collapse(ray_start_regular):
     """Round-2 verdict: throughput fell 5x between 2k and 10k queued
     (2.9k/s -> 0.6k/s). Guard the fix: end-to-end rate with a 40k-deep
-    backlog must stay within 2.5x of the 4k-deep rate, and clear the
+    backlog must stay within 3x of the 4k-deep rate, and clear the
     calibration ratio."""
-    calib = _calibration_rate()
-    _, shallow = _rates(4_000)
-    _, deep = _rates(40_000)
-    assert deep > shallow / 3.0, (
-        f"deep-backlog collapse: {deep:.0f}/s at 40k vs "
-        f"{shallow:.0f}/s at 4k queued")
-    assert deep > CALIB_E2E_RATIO * calib, (
-        f"deep end-to-end {deep:.0f}/s under {CALIB_E2E_RATIO} x "
-        f"calibration ({calib:.0f} ops/s)")
+    loop = hostratio.task_loop()
+
+    def measure():
+        calib = hostratio.calibration_op_seconds()
+        shallow = loop(4_000)[1] / 4_000
+        deep = loop(40_000)[1] / 40_000
+        return [("40k-deep s/task over 4k-deep", deep / shallow, 3.0),
+                ("40k-deep s/task over s/calibration op", deep / calib,
+                 1 / CALIB_E2E_RATIO)]
+
+    hostratio.judge(measure)
 
 
 def test_submit_rate_calibrated(ray_start_regular):
     """Owner-side submission keeps pace with the box's Python speed
     (quiet-box ~50us/task at ~5M calib ops/s -> ratio ~0.0047; guard
     at 0.002)."""
-    calib = _calibration_rate()
-    submit, e2e = _rates(20_000)
-    assert submit > CALIB_SUBMIT_RATIO * calib, (
-        f"submit {submit:.0f}/s under {CALIB_SUBMIT_RATIO} x "
-        f"calibration ({calib:.0f} ops/s)")
-    assert e2e > CALIB_E2E_RATIO * calib, (
-        f"end-to-end {e2e:.0f}/s under {CALIB_E2E_RATIO} x "
-        f"calibration ({calib:.0f} ops/s)")
+    loop = hostratio.task_loop()
+    hostratio.judge(lambda: _against_calibration(loop, 20_000))
 
 
 def test_throughput_guard_has_teeth(ray_start_regular):
@@ -108,10 +88,10 @@ def test_throughput_guard_has_teeth(ray_start_regular):
     item 7 done-criterion): inject ~2.5x the per-task submit budget as
     fixed pure-Python work per task — the same currency as the
     calibration, so this sabotage trips the guard on any box — and
-    assert the submit guard fails."""
+    assert the submit guard fails: every attempt it is given is over."""
     from ray_tpu.core import runtime as runtime_mod
 
-    calib = _calibration_rate()
+    loop = hostratio.task_loop()
     rt = runtime_mod.get_runtime()
     orig = rt.submit_spec
 
@@ -123,19 +103,17 @@ def test_throughput_guard_has_teeth(ray_start_regular):
 
     rt.submit_spec = regressed_submit
     try:
-        submit, _ = _rates(8_000)
+        with pytest.raises(AssertionError, match="in each of"):
+            hostratio.judge(lambda: _against_calibration(loop, 8_000)[:1])
     finally:
         rt.submit_spec = orig
-    assert submit < CALIB_SUBMIT_RATIO * calib, (
-        f"guard is toothless: sabotaged submit {submit:.0f}/s still "
-        f"clears {CALIB_SUBMIT_RATIO} x calibration ({calib:.0f})")
 
 
-def _wire_submit_rate(native: bool, n: int = 30_000,
-                      payload: bytes = b"x" * 700) -> float:
-    """Frames/s through a LoopConnection for SUBMIT-sized frames — the
-    wire leg of remote task submission (producer thread enqueues, the
-    loop flushes, a raw peer drains). Measures submit start to last
+def _wire_submit_seconds(native: bool, n: int = 30_000,
+                         payload: bytes = b"x" * 700) -> float:
+    """Seconds to push n SUBMIT-sized frames through a LoopConnection —
+    the wire leg of remote task submission (producer thread enqueues,
+    the loop flushes, a raw peer drains). Measures submit start to last
     frame received."""
     from ray_tpu.core.io_loop import IOLoop
     from ray_tpu.core.protocol import FrameReader
@@ -166,7 +144,7 @@ def _wire_submit_rate(native: bool, n: int = 30_000,
     conn.close()
     loop.stop()
     b.close()
-    return n / dt
+    return dt
 
 
 def test_native_wire_not_slower_than_fallback():
@@ -174,15 +152,20 @@ def test_native_wire_not_slower_than_fallback():
     at least as fast as the pure-Python fallback (best-of-3 each,
     interleaved so box-load drift hits both modes equally). Skips where
     the C toolchain is unavailable (the fallback is then the only
-    codec, and there is nothing to compare)."""
+    codec, and there is nothing to compare). Where the leg's three
+    threads spread over several CPUs the native codec, the default,
+    reads slower than the fallback, busy box or quiet: that is the
+    tree's, not the box's (ROADMAP D8), and this guard says so."""
     from ray_tpu.native import _lib
 
     if _lib.try_load() is None:
         pytest.skip("native wire codec unavailable (no C toolchain)")
-    best = {True: 0.0, False: 0.0}
-    for _ in range(3):
-        for mode in (False, True):
-            best[mode] = max(best[mode], _wire_submit_rate(mode))
-    assert best[True] >= best[False], (
-        f"native wire slower than fallback on the submit leg: "
-        f"native {best[True]:.0f}/s vs fallback {best[False]:.0f}/s")
+
+    def measure():
+        best = hostratio.interleaved_best(
+            {"fallback": lambda: _wire_submit_seconds(False),
+             "native": lambda: _wire_submit_seconds(True)}, 3)
+        return [("native s/frame over fallback",
+                 best["native"] / best["fallback"], 1.0)]
+
+    hostratio.judge(measure)
