@@ -1,21 +1,52 @@
 """Fused causal flash attention — streaming Pallas TPU kernels.
 
-Forward: online-softmax accumulation over K/V tiles (FlashAttention
-algorithm) with a (batch, head, q-block, k-block) grid — VMEM stays
-bounded at any sequence length, the [S, S] score matrix never touches
-HBM, and causally-masked K blocks are skipped (their compute is
-predicated off and their DMAs elided by clamping the block index map to
-the last valid block, so Mosaic's pipeline sees a repeated index and
-re-uses the buffer).
+Forward: online-softmax accumulation (FlashAttention algorithm) with a
+(batch, head, q-block, major) grid. A grid step owns one block of
+queries and walks, INSIDE the kernel, the tiles of K and V that its
+rows can see: the trip counts come from the block's index
+(``_key_tiles``), so a head's grid has no step above the causal
+diagonal, the tiles wholly under it run a body with no mask, and only
+the tiles the diagonal crosses run the masked one. K and V of a head
+stay resident in VMEM up to ``_MAJOR`` rows; a longer sequence is walked
+in several "major" grid steps (those past the diagonal clamp their block
+index, so their DMA is elided), which keeps VMEM bounded at any sequence
+length. The [S, S] score matrix never touches HBM.
+
+Scores are held keys x queries in flash_fwd and flash_dkv, so every
+per-query statistic (running max and sum, lse, delta) is a row along
+the lanes, reduced and broadcast along sublanes: no cross-lane
+reduction, no lane broadcast and no transposed product in either.
+flash_dq holds them queries x keys (its dq needs no statistic reduced)
+and turns its two rows into columns once a grid step.
 
 Backward: fused dq and dk/dv kernels using the saved logsumexp and the
-precomputed delta = rowsum(dO * O) — no score-matrix materialization in
-the backward either, which is where the naive VJP loses (a
-[B, H, S, S] f32 tensor per layer is HBM-bandwidth death at seq 2048+).
+precomputed delta = rowsum(dO * O), the same walk (flash_dkv owns a
+block of keys and walks the query tiles, ``_query_tiles``) — no
+score-matrix materialization in the backward either, which is where the
+naive VJP loses (a [B, H, S, S] f32 tensor per layer is HBM-bandwidth
+death at seq 2048+).
+
+The schedule is decided at trace time from (sq, sk, causal) alone
+(``tile_schedule`` is what the tests read). Where a grid axis has one
+step its index is the Python 0 and the schedule folds while tracing: a
+prefill bucket of one block is a kernel of one masked tile with no
+loop, branch or scratch. That is for the serving cells' warm start,
+which compiles nothing and lowers a prefill program eleven times (the
+reference check's three, the replica's four, and those four once more
+for ``engine.stats()``): with loops at every length it was 4 s dearer in
+the Jamba cell (PERF.md section 6, PR 56).
 
 All matmuls run with bf16 inputs and f32 accumulation
 (preferred_element_type) — the MXU's native mode; softmax statistics
 stay f32.
+
+Measured on a v5e (PERF.md section 6, PR 56; ms a call at [4, 2048, 32,
+128], device time in a trace): flash_fwd 1.81, flash_dq 1.85, flash_dkv
+2.44, against 4.06 / 3.23 / 3.70 for the (q-block, k-block) grid of 256
+x 512 tiles they replace, and 1.75 / 2.05 / 2.64 for the best block
+sizes of jax's splash attention; a prefill bucket's flash_fwd at [1, S,
+32, 128], S = 128 / 256 / 512 / 1024: 0.020 / 0.025 / 0.038 / 0.153
+against 0.023 / 0.041 / 0.093 / 0.285.
 
 Reference analog: the reference has no in-tree attention kernels (it
 delegates to vLLM/torch, SURVEY.md §5.7); this is the TPU-native
@@ -35,7 +66,7 @@ stays as the reference and the path of uncovered shapes.
 from __future__ import annotations
 
 import functools
-from typing import Optional
+from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -48,12 +79,23 @@ from ray_tpu.parallel.mesh import mesh_axes, mesh_axis_size
 
 NEG_INF = -1e30
 
-# Default tile sizes; shrunk to fit when seq is smaller. 128-multiples
-# keep every matmul MXU-aligned. 256x512 measured ~4x faster than
-# 512x512 on v5e (the [bq, bk] f32 score tile plus double-buffered
-# operands stays within VMEM without spilling).
-_BLOCK_Q = 256
-_BLOCK_K = 512
+# The kernels' three sizes, each shrunk to what divides the sequence
+# (128-multiples keep every product MXU-aligned). _BLOCK: the rows whose
+# accumulators one grid step holds (queries in flash_fwd and flash_dq,
+# keys in flash_dkv). _TILE: the rows of the walked operand one score
+# tile takes, so a tile is _BLOCK x _TILE float32. _MAJOR: the rows of
+# the walked operand resident in VMEM at once (K and V, or Q and dO: 1
+# MiB of bf16 a pair at 2048, double-buffered).
+# The diagonal is met in squares of _BLOCK, so _BLOCK / S of S^2 / 2 is
+# computed above it: 25% at 2048. Measured on a v5e at [4, 2048, 32,
+# 128] (PR 55's sweep, wall clock a call; PERF.md section 6, PR 56): a
+# tile step costs a fixed 0.4-0.5 us beside its scores, so 512 x 512
+# beats 256 x 512 (flash_fwd 2.14 against 3.18 ms), 512 x 256 (2.42)
+# and 512 x 1024 (2.36), and a tile narrow enough for the vector
+# registers, 256 x 128, is the slowest (7.07).
+_BLOCK = 512
+_TILE = 512
+_MAJOR = 2048
 # Run kernels in interpreter mode (CPU testing); toggled by tests.
 _INTERPRET = False
 # Shapes for which flash attention was asked for on a TPU and the
@@ -73,7 +115,7 @@ SAVED_LSE = "attn_lse"   # [B, H, S, 1] float32
 def _block_size(pref: int, dim: int) -> Optional[int]:
     """Largest 128-multiple block <= pref that tiles `dim` exactly."""
     for cand in (pref, 256, 128):
-        if cand <= dim and dim % cand == 0:
+        if cand <= min(pref, dim) and dim % cand == 0:
             return cand
     return None
 
@@ -91,138 +133,287 @@ def _attention_reference(q, k, v, causal: bool):
                       v.astype(jnp.float32)).astype(q.dtype)
 
 
+# --- the schedule: which tiles a grid step walks, and which of them the
+# diagonal crosses. The kernels' loops, the index maps and the tests
+# read the same functions (Python ints or traced scalars alike). -------
 
-# --- shared causal-geometry helpers (keep forward/backward in sync) ----
-
-def _causal_live(qi, ki, block_q: int, block_k: int, offset: int):
-    """Whether the (qi, ki) tile touches the causal lower triangle."""
-    return (qi + 1) * block_q - 1 + offset >= ki * block_k
-
-
-def _causal_mask(s, qi, ki, block_q: int, block_k: int, offset: int):
-    """NEG_INF-mask score tile entries above the causal diagonal."""
-    q_pos = qi * block_q + offset + jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 0)
-    k_pos = ki * block_k + jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 1)
-    return jnp.where(q_pos >= k_pos, s, NEG_INF)
+class _Plan(NamedTuple):
+    """Sizes of the three kernels for one (sq, sk). flash_fwd and
+    flash_dq give a grid step ``block_q`` queries and walk the keys in
+    tiles of ``tile_k``, ``major_k`` of them resident; flash_dkv gives
+    it ``block_k`` keys and walks the queries in tiles of ``tile_q``,
+    ``major_q`` resident."""
+    block_q: int
+    block_k: int
+    tile_q: int
+    tile_k: int
+    major_q: int
+    major_k: int
 
 
-def _clamped_kv_index(causal: bool, block_q: int, block_k: int,
-                      offset: int, nk: int):
-    """KV block index map: past-diagonal fetches clamp to the last live
-    block, so Mosaic sees a repeated index and elides the DMA."""
-    def index(bi, hi, qi, ki):
-        if causal:
-            last = jnp.minimum(
-                ((qi + 1) * block_q - 1 + offset) // block_k, nk - 1)
-            ki = jnp.minimum(ki, last)
-        return (bi, hi, ki, 0)
-    return index
+def _clip(x, lo, hi):
+    if all(isinstance(i, int) for i in (x, lo, hi)):
+        return max(lo, min(x, hi))
+    return jnp.minimum(jnp.maximum(x, lo), hi)
+
+
+def _key_tiles(q0, block_q: int, tile_k: int, offset: int, causal: bool,
+               first, end):
+    """(split, stop) for the queries [q0, q0 + block_q), query ``r``
+    seeing the keys ``c <= r + offset``: of the key tiles [first, end),
+    [first, split) lie wholly under the diagonal and need no mask,
+    [split, stop) cross it, and the tiles from ``stop`` on are wholly
+    masked and never visited."""
+    if not causal:
+        return end, end
+    return (_clip((q0 + offset + 1) // tile_k, first, end),
+            _clip((q0 + block_q - 1 + offset) // tile_k + 1, first, end))
+
+
+def _query_tiles(k0, block_k: int, tile_q: int, offset: int, causal: bool,
+                 first, end):
+    """(start, split) for the keys [k0, k0 + block_k): of the query
+    tiles [first, end), those before ``start`` see none of the keys and
+    are never visited, [start, split) cross the diagonal, [split, end)
+    see them all."""
+    if not causal:
+        return first, first
+    return (_clip((k0 - offset) // tile_q, first, end),
+            _clip((k0 + block_k - 2 - offset) // tile_q + 1, first, end))
+
+
+def tile_schedule(sq: int, sk: int, causal: bool, plan: _Plan):
+    """What the kernels visit for one head, as the tests and PERF.md
+    read it: ``{"by_query": tiles, "by_key": tiles, "dead_steps": n}``.
+    A tile is (q0, k0, rows, columns, masked); ``by_query`` is the walk
+    of flash_fwd and flash_dq, ``by_key`` flash_dkv's; ``dead_steps``
+    counts the grid steps of all three that visit no tile (none while
+    the walked operand fits one major block)."""
+    offset = sk - sq
+    by_query, by_key, dead = [], [], 0
+    n, per_major = sk // plan.tile_k, plan.major_k // plan.tile_k
+    for q0 in range(0, sq, plan.block_q):
+        split, stop = _key_tiles(q0, plan.block_q, plan.tile_k, offset,
+                                 causal, 0, n)
+        by_query += [(q0, j * plan.tile_k, plan.block_q, plan.tile_k,
+                      j >= split) for j in range(stop)]
+        dead += 2 * (n // per_major - -(-stop // per_major))
+    n, per_major = sq // plan.tile_q, plan.major_q // plan.tile_q
+    for k0 in range(0, sk, plan.block_k):
+        start, split = _query_tiles(k0, plan.block_k, plan.tile_q, offset,
+                                    causal, 0, n)
+        by_key += [(j * plan.tile_q, k0, plan.tile_q, plan.block_k,
+                    j < split) for j in range(start, n)]
+        dead += start // per_major
+    return {"by_query": by_query, "by_key": by_key, "dead_steps": dead}
+
+
+def _grid_step(axis: int, steps: int):
+    """This grid step's index along ``axis``; the Python 0 where the
+    axis has one step, so that the schedule of a short sequence folds
+    at trace time: a prefill bucket of one block and one tile is a
+    kernel with no loop, no branch and no scratch, which costs a warm
+    start less to trace and lower than the loops would."""
+    from jax.experimental import pallas as pl
+    return pl.program_id(axis) if steps > 1 else 0
+
+
+def _walk(step, carry, unmasked, masked):
+    """Run ``step(j, carry, masked=...)`` over the two half-open tile
+    ranges (``masked`` is None where nothing is masked); a range whose
+    end is not past its start runs nothing, and one known at trace time
+    to hold a single tile runs it without a loop."""
+    for bounds, with_mask in ((unmasked, False), (masked, True)):
+        if bounds is None:
+            continue
+        lo, hi = bounds
+        body = functools.partial(step, masked=with_mask)
+        if isinstance(lo, int) and isinstance(hi, int) and hi - lo <= 1:
+            carry = body(lo, carry) if hi > lo else carry
+        else:
+            carry = jax.lax.fori_loop(lo, hi, body, carry)
+    return carry
+
+
+def _across_majors(n_major: int, scratch, init, walk, finish):
+    """``finish(walk(... walk(init())))``, one ``walk`` a major grid
+    step (the innermost grid axis): the carry crosses the steps in the
+    ``scratch`` refs, which a walk of one major block does not have."""
+    from jax.experimental import pallas as pl
+    if n_major == 1:
+        finish(*walk(init()))
+        return
+    major = pl.program_id(3)
+
+    @pl.when(major == 0)
+    def _init():
+        for ref, value in zip(scratch, init()):
+            ref[...] = value
+
+    for ref, value in zip(scratch, walk(tuple(ref[...] for ref in scratch))):
+        ref[...] = value
+
+    @pl.when(major == n_major - 1)
+    def _finish():
+        finish(*(ref[...] for ref in scratch))
+
+
+def _query_less_key(shape, query_axis: int):
+    """index along the queries' axis less index along the keys', for a
+    score tile of ``shape``; the same for every tile, so made once a
+    grid step."""
+    return (jax.lax.broadcasted_iota(jnp.int32, shape, query_axis)
+            - jax.lax.broadcasted_iota(jnp.int32, shape, 1 - query_axis))
+
+
+def _mask_above_diagonal(s, query_less_key, q0, k0, offset: int):
+    """NEG_INF where the tile's key k0 + j lies past what its query
+    q0 + i sees (j <= i + offset): a compare and a select an element."""
+    return jnp.where(query_less_key >= k0 - q0 - offset, s, NEG_INF)
+
+
+def _tile_rows(j, first, tile: int):
+    """The rows of global tile ``j`` inside the resident major block
+    that starts at tile ``first``."""
+    from jax.experimental import pallas as pl
+    start = (j - first) * tile
+    return pl.ds(start if isinstance(start, int)
+                 else pl.multiple_of(start, tile), tile)
+
+
+def _as_column(row):
+    """[1, n] -> [n, 1], through a sublane broadcast and one transpose:
+    once a grid step, never a tile."""
+    return jnp.broadcast_to(row, (128, row.shape[1])).T[:, :1]
+
+
+_NT = (((1,), (1,)), ((), ()))    # a @ b.T
+_NN = (((1,), (0,)), ((), ()))    # a @ b
+_TN = (((0,), (0,)), ((), ()))    # a.T @ b
+
+
+def _dot(a, b, dims):
+    return jax.lax.dot_general(a, b, dims,
+                               preferred_element_type=jnp.float32)
 
 
 # ---------------------------------------------------------------------------
 # Forward kernel
 # ---------------------------------------------------------------------------
 
-def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
-                *, causal: bool, sm_scale: float, block_q: int,
-                block_k: int, offset: int):
-    from jax.experimental import pallas as pl
+def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch,
+                causal: bool, sm_scale: float, tile_k: int, offset: int,
+                steps: Tuple[int, int]):
+    """A grid step owns ``block_q`` queries and walks the key tiles of
+    the resident major block. Scores are held keys x queries, [tile_k,
+    block_q]: the running max and sum are rows [1, block_q], reduced
+    along sublanes and broadcast along them, and the output accumulates
+    transposed, [d, block_q], until the block's last tile."""
+    block_q, d = q_ref.shape[2:]
+    per_major = k_ref.shape[2] // tile_k
+    q0 = _grid_step(2, steps[0]) * block_q
+    first = _grid_step(3, steps[1]) * per_major
 
-    qi = pl.program_id(2)
-    ki = pl.program_id(3)
-    nk = pl.num_programs(3)
+    q = q_ref[0, 0]                                       # [bq, d] bf16
+    diagonal = _query_less_key((tile_k, block_q), 1) if causal else None
 
-    @pl.when(ki == 0)
-    def _init():
-        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[...] = jnp.zeros_like(l_scr)
-        acc_scr[...] = jnp.zeros_like(acc_scr)
+    def step(j, carry, masked):
+        m, l, acc = carry                      # [1, bq] x2, [d, bq] f32
+        rows = _tile_rows(j, first, tile_k)
+        s = _dot(k_ref[0, 0, rows, :], q, _NT) * sm_scale  # [tile, bq]
+        if masked:
+            s = _mask_above_diagonal(s, diagonal, q0, j * tile_k, offset)
+        m_new = jnp.maximum(m, jnp.max(s, axis=0, keepdims=True))
+        alpha = jnp.exp(m - m_new)
+        p = jnp.exp(s - m_new)
+        l = alpha * l + jnp.sum(p, axis=0, keepdims=True)
+        v = v_ref[0, 0, rows, :]                           # [tile, d]
+        acc = alpha * acc + _dot(v, p.astype(v.dtype), _TN)
+        return m_new, l, acc
 
-    run = (_causal_live(qi, ki, block_q, block_k, offset) if causal
-           else ki >= 0)
+    def init():
+        return (jnp.full((1, block_q), NEG_INF, jnp.float32),
+                jnp.zeros((1, block_q), jnp.float32),
+                jnp.zeros((d, block_q), jnp.float32))
 
-    @pl.when(run)
-    def _step():
-        q = q_ref[0, 0]                                   # [bq, d] bf16
-        k = k_ref[0, 0]                                   # [bk, d] bf16
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * sm_scale
-        if causal:
-            s = _causal_mask(s, qi, ki, block_q, block_k, offset)
-        m_prev = m_scr[...]                               # [bq, 128]
-        m_cur = jnp.max(s, axis=-1, keepdims=True)        # [bq, 1]
-        m_new = jnp.maximum(m_prev, m_cur)                # broadcast
-        alpha = jnp.exp(m_prev[:, :1] - m_new[:, :1])     # [bq, 1]
-        p = jnp.exp(s - m_new[:, :1])                     # [bq, bk] f32
-        l_new = alpha * l_scr[:, :1] + jnp.sum(p, axis=-1, keepdims=True)
-        acc = acc_scr[...] * alpha
-        acc += jax.lax.dot_general(
-            p.astype(v_ref.dtype), v_ref[0, 0],
-            (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_scr[...] = jnp.broadcast_to(m_new[:, :1], m_scr.shape)
-        l_scr[...] = jnp.broadcast_to(l_new, l_scr.shape)
-        acc_scr[...] = acc
+    def walk(carry):
+        split, stop = _key_tiles(q0, block_q, tile_k, offset, causal,
+                                 first, first + per_major)
+        return _walk(step, carry, (first, split),
+                     (split, stop) if causal else None)
 
-    @pl.when(ki == nk - 1)
-    def _finish():
-        l = l_scr[:, :1]
+    def finish(m, l, acc):
         l = jnp.where(l == 0.0, 1.0, l)  # fully-masked row guard
-        o_ref[0, 0] = (acc_scr[...] / l).astype(o_ref.dtype)
-        lse_ref[0, 0] = m_scr[:, :1] + jnp.log(l)          # [bq, 1]
+        o_ref[0, 0] = (acc / l).T.astype(o_ref.dtype)
+        lse_ref[0, 0] = _as_column(m + jnp.log(l))         # [bq, 1]
+
+    _across_majors(steps[1], scratch, init, walk, finish)
 
 
-def _flash_forward(q, k, v, causal: bool, block_q: int, block_k: int):
+def _last_live_major(causal: bool, block_q: int, tile_k: int, n_tiles: int,
+                     offset: int, per_major: int):
+    """Index map of the walked K and V: a major block past the last one
+    that holds a live tile clamps to that one, so Mosaic's pipeline sees
+    a repeated index and elides the DMA of a dead step."""
+    def index(bi, hi, qi, major):
+        if n_tiles == per_major:
+            return (bi, hi, 0, 0)
+        _, stop = _key_tiles(qi * block_q, block_q, tile_k, offset, causal,
+                             0, n_tiles)
+        return (bi, hi,
+                jnp.minimum(major, jnp.maximum(stop - 1, 0) // per_major), 0)
+    return index
+
+
+def _call_params(n_major: int, scratch_shapes):
+    """What the three calls share: the innermost grid axis is the walk
+    over major blocks, whose carry (a walk of several) lives in the
+    scratch."""
+    from jax.experimental.pallas import tpu as pltpu
+    return dict(
+        scratch_shapes=[pltpu.VMEM(shape, jnp.float32)
+                        for shape in scratch_shapes] if n_major > 1 else [],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel",
+                                 "arbitrary")),
+        interpret=_INTERPRET)
+
+
+def _flash_forward(q, k, v, causal: bool, plan: _Plan):
     """q,k,v: [B, H, S, D] -> (o [B, H, Sq, D], lse [B, H, Sq, 1] f32)."""
     from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
 
     b, h, sq, d = q.shape
     sk = k.shape[2]
     offset = sk - sq
-    nq, nk = sq // block_q, sk // block_k
-    grid = (b, h, nq, nk)
+    block_q, tile_k, major_k = plan.block_q, plan.tile_k, plan.major_k
+    steps = (sq // block_q, sk // major_k)
 
-    kv_index = _clamped_kv_index(causal, block_q, block_k, offset, nk)
-
-    kernel = functools.partial(
-        _fwd_kernel, causal=causal, sm_scale=d ** -0.5,
-        block_q=block_q, block_k=block_k, offset=offset)
+    q_index = lambda bi, hi, qi, major: (bi, hi, qi, 0)  # noqa: E731
+    kv_index = _last_live_major(causal, block_q, tile_k, sk // tile_k,
+                                offset, major_k // tile_k)
     out, lse = pl.pallas_call(
-        kernel,
-        grid=grid,
+        functools.partial(_fwd_kernel, causal=causal, sm_scale=d ** -0.5,
+                          tile_k=tile_k, offset=offset, steps=steps),
+        grid=(b, h, *steps),
         in_specs=[
-            pl.BlockSpec((1, 1, block_q, d),
-                         lambda bi, hi, qi, ki: (bi, hi, qi, 0)),
-            pl.BlockSpec((1, 1, block_k, d), kv_index),
-            pl.BlockSpec((1, 1, block_k, d), kv_index),
+            pl.BlockSpec((1, 1, block_q, d), q_index),
+            pl.BlockSpec((1, 1, major_k, d), kv_index),
+            pl.BlockSpec((1, 1, major_k, d), kv_index),
         ],
         out_specs=[
-            pl.BlockSpec((1, 1, block_q, d),
-                         lambda bi, hi, qi, ki: (bi, hi, qi, 0)),
+            pl.BlockSpec((1, 1, block_q, d), q_index),
             # trailing dim of 1 satisfies the (8, 128) tile rule via
-            # the block-equals-array-dim escape hatch, without the 128x
-            # lane padding the official kernel pays for its lse output
-            pl.BlockSpec((1, 1, block_q, 1),
-                         lambda bi, hi, qi, ki: (bi, hi, qi, 0)),
+            # the block-equals-array-dim escape hatch
+            pl.BlockSpec((1, 1, block_q, 1), q_index),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((b, h, sq, d), q.dtype),
             jax.ShapeDtypeStruct((b, h, sq, 1), jnp.float32),
         ],
-        scratch_shapes=[
-            pltpu.VMEM((block_q, 128), jnp.float32),   # running max
-            pltpu.VMEM((block_q, 128), jnp.float32),   # running sum
-            pltpu.VMEM((block_q, d), jnp.float32),     # output accum
-        ],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel",
-                                 "arbitrary")),
-        interpret=_INTERPRET,
         name="flash_fwd",
+        # running max, running sum, output accumulator
+        **_call_params(steps[1], [(1, block_q), (1, block_q), (d, block_q)]),
     )(q, k, v)
     return out, lse
 
@@ -232,170 +423,169 @@ def _flash_forward(q, k, v, causal: bool, block_q: int, block_k: int):
 # ---------------------------------------------------------------------------
 
 def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
-               dq_scr, *, causal: bool, sm_scale: float, block_q: int,
-               block_k: int, offset: int):
-    from jax.experimental import pallas as pl
+               *scratch, causal: bool, sm_scale: float, tile_k: int,
+               offset: int, steps: Tuple[int, int]):
+    """The forward's walk, scores held queries x keys, [block_q,
+    tile_k]: lse and delta arrive as rows [1, block_q] and become
+    columns once a grid step."""
+    block_q, d = q_ref.shape[2:]
+    per_major = k_ref.shape[2] // tile_k
+    q0 = _grid_step(2, steps[0]) * block_q
+    first = _grid_step(3, steps[1]) * per_major
 
-    qi = pl.program_id(2)
-    ki = pl.program_id(3)
-    nk = pl.num_programs(3)
+    q, do = q_ref[0, 0], do_ref[0, 0]                      # [bq, d] bf16
+    lse, delta = _as_column(lse_ref[0, 0]), _as_column(delta_ref[0, 0])
+    diagonal = _query_less_key((block_q, tile_k), 0) if causal else None
 
-    @pl.when(ki == 0)
-    def _init():
-        dq_scr[...] = jnp.zeros_like(dq_scr)
+    def step(j, carry, masked):
+        rows = _tile_rows(j, first, tile_k)
+        k = k_ref[0, 0, rows, :]
+        s = _dot(q, k, _NT) * sm_scale                     # [bq, tile]
+        if masked:
+            s = _mask_above_diagonal(s, diagonal, q0, j * tile_k, offset)
+        p = jnp.exp(s - lse)
+        ds = p * (_dot(do, v_ref[0, 0, rows, :], _NT) - delta)
+        return (carry[0] + _dot(ds.astype(k.dtype), k, _NN),)
 
-    run = (_causal_live(qi, ki, block_q, block_k, offset) if causal
-           else ki >= 0)
+    def walk(carry):
+        split, stop = _key_tiles(q0, block_q, tile_k, offset, causal,
+                                 first, first + per_major)
+        return _walk(step, carry, (first, split),
+                     (split, stop) if causal else None)
 
-    @pl.when(run)
-    def _step():
-        q = q_ref[0, 0]
-        k = k_ref[0, 0]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * sm_scale
-        if causal:
-            s = _causal_mask(s, qi, ki, block_q, block_k, offset)
-        p = jnp.exp(s - lse_ref[0, 0])                     # [bq, bk]
-        do = do_ref[0, 0]
-        dp = jax.lax.dot_general(
-            do, v_ref[0, 0], (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        ds = p * (dp - delta_ref[0, 0]) * sm_scale
-        dq_scr[...] += jax.lax.dot_general(
-            ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+    def finish(dq):
+        # ds's scale, once a block on the float32 sum
+        dq_ref[0, 0] = (dq * sm_scale).astype(dq_ref.dtype)
 
-    @pl.when(ki == nk - 1)
-    def _finish():
-        dq_ref[0, 0] = dq_scr[...].astype(dq_ref.dtype)
+    _across_majors(steps[1], scratch,
+                   lambda: (jnp.zeros((block_q, d), jnp.float32),),
+                   walk, finish)
 
 
 def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                dk_ref, dv_ref, dk_scr, dv_scr, *, causal: bool,
-                sm_scale: float, block_q: int, block_k: int, offset: int):
+                dk_ref, dv_ref, *scratch, causal: bool, sm_scale: float,
+                tile_q: int, offset: int, steps: Tuple[int, int]):
+    """A grid step owns ``block_k`` keys and walks the query tiles of
+    the resident major block, scores held keys x queries, [block_k,
+    tile_q]: lse and delta are rows [1, tile_q] broadcast along
+    sublanes, and neither product takes a transposed operand."""
+    block_k, d = k_ref.shape[2:]
+    per_major = q_ref.shape[2] // tile_q
+    k0 = _grid_step(2, steps[0]) * block_k
+    first = _grid_step(3, steps[1]) * per_major
+
+    k, v = k_ref[0, 0], v_ref[0, 0]                        # [bk, d] bf16
+    diagonal = _query_less_key((block_k, tile_q), 1) if causal else None
+
+    def step(j, carry, masked):
+        dk, dv = carry                                     # [bk, d] f32
+        rows = _tile_rows(j, first, tile_q)
+        q, do = q_ref[0, 0, rows, :], do_ref[0, 0, rows, :]
+        s = _dot(k, q, _NT) * sm_scale                     # [bk, tile]
+        if masked:
+            s = _mask_above_diagonal(s, diagonal, j * tile_q, k0, offset)
+        p = jnp.exp(s - lse_ref[0, 0, :, rows])
+        dv = dv + _dot(p.astype(do.dtype), do, _NN)
+        ds = p * (_dot(v, do, _NT) - delta_ref[0, 0, :, rows])
+        dk = dk + _dot(ds.astype(q.dtype), q, _NN)
+        return dk, dv
+
+    def walk(carry):
+        end = first + per_major
+        start, split = _query_tiles(k0, block_k, tile_q, offset, causal,
+                                    first, end)
+        return _walk(step, carry, (split, end),
+                     (start, split) if causal else None)
+
+    def finish(dk, dv):
+        dk_ref[0, 0] = (dk * sm_scale).astype(dk_ref.dtype)
+        dv_ref[0, 0] = dv.astype(dv_ref.dtype)
+
+    _across_majors(steps[1], scratch,
+                   lambda: (jnp.zeros((block_k, d), jnp.float32),) * 2,
+                   walk, finish)
+
+
+def _flash_backward(q, k, v, o, lse, do, causal: bool, plan: _Plan):
+    """All tensors [B, H, S, D] (lse [B, H, S, 1]); returns dq/dk/dv."""
     from jax.experimental import pallas as pl
-
-    ki = pl.program_id(2)
-    qi = pl.program_id(3)
-    nq = pl.num_programs(3)
-
-    @pl.when(qi == 0)
-    def _init():
-        dk_scr[...] = jnp.zeros_like(dk_scr)
-        dv_scr[...] = jnp.zeros_like(dv_scr)
-
-    run = (_causal_live(qi, ki, block_q, block_k, offset) if causal
-           else qi >= 0)
-
-    @pl.when(run)
-    def _step():
-        q = q_ref[0, 0]                                    # [bq, d]
-        k = k_ref[0, 0]                                    # [bk, d]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * sm_scale
-        if causal:
-            s = _causal_mask(s, qi, ki, block_q, block_k, offset)
-        p = jnp.exp(s - lse_ref[0, 0])                     # [bq, bk]
-        do = do_ref[0, 0]                                  # [bq, d]
-        # dv += p^T @ do
-        dv_scr[...] += jax.lax.dot_general(
-            p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        dp = jax.lax.dot_general(
-            do, v_ref[0, 0], (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        ds = p * (dp - delta_ref[0, 0]) * sm_scale
-        # dk += ds^T @ q
-        dk_scr[...] += jax.lax.dot_general(
-            ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-
-    @pl.when(qi == nq - 1)
-    def _finish():
-        dk_ref[0, 0] = dk_scr[...].astype(dk_ref.dtype)
-        dv_ref[0, 0] = dv_scr[...].astype(dv_ref.dtype)
-
-
-def _flash_backward(q, k, v, o, lse, do, causal: bool, block_q: int,
-                    block_k: int):
-    """All tensors [B, H, S, D] (lse/delta [B, H, S]); returns dq/dk/dv."""
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
 
     b, h, sq, d = q.shape
     sk = k.shape[2]
     offset = sk - sq
-    nq, nk = sq // block_q, sk // block_k
     sm_scale = d ** -0.5
+    # both kernels take the rows' statistics along the lanes, [B, H, 1,
+    # Sq]: a [.., Sq, 1] operand is laid out 128 x padded in HBM
+    lse = lse.reshape(b, h, 1, sq)
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
-                    axis=-1, keepdims=True)                   # [B,H,Sq,1]
+                    axis=-1)[:, :, None, :]
 
-    q_idx = lambda bi, hi, qi, ki: (bi, hi, qi, 0)
-
-    kv_idx = _clamped_kv_index(causal, block_q, block_k, offset, nk)
-
+    block_q, tile_k, major_k = plan.block_q, plan.tile_k, plan.major_k
+    steps = (sq // block_q, sk // major_k)
+    q_index = lambda bi, hi, qi, major: (bi, hi, qi, 0)  # noqa: E731
+    q_row = lambda bi, hi, qi, major: (bi, hi, 0, qi)  # noqa: E731
+    kv_index = _last_live_major(causal, block_q, tile_k, sk // tile_k,
+                                offset, major_k // tile_k)
     dq = pl.pallas_call(
         functools.partial(_dq_kernel, causal=causal, sm_scale=sm_scale,
-                          block_q=block_q, block_k=block_k, offset=offset),
-        grid=(b, h, nq, nk),
+                          tile_k=tile_k, offset=offset, steps=steps),
+        grid=(b, h, *steps),
         in_specs=[
-            pl.BlockSpec((1, 1, block_q, d), q_idx),
-            pl.BlockSpec((1, 1, block_k, d), kv_idx),
-            pl.BlockSpec((1, 1, block_k, d), kv_idx),
-            pl.BlockSpec((1, 1, block_q, d), q_idx),
-            pl.BlockSpec((1, 1, block_q, 1), q_idx),
-            pl.BlockSpec((1, 1, block_q, 1), q_idx),
+            pl.BlockSpec((1, 1, block_q, d), q_index),
+            pl.BlockSpec((1, 1, major_k, d), kv_index),
+            pl.BlockSpec((1, 1, major_k, d), kv_index),
+            pl.BlockSpec((1, 1, block_q, d), q_index),
+            pl.BlockSpec((1, 1, 1, block_q), q_row),
+            pl.BlockSpec((1, 1, 1, block_q), q_row),
         ],
-        out_specs=pl.BlockSpec((1, 1, block_q, d), q_idx),
+        out_specs=pl.BlockSpec((1, 1, block_q, d), q_index),
         out_shape=jax.ShapeDtypeStruct((b, h, sq, d), q.dtype),
-        scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel",
-                                 "arbitrary")),
-        interpret=_INTERPRET,
         name="flash_dq",
+        **_call_params(steps[1], [(block_q, d)]),
     )(q, k, v, do, lse, delta)
 
-    # dk/dv: iterate q blocks innermost for each k block. For causal,
-    # early (fully-masked) q blocks clamp forward to the first live one.
-    def q_idx_b(bi, hi, ki, qi):
-        if causal:
-            first = jnp.maximum((ki * block_k - offset) // block_q, 0)
-            qi = jnp.maximum(qi, first)
-        return (bi, hi, qi, 0)
+    # dk/dv: a grid step holds a block of keys and walks the queries;
+    # the major blocks before the first live one clamp forward to it.
+    block_k, tile_q, major_q = plan.block_k, plan.tile_q, plan.major_q
+    steps = (sk // block_k, sq // major_q)
+    per_major = major_q // tile_q
 
-    kv_idx_b = lambda bi, hi, ki, qi: (bi, hi, ki, 0)
+    def first_live_major(ki, major):
+        if steps[1] == 1:
+            return 0
+        start, _ = _query_tiles(ki * block_k, block_k, tile_q, offset,
+                                causal, 0, sq // tile_q)
+        return jnp.maximum(major, start // per_major)
+
+    def walked(bi, hi, ki, major):
+        return (bi, hi, first_live_major(ki, major), 0)
+
+    def walked_row(bi, hi, ki, major):
+        return (bi, hi, 0, first_live_major(ki, major))
+
+    k_index = lambda bi, hi, ki, major: (bi, hi, ki, 0)  # noqa: E731
     dk, dv = pl.pallas_call(
         functools.partial(_dkv_kernel, causal=causal, sm_scale=sm_scale,
-                          block_q=block_q, block_k=block_k, offset=offset),
-        grid=(b, h, nk, nq),
+                          tile_q=tile_q, offset=offset, steps=steps),
+        grid=(b, h, *steps),
         in_specs=[
-            pl.BlockSpec((1, 1, block_q, d), q_idx_b),
-            pl.BlockSpec((1, 1, block_k, d), kv_idx_b),
-            pl.BlockSpec((1, 1, block_k, d), kv_idx_b),
-            pl.BlockSpec((1, 1, block_q, d), q_idx_b),
-            pl.BlockSpec((1, 1, block_q, 1), q_idx_b),
-            pl.BlockSpec((1, 1, block_q, 1), q_idx_b),
+            pl.BlockSpec((1, 1, major_q, d), walked),
+            pl.BlockSpec((1, 1, block_k, d), k_index),
+            pl.BlockSpec((1, 1, block_k, d), k_index),
+            pl.BlockSpec((1, 1, major_q, d), walked),
+            pl.BlockSpec((1, 1, 1, major_q), walked_row),
+            pl.BlockSpec((1, 1, 1, major_q), walked_row),
         ],
         out_specs=[
-            pl.BlockSpec((1, 1, block_k, d), kv_idx_b),
-            pl.BlockSpec((1, 1, block_k, d), kv_idx_b),
+            pl.BlockSpec((1, 1, block_k, d), k_index),
+            pl.BlockSpec((1, 1, block_k, d), k_index),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((b, h, sk, d), k.dtype),
             jax.ShapeDtypeStruct((b, h, sk, d), v.dtype),
         ],
-        scratch_shapes=[
-            pltpu.VMEM((block_k, d), jnp.float32),
-            pltpu.VMEM((block_k, d), jnp.float32),
-        ],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel",
-                                 "arbitrary")),
-        interpret=_INTERPRET,
         name="flash_dkv",
+        **_call_params(steps[1], [(block_k, d), (block_k, d)]),
     )(q, k, v, do, lse, delta)
     return dq, dk, dv
 
@@ -404,17 +594,26 @@ def _flash_backward(q, k, v, o, lse, do, causal: bool, block_q: int,
 # Public op with custom VJP
 # ---------------------------------------------------------------------------
 
-def _kernel_plan(q, k):
-    """(block_q, block_k) if the kernels cover these shapes, else None."""
+def _major_size(dim: int, tile: int) -> int:
+    """The most rows <= _MAJOR, in whole tiles, that divide ``dim``."""
+    return max(m for m in range(tile, min(dim, _MAJOR) + 1, tile)
+               if dim % m == 0)
+
+
+def _kernel_plan(q, k) -> Optional[_Plan]:
+    """The kernels' sizes if they cover these shapes, else None."""
     b, sq, h, d = q.shape
     sk = k.shape[1]
     if not (_INTERPRET or jax_backend.on_tpu()):
         return None
-    bq = _block_size(_BLOCK_Q, sq)
-    bk = _block_size(_BLOCK_K, sk)
-    if d % 128 or bq is None or bk is None:
+    if d % 128 or sq % 128 or sk % 128:
         return None
-    return bq, bk
+    tile_q, tile_k = _block_size(_TILE, sq), _block_size(_TILE, sk)
+    return _Plan(block_q=_block_size(_BLOCK, sq),
+                 block_k=_block_size(_BLOCK, sk),
+                 tile_q=tile_q, tile_k=tile_k,
+                 major_q=_major_size(sq, tile_q),
+                 major_k=_major_size(sk, tile_k))
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
@@ -430,7 +629,7 @@ def _flash(q, k, v, causal: bool):
     # the transposes into the surrounding projections.
     out, _ = _flash_forward(
         q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
-        v.transpose(0, 2, 1, 3), causal, *plan)
+        v.transpose(0, 2, 1, 3), causal, plan)
     return out.transpose(0, 2, 1, 3)
 
 
@@ -442,7 +641,7 @@ def _fwd(q, k, v, causal):
     qt = q.transpose(0, 2, 1, 3)
     kt = k.transpose(0, 2, 1, 3)
     vt = v.transpose(0, 2, 1, 3)
-    out, lse = _flash_forward(qt, kt, vt, causal, *plan)
+    out, lse = _flash_forward(qt, kt, vt, causal, plan)
     out = checkpoint_name(out, SAVED_OUT)
     lse = checkpoint_name(lse, SAVED_LSE)
     return out.transpose(0, 2, 1, 3), (q, k, v, out, lse)
@@ -460,7 +659,7 @@ def _bwd(causal, res, g):
     dq, dk, dv = _flash_backward(
         q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
         v.transpose(0, 2, 1, 3), out, lse,
-        g.transpose(0, 2, 1, 3), causal, *plan)
+        g.transpose(0, 2, 1, 3), causal, plan)
     return (dq.transpose(0, 2, 1, 3), dk.transpose(0, 2, 1, 3),
             dv.transpose(0, 2, 1, 3))
 

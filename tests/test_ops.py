@@ -68,38 +68,130 @@ def test_rope_with_positions():
                                atol=1e-6)
 
 
-def test_flash_kernels_interpret_vs_reference():
-    # Run the actual Pallas kernels (forward + fused backward) in
-    # interpreter mode on CPU and compare against the jnp reference.
+@pytest.fixture
+def flash_interpreted(monkeypatch):
     from ray_tpu.ops import attention as att
+    monkeypatch.setattr(att, "_INTERPRET", True)
+    return att
 
-    prev = att._INTERPRET
-    att._INTERPRET = True
-    try:
-        for sq, sk in ((256, 256), (256, 512)):
-            ks = jax.random.split(jax.random.PRNGKey(0), 3)
-            q = jax.random.normal(ks[0], (1, sq, 2, 128), jnp.float32)
-            k = jax.random.normal(ks[1], (1, sk, 2, 128), jnp.float32)
-            v = jax.random.normal(ks[2], (1, sk, 2, 128), jnp.float32)
-            assert att._kernel_plan(q, k) is not None
-            out = att.flash_attention(q, k, v, True)
-            ref = att._attention_reference(q, k, v, True)
-            np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                                       atol=1e-2)
 
-            def loss_k(q, k, v):
-                return jnp.sum(att.flash_attention(q, k, v, True) * 0.1)
+def _assert_flash_matches_reference(att, sq, sk, heads, causal):
+    """The Pallas kernels (forward + both backward) in interpreter mode
+    against the jnp reference: outputs and all three gradients."""
+    ks = jax.random.split(jax.random.PRNGKey(0), 3)
+    q = jax.random.normal(ks[0], (1, sq, heads, 128), jnp.float32)
+    k = jax.random.normal(ks[1], (1, sk, heads, 128), jnp.float32)
+    v = jax.random.normal(ks[2], (1, sk, heads, 128), jnp.float32)
+    assert att._kernel_plan(q, k) is not None
+    out = att.flash_attention(q, k, v, causal)
+    ref = att._attention_reference(q, k, v, causal)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=1e-2)
 
-            def loss_r(q, k, v):
-                return jnp.sum(att._attention_reference(q, k, v, True) * 0.1)
+    def loss_k(q, k, v):
+        return jnp.sum(att.flash_attention(q, k, v, causal) * 0.1)
 
-            gk = jax.grad(loss_k, argnums=(0, 1, 2))(q, k, v)
-            gr = jax.grad(loss_r, argnums=(0, 1, 2))(q, k, v)
-            for a, b in zip(gk, gr):
-                np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                           atol=5e-3)
-    finally:
-        att._INTERPRET = prev
+    def loss_r(q, k, v):
+        return jnp.sum(att._attention_reference(q, k, v, causal) * 0.1)
+
+    gk = jax.grad(loss_k, argnums=(0, 1, 2))(q, k, v)
+    gr = jax.grad(loss_r, argnums=(0, 1, 2))(q, k, v)
+    for a, b in zip(gk, gr):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=5e-3)
+
+
+# What the cells and the other callers run, one case each. The train
+# cells' 2048 and the prefill buckets 128-1024 at the Mistral and Granite
+# cells' 32 heads, Jamba's 20; keys past the queries with an offset of
+# one and a half key tiles (256 -> 1024) and with tiles of 128 (a
+# sequence no 256 divides); no mask (dit, vit); and, with the three sizes
+# shrunk, a sequence of several major blocks: the statistics cross grid
+# steps through the scratch, and the steps wholly above the diagonal
+# clamp their block index and visit nothing.
+_SEVERAL_MAJORS = {"_BLOCK": 128, "_TILE": 128, "_MAJOR": 256}
+
+
+@pytest.mark.parametrize("sq, sk, heads, causal, sizes", [
+    (256, 256, 2, True, None), (256, 512, 2, True, None),
+    (128, 128, 32, True, None), (256, 256, 32, True, None),
+    (512, 512, 32, True, None), (1024, 1024, 32, True, None),
+    (2048, 2048, 32, True, None), (128, 128, 20, True, None),
+    (512, 512, 20, True, None), (256, 1024, 1, True, None),
+    (384, 640, 1, True, None), (384, 384, 1, True, None),
+    (512, 512, 1, False, None), (256, 768, 2, False, None),
+    (512, 768, 1, True, _SEVERAL_MAJORS),
+    (512, 512, 1, False, _SEVERAL_MAJORS)])
+def test_flash_kernels_interpret_vs_reference(flash_interpreted, monkeypatch,
+                                              sq, sk, heads, causal, sizes):
+    att = flash_interpreted
+    for name, rows in (sizes or {}).items():
+        monkeypatch.setattr(att, name, rows)
+    plan = att._kernel_plan(jnp.zeros((1, sq, heads, 128)),
+                            jnp.zeros((1, sk, heads, 128)))
+    if sizes:
+        assert sq // plan.major_q > 1 and sk // plan.major_k > 1
+    if (sq, sk) == (256, 1024):
+        assert (sk - sq) % plan.tile_k
+    _assert_flash_matches_reference(att, sq, sk, heads, causal)
+
+
+def _schedule(att, sq, sk, causal):
+    plan = att._kernel_plan(jnp.zeros((1, sq, 1, 128)),
+                            jnp.zeros((1, sk, 1, 128)))
+    return plan, att.tile_schedule(sq, sk, causal, plan)
+
+
+@pytest.mark.parametrize("sq, sk, causal, sizes, dead_steps", [
+    (128, 128, True, None, False), (512, 512, True, None, False),
+    (1024, 1024, True, None, False), (2048, 2048, True, None, False),
+    (256, 1024, True, None, False), (384, 640, True, None, False),
+    (4096, 4096, True, None, True), (512, 512, False, None, False),
+    (256, 768, False, None, False),
+    (512, 768, True, _SEVERAL_MAJORS, True),
+    (512, 512, False, _SEVERAL_MAJORS, False)])
+def test_flash_schedule_covers_the_triangle_once(flash_interpreted,
+                                                 monkeypatch, sq, sk, causal,
+                                                 sizes, dead_steps):
+    """Each walk visits every visible (query, key) pair in exactly one
+    tile, no tile that is wholly masked, and masks exactly the tiles
+    the diagonal crosses; a grid step that visits nothing exists only
+    under a mask once the walked operand takes several major blocks."""
+    for name, rows in (sizes or {}).items():
+        monkeypatch.setattr(flash_interpreted, name, rows)
+    _, schedule = _schedule(flash_interpreted, sq, sk, causal)
+    assert (schedule["dead_steps"] > 0) == dead_steps
+    rows = np.arange(sq)[:, None]
+    cols = np.arange(sk)[None, :]
+    visible = (cols <= rows + (sk - sq)) if causal else np.ones(
+        (sq, sk), bool)
+    for walk in ("by_query", "by_key"):
+        seen = np.zeros((sq, sk), int)
+        for q0, k0, n_q, n_k, masked in schedule[walk]:
+            tile = visible[q0:q0 + n_q, k0:k0 + n_k]
+            assert tile.any(), (walk, q0, k0)
+            assert masked == (not tile.all()), (walk, q0, k0)
+            seen[q0:q0 + n_q, k0:k0 + n_k] += 1
+        assert seen.max() == 1
+        assert (seen[visible] == 1).all()
+
+
+def test_flash_schedule_at_the_train_cells_shape(flash_interpreted):
+    """At 2048 x 2048: no grid step above the diagonal (the grid it
+    replaced predicated off 12 of a head's 32), a mask on the four
+    tiles the diagonal crosses and on no other (it masked all 20 live
+    ones), and the scores computed above the diagonal no more than the
+    block's share of the sequence."""
+    plan, schedule = _schedule(flash_interpreted, 2048, 2048, True)
+    assert (plan.major_q, plan.major_k) == (2048, 2048)
+    assert schedule["dead_steps"] == 0
+    for walk in ("by_query", "by_key"):
+        tiles = schedule[walk]
+        assert sum(masked for *_, masked in tiles) == 2048 // plan.block_q
+        computed = sum(n_q * n_k for _, _, n_q, n_k, _ in tiles)
+        owed = 2048 * 2049 // 2
+        assert computed <= owed * (1 + plan.block_q / 2048)
+    # ten tiles of 512 x 512 where the grid it replaced ran twenty of
+    # 256 x 512 for the same triangle
+    assert len(schedule["by_query"]) == len(schedule["by_key"]) == 10
 
 
 def test_int8_matmul_kernel_interpret_vs_reference():

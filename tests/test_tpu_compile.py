@@ -124,6 +124,29 @@ def test_flash_and_int8_kernels_compile(v5e):
     assert attention.kernel_fallbacks == []
 
 
+# The train cells' two shapes and one long sequence with gradients (all
+# three kernels), the serving cells' prefill buckets forward only (32
+# heads: Mistral, Granite; 20: Jamba). The long one holds the module's
+# promise that VMEM stays bounded at any sequence length: the walked
+# operand is resident by major blocks, inside the default scoped limit.
+@pytest.mark.parametrize("shape, with_grads", [
+    ((4, 2048, 32, 128), True), ((2, 2048, 32, 128), True),
+    ((1, 32768, 8, 128), True)] + [
+    ((1, s, h, 128), False) for h in (32, 20) for s in (128, 256, 512, 1024)])
+def test_flash_kernels_compile_at_the_callers_shapes(v5e, shape, with_grads):
+    qkv = _on(_mesh(v5e, 1), P(), shape, jnp.bfloat16)
+    fn = lambda q, k, v: attention.flash_attention(q, k, v, True)  # noqa: E731
+    if with_grads:
+        fn = jax.grad(lambda q, k, v: attention.flash_attention(
+            q, k, v, True).astype(jnp.float32).sum(), argnums=(0, 1, 2))
+    lowered = jax.jit(fn).lower(qkv, qkv, qkv)
+    assert [k.split("(")[0] for k in _kernels(lowered)] == (
+        ["flash_dkv", "flash_dq", "flash_fwd"] if with_grads
+        else ["flash_fwd"])
+    lowered.compile()
+    assert attention.kernel_fallbacks == []
+
+
 def _compile_train_step(devices, *, chips, n_layers, batch, seq=2048):
     """chip_smoke's trainer step, from abstract state sharded as its
     loop shards it. Returns (kernels, bytes per chip), having checked
