@@ -128,6 +128,13 @@ ENGINE_DECODE_KV_ROWS = _metrics.Counter(
     "parked slot's park row) and the rest of its slots x max_seq rows "
     "(skipped); all read where the decode kernel does not engage",
     tag_keys=("kind",))
+ENGINE_STATE_SLOTS = _metrics.Counter(
+    "ray_tpu_engine_state_slots_total",
+    "Slots x recurrent layers of the dense decode steps, by what the "
+    "step did with the slot's recurrent state: moved (a live slot's, "
+    "one step on) or parked (an empty slot's, neither read nor "
+    "written); only from a family whose decode step skips parked state",
+    tag_keys=("kind",))
 ENGINE_EXPERT_PICKS = _metrics.Counter(
     "ray_tpu_engine_expert_picks_total",
     "Experts picked by the router for live rows (a prompt's own "
@@ -848,6 +855,11 @@ class ContinuousBatchingEngine:
         self._kv_block = _attention_op.decode_block_rows(
             config.max_seq, c.n_kv_heads, c.head_dim) or config.max_seq
         self.decode_kv_rows = {"read": 0, "skipped": 0}
+        # slots x recurrent layers of those programs, by what became of
+        # the slot's state; a family that moves every slot's counts none
+        self._state_layers = (c.n_mamba_layers
+                              if self._family.skips_parked_state else 0)
+        self.state_slots = {"moved": 0, "parked": 0}
         # decode programs launched, by the sampler's branches their live
         # slots engaged, and the branches of the slots last gathered
         self.sampler_steps = {"greedy": 0, "topk": 0, "full": 0}
@@ -1424,7 +1436,8 @@ class ContinuousBatchingEngine:
 
     def _note_kv_rows(self, active) -> None:
         """The cache rows the dense step just launched covers, from the
-        positions it was given: counted while the device runs it."""
+        positions it was given, and whose recurrent state it moved
+        (``_state_layers``): counted while the device runs it."""
         block = self._kv_block
         parked = self.config.max_batch - len(active)
         read = block * (sum(s.pos // block for s in active) + len(active)
@@ -1436,6 +1449,11 @@ class ContinuousBatchingEngine:
         self._mbuf.inc(ENGINE_DECODE_KV_ROWS, float(read), {"kind": "read"})
         self._mbuf.inc(ENGINE_DECODE_KV_ROWS, float(skipped),
                        {"kind": "skipped"})
+        if self._state_layers:
+            for kind, slots in (("moved", len(active)), ("parked", parked)):
+                n = slots * self._state_layers
+                self.state_slots[kind] += n  # graftlint: disable=GL001
+                self._mbuf.inc(ENGINE_STATE_SLOTS, float(n), {"kind": kind})
 
     def _note_prefill_tokens(self, real: int, pad: int) -> None:
         """What one prefill program computed: the prompt's own
@@ -2406,6 +2424,10 @@ class ContinuousBatchingEngine:
                         _STEPPER_FAMILIES, self._mbuf.stepper_seconds)},
                 "stepper_read_at": self._mbuf._last_flush,
             }
+            if self._state_layers:
+                # slots x recurrent layers of the dense decode steps
+                # whose state moved and stayed parked
+                out["state_slots"] = dict(self.state_slots)
             if self._family.expert_counts:
                 # what the expert layers counted on the device, as the
                 # flush above read it: the router's picks for live rows

@@ -51,6 +51,10 @@ class ModelFamily:
     # an array its programs hand on and reads it where metrics flush.
     # (): the family has no routed experts, ``counts`` is None.
     expert_counts: tuple = ()
+    # whether decode_step leaves a parked slot's recurrent state where
+    # it lies, neither read nor written (the engine then counts, a
+    # dense step, the slots x ``config.n_mamba_layers`` moved and parked)
+    skips_parked_state: bool = False
 
 
 def insert_slot(cache, entry, slot):
@@ -139,7 +143,8 @@ def _granite() -> ModelFamily:
         cache_bytes=lambda cache: {
             "kv": _nbytes([cache["k"], cache["v"]]),
             "recurrent": _nbytes([cache["ssm"], cache["conv"]])},
-        recurrent=True, expert_counts=granite.EXPERT_COUNTS)
+        recurrent=True, expert_counts=granite.EXPERT_COUNTS,
+        skips_parked_state=True)
 
 
 _FAMILIES: Dict[str, Callable[[], ModelFamily]] = {

@@ -66,6 +66,7 @@ from ray_tpu.models.hybrid import (attn_decode, attn_sequence,
                                    layer as _layer, runs)
 from ray_tpu.ops.matmul import mm as _mm
 from ray_tpu.ops.rmsnorm import rms_norm
+from ray_tpu.ops.ssd_update import ssd_update
 from ray_tpu.parallel.moe import (EXPERT_COUNTS, gated_ffn,
                                   held_experts_ffn)
 
@@ -462,13 +463,14 @@ def granite_decode_step(params, token, cache, pos, live,
     position ``pos``); ``live`` [B]: which slots hold a request (the
     others are parked: computed, not counted); ``cache`` as
     granite_init_cache gives it. -> (logits [B, vocab] float32, the
-    cache with every slot's state moved one step and its K/V row
-    written at ``pos``, EXPERT_COUNTS uint32 of this step).
+    cache with every live slot's state moved one step and every slot's
+    K/V row written at ``pos``, EXPERT_COUNTS uint32 of this step).
 
-    Every slot's recurrent state is updated, a parked slot's too: what
-    it holds then is junk that the next admission replaces whole. The
-    caller's program must donate the cache and run on one device, and
-    every ``pos`` must lie in ``[0, S-1]``."""
+    Only a live slot's recurrent state moves (``ops.ssd_update``: one
+    pass over it, in place in the stack): a parked slot's keeps the
+    bytes it had, which the next admission replaces whole, and its
+    mixer output is zero. The caller's program must donate the cache
+    and run on one device, and every ``pos`` must lie in ``[0, S-1]``."""
     c = config
     b = token.shape[0]
     di, n = c.d_inner, c.mamba_d_state
@@ -499,12 +501,10 @@ def granite_decode_step(params, token, cache, pos, live,
             xs = xc[:, :di].reshape(b, c.mamba_n_heads, c.mamba_d_head)
             bb, cc = xc[:, di:di + n], xc[:, di + n:]
             dt = jax.nn.softplus(dt + p["dt_bias"])              # [B, H]
-            h = jax.lax.dynamic_index_in_dim(ssm, m, keepdims=False)
-            h = (jnp.exp(dt * -jnp.exp(p["A_log"]))[:, :, None, None] * h
-                 + (dt[:, :, None] * xs)[..., None] * bb[:, None, None, :])
-            y = (jnp.sum(h * cc[:, None, None, :], axis=-1)
-                 + p["D"][None, :, None] * xs).reshape(b, di)
-            ssm = jax.lax.dynamic_update_index_in_dim(ssm, h, m, 0)
+            ssm, y = ssd_update(
+                ssm, m, live, jnp.exp(dt * -jnp.exp(p["A_log"])),
+                dt[:, :, None] * xs, bb, cc, p["D"][None, :, None] * xs)
+            y = y.reshape(b, di)
         x = _gated_norm_out(p, x, y, z, c)
         x, n_layer = _ffn(p, params["mamba"], m, x, live, c)
         return (x, ssm, conv, counts + n_layer), None

@@ -22,8 +22,14 @@ from ray_tpu.models.granite import (GraniteConfig, granite_forward,
                                     granite_init, granite_init_cache,
                                     granite_prefill, ssd_chunked)
 from ray_tpu.models.llama import LlamaConfig
+from ray_tpu.ops import ssd_update as ssd_update_op
 
 CFG = GraniteConfig.tiny(dtype=jnp.float32)
+# the narrowest state ``ops.ssd_update``'s kernel covers (128 heads of
+# 8 x 128 states): hidden 512, the rest as above
+KERNEL_CFG = GraniteConfig.tiny(dtype=jnp.float32, dim=512,
+                                mamba_n_heads=128, mamba_d_head=8,
+                                mamba_d_state=128)
 TOL = 1e-4
 
 
@@ -33,9 +39,26 @@ def params():
                                                    CFG)
 
 
-def _engine(params, **kw):
+@pytest.fixture(scope="module")
+def kernel_params():
+    return jax.jit(granite_init, static_argnums=1)(jax.random.PRNGKey(0),
+                                                   KERNEL_CFG)
+
+
+@pytest.fixture
+def model(request, params, monkeypatch):
+    """(configuration, weights) by the test's ``kernel`` parameter:
+    True is the decode step through the ``ssd_update`` kernel (interpret
+    mode) at KERNEL_CFG, False its ``jax.numpy`` form at CFG."""
+    if not request.getfixturevalue("kernel"):
+        return CFG, params
+    monkeypatch.setattr(ssd_update_op, "_INTERPRET", True)
+    return KERNEL_CFG, request.getfixturevalue("kernel_params")
+
+
+def _engine(params, cfg=CFG, **kw):
     return ContinuousBatchingEngine(
-        EngineConfig(model=CFG, max_batch=3, max_seq=128, **kw),
+        EngineConfig(model=cfg, max_batch=3, max_seq=128, **kw),
         params=params)
 
 
@@ -76,14 +99,18 @@ def test_forward_matches_the_reference(params):
         assert float(jnp.abs(got[i] - want).max()) < TOL
 
 
-@pytest.mark.parametrize("length", [5, 16, 37, 64, 100])
-def test_engine_prefill_then_decode_matches_the_reference(params, length):
+@pytest.mark.parametrize("length,kernel", [
+    (5, False), (16, False), (37, False), (64, False), (100, False),
+    (37, True), (100, True)])
+def test_engine_prefill_then_decode_matches_the_reference(model, length,
+                                                          kernel):
     """A bucketed prefill told the prompt's true length, then whole-
     batch decode steps with two parked slots: every token's
     log-probability against the reference's one full pass. 16 and 64
     end on a chunk's boundary (chunks of 16), 5, 37 and 100 do not; 5
     is shorter than a chunk."""
-    engine = _engine(params)
+    cfg, params = model
+    engine = _engine(params, cfg)
     ids = _prompt(length, seed=length)
     request = engine.add_request(GenerationRequest(
         prompt_ids=ids, max_tokens=20, logprobs=0))
@@ -91,10 +118,13 @@ def test_engine_prefill_then_decode_matches_the_reference(params, length):
         engine.step()
     assert request.error is None and len(request.output_ids) == 20
     got = [e["logprob"] for e in request.logprob_data]
-    want = _reference_logprobs(params, ids + request.output_ids, 20)
+    want = _reference_logprobs(params, ids + request.output_ids, 20, cfg)
     assert np.abs(np.asarray(got) - want).max() < TOL
     assert engine._decode._cache_size() == 1
     assert engine.stats()["dropped_rows"] == 0
+    assert (ssd_update_op.head_block(
+        cfg.mamba_n_heads, cfg.mamba_d_head, cfg.mamba_d_state)
+        is not None) == kernel
 
 
 def test_padding_leaves_the_state_of_the_true_last_token(params):
@@ -180,18 +210,20 @@ def test_each_multiplier_moves_the_output_as_the_reference_says(
     assert float(jnp.abs(got - base).max()) > 1e-2
 
 
+@pytest.mark.parametrize("kernel", [False, True])
 def test_requests_admitted_at_different_steps_equal_their_solo_outputs(
-        params):
+        model, kernel):
     """Two requests of unequal length share the batch from different
-    steps on; a third takes the slot the first one left. Parked slots'
-    states are moved by every step and replaced whole at admission."""
+    steps on; a third takes the slot the first one left. A parked
+    slot's state stays as it lies and is replaced whole at admission."""
+    cfg, params = model
     prompts = [_prompt(9, 1), _prompt(40, 2), _prompt(17, 3)]
     lengths = [6, 14, 8]
     solo = []
     for ids, n in zip(prompts, lengths):
-        engine = _engine(params)
+        engine = _engine(params, cfg)
         solo.append(engine.generate([ids], max_tokens=n)[0])
-    engine = _engine(params)
+    engine = _engine(params, cfg)
     first = engine.add_request(GenerationRequest(
         prompt_ids=prompts[0], max_tokens=lengths[0]))
     for _ in range(3):
@@ -287,6 +319,50 @@ def test_stats_and_series_tell_the_cache_the_picks_and_the_hit_experts(
                    'ray_tpu_engine_cache_bytes{kind="kv"}'):
         assert series in text
     engine.close()
+
+
+def _state_slots_series():
+    from ray_tpu.util import metrics
+    with metrics._registry.lock:
+        return {dict(tags)["kind"]: value for (name, tags), value
+                in metrics._registry.counters.items()
+                if name == "ray_tpu_engine_state_slots_total"}
+
+
+def test_state_slots_count_what_the_decode_step_moved_and_left_parked(
+        params):
+    """Every dense decode step counts its slots x 3 recurrent layers,
+    a live slot's as moved and an empty one's as parked, in ``stats()``
+    and in the series; the families whose step moves every slot's state
+    (Llama has none, Jamba's ``mamba.update``) emit no such series."""
+    from ray_tpu.models.jamba import JambaConfig, jamba_init
+    before = _state_slots_series()
+    engine = _engine(params)
+    engine.generate([_prompt(5), _prompt(37)], max_tokens=3)
+    engine.generate([_prompt(9)], max_tokens=4)
+    stats = engine.stats()
+    # two steps of two live slots of three, then three steps of one
+    assert stats["decode_steps"] == 5
+    assert stats["state_slots"] == {"moved": (2 * 2 + 3 * 1) * 3,
+                                    "parked": (2 * 1 + 3 * 2) * 3}
+    assert sum(stats["state_slots"].values()) == 3 * 3 * 5
+    after = _state_slots_series()
+    assert {kind: after[kind] - before.get(kind, 0.0)
+            for kind in after} == stats["state_slots"]
+    engine.close()
+    for cfg, init in ((LlamaConfig.tiny(vocab_size=512, max_seq_len=128,
+                                        attention="reference"), None),
+                      (JambaConfig.tiny(dtype=jnp.float32), jamba_init)):
+        other = ContinuousBatchingEngine(
+            EngineConfig(model=cfg, max_batch=3, max_seq=128),
+            params=init and jax.jit(init, static_argnums=1)(
+                jax.random.PRNGKey(0), cfg))
+        other.generate([_prompt(5), _prompt(9)], max_tokens=3)
+        stats = other.stats()
+        assert stats["decode_steps"] > 0
+        assert "state_slots" not in stats and other._state_layers == 0
+        assert _state_slots_series() == after
+        other.close()
 
 
 def test_the_stepper_never_reads_the_expert_counts(params, monkeypatch):

@@ -306,3 +306,109 @@ def test_flash_fallback_on_a_tpu_is_counted(monkeypatch):
         np.asarray(out), np.asarray(att._attention_reference(q, q, q, True)),
         atol=1e-6)
     assert att.kernel_fallbacks == ["q[1, 128, 2, 16] k[128] float32"]
+
+
+_SSD_SHAPE = (3, 32, 128, 8, 128)      # [M, B, H, P, N]: 32 slots a layer
+_SSD_LIVE = {
+    "all": np.ones(32, bool),
+    # parked slots first, last and between live ones
+    "some": np.asarray([0, 0, 1, 1, 0, 1, 1, 1] * 3 + [1, 0, 1, 1, 0, 1, 0, 0],
+                       bool),
+    "none": np.zeros(32, bool),
+}
+
+
+def _ssd_steps(update, ssm, layer, live, steps):
+    """``steps`` consecutive updates of one layer on random inputs ->
+    (the stack after them, every step's y [steps, B, H, P])."""
+    _, b, h, p, n = ssm.shape
+
+    def body(ssm, key):
+        ks = jax.random.split(key, 5)
+        return update(
+            ssm, layer, live,
+            jax.random.uniform(ks[0], (b, h), jnp.float32, 0.5, 1.0),
+            jax.random.normal(ks[1], (b, h, p)),
+            jax.random.normal(ks[2], (b, n)), jax.random.normal(ks[3], (b, n)),
+            jax.random.normal(ks[4], (b, h, p)))
+
+    return jax.lax.scan(body, ssm,
+                        jax.random.split(jax.random.PRNGKey(1), steps))
+
+
+@pytest.mark.parametrize("live,layer,block", [
+    (live, layer, 32) for live in _SSD_LIVE for layer in (0, 1, 2)
+] + [("some", 1, 128), ("some", 2, 16), ("all", 0, 128)])
+def test_ssd_update_kernel_matches_the_jnp_form_over_64_steps(
+        monkeypatch, live, layer, block):
+    # the decode step's recurrence in one pass (interpret mode) against
+    # the jax.numpy lines it replaced, both float32: 64 steps on end,
+    # by how many heads a grid step owns (128: a slot is one block)
+    from ray_tpu.ops import ssd_update as op
+
+    monkeypatch.setattr(op, "_INTERPRET", True)
+    monkeypatch.setattr(op, "_HEADS", block)
+    assert op.head_block(*_SSD_SHAPE[2:]) == block
+    ssm = jax.random.normal(jax.random.PRNGKey(0), _SSD_SHAPE, jnp.float32)
+    lv = _SSD_LIVE[live]
+    got, y = jax.jit(lambda s: _ssd_steps(
+        op.ssd_update, s, layer, jnp.asarray(lv), 64))(ssm)
+    want, y_ref = jax.jit(lambda s: _ssd_steps(
+        op._update_reference, s, layer, jnp.asarray(lv), 64))(ssm)
+    ssm, got, want = np.asarray(ssm), np.asarray(got), np.asarray(want)
+    y, y_ref = np.asarray(y), np.asarray(y_ref)
+    assert got.dtype == np.float32 and y.dtype == np.float32
+    if lv.any():
+        assert (np.abs(got[layer][lv] - want[layer][lv]).max()
+                <= 1e-5 * np.abs(want[layer][lv]).max())
+        assert (np.abs(y - y_ref).max(axis=(1, 2, 3))
+                <= 1e-5 * np.abs(y_ref).max(axis=(1, 2, 3))).all()
+    # a parked slot's state is the bytes that went in, its y zero, and
+    # no other layer of the stack is touched
+    assert (got[layer][~lv] == ssm[layer][~lv]).all()
+    assert (y[:, ~lv] == 0).all()
+    others = [m for m in range(ssm.shape[0]) if m != layer]
+    assert (got[others] == ssm[others]).all()
+
+
+@pytest.mark.parametrize("live", ["some", "none"])
+def test_ssd_update_moves_the_donated_stack_where_it_lies(monkeypatch, live):
+    from ray_tpu.ops import ssd_update as op
+
+    monkeypatch.setattr(op, "_INTERPRET", True)
+    ssm = jax.random.normal(jax.random.PRNGKey(0), _SSD_SHAPE, jnp.float32)
+    where = ssm.unsafe_buffer_pointer()
+    new, _ = jax.jit(lambda s: _ssd_steps(
+        op.ssd_update, s, 1, jnp.asarray(_SSD_LIVE[live]), 2),
+        donate_argnums=0)(ssm)
+    assert ssm.is_deleted() and new.unsafe_buffer_pointer() == where
+
+
+def test_ssd_update_takes_the_jnp_form_where_the_kernel_does_not_engage(
+        monkeypatch):
+    # off the TPU, and for a head or state size that is no multiple of
+    # the tile, the reference runs; the answer is the same mathematics
+    from ray_tpu.accelerators import jax_backend
+    from ray_tpu.ops import ssd_update as op
+
+    assert op.head_block(128, 64, 128) is None          # the CPU
+    monkeypatch.setattr(jax_backend, "on_tpu", lambda: True)
+    assert op.head_block(128, 64, 128) == op._HEADS
+    for shape in ((8, 16, 16), (80, 64, 128), (128, 64, 96), (128, 12, 128)):
+        assert op.head_block(*shape) is None
+    ssm = jax.random.normal(jax.random.PRNGKey(0), (2, 3, 8, 16, 16))
+    live = np.asarray([True, False, True])
+    got, y = _ssd_steps(op.ssd_update, ssm, 1, jnp.asarray(live), 1)
+    # the one step by hand, on the keys _ssd_steps drew
+    ks = jax.random.split(jax.random.split(jax.random.PRNGKey(1), 1)[0], 5)
+    da = jax.random.uniform(ks[0], (3, 8), jnp.float32, 0.5, 1.0)
+    dtx, dx = (jax.random.normal(k, (3, 8, 16)) for k in (ks[1], ks[4]))
+    b, c = (jax.random.normal(k, (3, 16)) for k in (ks[2], ks[3]))
+    h = (da[:, :, None, None] * ssm[1]
+         + dtx[..., None] * b[:, None, None, :])
+    want_y = np.asarray(jnp.einsum("bhpn,bn->bhp", h, c) + dx)
+    ssm, got, y, h = (np.asarray(a) for a in (ssm, got, y[0], h))
+    np.testing.assert_allclose(got[1][live], h[live], rtol=1e-6, atol=1e-6)
+    np.testing.assert_allclose(y[live], want_y[live], rtol=1e-5, atol=1e-5)
+    assert (got[1][~live] == ssm[1][~live]).all() and (y[~live] == 0).all()
+    assert (got[0] == ssm[0]).all()

@@ -388,15 +388,17 @@ def test_granite_serving_programs_compile_at_published_widths(v5e):
     (granite-4.0-h-small's first period of ten layers, 36 of 72 experts
     and half the vocabulary held, batch 32, seq 2560): the decode
     program through the engine's family seam holds ``decode_attention``
-    beside ``rms_norm``, aliases the whole cache of two kinds (donated:
-    no state and no row is copied), hands its device counts on without
-    donating them and keeps its temporaries under 64 MiB; a prefill
-    program holds flash attention and no other Pallas kernel (the
-    grouped matmul is XLA's own ``ragged_dot``), and everything fits
-    one chip."""
+    and ``ssd_update`` (in the layer scan) beside ``rms_norm``, aliases
+    the whole cache of two kinds (donated: no state and no row is
+    copied, the kernel's aliased stack included), hands its device
+    counts on without donating them and keeps its temporaries under 64
+    MiB; a prefill program holds flash attention and no other Pallas
+    kernel (the grouped matmul is XLA's own ``ragged_dot``), and
+    everything fits one chip."""
     from ray_tpu.llm import engine as engine_mod
     from ray_tpu.models.granite import (GraniteConfig, granite_init,
                                         granite_init_cache)
+    from ray_tpu.ops import ssd_update
     cfg = GraniteConfig(
         vocab_size=50176, layer_types=GraniteConfig().layer_types[:10],
         experts_held=(0, 36), max_seq_len=2560)
@@ -431,11 +433,16 @@ def test_granite_serving_programs_compile_at_published_widths(v5e):
         _on(mesh, P(), (32, 50176), jnp.float32), counts, want_lp=False)
     # rms_norm twice: over the model's width and over d_inner
     assert sorted({k.split("(")[0] for k in _kernels(lowered)}) == [
-        "decode_attention", "rms_norm"]
+        "decode_attention", "rms_norm", "ssd_update"]
+    # the recurrence's one pass stands inside the layer scan: once for
+    # each of the period's two runs of Mamba layers, not once a layer
+    assert lowered.as_text().count('kernel_name = "ssd_update"') == 2
+    assert ssd_update.head_block(128, 64, 128) == 32
     compiled = lowered.compile()
     _assert_sampler_branches(compiled)
     memory = compiled.memory_analysis()
-    # the K/V rows of 1 layer and the state of 9: all of it in place
+    # the K/V rows of 1 layer and the state of 9: all of it in place,
+    # the kernel's aliased stack too (1.21 GB that no pass copies)
     kv = 2 * 32 * 2560 * 8 * 128 * 2
     state = 9 * 32 * (128 * 64 * 128 * 4 + 3 * 8448 * 2)
     assert kv + state <= memory.alias_size_in_bytes <= 1.2 * (kv + state)
