@@ -159,12 +159,25 @@ def tokens_inside(records: Sequence[Dict[str, Any]], window_s: float
 def result_line(correct: bool, attempted: int, failed: int,
                 metrics: Dict[str, Dict[str, Any]],
                 device: Dict[str, Any],
-                breakdown: Optional[Dict[str, Any]] = None) -> str:
+                breakdown: Optional[Dict[str, Any]] = None,
+                compared: Optional[Dict[str, List[Any]]] = None) -> str:
+    """``compared``: every number that ``correct`` compared, ``name ->
+    [number, limit]`` (the limit None where the number is only shown);
+    it comes last in the line, so that a run that is not correct says
+    in its last line by which of them."""
     line = {"correct": bool(correct), "attempted": int(attempted),
             "failed": int(failed), "metrics": metrics, "device": device}
     if breakdown:
         line["breakdown"] = breakdown
+    if compared:
+        line["compared"] = compared
     return json.dumps(line)
+
+
+def compared_lines(compared: Dict[str, List[Any]]) -> str:
+    """The same for the end of standard error: a line a number."""
+    return "".join(f"benchmark: compared {name}: {value} limit {limit}\n"
+                   for name, (value, limit) in compared.items())
 
 
 def device_and_breakdown(report: Dict[str, Any],

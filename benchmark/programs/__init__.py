@@ -23,5 +23,20 @@ reference_check name no model; a module here gives them
     ``train_step``.
 ``routed(config)``
     whether the model has a router, and so is compared by
-    reference_check.routed_report and not dense_report.
+    reference_check.routed_report and not dense_report. The limits
+    of either, a routed one's margin and floor among them, are the
+    configuration file's ``"check": {"limits": {...}}`` where it has
+    them (reference_check.check_limits).
+``controls(config) -> {name: apply}`` (optional)
+    the ways in which ``benchmark/run.py --workload <cell> --control
+    <name>[,<name>...]`` can make the PROGRAM wrong, to see that the
+    cell's comparison fails for it: ``apply(engine)`` changes the
+    check's engine once it is built (its weights, its cache, its
+    ``step``) while the reference keeps the weights as the seed gave
+    them. Each is a module-level function, so that it reaches the
+    worker by name. ``none`` is every family's: the sound program, for
+    calibration over seeds. A control is the precision under the one
+    the configuration states, or a part of the model left out; a
+    configuration's limits are set between the sound program's readings
+    and its controls' (``check.calibration`` in its file).
 """

@@ -13,6 +13,7 @@ from __future__ import annotations
 from typing import Any, Dict, List
 
 from benchmark.harness import BenchError
+from benchmark.programs.controls import state_bf16
 
 # what the CPU rehearsal runs in place of the published sizes
 # (GraniteConfig.tiny's): four layers of which the third is attention,
@@ -95,23 +96,43 @@ def vocab_size(config: Dict[str, Any], rehearse: bool) -> int:
 def kernels(program_name: str) -> List[str]:
     """What the engine's programs hold on a TPU: a prefill program
     flash attention (the one attention layer) and rms_norm; the decode
-    programs decode_attention and rms_norm. The chunked recurrence, the
-    state update and the expert layer are plain XLA (the grouped matmul
-    is ``jax.lax.ragged_dot``, which the compiler lowers itself)."""
+    programs decode_attention, rms_norm and, since PR 58, ssd_update
+    (the live slots' state moved in one pass). The chunked recurrence
+    and the expert layer are plain XLA (the grouped matmul is
+    ``jax.lax.ragged_dot``, which the compiler lowers itself)."""
     if program_name.startswith("prefill_"):
         return ["flash_fwd", "rms_norm"]
     if program_name == "train_step":
         raise BenchError("the Granite family has no training path yet")
-    return ["decode_attention", "rms_norm"]
+    return ["decode_attention", "rms_norm", "ssd_update"]
 
 
 def routed(config: Dict[str, Any]) -> bool:
-    """False, though the model has a router: reference_check's routed
-    report needs 30% of the tokens decided at a router margin of 0.04,
-    and with 72 logits and ten layers next to none is (PERF.md 7-15);
-    a flip here exchanges the smallest of ten gates. The dense limits
-    are twentyfold too loose for this family as well (``logits_scaling``
-    16; PERF.md 7-19a has the readings and the controls: the cell's
-    ``correct`` holds the masks, the layer kinds and the multipliers,
-    not one expert or the state's precision)."""
-    return False
+    """True since PR 59: judged by reference_check.routed_report under
+    the margin, the floor and the limits of its own configuration file
+    (``check.limits``; ``check.calibration`` has the readings). By the
+    constants it could not be: at a router margin of 0.04 next to none
+    of the tokens is decided over ten layers of 72 logits and ten picks,
+    and ``logits_scaling`` 16 shrinks every difference sixteenfold under
+    limits made for logits of unit spread."""
+    return True
+
+
+def expert_zeroed(engine) -> None:
+    """The first held expert's output projection is zero in every
+    layer: the program drops what that expert would add to the tokens
+    routed to it."""
+    import jax
+
+    zero = jax.jit(lambda w: w.at[:, 0].set(0))
+    p = engine.params
+    engine.params = {**p, **{kind: {**p[kind],
+                                    "w_out_e": zero(p[kind]["w_out_e"])}
+                             for kind in ("mamba", "attn")}}
+
+
+def controls(config: Dict[str, Any]) -> Dict[str, Any]:
+    """``expert_zeroed``: a part of the model left out (one of the 36
+    held experts). ``state_bf16``: the precision under the float32 that
+    the configuration's ``assumed.recurrence`` states for the state."""
+    return {"expert_zeroed": expert_zeroed, "state_bf16": state_bf16}

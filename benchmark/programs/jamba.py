@@ -8,6 +8,7 @@ from __future__ import annotations
 from typing import Any, Dict, List
 
 from benchmark.harness import BenchError
+from benchmark.programs.controls import state_bf16
 
 # what the CPU rehearsal runs in place of the published sizes
 # (JambaConfig.tiny's): four layers of which the third is attention, so
@@ -83,3 +84,9 @@ def kernels(program_name: str) -> List[str]:
 
 def routed(config: Dict[str, Any]) -> bool:
     return False
+
+
+def controls(config: Dict[str, Any]) -> Dict[str, Any]:
+    """``state_bf16``: the precision under the float32 that the
+    configuration's ``assumed.recurrence`` states for the state."""
+    return {"state_bf16": state_bf16}

@@ -81,7 +81,9 @@ left to judge.
 from __future__ import annotations
 
 import importlib
-from typing import Any, Dict, List
+from typing import Any, Callable, Dict, List, Optional
+
+from benchmark.harness import BenchError
 
 LOGPROB_TOL = 0.15        # worst |difference| of a chosen token's logprob
 LOGPROB_MEAN_TOL = 0.04   # mean |difference|
@@ -91,6 +93,29 @@ ROUTER_MARGIN = 0.04             # router logits; under it a token is undecided
 ROUTED_DECIDED_SHARE_MIN = 0.3   # decided tokens, of all
 ROUTED_OVER_SHARE_MAX = 0.05     # decided tokens over LOGPROB_TOL, of decided
 ROUTED_MEDIAN_MAX = 0.026        # median |difference| of decided tokens
+
+# The constants above are what a configuration is judged by where its
+# file says nothing. A file's ``"check": {"limits": {...}}`` overrides
+# any of them for that configuration (check_limits): a family whose
+# logits are scaled, or whose router has many small experts, brings its
+# own limits, margin and floor, calibrated by the rule above through
+# ``benchmark/run.py --control`` (programs/__init__.py), and writes the
+# readings beside them under ``"check": {"calibration": ...}``.
+DENSE_LIMITS = {"worst": LOGPROB_TOL, "mean": LOGPROB_MEAN_TOL}
+ROUTED_LIMITS = {
+    "router_margin": ROUTER_MARGIN,
+    "decided_share_at_least": ROUTED_DECIDED_SHARE_MIN,
+    "decided_mean": LOGPROB_MEAN_TOL,
+    "decided_median": ROUTED_MEDIAN_MAX,
+    "decided_over_share": ROUTED_OVER_SHARE_MAX,
+    "over": LOGPROB_TOL}     # the level a decided token is counted over
+# the statistics a report gates on, and the limit each is held to
+GATES = {"worst": "worst", "mean": "mean",
+         "decided_share": "decided_share_at_least",
+         "decided_mean": "decided_mean", "decided_median": "decided_median",
+         "decided_over_share": "decided_over_share"}
+# where --control reads the routed report beside the gating one
+CONTROL_MARGINS = (0.005, 0.01, 0.02, 0.04)
 
 
 # the check's sizes where a configuration file has no "check" key:
@@ -110,6 +135,21 @@ def check_sizes(config: Dict[str, Any], routed: bool):
     return (list(check.get("prompt_lens", CHECK_PROMPTS)),
             int(check.get("new_tokens",
                           ROUTED_CHECK_TOKENS if routed else CHECK_TOKENS)))
+
+
+def check_limits(config: Dict[str, Any], routed: bool) -> Dict[str, float]:
+    """The limits of the serving comparison: the constants, under the
+    configuration file's ``"check": {"limits": {...}}``. A key that the
+    report of that kind does not gate on is refused."""
+    defaults = ROUTED_LIMITS if routed else DENSE_LIMITS
+    given = config.get("check", {}).get("limits", {})
+    unknown = sorted(set(given) - set(defaults))
+    if unknown:
+        raise BenchError(
+            f"check.limits has {unknown}; a "
+            f"{'routed' if routed else 'dense'} report takes "
+            f"{sorted(defaults)}")
+    return {**defaults, **{k: float(v) for k, v in given.items()}}
 
 
 def _reference(name: str):
@@ -173,70 +213,121 @@ def differences(generated, params, ref, model):
     return diffs, margins
 
 
-def dense_report(diffs: List[float]) -> Dict[str, Any]:
+def dense_report(diffs: List[float],
+                 limits: Optional[Dict[str, float]] = None
+                 ) -> Dict[str, Any]:
+    import statistics
+
+    limits = dict(limits or DENSE_LIMITS)
     report = {"worst": max(diffs), "mean": sum(diffs) / len(diffs),
-              "tokens": len(diffs),
-              "limits": {"worst": LOGPROB_TOL, "mean": LOGPROB_MEAN_TOL}}
-    report["ok"] = (report["worst"] <= LOGPROB_TOL
-                    and report["mean"] <= LOGPROB_MEAN_TOL)
+              "median": statistics.median(diffs), "tokens": len(diffs),
+              "limits": limits}
+    report["ok"] = (report["worst"] <= limits["worst"]
+                    and report["mean"] <= limits["mean"])
     return report
 
 
-def routed_report(diffs: List[float], margins: List[float]
+def routed_report(diffs: List[float], margins: List[float],
+                  limits: Optional[Dict[str, float]] = None
                   ) -> Dict[str, Any]:
     """The comparison for a model with a router (see ROUTED above):
     every statistic, its limit, and ``ok``. What is taken over all
     tokens (``worst``, ``mean``, ``median``) decides nothing."""
     import statistics
 
-    decided = [d for d, m in zip(diffs, margins) if m >= ROUTER_MARGIN]
-    undecided = [d for d, m in zip(diffs, margins) if m < ROUTER_MARGIN]
+    limits = dict(limits or ROUTED_LIMITS)
+    margin = limits["router_margin"]
+    decided = [d for d, m in zip(diffs, margins) if m >= margin]
+    undecided = [d for d, m in zip(diffs, margins) if m < margin]
     mean = lambda xs: sum(xs) / len(xs) if xs else 0.0  # noqa: E731
     report = {
         "worst": max(diffs), "mean": mean(diffs),
         "median": statistics.median(diffs), "tokens": len(diffs),
-        "router_margin": ROUTER_MARGIN,
+        "router_margin": margin,
         "decided_share": len(decided) / len(diffs),
         "decided_mean": mean(decided),
         "decided_median": statistics.median(decided) if decided else 0.0,
         "decided_over_share": (
-            sum(d > LOGPROB_TOL for d in decided) / len(decided)
+            sum(d > limits["over"] for d in decided) / len(decided)
             if decided else 1.0),
         "undecided_worst": max(undecided, default=0.0),
         "undecided_mean": mean(undecided),
-        "limits": {"decided_mean": LOGPROB_MEAN_TOL,
-                   "decided_over_share": ROUTED_OVER_SHARE_MAX,
-                   "decided_median": ROUTED_MEDIAN_MAX,
-                   "decided_share_at_least": ROUTED_DECIDED_SHARE_MIN}}
+        "limits": limits}
     report["ok"] = bool(
         decided
-        and report["decided_share"] >= ROUTED_DECIDED_SHARE_MIN
-        and report["decided_mean"] <= LOGPROB_MEAN_TOL
-        and report["decided_over_share"] <= ROUTED_OVER_SHARE_MAX
-        and report["decided_median"] <= ROUTED_MEDIAN_MAX)
+        and report["decided_share"] >= limits["decided_share_at_least"]
+        and report["decided_mean"] <= limits["decided_mean"]
+        and report["decided_over_share"] <= limits["decided_over_share"]
+        and report["decided_median"] <= limits["decided_median"])
     return report
 
 
+def compared(report: Dict[str, Any]) -> Dict[str, List[float]]:
+    """A report's gating statistics, each ``[reading, limit]``
+    (``decided_share`` beside its floor), for a run's last line."""
+    return {name: [report[name], report["limits"][key]]
+            for name, key in GATES.items() if key in report["limits"]}
+
+
+def control_readings(diffs: List[float], margins: List[float]
+                     ) -> Dict[str, Any]:
+    """What a calibration reads beside the gating report: the dense
+    statistics, and the routed ones at each of CONTROL_MARGINS (the
+    margins are +inf where the reference has no router)."""
+    dense = dense_report(diffs)
+    out = {"dense": {k: dense[k] for k in ("worst", "mean", "median")},
+           "routed": []}
+    for margin in CONTROL_MARGINS:
+        routed = routed_report(diffs, margins,
+                               {**ROUTED_LIMITS, "router_margin": margin})
+        out["routed"].append({k: routed[k] for k in (
+            "router_margin", "decided_share", "decided_mean",
+            "decided_median", "decided_over_share")})
+    return out
+
+
 def check_serving(engine_config, reference: str, prompt_lens: List[int],
-                  new_tokens: int, seed: int, routed: bool
-                  ) -> Dict[str, Any]:
+                  new_tokens: int, seed: int, routed: bool,
+                  limits: Optional[Dict[str, float]] = None,
+                  control: Optional[Callable] = None,
+                  readings: bool = False) -> Dict[str, Any]:
     """Runs in a worker that owns the chip (or, rehearsing, the CPU).
-    ``routed`` is the program module's word on the model."""
+    ``routed`` is the program module's word on the model, ``limits``
+    check_limits' of its configuration. ``control`` (one of the program
+    module's ``controls``) makes the engine wrong in one named way once
+    it is built; the reference keeps the weights as the seed gives
+    them. ``readings`` adds control_readings to the report."""
     import gc
+    import time
 
     from ray_tpu.accelerators import jax_backend
     from ray_tpu.llm.engine import ContinuousBatchingEngine
 
+    t0 = time.monotonic()
     engine = ContinuousBatchingEngine(engine_config)
-    diffs, margins = differences(
-        generate(engine, prompt_lens, new_tokens, seed), engine.params,
-        _reference(reference), engine_config.model)
-    report = (routed_report(diffs, margins) if routed
-              else dense_report(diffs))
-    report["device"] = jax_backend.device_report()
+    params = engine.params
+    if control is not None:
+        control(engine)
+    t1 = time.monotonic()
+    generated = generate(engine, prompt_lens, new_tokens, seed)
+    t2 = time.monotonic()
     # the runtime may hand this worker, chip and all, to the replica:
-    # give the engine's weights and cache back first
+    # give the engine's cache (and a control's weights) back, before
+    # the reference takes its room
     del engine
+    gc.collect()
+    diffs, margins = differences(generated, params, _reference(reference),
+                                 engine_config.model)
+    report = (routed_report(diffs, margins, limits) if routed
+              else dense_report(diffs, limits))
+    # what the check adds to setup_s, by part
+    report["seconds"] = {"build": t1 - t0, "generate": t2 - t1,
+                         "reference": time.monotonic() - t2}
+    if readings:
+        report["readings"] = {**control_readings(diffs, margins),
+                              "diffs": diffs, "margins": margins}
+    report["device"] = jax_backend.device_report()
+    del params
     gc.collect()
     return report
 

@@ -15,7 +15,13 @@ tiny sizes the program module keeps for its family: it says
 ``"correct": false`` and a device that is ``cpu``, and writes no
 metric. Without it a run that finds no chip fails. ``--sweep 2,3,4``
 (serving cells) offers each rate in turn to one replica and prints a
-row for each: how a traffic file's rate was found.
+row for each: how a traffic file's rate was found. ``--control
+none,<name>`` (serving cells) runs the cell's reference check alone, at
+the cell's sizes, once under each named control of the program module
+(benchmark/programs/__init__.py; ``none`` is the sound program), and
+prints one line a control, ``{"control", "workload", "seed",
+"report"}``: no cell result, so it can never be read as a run of the
+cell. How a configuration file's ``check.limits`` were found.
 """
 
 from __future__ import annotations
@@ -25,6 +31,7 @@ import time
 T_START = time.monotonic()
 
 import argparse  # noqa: E402
+import json  # noqa: E402
 import os  # noqa: E402
 import sys  # noqa: E402
 
@@ -41,6 +48,9 @@ def main() -> int:
     ap.add_argument("--rehearse", action="store_true")
     ap.add_argument("--sweep", type=lambda s: [float(x) for x in
                                                s.split(",")], default=None)
+    ap.add_argument("--control", default=None,
+                    help="names of the program module's controls, with "
+                         "commas: the reference check alone under each")
     ap.add_argument("--dump", default=None,
                     help="directory for what helps to look at a run by "
                          "hand: raw records, the trace's planes")
@@ -66,6 +76,16 @@ def main() -> int:
                     f"cell {args.workload} asks for {cell['chips']} "
                     f"chips, this machine has {chips}")
         runner = harness.runner_for(cell["traffic_file"]["kind"])
+        if args.control:
+            if not hasattr(runner, "control_reports"):
+                raise harness.BenchError(
+                    f"runner {cell['traffic_file']['kind']} has no "
+                    "reference check to run under a control")
+            reports = runner.control_reports(cell, args)
+            sys.stdout.flush()
+            for report in reports:
+                print(json.dumps(report))
+            return 0
         out = runner.run(cell, args, T_START)
         if args.rehearse:
             metrics = {}
@@ -79,9 +99,11 @@ def main() -> int:
     # after the runtime has shut down: the log monitor echoes worker
     # output to stdout, and the result must be the last line
     sys.stdout.flush()
+    sys.stderr.write(harness.compared_lines(out.get("compared") or {}))
+    sys.stderr.flush()
     print(harness.result_line(out["correct"], out["attempted"],
                               out["failed"], metrics, out["device"],
-                              out.get("breakdown")))
+                              out.get("breakdown"), out.get("compared")))
     return 0
 
 

@@ -6,6 +6,9 @@ This process starts the runtime, the proxy and (through the runtime)
 the replica that owns the chip; it never initializes a JAX backend.
 The load generator is a child process (benchmark/loadgen.py).
 
+``control_reports`` (``run.py --control``) runs the reference check
+alone, with the program made wrong in a named way, and no window.
+
 Phases: build (the cell's EngineConfig, LLMConfig and schedules; no
 runtime yet) -> reference check on the chip (a task that builds the
 same engine, see reference_check.py) -> deploy -> warm every prefill
@@ -137,12 +140,13 @@ def build(cell: Dict[str, Any], args) -> types.SimpleNamespace:
                     max_ongoing_requests=sizes["max_ongoing_requests"])
     routed = program.routed(config)
     check_lens, check_tokens = reference_check.check_sizes(config, routed)
+    check_limits = reference_check.check_limits(config, routed)
     scale = ({"prompt": 0.1, "output": 0.1} if args.rehearse else None)
     rates = args.sweep or [mix["rate_rps"]]
     return types.SimpleNamespace(
         program=program, engine=engine, llm=llm, routed=routed,
         check_lens=[min(n, sizes["max_seq"] // 2) for n in check_lens],
-        check_tokens=check_tokens, rates=rates,
+        check_tokens=check_tokens, check_limits=check_limits, rates=rates,
         schedules=[traffic.open_loop_schedule(
             {**mix, "rate_rps": rate}, args.seed, args.seconds, scale)
             for rate in rates],
@@ -150,9 +154,11 @@ def build(cell: Dict[str, Any], args) -> types.SimpleNamespace:
         no_eos={str(ByteTokenizer.eos_id): -100})
 
 
-def _reference_check(cell: Dict[str, Any], built, rehearse: bool
-                     ) -> Dict[str, Any]:
-    """The program against the plain reference, on the chip."""
+def _reference_check(cell: Dict[str, Any], built, rehearse: bool,
+                     control=None) -> Dict[str, Any]:
+    """The program against the plain reference, on the chip.
+    ``control``: one of the program module's ``controls``, for
+    control_reports; the report then carries every reading."""
     import ray_tpu
 
     check = ray_tpu.get(
@@ -160,9 +166,11 @@ def _reference_check(cell: Dict[str, Any], built, rehearse: bool
             reference_check.check_serving).remote(
             built.engine, cell["config_file"]["reference"],
             built.check_lens, built.check_tokens, built.engine.seed,
-            built.routed),
+            built.routed, built.check_limits,
+            control=control and control[1], readings=control is not None),
         timeout=DEPLOY_TIMEOUT_S)
-    _log("reference check:", json.dumps(check))
+    _log("reference check" + (f" under control {control[0]}:" if control
+                              else ":"), json.dumps(check))
     where = check["device"]
     if not rehearse and where["platform"] != "tpu":
         raise harness.BenchError(f"the worker computes on {where}")
@@ -171,6 +179,33 @@ def _reference_check(cell: Dict[str, Any], built, rehearse: bool
             f"cell asks for {cell['chips']} chips, the worker sees "
             f"{where['device_ids']}")
     return check
+
+
+def control_reports(cell: Dict[str, Any], args) -> List[Dict[str, Any]]:
+    """``run.py --control a,b``: the cell's reference check alone, at
+    the cell's sizes and on its seed, once under each named control of
+    the program module (``none``: the sound program). No window, no
+    replica, no metric: what comes back is one report a control."""
+    import ray_tpu
+
+    built = build(cell, args)
+    known = {"none": None, **getattr(built.program, "controls",
+                                     lambda config: {})(cell["config_file"])}
+    names = [n.strip() for n in args.control.split(",")]
+    unknown = [n for n in names if n not in known]
+    if unknown:
+        raise harness.BenchError(
+            f"program {cell['config_file']['program']!r} has no control "
+            f"{unknown} (has {sorted(known)})")
+    ray_tpu.init(**({"num_tpus": cell["chips"]} if args.rehearse else {}))
+    try:
+        return [{"control": name, "workload": cell["name"],
+                 "seed": built.engine.seed,
+                 "report": _reference_check(cell, built, args.rehearse,
+                                            (name, known[name]))}
+                for name in names]
+    finally:
+        ray_tpu.shutdown()
 
 
 def _warm_up(port: int, built, mix: Dict[str, Any]) -> None:
@@ -305,18 +340,30 @@ def _result(cell: Dict[str, Any], args, built, check: Dict[str, Any],
     # chip's machine it ran 1.1 ms late on average and 3 ms at worst
     # below the knee, with lone spikes of 0.1 s when saturated; a run
     # with a mean over MAX_MEAN_LAG_S says so and is one to discard.
-    correct = (check["ok"] and not wrong and kernels_ok
-               and compiled == 0.0 and unsent == 0
-               and dev1["pid"] == dev0["pid"]      # no replica was replaced
-               and not stats1["flash_fallbacks"]
-               and dev1["platform"] == where["platform"])
+    # The conjuncts of ``correct``, each a number beside what it has to
+    # be, and before them the check's statistics beside their limits
+    # (``check`` is their verdict): the result's last line carries them,
+    # so that the ledger tells an output's fault (check, wrong_counts)
+    # from a run's (the rest). How late the generator ran and the worst
+    # first token are shown, not judged.
+    conjuncts = {
+        "check": [int(bool(check["ok"])), 1],
+        "wrong_counts": [len(wrong), 0],
+        "kernels_ok": [int(kernels_ok), 1],
+        "compiled_in_window_s": [compiled, 0.0], "unsent": [unsent, 0],
+        "replica_replaced": [int(dev1["pid"] != dev0["pid"]), 0],
+        "fallbacks": [len(stats1["flash_fallbacks"]), 0],
+        "platform_ok": [int(dev1["platform"] == where["platform"]), 1]}
+    fell = [name for name, (value, wanted) in conjuncts.items()
+            if value != wanted]
+    correct = not fell
+    compared = {**reference_check.compared(check), **conjuncts,
+                "lag_worst_s": [lat["lag_worst_s"], None],
+                "ttft_max_ms": [max(lat["ttft_s"]) * 1e3, None]}
     if not correct:
         _log("NOT CORRECT:", json.dumps({
-            "check": check["ok"], "wrong_counts": len(wrong),
-            "kernels": kernel_note, "compiled_in_window_s": compiled,
-            "replica_pids": [dev0["pid"], dev1["pid"]],
-            "unsent": unsent, "fallbacks": stats1["flash_fallbacks"],
-            "lag_worst_s": lat["lag_worst_s"]}))
+            "fell": fell, "compared": compared, "kernels": kernel_note,
+            "replica_pids": [dev0["pid"], dev1["pid"]]}))
     observed = {
         "client": lat, "series_before": seen["series_before"],
         "series_after": seen["series_after"],
@@ -326,7 +373,7 @@ def _result(cell: Dict[str, Any], args, built, check: Dict[str, Any],
     return {"correct": correct and not args.rehearse,
             "attempted": len(records_done), "failed": len(failed),
             "measured": measured, "observed": observed, "device": device,
-            "breakdown": breakdown}
+            "breakdown": breakdown, "compared": compared}
 
 
 def _log_tails() -> str:

@@ -19,7 +19,7 @@ import json
 import time
 from typing import Any, Dict
 
-from benchmark import harness, traffic
+from benchmark import harness, reference_check, traffic
 
 
 def _programs(family, mesh, opt):
@@ -209,11 +209,20 @@ def run(cell: Dict[str, Any], args, t_start: float) -> Dict[str, Any]:
     missing = [] if args.rehearse else harness.missing_kernels(
         s["kernels"], program.kernels("train_step"))
     finite = all(x == x and abs(x) != float("inf") for x in s["losses"])
-    correct = (s["check"]["ok"] and finite and not missing
-               and not s["flash_fallbacks"]
-               and s["compiled_in_window_s"] == 0.0
-               and not s["data_exhausted"]
-               and len(where["device_ids"]) == chips)
+    # every number that ``correct`` compares beside what it may be, for
+    # the result's last line (``loss_diff`` is the check's, beside
+    # reference_check.LOSS_TOL: at most; the others: equal)
+    conjuncts = {
+        "check": [int(bool(s["check"]["ok"])), 1],
+        "losses_finite": [int(finite), 1],
+        "kernels_missing": [len(missing), 0],
+        "fallbacks": [len(s["flash_fallbacks"]), 0],
+        "compiled_in_window_s": [s["compiled_in_window_s"], 0.0],
+        "data_exhausted": [int(bool(s["data_exhausted"])), 0],
+        "devices": [len(where["device_ids"]), chips]}
+    correct = all(value == wanted for value, wanted in conjuncts.values())
+    compared = {"loss_diff": [s["check"]["diff"], reference_check.LOSS_TOL],
+                **conjuncts}
     log("check:", json.dumps(s["check"]), "losses:",
         [round(x, 4) for x in s["losses"][:3]], "...",
         round(s["losses"][-1], 4))
@@ -247,4 +256,4 @@ def run(cell: Dict[str, Any], args, t_start: float) -> Dict[str, Any]:
             "failed": sum(1 for x in s["losses"]
                           if x != x or abs(x) == float("inf")),
             "measured": measured, "observed": observed, "device": device,
-            "breakdown": breakdown}
+            "breakdown": breakdown, "compared": compared}

@@ -353,9 +353,7 @@ def test_routed_report_on_numbers_written_out():
     assert ok["ok"] and ok["worst"] == 3.0 and ok["tokens"] == 192
     assert ok["decided_share"] == pytest.approx(191 / 192)
     assert ok["undecided_worst"] == 3.0 and ok["undecided_mean"] == 3.0
-    assert set(ok["limits"]) == {"decided_mean", "decided_over_share",
-                                 "decided_median",
-                                 "decided_share_at_least"}
+    assert ok["limits"] == rc.ROUTED_LIMITS
     # a fault in every token fails, however small the worst
     every = rc.routed_report([2.5 * rc.LOGPROB_MEAN_TOL] * 192, [far] * 192)
     assert not every["ok"] and every["worst"] < rc.LOGPROB_TOL

@@ -15,7 +15,7 @@ ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 sys.path.insert(0, ROOT)
 
-from benchmark import harness  # noqa: E402
+from benchmark import harness, reference_check  # noqa: E402
 from benchmark.readers import counter_share  # noqa: E402
 from benchmark.runners import serve_open_loop  # noqa: E402
 
@@ -69,8 +69,16 @@ def test_configuration_file_holds_the_catalog_rows_numbers():
     assert config["vocab_size"] * 2 == config["published"]["vocab_size"]
     assert (config["program"], config["reference"]) == ("granite",
                                                         "granite")
-    assert config["check"] == {"prompt_lens": [100, 200, 300],
-                               "new_tokens": 64}
+    # 384 tokens, and the limits of a router of many small experts
+    # with the runs they were read from
+    check = config["check"]
+    assert (check["prompt_lens"], check["new_tokens"]) \
+        == ([100, 200, 300] * 2, 64)
+    assert set(check["limits"]) == {"router_margin", "decided_mean",
+                                    "decided_share_at_least",
+                                    "decided_median"}
+    assert set(check["calibration"]["controls"]) == set(
+        harness.program_for("granite").controls(config))
 
 
 def test_build_gives_the_published_widths_and_this_ranks_share():
@@ -85,8 +93,15 @@ def test_build_gives_the_published_widths_and_this_ranks_share():
     assert (model.n_experts, model.top_k, model.expert_dim,
             model.shared_expert_dim) == (72, 10, 768, 1536)
     assert (built.engine.max_batch, built.engine.max_seq) == (32, 2560)
-    assert (built.check_lens, built.check_tokens) == ([100, 200, 300], 64)
-    assert not built.routed and built.drain
+    # 384 tokens, judged as a router's, by the file's margin and floor
+    assert (built.check_lens, built.check_tokens) \
+        == ([100, 200, 300] * 2, 64)
+    assert built.routed and built.drain
+    limits = built.check_limits
+    assert (limits["router_margin"], limits["decided_share_at_least"]) \
+        == (0.005, 0.2)
+    assert limits == {**reference_check.ROUTED_LIMITS,
+                      **cell()["config_file"]["check"]["limits"]}
 
 
 def test_build_rehearsing_keeps_both_kinds_of_layer_and_the_share():
@@ -112,11 +127,11 @@ def test_the_program_modules_five_functions_answer():
     assert granite.kernels("prefill_256") == granite.kernels("prefill_1024") \
         == ["flash_fwd", "rms_norm"]
     assert granite.kernels("decode") == granite.kernels("decode_lp") \
-        == ["decode_attention", "rms_norm"]
+        == ["decode_attention", "rms_norm", "ssd_update"]
     with pytest.raises(harness.BenchError):
         granite.kernels("train_step")
-    # the dense limits judge it: PERF.md 7-15 and 7-19a say why
-    assert not granite.routed(config)
+    # the routed report judges it, by the file's limits (PERF.md 3)
+    assert granite.routed(config)
     with pytest.raises(harness.BenchError):
         granite.serving_model(
             {**config, "position_embedding_type": "rope"}, 2560, False)
@@ -209,8 +224,29 @@ def test_cell_rehearses_on_the_cpu(trace, tmp_path):
     check = next(l for l in done.stdout.splitlines()
                  if "reference check:" in l)
     report = json.loads(check.split("reference check:", 1)[1])
-    assert report["ok"] and report["tokens"] == 192
+    assert report["ok"] and report["tokens"] == 384
     assert report["worst"] < 1e-4
+    # the file's limits, not the constants, judged it
+    given = cell()["config_file"]["check"]["limits"]
+    assert {k: report["limits"][k] for k in given} == given
+    assert report["router_margin"] == given["router_margin"]
+    # the last line ends with every number compared, beside its limit:
+    # a rehearsal is not correct by being one, and by nothing here
+    assert list(line)[-1] == "compared"
+    got = line["compared"]
+    assert got["decided_mean"] == [report["decided_mean"],
+                                   given["decided_mean"]]
+    assert got["decided_share"] == [report["decided_share"],
+                                    given["decided_share_at_least"]]
+    for name in ("check", "kernels_ok", "platform_ok"):
+        assert got[name] == [1, 1]
+    for name in ("wrong_counts", "unsent", "replica_replaced", "fallbacks",
+                 "compiled_in_window_s"):
+        assert got[name] == [0, 0]
+    assert got["lag_worst_s"][0] >= 0 and got["ttft_max_ms"][0] > 0
+    for name, (value, limit) in got.items():
+        assert f"benchmark: compared {name}: {value} limit {limit}" \
+            in done.stderr
     if trace:
         # a rehearsal's line holds no metric; the dumped series do. 8
         # experts of which 4 are held; a few rows of 3 picks
@@ -219,3 +255,44 @@ def test_cell_rehearses_on_the_cpu(trace, tmp_path):
                 {"per_layer": cell()["per_layer"][-2:]}, json.load(f))
         assert 30 < shares["expert_held_share"]["value"] < 70
         assert 0 < shares["expert_hit_share"]["value"] <= 100
+
+
+def test_controls_through_the_harness_on_the_cpu():
+    """``run.py --control``: the reference check alone, the program
+    made wrong by name. In float32 the sound program agrees to 1e-4
+    and the dropped expert reads tenths; the state in bf16 reads under
+    the limits here and is what the chip's calibration is for."""
+    done = subprocess.run(
+        [sys.executable, os.path.join(ROOT, "benchmark", "run.py"),
+         "--workload", CELL, "--rehearse", "--seed", str(SEED),
+         "--control", "none,expert_zeroed,state_bf16"],
+        capture_output=True, text=True, timeout=900, cwd=ROOT)
+    assert done.returncode == 0, done.stderr[-2000:]
+    lines = [json.loads(l) for l in done.stdout.splitlines()
+             if l.startswith('{"control"')]
+    assert [l["control"] for l in lines] == ["none", "expert_zeroed",
+                                             "state_bf16"]
+    # the last line is a control's report and no cell's result
+    last = json.loads(done.stdout.strip().splitlines()[-1])
+    assert last == lines[-1]
+    assert not {"correct", "attempted", "failed", "metrics"} & set(last)
+    assert "window" not in done.stdout and "generator lag" not in done.stdout
+    sound, zeroed, rounded = (l["report"] for l in lines)
+    assert sound["ok"] and sound["worst"] < 1e-4 and sound["tokens"] == 384
+    assert not zeroed["ok"] and zeroed["mean"] > 100 * sound["mean"]
+    assert zeroed["decided_mean"] > zeroed["limits"]["decided_mean"]
+    assert sound["mean"] < rounded["mean"] < zeroed["mean"]
+    for report in (sound, zeroed, rounded):
+        assert [r["router_margin"] for r in report["readings"]["routed"]] \
+            == [0.005, 0.01, 0.02, 0.04]
+        assert report["readings"]["dense"]["mean"] == report["mean"]
+        assert all(l["seed"] == SEED % (2**31 - 1) for l in lines)
+
+
+def test_a_control_the_program_does_not_have_is_refused():
+    done = subprocess.run(
+        [sys.executable, os.path.join(ROOT, "benchmark", "run.py"),
+         "--workload", CELL, "--rehearse", "--control", "experts_in_int4"],
+        capture_output=True, text=True, timeout=300, cwd=ROOT)
+    assert done.returncode == 1 and not done.stdout.strip()
+    assert "has no control" in done.stderr

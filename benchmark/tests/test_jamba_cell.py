@@ -42,8 +42,12 @@ def test_configuration_file_holds_the_catalog_rows_numbers():
             assert config[key] == value, key
     assert config["reduced"] == {} and config["num_hidden_layers"] == 28
     assert (config["program"], config["reference"]) == ("jamba", "jamba")
-    assert config["check"] == {"prompt_lens": [100, 200, 300],
-                               "new_tokens": 64}
+    # sizes, and what PR 59 read of the one control: no limit of its own
+    check = config["check"]
+    assert (check["prompt_lens"], check["new_tokens"]) \
+        == ([100, 200, 300], 64)
+    assert "limits" not in check
+    assert set(check["calibration"]["controls"]) == {"state_bf16"}
 
 
 def test_build_gives_the_published_model_uncut():
@@ -187,9 +191,13 @@ def test_cell_reports_the_serving_metrics_and_its_own():
     assert [m["name"] for m in cell["end_to_end"]] == [
         "ttft_p50_ms", "itl_p90_ms", "setup_s"]
     names = [m["name"] for m in cell["per_layer"]]
-    assert names[-3:] == ["scan_roofline", "scan_time_share",
-                          "prefill_pad_share"]
-    assert len(names) == 14
+    # PR 34's fourteen; later PRs appended theirs
+    assert names[11:14] == ["scan_roofline", "scan_time_share",
+                            "prefill_pad_share"]
+    assert names[14:] == ["stream_held_share", "admit_launch_ms",
+                          "stepper_blocked_share.lat",
+                          "stepper_emit_share.lat",
+                          "stepper_stall_share.lat", "decode_kv_read_share"]
     for name in names:
         spec = harness.load_json("layer_metrics", name + ".json")
         harness.reader_for(spec["reader"])

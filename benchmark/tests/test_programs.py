@@ -166,8 +166,124 @@ def test_check_sizes_default_and_from_the_configuration_file():
         ([100, 200, 300], 64)
     assert sizes({"check": {"prompt_lens": [96, 480], "new_tokens": 48}},
                  True) == ([96, 480], 48)
+    # a file's "check" holds sizes, limits and how the limits were found
     for name in os.listdir(os.path.join(harness.HERE, "configs")):
-        assert "check" not in harness.load_json("configs", name)
+        check = harness.load_json("configs", name).get("check", {})
+        assert set(check) <= {"prompt_lens", "new_tokens", "limits",
+                              "calibration"}, name
+        assert "calibration" in check or "limits" not in check, name
+
+
+def test_check_limits_default_to_the_constants():
+    rc = reference_check
+    assert rc.check_limits({}, False) == {"worst": rc.LOGPROB_TOL,
+                                          "mean": rc.LOGPROB_MEAN_TOL}
+    assert rc.check_limits({"check": {"new_tokens": 8}}, True) == {
+        "router_margin": rc.ROUTER_MARGIN,
+        "decided_share_at_least": rc.ROUTED_DECIDED_SHARE_MIN,
+        "decided_mean": rc.LOGPROB_MEAN_TOL,
+        "decided_median": rc.ROUTED_MEDIAN_MAX,
+        "decided_over_share": rc.ROUTED_OVER_SHARE_MAX,
+        "over": rc.LOGPROB_TOL}
+    # the Mistral files have none: judged as before there were any
+    for workload in ("mistral7b-chat-steady", "mistral7b-chat-saturated"):
+        built = serve_open_loop.build(harness.load_cell(workload), _args())
+        assert built.check_limits == {"worst": 0.15, "mean": 0.04}
+        report = rc.dense_report([0.01, 0.02], built.check_limits)
+        assert report["limits"] == rc.dense_report([0.01, 0.02])["limits"] \
+            == {"worst": 0.15, "mean": 0.04}
+
+
+@pytest.mark.parametrize("routed,given", [
+    (False, {"wurst": 0.1}), (False, {"router_margin": 0.01}),
+    (False, {"median": 0.01}), (True, {"worst": 0.1}),
+    (True, {"decided_share": 0.2})])
+def test_check_limits_refuse_a_key_the_report_does_not_gate_on(routed,
+                                                               given):
+    with pytest.raises(harness.BenchError, match="check.limits"):
+        reference_check.check_limits({"check": {"limits": given}}, routed)
+
+
+def test_a_files_limits_reach_the_report():
+    rc = reference_check
+    dense = rc.check_limits({"check": {"limits": {"mean": 0.001}}}, False)
+    assert dense == {"worst": rc.LOGPROB_TOL, "mean": 0.001}
+    assert rc.dense_report([0.002] * 18)["ok"]
+    tight = rc.dense_report([0.002] * 18, dense)
+    assert not tight["ok"] and tight["limits"] == dense
+    # a router of many small experts: its own margin, floor and level
+    routed = rc.check_limits({"check": {"limits": {
+        "router_margin": 0.01, "decided_share_at_least": 0.1,
+        "over": 0.02}}}, True)
+    diffs = [0.001] * 150 + [0.03] * 42
+    margins = [0.02] * 40 + [0.005] * 110 + [0.02] * 42
+    theirs = rc.routed_report(diffs, margins, routed)
+    assert theirs["router_margin"] == 0.01 and theirs["limits"] == routed
+    assert theirs["decided_share"] == pytest.approx(82 / 192)
+    # 42 of the 82 decided read over the file's level: a fault
+    assert theirs["decided_over_share"] == pytest.approx(42 / 82)
+    assert not theirs["ok"]
+    # by the constants nothing is decided at all, whatever it reads
+    ours = rc.routed_report(diffs, margins)
+    assert ours["decided_share"] == 0.0 and not ours["ok"]
+    # every cell's file builds, and its limits are what build hands on
+    for workload, kind in (("jamba2-3b-chat-steady", rc.DENSE_LIMITS),
+                           ("granite4h-agent-steady", rc.ROUTED_LIMITS)):
+        cell = harness.load_cell(workload)
+        built = serve_open_loop.build(cell, _args())
+        given = cell["config_file"]["check"].get("limits", {})
+        assert built.check_limits == {**kind, **given}
+        # a router's margin and floor are its own; what a token may
+        # read is never looser than the constant
+        for key in set(given) - {"router_margin", "decided_share_at_least"}:
+            assert given[key] <= kind[key], key
+
+
+def test_control_readings_hold_the_dense_and_the_routed_statistics():
+    rc = reference_check
+    got = rc.control_readings([0.01, 0.03, 0.02, 0.5],
+                              [0.003, 0.008, 0.03, 0.05])
+    assert got["dense"] == {"worst": 0.5, "mean": pytest.approx(0.14),
+                            "median": pytest.approx(0.025)}
+    assert [r["router_margin"] for r in got["routed"]] \
+        == list(rc.CONTROL_MARGINS)
+    assert [r["decided_share"] for r in got["routed"]] \
+        == [0.75, 0.5, 0.5, 0.25]
+    assert got["routed"][-1]["decided_over_share"] == 1.0
+
+
+def test_result_line_ends_with_what_was_compared():
+    compared = {"worst": [0.2, 0.15], "check": [0, 1],
+                "lag_worst_s": [0.001, None]}
+    device = {"platform": "tpu", "kind": "TPU v5 lite", "count": 1,
+              "memory_peak_bytes": 1}
+    plain = json.loads(harness.result_line(True, 3, 0, {}, device))
+    assert list(plain) == ["correct", "attempted", "failed", "metrics",
+                           "device"]
+    traced = json.loads(harness.result_line(
+        False, 3, 0, {}, device, {"device_ops": [["a", 1.0]]}, compared))
+    assert list(traced)[-2:] == ["breakdown", "compared"]
+    assert traced["compared"] == compared
+
+
+def test_programs_give_their_controls_by_name():
+    import pickle
+
+    granite = harness.program_for("granite")
+    config = harness.load_json("configs", "granite-4.0-h-small-L10-ep2.json")
+    assert sorted(granite.controls(config)) == ["expert_zeroed",
+                                                "state_bf16"]
+    jamba = harness.program_for("jamba")
+    assert sorted(jamba.controls(
+        harness.load_json("configs", "jamba2-3b.json"))) == ["state_bf16"]
+    assert not hasattr(harness.program_for("llama"), "controls")
+    # each reaches the worker by name
+    for apply in granite.controls(config).values():
+        assert pickle.loads(pickle.dumps(apply)) is apply
+    # the decode program is held to PR 58's kernel
+    assert granite.kernels("decode") == ["decode_attention", "rms_norm",
+                                         "ssd_update"]
+    assert granite.kernels("prefill_256") == ["flash_fwd", "rms_norm"]
 
 
 def test_series_of_every_ray_tpu_family_reach_the_readers():
@@ -397,20 +513,51 @@ def _request_never_sent(seen, check):
                                              finished=False)
 
 
-@pytest.mark.parametrize("fault", [
-    None, _short_of_one_token, _prefill_without_its_kernel,
-    _reference_disagrees, _compiled_inside_the_window, _replica_replaced,
-    _request_never_sent],
-    ids=lambda f: f.__name__.strip("_") if f else "sound")
-def test_result_is_correct_only_if_nothing_is_broken(fault):
+# the conjuncts of ``correct`` as the result's last line carries them,
+# each beside what it has to be, where nothing fell
+_ALL_HELD = {"check": [1, 1], "wrong_counts": [0, 0], "kernels_ok": [1, 1],
+             "compiled_in_window_s": [0.0, 0.0], "unsent": [0, 0],
+             "replica_replaced": [0, 0], "fallbacks": [0, 0],
+             "platform_ok": [1, 1]}
+
+
+@pytest.mark.parametrize("fault,fell", [
+    (None, {}), (_short_of_one_token, {"wrong_counts": 1}),
+    (_prefill_without_its_kernel, {"kernels_ok": 0}),
+    (_reference_disagrees, {"check": 0}),
+    (_compiled_inside_the_window, {"compiled_in_window_s": 2.5}),
+    (_replica_replaced, {"replica_replaced": 1}),
+    (_request_never_sent, {"unsent": 1})],
+    ids=lambda f: f.__name__.strip("_") if callable(f) else "")
+def test_result_is_correct_only_if_nothing_is_broken(fault, fell):
     cell = harness.load_cell("mistral7b-chat-steady")
     built = serve_open_loop.build(cell, _args())
     seen = _seen(built)
-    check = {"ok": True, "device": {"platform": "tpu", "device_ids": [0]}}
+    check = {**reference_check.dense_report([0.01, 0.03],
+                                            built.check_limits),
+             "device": {"platform": "tpu", "device_ids": [0]}}
     if fault:
         fault(seen, check)
     out = serve_open_loop._result(cell, _args(), built, check, seen)
     assert out["correct"] is (fault is None)
+    # the last line names every number compared beside its limit, last
+    # of its keys, so a run that is not correct says by which it fell
+    line = json.loads(harness.result_line(
+        out["correct"], out["attempted"], out["failed"], {}, out["device"],
+        out["breakdown"], out["compared"]))
+    assert list(line) == ["correct", "attempted", "failed", "metrics",
+                          "device", "compared"]
+    got = line["compared"]
+    assert list(got)[:2] == ["worst", "mean"]
+    assert got["worst"] == [0.03, 0.15]
+    assert got["mean"] == [pytest.approx(0.02), 0.04]
+    for name, (value, wanted) in _ALL_HELD.items():
+        assert got[name] == [pytest.approx(fell.get(name, value)), wanted]
+    assert got["ttft_max_ms"] == [pytest.approx(100.0), None]
+    assert got["lag_worst_s"][1] is None
+    stderr = harness.compared_lines(out["compared"])
+    assert stderr.count("\n") == len(got)
+    assert "benchmark: compared worst: 0.03 limit 0.15\n" in stderr
     assert out["measured"]["setup_s"] == 34.0
     assert out["measured"]["ttft_p50_ms"] == pytest.approx(100.0)
     assert out["measured"]["itl_p90_ms"] == pytest.approx(50.0)
