@@ -6,10 +6,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax._src import core as jax_core
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ray_tpu.models.llama import (
     LlamaConfig,
+    chunked_cross_entropy,
     llama_forward,
     llama_init,
     llama_loss,
@@ -120,6 +122,147 @@ def test_chunked_cross_entropy_matches_dense():
         flat_c = ravel_pytree(chunked_grads)[0]
         assert jnp.allclose(flat_d, flat_c, rtol=5e-3, atol=5e-4), (
             "grad mismatch", float(jnp.abs(flat_d - flat_c).max()))
+
+
+def _checkpointed_cross_entropy(hidden, lm_head, targets, mask=None, *,
+                                chunk_tokens):
+    """The loss as it was until PR 62, kept as the plain reference: the
+    chunks in a rematerialized scan, so autodiff runs each chunk's head
+    product a second time in the backward pass."""
+    dim = hidden.shape[-1]
+    n = targets.size
+    chunk = min(chunk_tokens, n)
+    pad = (-n) % chunk
+    flat_m = (jnp.ones((n,), jnp.float32) if mask is None
+              else mask.reshape(-1).astype(jnp.float32))
+    flat_h = jnp.pad(hidden.reshape(n, dim), ((0, pad), (0, 0)))
+    flat_t = jnp.pad(targets.reshape(n), (0, pad))
+    flat_m = jnp.pad(flat_m, (0, pad))
+
+    def body(carry, inp):
+        h_c, t_c, m_c = inp
+        logits = (h_c @ lm_head).astype(jnp.float32)
+        lse = jax.nn.logsumexp(logits, axis=-1)
+        tgt = jnp.take_along_axis(logits, t_c[:, None], axis=-1)[:, 0]
+        return carry + jnp.sum((lse - tgt) * m_c), None
+
+    total, _ = jax.lax.scan(
+        jax.checkpoint(body), jnp.zeros((), jnp.float32),
+        (flat_h.reshape(-1, chunk, dim), flat_t.reshape(-1, chunk),
+         flat_m.reshape(-1, chunk)))
+    return total / jnp.maximum(jnp.sum(flat_m), 1.0)
+
+
+def _bf16_ulp(x):
+    """The distance between neighbouring bfloat16 values (8 bits of
+    significand) at the magnitude of ``x``."""
+    return 2.0 ** (np.floor(np.log2(x)) - 7)
+
+
+@pytest.mark.parametrize("scale", [1.0, 3.0], ids=["grad", "grad_of_3x"])
+@pytest.mark.parametrize("chunk", [13, 64], ids=["42_in_13s", "one_chunk"])
+@pytest.mark.parametrize("masked", [False, True], ids=["all", "masked"])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+def test_chunked_cross_entropy_matches_the_checkpointed_scan(
+        dtype, masked, chunk, scale):
+    """The custom_vjp forms dlogits in the forward chunk: the loss is the
+    checkpointed scan's to the bit, and so is dH in bfloat16 (the same
+    operands into the same product); dW sums the chunks in float32 where
+    the scan's transpose summed them in the weights' dtype."""
+    keys = jax.random.split(jax.random.PRNGKey(7), 4)
+    hidden = jax.random.normal(keys[0], (2, 21, 64)).astype(dtype)
+    lm_head = (jax.random.normal(keys[1], (64, 97)) / 8).astype(dtype)
+    targets = jax.random.randint(keys[2], (2, 21), 0, 97)
+    mask = ((jax.random.uniform(keys[3], (2, 21)) > 0.3).astype(jnp.float32)
+            if masked else None)
+
+    def loss_and_grads(fn):
+        loss, (d_h, d_w) = jax.value_and_grad(
+            lambda h, w: scale * fn(h, w, targets, mask,
+                                    chunk_tokens=chunk), argnums=(0, 1))(
+                hidden, lm_head)
+        assert (d_h.dtype, d_w.dtype) == (dtype, dtype)
+        return (float(loss), np.asarray(d_h, np.float32),
+                np.asarray(d_w, np.float32))
+
+    loss, d_h, d_w = loss_and_grads(chunked_cross_entropy)
+    want_loss, want_d_h, want_d_w = loss_and_grads(
+        _checkpointed_cross_entropy)
+    assert loss == want_loss
+    if dtype == jnp.float32:
+        np.testing.assert_allclose(d_h, want_d_h, rtol=1e-5, atol=1e-7)
+        np.testing.assert_allclose(d_w, want_d_w, rtol=1e-5, atol=1e-6)
+        return
+    if scale == 1.0:
+        np.testing.assert_array_equal(d_h, want_d_h)
+        ulps = 1
+    else:  # g * bf16(dlogits) against bf16(g * dlogits) in every product
+        ulps = 4
+        assert np.abs(d_h - want_d_h).max() <= ulps * _bf16_ulp(
+            np.abs(want_d_h).max())
+    assert np.abs(d_w - want_d_w).max() <= ulps * _bf16_ulp(
+        np.abs(want_d_w).max())
+
+
+def vocab_products(jaxpr, vocab):
+    """dot_generals of a jaxpr, its sub-jaxprs included, with the
+    vocabulary dimension among their operands' or result's."""
+    n = 0
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "dot_general" and any(
+                vocab in v.aval.shape for v in (*eqn.invars, *eqn.outvars)):
+            n += 1
+        n += sum(vocab_products(getattr(sub, "jaxpr", sub), vocab)
+                 for sub in jax_core.jaxprs_in_params(eqn.params))
+    return n
+
+
+@pytest.mark.parametrize("differentiated, products", [
+    (True, 3), (False, 1)], ids=["value_and_grad", "loss"])
+def test_chunked_loss_runs_the_head_products_the_mathematics_needs(
+        differentiated, products):
+    """A train step holds logits, dH = dlogits W^T and dW = H^T dlogits
+    and no second logits (4 until PR 62); the loss alone holds one."""
+    cfg = LlamaConfig.tiny(vocab_size=97, remat=True, ce_chunk_tokens=13)
+    params = llama_init(jax.random.PRNGKey(0), cfg)
+    tokens, targets = _data(cfg, batch=2, seq=21)
+
+    def loss(p):
+        return llama_loss(p, tokens, targets, cfg)
+
+    traced = jax.make_jaxpr(
+        jax.value_and_grad(loss) if differentiated else loss)(params)
+    assert vocab_products(traced.jaxpr, cfg.vocab_size) == products
+
+
+def test_chunked_loss_under_fsdp4_matches_one_device():
+    """lm_head and its gradient P(None, "fsdp"), the batch over fsdp:
+    the loss and every gradient equal the unsharded ones."""
+    cfg = LlamaConfig.tiny(ce_chunk_tokens=48)
+    params = llama_init(jax.random.PRNGKey(0), cfg)
+    tokens, targets = _data(cfg, batch=8, seq=16)  # 128 tokens: 3 chunks
+    mesh = make_mesh(MeshSpec(fsdp=4))
+    sharded = shard_pytree(params, mesh, llama_sharding_rules("fsdp"))
+    assert sharded["lm_head"].sharding.spec == P(None, "fsdp")
+    batch_sh = NamedSharding(mesh, P(("data", "fsdp")))
+
+    def loss_and_grads(p, t, y):
+        return jax.value_and_grad(
+            lambda p_: llama_loss(p_, t, y, cfg))(p)
+
+    loss, grads = jax.jit(loss_and_grads)(
+        sharded, jax.device_put(tokens, batch_sh),
+        jax.device_put(targets, batch_sh))
+    want_loss, want_grads = jax.jit(loss_and_grads)(params, tokens, targets)
+    assert grads["lm_head"].sharding.spec == P(None, "fsdp")
+    np.testing.assert_allclose(float(loss), float(want_loss), rtol=1e-6)
+    for (path, got), want in zip(
+            jax.tree_util.tree_leaves_with_path(grads),
+            jax.tree.leaves(want_grads)):
+        np.testing.assert_allclose(
+            np.asarray(got), np.asarray(want), rtol=1e-4, atol=1e-6,
+            err_msg=jax.tree_util.keystr(path))
 
 
 @pytest.fixture

@@ -21,6 +21,7 @@ from ray_tpu.models.llama import (
     llama_prefill)
 from ray_tpu.ops import attention, quant_matmul, rmsnorm
 from ray_tpu.parallel.mesh import AXIS_ORDER
+from test_models import vocab_products
 
 HBM_BYTES = 16 * 2**30  # one v5e chip
 
@@ -150,7 +151,8 @@ def test_flash_kernels_compile_at_the_callers_shapes(v5e, shape, with_grads):
 def _compile_train_step(devices, *, chips, n_layers, batch, seq=2048):
     """chip_smoke's trainer step, from abstract state sharded as its
     loop shards it. Returns (kernels, bytes per chip), having checked
-    that the lowered step calls each flash kernel ONCE: the layer scan's
+    that the traced step holds the head's three products and no fourth,
+    and that the lowered step calls each flash kernel ONCE: the layer scan's
     forward body holds flash_fwd and its backward body flash_dq and
     flash_dkv, because remat keeps flash_fwd's output and row sums
     (llama.REMAT_SAVED). A second flash_fwd means a name no longer
@@ -163,8 +165,13 @@ def _compile_train_step(devices, *, chips, n_layers, batch, seq=2048):
     params, opt_state = _abstract(
         jax.eval_shape(init, jax.random.PRNGKey(0)), shardings)
     tokens = _on(mesh, P(("data", "fsdp")), (batch, seq), jnp.int32)
-    lowered = jax.jit(train_step, donate_argnums=(0, 1)).lower(
+    traced = jax.jit(train_step, donate_argnums=(0, 1)).trace(
         params, opt_state, tokens, tokens)
+    # the head's products: a chunk's logits, dH and dW, all in the loss's
+    # forward rule (llama._chunked_nll_fwd); a fourth is a chunk's logits
+    # computed again
+    assert vocab_products(traced.jaxpr.jaxpr, cfg.vocab_size) == 3
+    lowered = traced.lower()
     text = lowered.as_text()
     assert [text.count(f'kernel_name = "{k}"') for k in
             ("flash_fwd", "flash_dq", "flash_dkv")] == [1, 1, 1]
