@@ -437,9 +437,10 @@ class IOLoop:
                                 ("waker", None))
         self._thread = threading.Thread(target=self._run, name=name,
                                         daemon=True)
-        # Opt-in runtime enforcement (RAY_TPU_THREADGUARD=1): a stall
-        # watchdog samples this thread's stack when one dispatch pass
-        # exceeds RAY_TPU_THREADGUARD_STALL_S.
+        # Opt-in runtime enforcement (RAY_TPU_THREADGUARD=1): this
+        # thread's busy window becomes a probe of the process's stall
+        # watch, which reports its stack when one dispatch pass exceeds
+        # RAY_TPU_THREADGUARD_STALL_S.
         self._guard = (threadguard.LoopStallWatchdog(self._thread)
                        if threadguard.enabled() else None)
         self._thread.start()

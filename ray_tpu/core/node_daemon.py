@@ -479,6 +479,8 @@ def main(argv=None) -> None:
         labels=json.loads(args.labels) or None,
         object_store_memory=args.object_store_memory,
         session_dir=args.session_dir)
+    from ray_tpu.util import flight_recorder
+    flight_recorder.start_stall_watch("node")
     daemon.serve_forever()
 
 

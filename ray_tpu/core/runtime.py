@@ -116,6 +116,9 @@ class DriverRuntime:
         # when cfg.flight_recorder_enabled.
         from ray_tpu.util import flight_recorder
         flight_recorder.init_driver()
+        # ... and the process's stall watch: always on, started once,
+        # it outlives the session.
+        flight_recorder.start_stall_watch("driver")
         # Same idea for the lifetime sanitizer: fresh collector per
         # session, ledger enabled iff RAY_TPU_REFSAN is exported.
         refsan.init_driver()

@@ -652,6 +652,9 @@ def worker_main(socket_path: str, node_id_hex: str, worker_id_hex: str,
     # driver turned it on (flag rides the inherited environment).
     from ray_tpu.util import flight_recorder
     flight_recorder.init_worker(rt, worker_id)
+    # The process's stall watch, always on: a worker that becomes a
+    # serve replica or a train worker has one by construction.
+    flight_recorder.start_stall_watch("worker")
     # Lifetime sanitizer: same inherit-the-env contract — the ledger and
     # its push flusher start only when the driver exported RAY_TPU_REFSAN.
     refsan.init_worker(rt, worker_id)

@@ -12,12 +12,13 @@ runtime half, in the locktrace mold:
 * ``@loop_owned("attr", ...)`` — class decorator declaring which
   attributes are loop-thread-only. Purely declarative: it feeds the
   static GL011 rule and documentation; no runtime wrapping.
-* ``LoopStallWatchdog`` — samples the loop thread's stack via
-  ``sys._current_frames`` whenever a dispatch exceeds
-  ``RAY_TPU_THREADGUARD_STALL_S`` (default 1.0s), reporting the
-  blocking frame so GL009 escapes get caught live. Wired up by
-  ``IOLoop`` itself when threadguard is enabled; it only logs and
-  records, never raises.
+* ``LoopStallWatchdog`` — a dispatch that exceeds
+  ``RAY_TPU_THREADGUARD_STALL_S`` (default 1.0s) is reported with the
+  loop thread's stack, so GL009 escapes get caught live. It is a probe
+  of the process's stall watch (``util/flight_recorder.py``), which is
+  the one thread that polls and the one place that samples a stack.
+  Wired up by ``IOLoop`` itself when threadguard is enabled; it only
+  logs and records, never raises.
 
 Enable with::
 
@@ -25,7 +26,7 @@ Enable with::
     RAY_TPU_THREADGUARD=1 RAY_TPU_THREADGUARD_STALL_S=0.25 pytest ...
 
 Like everything in devtools, importing this module must stay cheap:
-no jax, no runtime imports.
+no jax, no runtime imports (the flight recorder is stdlib and metrics).
 """
 
 from __future__ import annotations
@@ -33,11 +34,11 @@ from __future__ import annotations
 import functools
 import logging
 import os
-import sys
 import threading
 import time
-import traceback
 from typing import Callable, List, Optional
+
+from ray_tpu.util import flight_recorder
 
 logger = logging.getLogger(__name__)
 
@@ -146,14 +147,14 @@ def loop_owned(*names: str):
 
 
 class LoopStallWatchdog:
-    """Samples a loop thread's stack when one dispatch runs too long.
+    """Reports a loop thread's stack when one dispatch runs too long.
 
     The loop publishes busy-ness via ``enter()``/``exit_busy()`` around
-    each batch of work (callbacks, handlers, timers). A daemon watcher
-    thread polls at stall_s/4; when the busy window exceeds
-    ``stall_s`` it formats the loop thread's current stack from
-    ``sys._current_frames`` and appends a report (one per stall
-    episode). It never raises into the loop."""
+    each batch of work (callbacks, handlers, timers). That busy window
+    is a probe of the process's stall watch: when one has lasted
+    ``stall_s`` the watch hands over the loop thread's stack and a
+    report is appended (one per stall episode). It never raises into
+    the loop."""
 
     def __init__(self, thread: threading.Thread,
                  stall_s: Optional[float] = None):
@@ -161,12 +162,10 @@ class LoopStallWatchdog:
         self._stall_s = stall_s if stall_s is not None \
             else stall_default_s()
         self._busy_since: Optional[float] = None
-        self._reported_for: Optional[float] = None
-        self._stop_evt = threading.Event()
-        self._watcher = threading.Thread(
-            target=self._watch, name="rtpu-threadguard-watchdog",
-            daemon=True)
-        self._watcher.start()
+        self._probe = flight_recorder.add_probe(
+            "io_loop", self._busy, lambda: thread.ident,
+            threshold_s=self._stall_s, on_held=self._report)
+        flight_recorder.start_stall_watch()
 
     # called from the loop thread only
     def enter(self) -> None:
@@ -176,38 +175,27 @@ class LoopStallWatchdog:
         self._busy_since = None
 
     def stop(self) -> None:
-        self._stop_evt.set()
+        flight_recorder.remove_probe(self._probe)
 
-    def _watch(self) -> None:
-        interval = max(0.01, self._stall_s / 4.0)
-        while not self._stop_evt.wait(interval):
-            if self._thread.ident is None:
-                continue    # loop thread not started yet
-            if not self._thread.is_alive():
-                return
-            t0 = self._busy_since
-            if t0 is None or t0 == self._reported_for:
-                continue
-            stalled = time.monotonic() - t0
-            if stalled < self._stall_s:
-                continue
-            frame = sys._current_frames().get(self._thread.ident)
-            stack = "".join(traceback.format_stack(frame)) if frame \
-                else "<no frame available>"
-            report = {
-                "thread": self._thread.name,
-                "ident": self._thread.ident,
-                "stalled_s": stalled,
-                "stack": stack,
-            }
-            with _reports_lock:
-                _reports.append(report)
-            logger.warning(
-                "threadguard: IO loop thread %r busy for %.3fs "
-                "(> %.3fs stall threshold); current stack:\n%s",
-                self._thread.name, stalled, self._stall_s, stack)
-            # one report per stall episode, keyed by its start stamp
-            self._reported_for = t0
+    # called from the stall watch's thread only
+    def _busy(self) -> Optional[tuple]:
+        t0 = self._busy_since
+        return None if t0 is None else ("dispatch", t0)
+
+    def _report(self, episode: dict) -> None:
+        stack = "".join(episode["stack"])
+        report = {
+            "thread": self._thread.name,
+            "ident": self._thread.ident,
+            "stalled_s": episode["seconds"],
+            "stack": stack,
+        }
+        with _reports_lock:
+            _reports.append(report)
+        logger.warning(
+            "threadguard: IO loop thread %r busy for %.3fs "
+            "(> %.3fs stall threshold); current stack:\n%s",
+            self._thread.name, episode["seconds"], self._stall_s, stack)
 
 
 def stall_reports() -> List[dict]:

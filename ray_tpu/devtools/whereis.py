@@ -63,6 +63,9 @@ def attribution(journals: Optional[Dict[str, List[tuple]]] = None
              "weight_sync": 0, "replay_wait": 0}
     rl_env_steps = 0
     rl_seen = False
+    # the stall watch's episodes (category ``proc``), by journal:
+    # "frozen" / "starved" / "held:<thread>/<phase>" -> [count, seconds]
+    stalls: Dict[str, Dict[str, List[float]]] = {}
     t_lo: Optional[int] = None
     t_hi: Optional[int] = None
 
@@ -93,6 +96,14 @@ def attribution(journals: Optional[Dict[str, List[tuple]]] = None
                 coll_wire += int(a.get("wire", 0))
                 if "ratio" in (a or {}):
                     coll_ratios.append(float(a["ratio"]))
+            elif cat == "proc":
+                a = args or {}
+                what = (f"held:{a.get('thread')}/{a.get('phase')}"
+                        if name == "thread.held" else a.get("kind"))
+                entry = stalls.setdefault(label, {}).setdefault(
+                    what, [0, 0.0])
+                entry[0] += 1
+                entry[1] = round(entry[1] + dur / 1e9, 6)
             elif cat == "rl":
                 rl_seen = True
                 a = args or {}
@@ -194,6 +205,7 @@ def attribution(journals: Optional[Dict[str, List[tuple]]] = None
                             round(sum(coll_ratios) / len(coll_ratios),
                                   3) if coll_ratios else None)},
         "rl": rl_report,
+        "stalls": stalls,
     }
 
 
@@ -374,6 +386,10 @@ def render(report: Dict[str, Any]) -> str:
         if "env_steps_per_sec" in rl:
             line += f"  ({rl['env_steps_per_sec']:.0f} steps/s)"
         lines.append(line)
+    for label, kinds in sorted((report.get("stalls") or {}).items()):
+        lines.append(f"  stalls of {label}: " + "  ".join(
+            f"{what} x{n} {seconds * 1e3:.0f}ms"
+            for what, (n, seconds) in sorted(kinds.items())))
     return "\n".join(lines)
 
 

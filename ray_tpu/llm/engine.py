@@ -230,7 +230,8 @@ class _StepperAccount:
     stretches in which that clock is read, and ``cpu_wall`` the wall
     seconds of the same stretches. Only the stepper writes (a switch
     charges what passed to the phase it leaves); the metrics buffer's
-    flush copies the lists."""
+    flush copies the lists and the process's stall watch reads
+    ``probe``."""
 
     __slots__ = ("clock", "cpu_clock", "cpu_every", "wall", "cpu",
                  "cpu_wall", "phase", "thread", "prefills", "t", "_c",
@@ -258,6 +259,13 @@ class _StepperAccount:
             if self.thread is None:
                 self.t = self.clock()
             self.thread, self._reading = ident, False
+
+    def probe(self) -> Optional[tuple]:
+        """For the stall watch, on its thread: the phase the stepper is
+        in and the clock at its last switch, None while it waits for
+        work. Two loads; the stepper writes nothing for it."""
+        phase = self.phase
+        return None if phase == _WAIT else (STEPPER_PHASES[phase], self.t)
 
     def switch(self, phase: int) -> None:
         t, before = self.clock(), self.phase
@@ -337,6 +345,8 @@ class _MetricsBuffer(_metrics.LocalBuffer):
         self._flush_lock = locktrace.traced_lock("llm.engine.flush")
         self._thread: Optional[threading.Thread] = None
         self._stop = threading.Event()
+        # the stall watch's counts ride this buffer's flushes
+        _flight.stall_carriers.add(self)
 
     def note_step(self, phase: str, dt: float, host_dt: float,
                   upload_dt: float, tokens: int) -> None:
@@ -366,6 +376,9 @@ class _MetricsBuffer(_metrics.LocalBuffer):
         engine = self._engine()
         with self._flush_lock:
             now = time.perf_counter()
+            stalls = _flight.take_stall_counts()
+            if stalls:
+                self.merge(stalls)
             if engine is not None and (force or self._pending.histograms
                                        or self._pending.counters):
                 elapsed = now - self._last_flush
@@ -412,6 +425,7 @@ class _MetricsBuffer(_metrics.LocalBuffer):
 
     def close(self) -> None:
         """Stop the flush thread after one last flush."""
+        _flight.stall_carriers.discard(self)
         self._stop.set()
         thread = self._thread
         if thread is not None and thread is not threading.current_thread():
@@ -882,7 +896,13 @@ class ContinuousBatchingEngine:
         # step() calls so far: the number an engine.step span and its
         # child spans share
         self._steps = 0
-        self._account = _StepperAccount()
+        self._account = account = _StepperAccount()
+        # the process's stall watch looks at the account each tick: a
+        # stepper that sits in one phase past its threshold is a
+        # ``thread.held`` episode (the probe keeps the account alone)
+        probe = _flight.add_probe("stepper", account.probe,
+                                  lambda: account.thread)
+        weakref.finalize(self, _flight.remove_probe, probe)
         # multi-LoRA bank: slot 0 is the all-zero base adapter, so
         # "no adapter" needs no conditional in the decode program
         self._adapters: Dict[str, int] = {}
@@ -2423,6 +2443,9 @@ class ContinuousBatchingEngine:
                     for (kind, _, phases), seconds in zip(
                         _STEPPER_FAMILIES, self._mbuf.stepper_seconds)},
                 "stepper_read_at": self._mbuf._last_flush,
+                # the process's last stall episodes (the stall watch's
+                # ring: flight_recorder.StallWatch has the keys)
+                "stalls": _flight.stalls(_flight.STALL_STACK_FRAMES),
             }
             if self._state_layers:
                 # slots x recurrent layers of the dense decode steps

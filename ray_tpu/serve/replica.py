@@ -17,7 +17,7 @@ import time
 from typing import Any, Dict, Optional
 
 from ray_tpu.core import serialization
-from ray_tpu.util import tracing
+from ray_tpu.util import flight_recorder, tracing
 from ray_tpu.util.metrics import Counter, Gauge, Histogram
 
 # Replica-side instrumentation (reference: replica request metrics
@@ -55,6 +55,8 @@ class Replica:
                  multiplex_max_models: int = 3):
         self.deployment_name = deployment_name
         self.replica_id = replica_id
+        # this worker's stall watch speaks as a replica from here on
+        flight_recorder.rename_worker("replica")
         cls_or_fn = serialization.loads(callable_blob)
         init_args, init_kwargs = serialization.loads(init_args_blob)
         if isinstance(cls_or_fn, type):

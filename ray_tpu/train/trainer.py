@@ -30,6 +30,7 @@ from ray_tpu.core import serialization
 from ray_tpu.exceptions import ActorError, RayTpuError, TaskError, WorkerCrashedError
 from ray_tpu.train.checkpoint import Checkpoint, CheckpointManager
 from ray_tpu.train.config import Result, RunConfig, ScalingConfig
+from ray_tpu.util import flight_recorder
 from ray_tpu.util.placement_group import placement_group, remove_placement_group
 
 logger = logging.getLogger(__name__)
@@ -47,6 +48,8 @@ class _TrainWorker:
                  use_tpu: bool = False):
         self.rank = rank
         self.world_size = world_size
+        # this worker's stall watch speaks as a train worker from here on
+        flight_recorder.rename_worker("train_worker")
         self.storage_path = storage_path
         self.group_name = group_name
         self.grad_compression = grad_compression

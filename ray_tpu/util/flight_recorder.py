@@ -49,17 +49,32 @@ dispatch / stream chunks), ``object`` (put/get/transfer), ``pipeline``
 ``collective`` (allreduce &co with compression ratio), ``serve``
 (engine steps, their phases as ``span``s, request queue waits), ``rl``
 (podracer spans: rollout / infer_batch / replay_wait / learn_step /
-weight_push).
+weight_push), ``proc`` (the stall watch's ``process.stall`` and
+``thread.held``).
+
+4. **Stall watch** (every process, always on) — one daemon thread,
+   ``rtpu-stall-watch``, that says when the process did not run
+   (``frozen``: nothing in it ran; ``starved``: some thread ran and the
+   watch could not) and when a thread that owns a loop sat in one place
+   (``held``; the loop registers a *probe*). See ``StallWatch``.
 """
 
 from __future__ import annotations
 
 import itertools
+import logging
 import os
+import sys
 import threading
 import time
+import traceback
+import weakref
 from collections import deque
-from typing import Any, Dict, List, Optional, Tuple
+from typing import Any, Callable, Dict, List, Optional, Tuple
+
+from ray_tpu.util import metrics as _metrics
+
+logger = logging.getLogger(__name__)
 
 DEFAULT_CAPACITY = 4096
 # events kept per remote process in the driver-side collector
@@ -579,3 +594,388 @@ def store_tail_text(label_substr: str, n: int = TAIL_EVENTS) -> str:
         return ""
     lines = store.tail(label_substr, n)
     return tail_text(lines) if lines else ""
+
+
+# --- stall watch ---------------------------------------------------------
+# One daemon thread a process. It sleeps a tick and, on waking, says
+# two things the spans cannot: that the process did not run (its own
+# wake came late), and that a thread which owns a loop sat in one place
+# (a probe named the same ``since`` for too long). It takes no lock
+# that a hot path takes, and a probe is read, never written.
+#
+# What held the interpreter DURING a stop only a thread that needs no
+# interpreter lock could say, and ``faulthandler.dump_traceback_later``
+# is one; it is left out on purpose. Its C thread reads the other
+# threads' frames while they run (after a frozen process thaws they all
+# do), and on this interpreter (3.12) that is a segmentation fault in
+# under a thousand firings against busy threads; on the chip it killed
+# the Jamba cell's check worker in 5 runs of 10 (PR 63, PERF.md). A
+# ``starved`` episode carries instead where every thread stands right
+# after it, taken under the lock like any stack here.
+
+STALL_TICK_S = 0.020        # the watch's sleep
+STALL_LATE_S = 0.100        # a wake this late is an episode
+STALL_HELD_S = 0.250        # a probe naming one ``since`` this long is held
+STALL_AFTER_S = 1.0         # a starved one this long: where threads stand
+STALL_RING = 32             # episodes kept in the process
+STALL_STACK_FRAMES = 12     # frames of a stack in stats() and the journal
+_TICK_NS, _LATE_NS = int(STALL_TICK_S * 1e9), int(STALL_LATE_S * 1e9)
+
+PROCESS_STALL_SECONDS = _metrics.Counter(
+    "ray_tpu_process_stall_seconds_total",
+    "Seconds in which the process did not run: its stall watch woke "
+    "that much later than its tick, by the process's role",
+    tag_keys=("process",))
+PROCESS_STALLS = _metrics.Counter(
+    "ray_tpu_process_stalls_total",
+    "Episodes in which the process did not run, by role and kind: "
+    "frozen (its CPU clock stood still: the machine, the sandbox, a "
+    "stop signal) or starved (some thread ran and the watch could not: "
+    "the interpreter lock held through one long call, or no core)",
+    tag_keys=("process", "kind"))
+THREAD_HELD_SECONDS = _metrics.Counter(
+    "ray_tpu_thread_held_seconds_total",
+    "Seconds a thread that owns a loop sat in one place past the "
+    "watch's threshold while the process ran, by role and thread",
+    tag_keys=("process", "thread"))
+THREAD_HELD = _metrics.Counter(
+    "ray_tpu_thread_held_total",
+    "Episodes of a thread held in one place, by role, thread and the "
+    "phase it sat in",
+    tag_keys=("process", "thread", "phase"))
+
+
+class _Probe:
+    """A loop's word to the watch: ``read()`` gives ``(what, since)``
+    while the loop's thread is in some place since ``since`` (any value
+    that changes when the place does) and None while it idles;
+    ``ident()`` gives that thread's ident."""
+
+    __slots__ = ("thread", "read", "ident", "threshold_ns", "on_held",
+                 "since", "seen_ns", "episode", "skip")
+
+    def __init__(self, thread: str, read: Callable[[], Optional[tuple]],
+                 ident: Callable[[], Optional[int]], threshold_s: float,
+                 on_held: Optional[Callable[[dict], None]]):
+        self.thread, self.read, self.ident = thread, read, ident
+        self.threshold_ns = int(threshold_s * 1e9)
+        self.on_held = on_held
+        self.since: Any = None
+        self.seen_ns = 0            # the watch's clock when it first saw it
+        self.episode: Optional[dict] = None
+        self.skip = False           # this ``since`` has no thread to show
+
+
+class StallWatch:
+    """The process's stall watch. ``tick()`` is one turn of its thread:
+    sleep, then look. The clocks and the sleep can be given, so that a
+    test decides what passed.
+
+    An episode is one dict: ``name`` (``process.stall`` / ``thread.held``),
+    ``kind`` (``frozen`` / ``starved`` / ``held``), ``process`` (the
+    role), ``pid``, ``t0_ns`` (the journal's clock), ``epoch_s``
+    (``time.time()`` at its start), ``seconds``; a process episode adds
+    ``cpu_s`` (the process's CPU seconds over the late sleep),
+    ``cpu_usual_s`` (what a tick on time had taken just before) and,
+    where it is ``starved`` for a second or more, ``after`` (thread name
+    -> its three innermost frames at the wake: the one that held the
+    interpreter has just come out of its call); a held one ``thread``,
+    ``phase``, ``stack`` (the frames of the thread when the threshold
+    passed, outermost first) and ``open`` while it lasts."""
+
+    def __init__(self, role: str = "driver",
+                 clock: Callable[[], int] = clock_ns,
+                 cpu_clock: Callable[[], float] = time.process_time,
+                 wall: Callable[[], float] = time.time,
+                 sleep: Callable[[float], Any] = time.sleep):
+        self.role = role
+        self.pid = os.getpid()
+        self._clock, self._cpu_clock = clock, cpu_clock
+        self._wall, self._sleep = wall, sleep
+        # replaced whole by add/remove, so a tick walks it with no lock
+        self._probes: Tuple[_Probe, ...] = ()
+        self._probes_lock = threading.Lock()    # its writers' alone
+        self._ring: deque = deque(maxlen=STALL_RING)
+        self._counts = _metrics.LocalBuffer()
+        self._unsent = False    # counts wait for ship() or a carrier
+        self._closed = False    # this tick closed an episode
+        self._cpu = cpu_clock()
+        self._cpu_usual = 0.0
+        self._thread: Optional[threading.Thread] = None
+
+    # -- the thread ------------------------------------------------------
+
+    def start(self) -> None:
+        if self._thread is not None and self._thread.is_alive():
+            return
+        self.pid = os.getpid()
+        self._cpu = self._cpu_clock()
+        self._thread = threading.Thread(
+            target=self._run, name="rtpu-stall-watch", daemon=True)
+        self._thread.start()
+
+    def _run(self) -> None:
+        while True:     # for the process's life: a daemon thread
+            try:
+                self.tick()
+            except Exception:  # noqa: BLE001 — the watch must outlive
+                logger.exception("stall watch: tick failed")  # its faults
+
+    def tick(self) -> None:
+        t_sleep = self._clock()
+        self._sleep(STALL_TICK_S)
+        now = self._clock()
+        cpu = self._cpu_clock()
+        grown, self._cpu = cpu - self._cpu, cpu
+        late_ns = now - t_sleep - _TICK_NS
+        if late_ns >= _LATE_NS:
+            self._process_stall(now - late_ns, late_ns, grown)
+            for probe in self._probes:
+                # the time the process did not run is no thread's stay
+                probe.seen_ns += late_ns
+        else:
+            self._cpu_usual = grown
+        for probe in self._probes:
+            self._look(probe, now)
+        if self._unsent and not stall_carriers:
+            self.ship()
+        if self._closed:
+            # what closing took (a log line, the ship) is no tick's
+            self._closed = False
+            self._cpu = self._cpu_clock()
+
+    # -- the process did not run -----------------------------------------
+
+    def _process_stall(self, t0_ns: int, late_ns: int, cpu_s: float) -> None:
+        seconds = late_ns / 1e9
+        # what the process's threads took beyond a usual tick's share
+        ran = cpu_s - self._cpu_usual
+        episode = self._episode(
+            "process.stall", "frozen" if ran < seconds / 10 else "starved",
+            t0_ns, seconds)
+        episode.update(cpu_s=cpu_s, cpu_usual_s=self._cpu_usual)
+        if episode["kind"] == "starved" and seconds >= STALL_AFTER_S:
+            episode["after"] = _where_threads_stand()
+        # the watch's thread alone appends
+        self._ring.append(episode)  # graftlint: disable=GL001
+        self._counts.inc(PROCESS_STALL_SECONDS, seconds,
+                         {"process": self.role})
+        self._counts.inc(PROCESS_STALLS, 1.0,
+                         {"process": self.role, "kind": episode["kind"]})
+        self._close(episode)
+        logger.warning(
+            "stall watch: %s pid %d did not run for %.3f s from %.3f "
+            "(%s: its threads took %.3f CPU s, %.3f in a usual tick)%s",
+            self.role, self.pid, seconds, episode["epoch_s"],
+            episode["kind"], cpu_s, self._cpu_usual,
+            "".join(f"\n  {name}: {where}" for name, where
+                    in episode.get("after", {}).items()))
+
+    # -- a thread sat in one place ---------------------------------------
+
+    def add_probe(self, thread: str, read, ident,
+                  threshold_s: float = STALL_HELD_S,
+                  on_held=None) -> _Probe:
+        probe = _Probe(thread, read, ident, threshold_s, on_held)
+        with self._probes_lock:
+            self._probes += (probe,)
+        return probe
+
+    def remove_probe(self, probe: _Probe) -> None:
+        with self._probes_lock:
+            self._probes = tuple(p for p in self._probes
+                                 if p is not probe)
+
+    def _look(self, probe: _Probe, now: int) -> None:
+        try:
+            read = probe.read()
+        except Exception:  # noqa: BLE001 — a probe's fault is not the
+            read = None    # watch's: it reads as idle
+        what, since = read if read is not None else (None, None)
+        if since is None or since != probe.since:
+            if probe.episode is not None:
+                self._close_held(probe, now)
+            probe.since, probe.seen_ns, probe.skip = since, now, False
+            return
+        stayed = now - probe.seen_ns
+        if probe.episode is not None:
+            probe.episode["seconds"] = stayed / 1e9
+        elif stayed >= probe.threshold_ns and not probe.skip:
+            self._open_held(probe, what, stayed)
+
+    def _open_held(self, probe: _Probe, what: str, stayed: int) -> None:
+        ident = probe.ident()
+        frame = sys._current_frames().get(ident)
+        if frame is None:
+            probe.skip = True   # its thread is gone: nobody is held
+            return
+        episode = self._episode("thread.held", "held", probe.seen_ns,
+                                stayed / 1e9)
+        episode.update(thread=probe.thread, phase=what,
+                       stack=traceback.format_stack(frame), open=True)
+        del frame
+        probe.episode = episode
+        # the watch's thread alone appends
+        self._ring.append(episode)  # graftlint: disable=GL001
+        if probe.on_held is not None:
+            try:
+                probe.on_held(episode)
+            except Exception:  # noqa: BLE001
+                logger.exception("stall watch: on_held of %s failed",
+                                 probe.thread)
+
+    def _close_held(self, probe: _Probe, now: int) -> None:
+        episode, probe.episode = probe.episode, None
+        seconds = episode["seconds"] = (now - probe.seen_ns) / 1e9
+        del episode["open"]
+        self._counts.inc(THREAD_HELD_SECONDS, seconds,
+                         {"process": self.role, "thread": probe.thread})
+        self._counts.inc(THREAD_HELD, 1.0,
+                         {"process": self.role, "thread": probe.thread,
+                          "phase": episode["phase"]})
+        self._close(episode)
+        logger.warning(
+            "stall watch: %s pid %d thread %s sat in %s for %.3f s from "
+            "%.3f while the process ran; its stack when %.3f s had "
+            "passed:\n%s", self.role, self.pid, probe.thread,
+            episode["phase"], seconds, episode["epoch_s"],
+            probe.threshold_ns / 1e9,
+            "".join(episode["stack"][-STALL_STACK_FRAMES:]))
+
+    # -- an episode's way out --------------------------------------------
+
+    def _episode(self, name: str, kind: str, t0_ns: int,
+                 seconds: float) -> dict:
+        return {"name": name, "kind": kind, "process": self.role,
+                "pid": self.pid, "t0_ns": t0_ns,
+                "epoch_s": self._wall() - (self._clock() - t0_ns) / 1e9,
+                "seconds": seconds}
+
+    def _close(self, episode: dict) -> None:
+        """A finished episode into the journal; its counts wait for
+        ``ship`` or for a carrier's flush."""
+        self._unsent = self._closed = True
+        rec = RECORDER
+        if rec is not None:
+            rec.record("proc", episode["name"], episode["t0_ns"],
+                       int(episode["seconds"] * 1e9),
+                       _journal_args(episode))
+
+    def ship(self) -> None:
+        """The counts of the episodes since the last ship as ONE
+        ``record_batch`` (from a worker one control-plane call, which
+        may take the channel's timeout where the channel is not up)."""
+        self._unsent = False
+        try:
+            self._counts.flush()
+        except Exception:  # graftlint: disable=GL004
+            pass  # observability is best-effort
+
+    def take_counts(self) -> List[tuple]:
+        """The unsent counts as ``record_batch`` items, for a carrier
+        that ships them with its own."""
+        self._unsent = False
+        return self._counts.drain()
+
+    def stalls(self, frames: Optional[int] = None) -> List[dict]:
+        out = []
+        for episode in list(self._ring):
+            episode = dict(episode)
+            if frames is not None and "stack" in episode:
+                episode["stack"] = episode["stack"][-frames:]
+            out.append(episode)
+        return out
+
+
+def _where_threads_stand() -> Dict[str, str]:
+    """Thread name -> its three innermost frames, innermost first; the
+    caller's own thread left out."""
+    names = {t.ident: t.name for t in threading.enumerate()}
+    me = threading.get_ident()
+    out = {}
+    for ident, frame in sys._current_frames().items():
+        if ident == me:
+            continue
+        frames = []
+        while frame is not None and len(frames) < 3:
+            code = frame.f_code
+            frames.append(
+                f"{code.co_filename}:{frame.f_lineno} {code.co_name}")
+            frame = frame.f_back
+        out[names.get(ident, str(ident))] = " <- ".join(frames)
+    return out
+
+
+def _journal_args(episode: dict) -> dict:
+    args = {k: v for k, v in episode.items()
+            if k not in ("name", "t0_ns", "stack")}
+    if "stack" in episode:
+        args["stack"] = "".join(episode["stack"][-STALL_STACK_FRAMES:])
+    return args
+
+
+# Buffers that ship the watch's counts with their own flush (the
+# engine's _MetricsBuffer: /v1/stats' flush then carries them, and the
+# watch's thread of a replica makes no control-plane call). While there
+# is none the watch ships an episode's counts itself.
+stall_carriers: "weakref.WeakSet" = weakref.WeakSet()
+
+_WATCH: Optional[StallWatch] = None
+_watch_lock = threading.Lock()
+
+
+def stall_watch() -> StallWatch:
+    """The process's one watch; made on first use, started by
+    ``start_stall_watch``."""
+    global _WATCH
+    watch = _WATCH
+    if watch is None:
+        with _watch_lock:
+            if _WATCH is None:
+                _WATCH = StallWatch()
+            watch = _WATCH
+    return watch
+
+
+def start_stall_watch(role: Optional[str] = None) -> StallWatch:
+    """Start the process's watch (once: a second call names the role
+    anew). ``role``: ``driver`` (what a process is until it says
+    otherwise), ``node``, ``worker``."""
+    watch = stall_watch()
+    if role is not None:
+        watch.role = role
+    watch.start()
+    return watch
+
+
+def rename_worker(role: str) -> None:
+    """A worker has become a ``replica`` or a ``train_worker``: its
+    later episodes say so. Any other process keeps its role (a replica
+    in serve's local mode lives in the driver)."""
+    watch = stall_watch()
+    if watch.role == "worker":
+        watch.role = role
+
+
+def add_probe(thread: str, read, ident, threshold_s: float = STALL_HELD_S,
+              on_held=None) -> _Probe:
+    return stall_watch().add_probe(thread, read, ident, threshold_s,
+                                   on_held)
+
+
+def remove_probe(probe: _Probe) -> None:
+    stall_watch().remove_probe(probe)
+
+
+def stalls(frames: Optional[int] = None) -> List[dict]:
+    """The process's last ``STALL_RING`` episodes, oldest first; a held
+    thread's is there from the moment the threshold passed (``open``)."""
+    return stall_watch().stalls(frames)
+
+
+def take_stall_counts() -> List[tuple]:
+    """What a carrier's flush takes with it: nothing, at the cost of
+    two loads, while no episode closed since the last."""
+    watch = _WATCH
+    return watch.take_counts() if watch is not None and watch._unsent \
+        else []

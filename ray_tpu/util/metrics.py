@@ -297,9 +297,18 @@ class LocalBuffer:
             tags: Optional[Dict[str, str]] = None) -> None:
         self._apply("gauge", gauge, value, tags)
 
+    def drain(self) -> List[tuple]:
+        """Take what gathered, as ``record_batch`` items, unshipped:
+        for a buffer that another buffer's flush carries."""
+        return self._pending.drain()
+
+    def merge(self, items) -> None:
+        """Take another buffer's drained items in among this one's."""
+        self._pending.apply_batch(items)
+
     def flush(self) -> List[tuple]:
         """Ship and forget what gathered; returns the items sent."""
-        items = self._pending.drain()
+        items = self.drain()
         record_batch(items)
         return items
 

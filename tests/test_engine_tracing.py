@@ -9,10 +9,12 @@ import threading
 import time
 import urllib.request
 
+import numpy as np
 import pytest
 
 import ray_tpu
 from ray_tpu import serve
+from ray_tpu.llm import engine as engine_mod
 from ray_tpu.llm.engine import (
     ContinuousBatchingEngine, EngineConfig, GenerationRequest)
 from ray_tpu.models.llama import LlamaConfig
@@ -229,9 +231,12 @@ def test_request_stages_add_up_and_host_time_is_inside_step_time():
 
 # -- (c2) how often the dense step's state stays on the device ------------
 
-def _counter(name):
+def _counter(name, **tags):
+    """One counter series of this process's registry, 0.0 if absent."""
+    labels = ",".join(f'{k}="{v}"' for k, v in sorted(tags.items()))
+    series = f"{name}{{{labels}}} " if labels else name + " "
     for line in prometheus_text().splitlines():
-        if line.startswith(name + " "):
+        if line.startswith(series):
             return float(line.split()[-1])
     return 0.0
 
@@ -609,6 +614,127 @@ def test_account_reaches_its_counter_families_and_stats():
     # a second flush with nothing new adds nothing
     engine.flush_metrics()
     assert _phase_series(STEPPER_CPU_WALL) == after
+
+
+# -- (d2) the stall watch reads the account and writes nothing ------------
+
+HELD_SECONDS = "ray_tpu_thread_held_seconds_total"
+HELD = "ray_tpu_thread_held_total"
+
+
+def test_a_slow_readback_is_a_held_episode_in_stats_and_the_counters(
+        monkeypatch):
+    """A tiny engine whose first read-back takes 0.4 s: the process's
+    watch (real clocks) reports the stepper held in ``blocked`` in
+    stats()["stalls"], with the stepper's stack, and flush_metrics()
+    carries the episode's counts."""
+    watch = flight_recorder.start_stall_watch()
+    engine = ContinuousBatchingEngine(_engine_config())
+    assert engine._mbuf in flight_recorder.stall_carriers
+    slept = []
+
+    def slow_asarray(a, real=engine_mod.np.asarray):
+        if not slept:
+            slept.append(True)
+            time.sleep(0.4)
+        return real(a)
+
+    # engine.np is numpy itself: patch the name the read-back calls
+    # through a stand-in module that has only ``asarray`` slowed
+    class _SlowNumpy:
+        def __getattr__(self, name):
+            return getattr(np, name)
+    slow_np = _SlowNumpy()
+    slow_np.asarray = slow_asarray
+    role = watch.role
+    seconds0 = _counter(HELD_SECONDS, process=role, thread="stepper")
+    count0 = _counter(HELD, process=role, thread="stepper",
+                         phase="blocked")
+    request = engine.add_request(GenerationRequest(
+        prompt_ids=[1, 2, 3, 4], max_tokens=3))
+    engine.step()           # compiles, so that the slow step is plain
+    monkeypatch.setattr(engine_mod, "np", slow_np)
+    while not request.done:
+        engine.step()
+    assert slept
+    monkeypatch.undo()
+    with engine.idling():   # ``since`` moves: the episode closes
+        deadline = time.monotonic() + 10.0
+        mine = []
+        while time.monotonic() < deadline and not mine:
+            time.sleep(0.05)
+            mine = [e for e in engine.stats()["stalls"]
+                    if e["kind"] == "held" and e["thread"] == "stepper"
+                    and e["phase"] == "blocked" and "open" not in e
+                    and "slow_asarray" in "".join(e["stack"])]
+    assert mine, engine.stats()["stalls"]
+    episode = mine[-1]
+    assert 0.3 <= episode["seconds"] <= 5.0 and episode["process"] == role
+    assert len(episode["stack"]) <= flight_recorder.STALL_STACK_FRAMES
+    assert "time.sleep(0.4)" in "".join(episode["stack"])
+    engine.flush_metrics()
+    assert _counter(HELD_SECONDS, process=role, thread="stepper") \
+        - seconds0 >= episode["seconds"] - 1e-6
+    assert _counter(HELD, process=role, thread="stepper",
+                       phase="blocked") - count0 >= 1.0
+    engine.close()
+    assert engine._mbuf not in flight_recorder.stall_carriers
+
+
+def test_the_watch_writes_nothing_into_the_stepper_account():
+    """The same scripted steps with a watch that looks at the account
+    at every turn and with none: the account's lists, its phase and the
+    count of clock reads are equal, so the probe neither writes nor
+    reads a clock; and a probe of a collected engine goes."""
+
+    def run(watched):
+        engine = ContinuousBatchingEngine(_engine_config())
+        account = engine._account
+        reads = _scripted(engine, cpu_every=1)
+        watch = flight_recorder.StallWatch(sleep=lambda seconds: None)
+        probe = watch.add_probe("stepper", account.probe,
+                                lambda: account.thread)
+        seen = []
+
+        def look():
+            if watched:
+                watch.tick()
+                seen.append(probe.since)
+
+        account.bind()
+        for _ in range(6):
+            with engine._span("engine.step"):
+                look()
+                with engine._span("engine.launch"):
+                    look()
+                with engine._span("engine.readback"):
+                    look()
+                with engine._span("engine.emit"):
+                    look()
+            with engine.idling():
+                look()
+                assert account.probe() is None      # idle in ``wait``
+        assert account.probe() == ("other", account.t)
+        return (list(account.wall), list(account.cpu),
+                list(account.cpu_wall), account.phase, account.t,
+                dict(reads)), seen
+
+    alone, _ = run(watched=False)
+    beside, seen = run(watched=True)
+    assert beside == alone
+    # the watch did look: it saw ``since`` move with every switch, and
+    # None in every wait
+    assert len(seen) == 30 and seen.count(None) == 6
+    assert len(set(seen)) == 25
+    # the engine's own probe lives as long as the engine
+    watch = flight_recorder.stall_watch()
+    before = len(watch._probes)
+    engine = ContinuousBatchingEngine(_engine_config())
+    assert len(watch._probes) == before + 1
+    assert watch._probes[-1].thread == "stepper"
+    del engine
+    gc.collect()
+    assert len(watch._probes) == before
 
 
 def test_span_without_recorder_or_profiler_is_inert():

@@ -280,3 +280,430 @@ def test_recorder_overhead_ratio_guard(ray_start_regular):
                                  lambda: fr.enable("driver:overhead"))
     finally:
         fr.RECORDER = saved
+
+
+# --- the stall watch (PR 63) -------------------------------------------
+# Clocks and the sleep are the test's: a loaded box can neither make an
+# episode nor hide one.
+
+class _Script:
+    """A watch whose clock, CPU clock and sleep a script advances:
+    ``tick(late_s, cpu_s)`` is one turn in which the sleep took the
+    tick and ``late_s`` more while the process's threads took ``cpu_s``
+    CPU seconds. While it lives the counts have a carrier (this),
+    so the watch ships none itself and ``counts()`` finds them."""
+
+    def __init__(self, role="worker"):
+        fr.stall_carriers.add(self)
+        self.now_ns, self.cpu = 5_000_000_000, 3.0
+        self.late_s = self.cpu_s = 0.0
+        self.watch = fr.StallWatch(
+            role, clock=lambda: self.now_ns, cpu_clock=lambda: self.cpu,
+            wall=lambda: 1_700_000_000.0 + self.now_ns / 1e9,
+            sleep=self._sleep)
+
+    def _sleep(self, seconds):
+        assert seconds == fr.STALL_TICK_S
+        self.now_ns += int((seconds + self.late_s) * 1e9)
+        self.cpu += self.cpu_s
+
+    def tick(self, late_s=0.0, cpu_s=0.0):
+        self.late_s, self.cpu_s = late_s, cpu_s
+        self.watch.tick()
+
+    def counts(self):
+        """{(series, tags): value} of what the watch has not shipped."""
+        return {(name, tags): value for _, name, tags, value, _
+                in self.watch.take_counts()}
+
+
+@pytest.mark.parametrize("late_s,cpu_s,kind", [
+    (2.0, 0.0, "frozen"),      # nothing in the process ran
+    (2.0, 0.15, "frozen"),     # under a tenth of the overshoot
+    (2.0, 1.9, "starved"),     # a thread ran and the watch could not
+    (0.5, 0.06, "starved"),
+    (0.099, 0.0, None),        # under 100 ms: no episode
+    (0.0, 0.02, None)])
+def test_late_wake_is_frozen_or_starved_by_the_cpu_clock(late_s, cpu_s,
+                                                         kind):
+    script = _Script()
+    script.tick()
+    t_due = script.now_ns + int(fr.STALL_TICK_S * 1e9)
+    script.tick(late_s, cpu_s)
+    episodes = script.watch.stalls()
+    if kind is None:
+        assert episodes == [] and script.counts() == {}
+        return
+    (ep,) = episodes
+    assert (ep["name"], ep["kind"], ep["process"]) == (
+        "process.stall", kind, "worker")
+    assert ep["seconds"] == pytest.approx(late_s, abs=1e-6)
+    assert ep["cpu_s"] == pytest.approx(cpu_s)
+    # it starts where the wake was due, on both clocks
+    assert abs(ep["t0_ns"] - t_due) <= 1
+    assert ep["epoch_s"] == pytest.approx(1_700_000_000.0 + t_due / 1e9,
+                                          abs=1e-3)
+    assert ep["pid"] > 0
+    # a starved second or more says where the threads stand after it
+    assert ("after" in ep) == (kind == "starved" and late_s >= 1.0)
+    assert script.counts() == {
+        ("ray_tpu_process_stall_seconds_total",
+         (("process", "worker"),)): pytest.approx(late_s, abs=1e-6),
+        ("ray_tpu_process_stalls_total",
+         (("kind", kind), ("process", "worker"))): 1.0}
+
+
+def test_a_busy_process_frozen_is_not_read_as_starved():
+    """Thirteen busy cores take 0.26 CPU s in a tick that is on time:
+    the same 0.26 s in a wake 2 s late is the tick's usual share, and
+    nothing ran in the 2 s."""
+    script = _Script()
+    for _ in range(3):
+        script.tick(0.0, 0.26)
+    script.tick(2.0, 0.26)
+    (ep,) = script.watch.stalls()
+    assert ep["kind"] == "frozen"
+    assert (ep["cpu_s"], ep["cpu_usual_s"]) == (
+        pytest.approx(0.26), pytest.approx(0.26))
+
+
+def _parked_thread():
+    """A thread that sits in ``_parked_here`` until released."""
+    import threading
+    release, started = threading.Event(), threading.Event()
+
+    def _parked_here():
+        started.set()
+        release.wait(30.0)
+
+    thread = threading.Thread(target=_parked_here, daemon=True)
+    thread.start()
+    assert started.wait(5.0)
+    return thread, release
+
+
+def test_probe_past_the_threshold_is_one_held_episode_of_the_whole_stay():
+    script = _Script(role="replica")
+    thread, release = _parked_thread()
+    place = {"now": None}
+    heard = []
+    script.watch.add_probe("stepper", lambda: place["now"],
+                           lambda: thread.ident, on_held=heard.append)
+    try:
+        script.tick()
+        place["now"] = ("blocked", 41.5)
+        script.tick()                       # first seen here
+        t_seen = script.now_ns
+        for _ in range(12):                 # 240 ms: under the threshold
+            script.tick()
+        assert script.watch.stalls() == [] and heard == []
+        script.tick()                       # 260 ms
+        (ep,) = script.watch.stalls()
+        assert ep["open"] is True and heard == [script.watch._ring[0]]
+        assert (ep["kind"], ep["thread"], ep["phase"], ep["process"]) == (
+            "held", "stepper", "blocked", "replica")
+        # the stack is the probed thread's, taken once
+        assert "_parked_here" in "".join(ep["stack"])
+        assert "release.wait(30.0)" in "".join(ep["stack"])
+        for _ in range(187):                # it stays 4 s in all
+            script.tick()
+        assert len(script.watch.stalls()) == 1 and len(heard) == 1
+        assert script.counts() == {}        # nothing counted while open
+        place["now"] = ("launch", 45.5)     # ``since`` moves: it closes
+        script.tick()
+    finally:
+        release.set()
+    (ep,) = script.watch.stalls()
+    assert "open" not in ep and ep["t0_ns"] == t_seen
+    assert ep["seconds"] == pytest.approx(201 * fr.STALL_TICK_S, abs=1e-6)
+    assert ep["phase"] == "blocked" and len(ep["stack"]) >= 1
+    assert script.counts() == {
+        ("ray_tpu_thread_held_seconds_total",
+         (("process", "replica"), ("thread", "stepper"))):
+        pytest.approx(ep["seconds"]),
+        ("ray_tpu_thread_held_total",
+         (("phase", "blocked"), ("process", "replica"),
+          ("thread", "stepper"))): 1.0}
+    # the next place starts its own count: 240 ms more is no episode
+    for _ in range(12):
+        script.tick()
+    assert len(script.watch.stalls()) == 1
+
+
+def test_idle_probe_and_a_probe_whose_thread_is_gone_give_none():
+    script = _Script()
+    thread, release = _parked_thread()
+    release.set()
+    thread.join(5.0)
+    reads = []
+
+    def idle():
+        reads.append(script.now_ns)
+        return None
+
+    script.watch.add_probe("stepper", idle, lambda: 0)
+    gone = script.watch.add_probe("io_loop", lambda: ("dispatch", 7.0),
+                                  lambda: thread.ident)
+    for _ in range(40):
+        script.tick()
+    assert len(reads) == 40 and script.watch.stalls() == []
+    assert script.counts() == {}
+    script.watch.remove_probe(gone)
+    assert [p.thread for p in script.watch._probes] == ["stepper"]
+
+
+def test_probes_come_and_go_under_a_ticking_watch():
+    """Eight threads add and remove probes while the watch ticks as
+    fast as it can: no probe is lost and none is left."""
+    import sys
+    import threading
+    script = _Script()
+    kept, errors, done = [], [], threading.Event()
+
+    def churn(k):
+        try:
+            for i in range(200):
+                probe = script.watch.add_probe(
+                    f"t{k}", lambda: None, lambda: None)
+                if i % 50 == 0:
+                    kept.append(probe)
+                else:
+                    script.watch.remove_probe(probe)
+        except Exception as exc:  # noqa: BLE001
+            errors.append(exc)
+
+    def ticking():
+        while not done.is_set():
+            script.tick()
+
+    was = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        ticker = threading.Thread(target=ticking, daemon=True)
+        ticker.start()
+        threads = [threading.Thread(target=churn, args=(k,), daemon=True)
+                   for k in range(8)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(60.0)
+        done.set()
+        ticker.join(10.0)
+    finally:
+        sys.setswitchinterval(was)
+    assert not errors and not ticker.is_alive()
+    assert not any(t.is_alive() for t in threads)
+    assert len(kept) == 32
+    assert sorted(map(id, script.watch._probes)) == sorted(map(id, kept))
+    assert script.watch.stalls() == []
+
+
+def test_the_time_a_process_did_not_run_is_no_threads_stay():
+    """A stepper 200 ms into a phase when the process stops for 2 s:
+    one frozen episode, no held one, and its stay goes on from 200 ms."""
+    script = _Script(role="replica")
+    thread, release = _parked_thread()
+    script.watch.add_probe("stepper", lambda: ("blocked", 1.0),
+                           lambda: thread.ident)
+    try:
+        for _ in range(11):
+            script.tick()                   # seen for 200 ms
+        script.tick(2.0, 0.0)
+        assert [e["kind"] for e in script.watch.stalls()] == ["frozen"]
+        script.tick()                       # 240 ms of its own
+        assert len(script.watch.stalls()) == 1
+        script.tick()                       # 260 ms
+        assert [e["kind"] for e in script.watch.stalls()] == [
+            "frozen", "held"]
+    finally:
+        release.set()
+
+
+def test_counters_ring_and_journal_agree(fresh_recorder):
+    rec = fr.enable("test:stalls", capacity=64)
+    script = _Script(role="train_worker")
+    thread, release = _parked_thread()
+    place = {"now": ("upload", 1.0)}
+    script.watch.add_probe("stepper", lambda: place["now"],
+                           lambda: thread.ident)
+    try:
+        script.tick()
+        script.tick(0.3, 0.0)
+        script.tick(1.2, 1.1)
+        for _ in range(20):
+            script.tick()
+        place["now"] = None
+        script.tick()
+        script.tick(0.7, 0.0)
+    finally:
+        release.set()
+    ring = script.watch.stalls()
+    assert [e["kind"] for e in ring] == ["frozen", "starved", "held",
+                                         "frozen"]
+    counts = script.counts()
+    role = ("process", "train_worker")
+    stalled = [e for e in ring if e["name"] == "process.stall"]
+    assert counts[("ray_tpu_process_stall_seconds_total", (role,))] == \
+        pytest.approx(sum(e["seconds"] for e in stalled))
+    assert counts[("ray_tpu_process_stalls_total",
+                   (("kind", "frozen"), role))] == 2.0
+    assert counts[("ray_tpu_process_stalls_total",
+                   (("kind", "starved"), role))] == 1.0
+    assert counts[("ray_tpu_thread_held_seconds_total",
+                   (role, ("thread", "stepper")))] == \
+        pytest.approx(ring[2]["seconds"])
+    assert len(counts) == 5 and script.counts() == {}
+    # the journal has each under ``proc``, on the journal's clock
+    events = [ev for ev in rec.snapshot() if ev[3] == "proc"]
+    # (in the order they closed)
+    assert [(ev[4], ev[5]["kind"]) for ev in events] == [
+        ("process.stall", "frozen"), ("process.stall", "starved"),
+        ("thread.held", "held"), ("process.stall", "frozen")]
+    for ev in events:
+        ep = next(e for e in ring if e["t0_ns"] == ev[1])
+        assert ev[2] == int(ep["seconds"] * 1e9)
+        assert ev[5]["process"] == "train_worker" and "t0_ns" not in ev[5]
+    held = next(ev for ev in events if ev[4] == "thread.held")
+    assert held[5]["thread"] == "stepper" and held[5]["phase"] == "upload"
+    assert "_parked_here" in held[5]["stack"]
+    # and the timeline shows them on the process's track
+    names = {e["name"] for e in fr.chrome_events()
+             if e.get("cat") == "flight:proc"}
+    assert names == {"process.stall", "thread.held"}
+    # ... and whereis sums them by journal and kind
+    from ray_tpu.devtools import whereis
+    report = whereis.attribution(fr.merged_journals())
+    assert report["stalls"] == {"test:stalls": {
+        "frozen": [2, pytest.approx(1.0)],
+        "starved": [1, pytest.approx(1.2)],
+        "held:stepper/upload": [1, pytest.approx(ring[2]["seconds"],
+                                                 abs=1e-6)]}}
+    assert "stalls of test:stalls: frozen x2 1000ms" in \
+        whereis.render(report)
+    # the ring keeps the last 32
+    for _ in range(40):
+        script.tick(0.2, 0.0)
+    assert len(script.watch.stalls()) == fr.STALL_RING == 32
+
+
+def test_with_no_carrier_the_watch_ships_an_episodes_counts_itself(
+        monkeypatch):
+    import weakref
+
+    from ray_tpu.util import metrics
+    script = _Script(role="node")
+    monkeypatch.setattr(fr, "stall_carriers", weakref.WeakSet())
+    batches = []
+    real = metrics.record_batch
+    monkeypatch.setattr(metrics, "record_batch",
+                        lambda items: (batches.append(items), real(items)))
+    for _ in range(5):
+        script.tick()
+    assert batches == []            # nothing while nothing happens
+    script.tick(0.25, 0.0)
+    script.tick()
+    assert len(batches) == 1        # one record_batch an episode
+    assert script.counts() == {}
+    lines = [line for line in metrics.prometheus_text().splitlines()
+             if 'process="node"' in line]
+    assert sorted(line.split("{")[0] for line in lines) == [
+        "ray_tpu_process_stall_seconds_total",
+        "ray_tpu_process_stalls_total"]
+    for name in ("ray_tpu_process_stall_seconds_total",
+                 "ray_tpu_process_stalls_total"):
+        metrics.remove_series(name, {"process": "node"})
+    metrics.remove_series("ray_tpu_process_stalls_total",
+                          {"process": "node", "kind": "frozen"})
+
+
+def test_a_starved_second_says_where_the_threads_stand_after_it():
+    """What held the interpreter during a stop no thread under the
+    lock can see; right after it the holder has just come out of its
+    call, and the episode keeps every thread's three innermost frames."""
+    script = _Script()
+    thread, release = _parked_thread()
+    thread.name = "parked-for-the-test"
+    try:
+        script.tick()
+        script.tick(1.6, 1.5)
+        script.tick(0.5, 0.4)       # too short to be worth the stacks
+    finally:
+        release.set()
+    long, short = script.watch.stalls()
+    assert (long["kind"], short["kind"]) == ("starved", "starved")
+    assert "after" not in short
+    where = long["after"]["parked-for-the-test"]
+    # innermost first: the two waits, then the frame that called them
+    assert " wait <- " in where and where.endswith("_parked_here")
+    assert __file__ in where
+    import threading
+    assert threading.current_thread().name not in long["after"]
+
+
+_STOPPED_CHILD = """
+import json, sys, time
+from ray_tpu.util import flight_recorder as fr
+fr.start_stall_watch("worker")
+print("up", flush=True)
+sys.stdin.readline()
+time.sleep(0.2)
+print(json.dumps(fr.stalls()), flush=True)
+"""
+
+
+@pytest.mark.skipif(not hasattr(__import__("signal"), "SIGSTOP"),
+                    reason="the platform has no SIGSTOP")
+def test_a_stopped_child_reports_one_frozen_episode():
+    import os
+    import signal
+    import subprocess
+    import sys
+    import threading
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    child = subprocess.Popen(
+        [sys.executable, "-c", _STOPPED_CHILD], cwd=root,
+        stdin=subprocess.PIPE, stdout=subprocess.PIPE, text=True,
+        env={**os.environ, "PYTHONPATH": root})
+    try:
+        assert child.stdout.readline().strip() == "up"
+        time.sleep(0.2)
+        os.kill(child.pid, signal.SIGSTOP)
+        time.sleep(0.6)
+        os.kill(child.pid, signal.SIGCONT)
+        child.stdin.write("\n")
+        child.stdin.flush()
+        episodes = json.loads(child.stdout.readline())
+    finally:
+        child.kill()
+        child.wait()
+    frozen = [e for e in episodes if e["kind"] == "frozen"
+              and 0.5 <= e["seconds"] <= 1.5]
+    assert len(frozen) == 1, episodes
+    assert frozen[0]["process"] == "worker" and frozen[0]["pid"] == child.pid
+    assert threading.active_count() >= 1
+
+
+def test_one_watch_thread_in_the_driver_and_in_every_worker(
+        ray_start_regular):
+    import threading
+
+    import ray_tpu
+
+    @ray_tpu.remote
+    def look():
+        import threading
+        from ray_tpu.util import flight_recorder
+        watch = flight_recorder.stall_watch()
+        return [t.name for t in threading.enumerate()], watch.role
+
+    names, role = ray_tpu.get(look.remote())
+    assert names.count("rtpu-stall-watch") == 1 and role == "worker"
+    assert not [n for n in names if "watchdog" in n]
+    mine = [t.name for t in threading.enumerate()]
+    assert mine.count("rtpu-stall-watch") == 1
+    watch = fr.stall_watch()
+    assert watch.role == "driver"
+    # a worker that becomes a replica or a train worker says so; no
+    # other process changes its name
+    fr.rename_worker("replica")
+    assert watch.role == "driver"
