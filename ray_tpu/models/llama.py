@@ -6,15 +6,19 @@ Llama-2-7B fine-tune ≥35% MFU on v5p). Design choices for TPU:
 - Layers are *stacked* (leading n_layers axis) and iterated with
   `lax.scan`: one compiled block regardless of depth, fast compiles,
   and `jax.checkpoint` per block gives layer-granular rematerialization.
-  With ``remat=True`` a block keeps its input and its narrow residuals
-  (`REMAT_SAVED`) and recomputes only the FFN's wide tensors: per
-  layer ``tokens * (2*dim + 2*n_heads*head_dim + 2*n_kv_heads*head_dim)``
-  elements in the model dtype plus ``4 * tokens * n_heads`` bytes of
-  float32 row sums (289 MiB a layer at 8192 tokens of Mistral-7B's
-  widths, of which 64 MiB are the input that full recomputation kept
-  too), and the compiled step's temporaries grow by up to twice the
-  difference. A job that sat at the memory's edge under full
-  recomputation has to cut its batch.
+  With ``remat=True`` a block keeps its input, its narrow residuals
+  and, of a dense FFN, the gate and up products before the activation
+  (`REMAT_SAVED`), so the backward pass runs no product of the forward
+  pass again: only the norms, ``silu`` and the elementwise product are
+  recomputed. Per layer ``tokens * (2*dim + 2*n_heads*head_dim +
+  2*n_kv_heads*head_dim + 2*hidden_dim)`` elements in the model dtype
+  plus ``4 * tokens * n_heads`` bytes of float32 row sums: 737 MiB a
+  layer at 8192 tokens of Mistral-7B's widths, of which 448 MiB are
+  the FFN's two (``2 * tokens * hidden_dim``), 225 the narrow ones and
+  64 the input that full recomputation kept too. The compiled step's
+  temporaries grow by up to twice what is kept. A routed FFN
+  (``moe_experts``) keeps nothing and is recomputed whole. A job at
+  the memory's edge has to cut its batch.
 - All matmuls stay [tokens, features] × [features, out] — large, MXU-
   shaped, bfloat16 by default with float32 accumulation.
 - Attention pluggable: "flash" (Pallas kernel, ray_tpu/ops/attention.py),
@@ -59,9 +63,10 @@ class LlamaConfig:
     norm_eps: float = 1e-5
     dtype: Any = jnp.bfloat16
     attention: str = "flash"  # flash | reference | ring | ulysses
-    # Recompute each block's wide tensors (the FFN's gate, up and their
-    # product, the norms) in the backward pass and keep only the narrow
-    # ones (REMAT_SAVED: the module docstring has what they cost).
+    # Recompute in the backward pass what is elementwise (the norms,
+    # silu, the FFN's product) and keep what a matrix product or a
+    # kernel made (REMAT_SAVED: a further 2 * tokens * hidden_dim
+    # elements a dense layer; the module docstring has what all cost).
     remat: bool = True
     # Chunked cross-entropy: tokens per chunk (0/None = dense loss).
     # Avoids materializing [B, S, vocab] fp32 logits — at large batch
@@ -201,15 +206,17 @@ SCOPE_HEAD = "head"             # final norm and output projection
 SCOPE_LOSS = "cross_entropy"
 
 # What a rematerialized block keeps for its backward pass besides its
-# input: the tensors no wider than the model dimension. q and k are
-# named after rope and k and v BEFORE the grouped-query repeat (at
-# n_kv_heads; the repeat is a copy and is recomputed), the attention
-# output and the flash kernel's row sums where they are produced
-# (ops/attention.py names the kernel's own; `_attention` names the
-# output of the other implementations), and the stream after the
-# attention projection. Everything inside `_ffn` is recomputed.
+# input. q and k are named after rope and k and v BEFORE the
+# grouped-query repeat (at n_kv_heads; the repeat is a copy and is
+# recomputed), the attention output and the flash kernel's row sums
+# where they are produced (ops/attention.py names the kernel's own;
+# `_attention` names the output of the other implementations), the
+# stream after the attention projection, and a dense FFN's gate and up
+# products BEFORE silu (`_ffn`; the backward pass needs the
+# pre-activation, and silu and gate * up fuse into their consumers).
+# The norms are recomputed, and a routed or int8 FFN whole.
 REMAT_SAVED = ("attn_q", "attn_k", "attn_v", SAVED_OUT, SAVED_LSE,
-               "attn_proj")
+               "attn_proj", "ffn_gate", "ffn_up")
 
 
 def _attention(q, k, v, config: LlamaConfig, mesh):
@@ -266,8 +273,9 @@ def _ffn(layer_params, h, config: LlamaConfig):
                      layer_params["w2_q8"], layer_params["w2_s"])
         return (y.reshape(*b_t, -1).astype(h.dtype),
                 jnp.zeros((), jnp.float32))
-    gate = jax.nn.silu(h @ layer_params["w1"])
-    up = h @ layer_params["w3"]
+    # named BEFORE silu: what a rematerialized block keeps (REMAT_SAVED)
+    gate = jax.nn.silu(checkpoint_name(h @ layer_params["w1"], "ffn_gate"))
+    up = checkpoint_name(h @ layer_params["w3"], "ffn_up")
     return (gate * up) @ layer_params["w2"], jnp.zeros((), jnp.float32)
 
 

@@ -205,15 +205,16 @@ def test_chunked_cross_entropy_matches_the_checkpointed_scan(
         np.abs(want_d_w).max())
 
 
-def vocab_products(jaxpr, vocab):
-    """dot_generals of a jaxpr, its sub-jaxprs included, with the
-    vocabulary dimension among their operands' or result's."""
+def products_carrying(jaxpr, size):
+    """dot_generals of a jaxpr, its sub-jaxprs included, with a
+    dimension of ``size`` (the vocabulary, the FFN's width) among their
+    operands' or result's. A scan's body counts once: a layer."""
     n = 0
     for eqn in jaxpr.eqns:
         if eqn.primitive.name == "dot_general" and any(
-                vocab in v.aval.shape for v in (*eqn.invars, *eqn.outvars)):
+                size in v.aval.shape for v in (*eqn.invars, *eqn.outvars)):
             n += 1
-        n += sum(vocab_products(getattr(sub, "jaxpr", sub), vocab)
+        n += sum(products_carrying(getattr(sub, "jaxpr", sub), size)
                  for sub in jax_core.jaxprs_in_params(eqn.params))
     return n
 
@@ -233,7 +234,7 @@ def test_chunked_loss_runs_the_head_products_the_mathematics_needs(
 
     traced = jax.make_jaxpr(
         jax.value_and_grad(loss) if differentiated else loss)(params)
-    assert vocab_products(traced.jaxpr, cfg.vocab_size) == products
+    assert products_carrying(traced.jaxpr, cfg.vocab_size) == products
 
 
 def test_chunked_loss_under_fsdp4_matches_one_device():
@@ -307,13 +308,58 @@ def test_remat_gives_the_loss_and_gradients_of_no_remat(
             err_msg=jax.tree_util.keystr(path))
 
 
-def test_remat_keeps_the_narrow_residuals_and_one_flash_forward(
+@pytest.mark.parametrize("ce_chunk_tokens", [0, 64])
+@pytest.mark.parametrize("attn", ["reference", "flash"])
+def test_remat_runs_each_ffn_product_once(flash_interpreted, attn,
+                                          ce_chunk_tokens):
+    """A layer of a train step holds nine products that carry the FFN's
+    width: gate, up and down, and dH and dW of each. Eleven (until
+    PR 64) are gate and up run again in the backward pass: a name no
+    longer reaches the checkpoint's policy."""
+    cfg = _flash_sized(attention=attn, remat=True, n_layers=3,
+                       ce_chunk_tokens=ce_chunk_tokens)
+    params = llama_init(jax.random.PRNGKey(0), cfg)
+    tokens, targets = _data(cfg, batch=2, seq=128)
+    for c in (cfg, dataclasses.replace(cfg, remat=False)):
+        traced = jax.make_jaxpr(jax.value_and_grad(
+            lambda p: llama_loss(p, tokens, targets, c)))(params)
+        assert products_carrying(traced.jaxpr, cfg.hidden_dim) == 9
+
+
+@pytest.mark.parametrize("preset", [LlamaConfig.tiny, LlamaConfig.tiny_moe],
+                         ids=["dense", "moe"])
+def test_remat_keeps_of_an_ffn_its_two_pre_activations(preset):
+    """What the policy keeps of `_ffn` besides its arguments: of a
+    dense one `h @ w1` (NOT silu of it) and `h @ w3` (NOT the product),
+    of a routed one nothing."""
+    from ray_tpu.models.llama import REMAT_SAVED, _ffn
+
+    cfg = _flash_sized(preset, remat=True)
+    layer = jax.tree.map(lambda a: a[0], llama_init(
+        jax.random.PRNGKey(0), cfg)["layers"])
+    h = jax.random.normal(jax.random.PRNGKey(1), (2, 128, cfg.dim),
+                          cfg.dtype)
+    ffn = jax.checkpoint(
+        lambda p, h_: _ffn(p, h_, cfg)[0],
+        policy=jax.checkpoint_policies.save_only_these_names(*REMAT_SAVED))
+    # the backward function closes over what the forward pass kept
+    kept = [np.asarray(a) for a in jax.tree.leaves(jax.vjp(ffn, layer, h)[1])
+            if a.shape[:-1] == h.shape[:-1]]
+    want = [h] if cfg.moe_experts else [
+        h, h @ layer["w1"], h @ layer["w3"]]
+    assert len(kept) == len(want)
+    for got, w in zip(kept, want):
+        np.testing.assert_array_equal(got, np.asarray(w))
+
+
+def test_remat_keeps_the_named_residuals_and_one_flash_forward(
         flash_interpreted):
     """What the checkpointed block hands its backward pass: its input,
     q, k and v at their own head counts (k and v NOT repeated to
     n_heads), the kernel's output and row sums, the stream after the
-    attention projection; nothing as wide as the FFN, and so no second
-    forward kernel in the backward scan."""
+    attention projection, and of the FFN its two pre-activations and
+    nothing else as wide; so no second forward kernel in the backward
+    scan."""
     from jax._src.ad_checkpoint import saved_residuals
 
     cfg = _flash_sized(attention="flash", remat=True, n_layers=3)
@@ -325,10 +371,10 @@ def test_remat_keeps_the_narrow_residuals_and_one_flash_forward(
 
     computed = [aval.shape for aval, why in saved_residuals(loss, params)
                 if "from the argument" not in why]
-    assert not any(shape[-1:] == (cfg.hidden_dim,) for shape in computed)
     # the layer scan stacks what each block keeps: [n_layers, ...]
     kept = sorted(shape[1:] for shape in computed
                   if shape[:1] == (cfg.n_layers,))
+    assert sum(shape[-1:] == (cfg.hidden_dim,) for shape in computed) == 2
     b, s, hd = 2, 128, cfg.head_dim
     assert kept == sorted([
         (b, s, cfg.dim),                    # the block's input
@@ -338,6 +384,8 @@ def test_remat_keeps_the_narrow_residuals_and_one_flash_forward(
         (b, cfg.n_heads, s, hd),            # flash_fwd's out
         (b, cfg.n_heads, s, 1),             # flash_fwd's lse
         (b, s, cfg.dim),                    # x + attn @ wo
+        (b, s, cfg.hidden_dim),             # h @ w1, before silu
+        (b, s, cfg.hidden_dim),             # h @ w3
     ])
 
     def calls(c):

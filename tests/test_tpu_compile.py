@@ -21,7 +21,7 @@ from ray_tpu.models.llama import (
     llama_prefill)
 from ray_tpu.ops import attention, quant_matmul, rmsnorm
 from ray_tpu.parallel.mesh import AXIS_ORDER
-from test_models import vocab_products
+from test_models import products_carrying
 
 HBM_BYTES = 16 * 2**30  # one v5e chip
 
@@ -151,9 +151,10 @@ def test_flash_kernels_compile_at_the_callers_shapes(v5e, shape, with_grads):
 def _compile_train_step(devices, *, chips, n_layers, batch, seq=2048):
     """chip_smoke's trainer step, from abstract state sharded as its
     loop shards it. Returns (kernels, bytes per chip), having checked
-    that the traced step holds the head's three products and no fourth,
-    and that the lowered step calls each flash kernel ONCE: the layer scan's
-    forward body holds flash_fwd and its backward body flash_dq and
+    that the traced step holds the head's three products and no fourth
+    and a layer's nine FFN products and no eleven, and that the lowered
+    step calls each flash kernel ONCE: the layer scan's forward body
+    holds flash_fwd and its backward body flash_dq and
     flash_dkv, because remat keeps flash_fwd's output and row sums
     (llama.REMAT_SAVED). A second flash_fwd means a name no longer
     reaches the checkpoint's policy."""
@@ -170,7 +171,10 @@ def _compile_train_step(devices, *, chips, n_layers, batch, seq=2048):
     # the head's products: a chunk's logits, dH and dW, all in the loss's
     # forward rule (llama._chunked_nll_fwd); a fourth is a chunk's logits
     # computed again
-    assert vocab_products(traced.jaxpr.jaxpr, cfg.vocab_size) == 3
+    assert products_carrying(traced.jaxpr.jaxpr, cfg.vocab_size) == 3
+    # a layer's FFN: gate, up and down, dH and dW of each; gate and up
+    # a second time (11) if remat no longer keeps them
+    assert products_carrying(traced.jaxpr.jaxpr, cfg.hidden_dim) == 9
     lowered = traced.lower()
     text = lowered.as_text()
     assert [text.count(f'kernel_name = "{k}"') for k in
