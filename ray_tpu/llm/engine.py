@@ -149,13 +149,22 @@ ENGINE_EXPERT_SLOTS = _metrics.Counter(
     "hit (at least one live row picked it) or idle (none did, its "
     "weights were read for nothing)",
     tag_keys=("state",))
-# the expert layers' device counts (parallel.moe.EXPERT_COUNTS) that
-# are series: name -> (counter, tags)
+ENGINE_ROUTER_PICKS = _metrics.Counter(
+    "ray_tpu_engine_router_picks_total",
+    "Experts picked for live rows by a router that adds a selection "
+    "bias to its scores for the choice alone, by what the bias did: "
+    "moved (the pick is not among the row's k largest scores) or kept; "
+    "only from a family whose router has such a bias",
+    tag_keys=("bias",))
+# the expert layers' device counts (parallel.moe.EXPERT_COUNTS and
+# BIAS_COUNTS) that are series: name -> (counter, tags)
 _EXPERT_COUNT_SERIES = {
     "picks_held": (ENGINE_EXPERT_PICKS, {"where": "held"}),
     "picks_absent": (ENGINE_EXPERT_PICKS, {"where": "absent"}),
     "slots_hit": (ENGINE_EXPERT_SLOTS, {"state": "hit"}),
-    "slots_idle": (ENGINE_EXPERT_SLOTS, {"state": "idle"})}
+    "slots_idle": (ENGINE_EXPERT_SLOTS, {"state": "idle"}),
+    "picks_bias_moved": (ENGINE_ROUTER_PICKS, {"bias": "moved"}),
+    "picks_bias_kept": (ENGINE_ROUTER_PICKS, {"bias": "kept"})}
 ENGINE_ADMIT_LAUNCH_SECONDS = _metrics.Histogram(
     "ray_tpu_engine_admit_launch_seconds",
     "Time from the stepper's pop of a waiting request to the return of "
@@ -867,7 +876,7 @@ class ContinuousBatchingEngine:
         # and did not: the decode kernel reads whole blocks up to a
         # slot's position (ops/attention.py), the XLA form every row
         self._kv_block = _attention_op.decode_block_rows(
-            config.max_seq, c.n_kv_heads, c.head_dim) or config.max_seq
+            config.max_seq, *self._family.kv_row_shape(c)) or config.max_seq
         self.decode_kv_rows = {"read": 0, "skipped": 0}
         # slots x recurrent layers of those programs, by what became of
         # the slot's state; a family that moves every slot's counts none
@@ -2466,6 +2475,10 @@ class ContinuousBatchingEngine:
                                        "idle": n["slots_idle"]}
                 out["dropped_rows"] = (n["picks_held"]
                                        - n["picks_computed"])
+                if "picks_bias_moved" in n:
+                    # the picks a selection bias changed, and the rest
+                    out["router_picks"] = {"moved": n["picks_bias_moved"],
+                                           "kept": n["picks_bias_kept"]}
             if self._prefix_cache is not None:
                 out["prefix_cache_entries"] = len(self._prefix_cache)
                 out["prefix_hits"] = self.prefix_hits

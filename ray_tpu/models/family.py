@@ -45,7 +45,8 @@ class ModelFamily:
     # cache -> bytes by kind, {"kv": ..., "recurrent": ...}
     cache_bytes: Callable
     recurrent: bool
-    # parallel.moe.EXPERT_COUNTS where the family's programs count their
+    # parallel.moe.EXPERT_COUNTS (and, after them, a family's own
+    # names, as BIAS_COUNTS) where the family's programs count their
     # expert layers' picks ON THE DEVICE: the ``counts`` above are then
     # [n] uint32 of that prompt or step, and the engine adds them up in
     # an array its programs hand on and reads it where metrics flush.
@@ -55,6 +56,11 @@ class ModelFamily:
     # it lies, neither read nor written (the engine then counts, a
     # dense step, the slots x ``config.n_mamba_layers`` moved and parked)
     skips_parked_state: bool = False
+    # config -> the last two axes of the cache's K and V leaves, [rows,
+    # lanes] of one position: (n_kv_heads, head_dim) unless the family
+    # packs narrow heads (ops.attention.cache_row_shape); what the
+    # engine asks ops.attention.decode_block_rows about
+    kv_row_shape: Callable = lambda c: (c.n_kv_heads, c.head_dim)
 
 
 def insert_slot(cache, entry, slot):
@@ -147,14 +153,40 @@ def _granite() -> ModelFamily:
         skips_parked_state=True)
 
 
+@functools.cache
+def _lfm2() -> ModelFamily:
+    from ray_tpu.models import lfm2
+    from ray_tpu.ops.attention import cache_row_shape
+
+    def prefill(params, tokens, length, config, lora):
+        return lfm2.lfm2_prefill(params, tokens, length, config)
+
+    def decode_step(params, token, cache, pos, live, config, lora_bank,
+                    lora_idx):
+        return lfm2.lfm2_decode_step(params, token, cache, pos, live,
+                                     config)
+
+    return ModelFamily(
+        init=lfm2.lfm2_init,
+        hidden=lambda params, tokens, config: lfm2.lfm2_forward(
+            params, tokens, config, return_hidden=True),
+        init_cache=lfm2.lfm2_init_cache, prefill=prefill,
+        decode_step=decode_step,
+        cache_bytes=lambda cache: {
+            "kv": _nbytes([cache["k"], cache["v"]]),
+            "recurrent": _nbytes([cache["conv"]])},
+        recurrent=True, expert_counts=lfm2.EXPERT_COUNTS,
+        kv_row_shape=lambda c: cache_row_shape(c.n_kv_heads, c.head_dim))
+
+
 _FAMILIES: Dict[str, Callable[[], ModelFamily]] = {
     "LlamaConfig": _llama, "JambaConfig": _jamba,
-    "GraniteConfig": _granite}
+    "GraniteConfig": _granite, "Lfm2Config": _lfm2}
 
 
 def family_of(config: Any) -> ModelFamily:
     """The family of a model configuration, by the configuration's
-    class (LlamaConfig, JambaConfig, GraniteConfig)."""
+    class (LlamaConfig, JambaConfig, GraniteConfig, Lfm2Config)."""
     try:
         return _FAMILIES[type(config).__name__]()
     except KeyError:

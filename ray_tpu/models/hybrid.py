@@ -7,11 +7,14 @@ two stacks of layers walked by runs of one kind.
 - ``layer``: one layer's weights out of a stack by a traced index, so no
   stack is ever sliced into a copy.
 - ``attn_sequence`` / ``attn_decode``: the attention sublayer (grouped-
-  query, causal, NO position encoding: the mixers carry position), over
-  one sequence and over every slot against the cache. A family says what
-  differs: its KV heads (through its configuration), a scale on ``q``
-  where its scores are not times ``head_dim ** -0.5``, and a multiplier
-  on what the sublayer adds to the residual stream.
+  query, causal, NO position encoding of its own: the mixers carry
+  position), over one sequence and over every slot against the cache. A
+  family says what differs: its KV heads (through its configuration), a
+  scale on ``q`` where its scores are not times ``head_dim ** -0.5``, a
+  multiplier on what the sublayer adds to the residual stream, and
+  ``qk``: what it does to ``q`` and ``k`` between projection and kernel
+  (LFM2: a norm over each head, then rotary), ``qk(p, q [rows, H, HD],
+  k [rows, KVH, HD], positions [rows]) -> (q, k)``, in float32.
 
 ``c`` below is the family's configuration: ``n_heads``, ``n_kv_heads``,
 ``head_dim``, ``norm_eps``, ``dtype``, ``attention`` ("flash" |
@@ -64,14 +67,36 @@ def _q(h, wq, q_scale):
             * q_scale).astype(h.dtype)
 
 
-def attn_sequence(p, x, c, q_scale: float = 1.0, residual: float = 1.0):
+def _q_k(p, h, c, q_scale, qk, positions, q_shape):
+    """-> (q in ``q_shape``, k [rows, KVH * HD]) as the kernels take
+    them. With ``qk`` both leave their matmuls in float32, go through
+    it by heads, and are rounded once, after it and the scale."""
+    if qk is None:
+        return _q(h, p["wq"], q_scale).reshape(q_shape), h @ p["wk"]
+    rows, hd = h.shape[0], c.head_dim
+    q, k = qk(
+        p,
+        jnp.dot(h, p["wq"], preferred_element_type=jnp.float32)
+        .reshape(rows, c.n_heads, hd),
+        jnp.dot(h, p["wk"], preferred_element_type=jnp.float32)
+        .reshape(rows, c.n_kv_heads, hd), positions)
+    if q_scale != 1.0:
+        q = q * q_scale
+    return (q.astype(h.dtype).reshape(q_shape),
+            k.astype(h.dtype).reshape(rows, c.n_kv_heads * hd))
+
+
+def attn_sequence(p, x, c, q_scale: float = 1.0, residual: float = 1.0,
+                  qk=None):
     """One attention layer over one sequence. x [L, dim] -> (x, k, v
-    [L, KVH, HD]). Causal, no position encoding."""
+    [L, KVH, HD]). Causal; ``qk`` sees the positions 0 .. L - 1."""
     seq, hd = x.shape[0], c.head_dim
     with jax.named_scope(SCOPE_ATTN):
         h = rms_norm(x, p["in_norm"], c.norm_eps).astype(c.dtype)
-        q = _q(h, p["wq"], q_scale).reshape(1, seq, c.n_heads, hd)
-        k = (h @ p["wk"]).reshape(1, seq, c.n_kv_heads, hd)
+        q, k = _q_k(p, h, c, q_scale, qk,
+                    None if qk is None else jnp.arange(seq),
+                    (1, seq, c.n_heads, hd))
+        k = k.reshape(1, seq, c.n_kv_heads, hd)
         v = (h @ p["wv"]).reshape(1, seq, c.n_kv_heads, hd)
         n_rep = c.n_heads // c.n_kv_heads
         kk = jnp.repeat(k, n_rep, axis=2) if n_rep > 1 else k
@@ -87,7 +112,7 @@ def attn_sequence(p, x, c, q_scale: float = 1.0, residual: float = 1.0):
 
 
 def attn_decode(p, x, k_cache, v_cache, a, pos, c, q_scale: float = 1.0,
-                residual: float = 1.0):
+                residual: float = 1.0, qk=None):
     """One attention layer for every slot. x [B, dim]; ``k_cache`` /
     ``v_cache`` [A, B, S, KVH, HD], of which this is layer ``a``; pos
     [B]. The layer writes its row at ``pos`` and hands the stacked K
@@ -99,11 +124,14 @@ def attn_decode(p, x, k_cache, v_cache, a, pos, c, q_scale: float = 1.0,
     slots = jnp.arange(b)
     with jax.named_scope(SCOPE_ATTN):
         h = rms_norm(x, p["in_norm"], c.norm_eps).astype(c.dtype)
-        q = _q(h, p["wq"], q_scale).reshape(b, kvh, c.n_heads // kvh, hd)
-        k_cache = k_cache.at[a, slots, pos].set(
-            (h @ p["wk"]).reshape(b, kvh, hd))
+        q, k = _q_k(p, h, c, q_scale, qk, pos,
+                    (b, kvh, c.n_heads // kvh, hd))
+        # a row as the cache keeps it: [KVH, HD], or packed to 128
+        # lanes where heads are narrower (ops.attention.cache_row_shape)
+        row = (b,) + k_cache.shape[-2:]
+        k_cache = k_cache.at[a, slots, pos].set(k.reshape(row))
         v_cache = v_cache.at[a, slots, pos].set(
-            (h @ p["wv"]).reshape(b, kvh, hd))
+            (h @ p["wv"]).reshape(row))
         out = decode_attention(q, k_cache, v_cache, a, pos, c.dtype)
         out = mm(out.reshape(b, c.n_heads * hd), p["wo"])
         x = x + (out if residual == 1.0 else residual * out)

@@ -379,11 +379,14 @@ def _call_params(n_major: int, scratch_shapes):
         interpret=_INTERPRET)
 
 
-def _flash_forward(q, k, v, causal: bool, plan: _Plan):
-    """q,k,v: [B, H, S, D] -> (o [B, H, Sq, D], lse [B, H, Sq, 1] f32)."""
+def _flash_forward(q, k, v, causal: bool, plan: _Plan,
+                   sm_scale: Optional[float] = None):
+    """q,k,v: [B, H, S, D] -> (o [B, H, Sq, D], lse [B, H, Sq, 1] f32).
+    ``sm_scale``: the scores' scale where it is not ``D ** -0.5``."""
     from jax.experimental import pallas as pl
 
     b, h, sq, d = q.shape
+    sm_scale = d ** -0.5 if sm_scale is None else sm_scale
     sk = k.shape[2]
     offset = sk - sq
     block_q, tile_k, major_k = plan.block_q, plan.tile_k, plan.major_k
@@ -393,7 +396,7 @@ def _flash_forward(q, k, v, causal: bool, plan: _Plan):
     kv_index = _last_live_major(causal, block_q, tile_k, sk // tile_k,
                                 offset, major_k // tile_k)
     out, lse = pl.pallas_call(
-        functools.partial(_fwd_kernel, causal=causal, sm_scale=d ** -0.5,
+        functools.partial(_fwd_kernel, causal=causal, sm_scale=sm_scale,
                           tile_k=tile_k, offset=offset, steps=steps),
         grid=(b, h, *steps),
         in_specs=[
@@ -666,16 +669,53 @@ def _bwd(causal, res, g):
 
 _flash.defvjp(_fwd, _bwd)
 
+# the lanes of a tile: the head size the kernels are written for
+_LANES = 128
+
+
+def _narrow(d: int) -> bool:
+    """Whether heads of ``d`` reach the kernels through 128 lanes: heads
+    of 64, half a tile's lanes (narrower ones stay uncovered: padding
+    wastes three quarters of the matrix unit and more)."""
+    return 2 * d == _LANES and (_INTERPRET or jax_backend.on_tpu())
+
+
+def _pad_lanes(x):
+    return jnp.pad(x, ((0, 0),) * (x.ndim - 1)
+                   + ((0, _LANES - x.shape[-1]),))
+
+
+def _flash_narrow(q, k, v, causal: bool):
+    """Heads narrower than a tile's 128 lanes (64: LFM2's), FORWARD
+    ONLY (the serving path's prefill): q, k and v zero-padded to 128
+    lanes, which adds nothing to a score nor to a kept output lane, and
+    the forward kernel told the scale of the true head size. None where
+    the kernel does not cover the padded shapes."""
+    d = q.shape[-1]
+    q, k, v = (_pad_lanes(x) for x in (q, k, v))
+    plan = _kernel_plan(q, k)
+    if plan is None:
+        return None
+    out, _ = _flash_forward(*(x.transpose(0, 2, 1, 3) for x in (q, k, v)),
+                            causal, plan, sm_scale=d ** -0.5)
+    return out.transpose(0, 2, 1, 3)[..., :d]
+
 
 def flash_attention(q, k, v, causal: bool = True, mesh=None):
     """Fused causal attention: [B, S, H, D] x3 -> [B, S, H, D].
 
     K/V head count must equal Q head count (expand GQA groups first).
+    Heads of 64 on one device go through ``_flash_narrow`` (forward
+    only).
     With a ``mesh`` of more than one device the op runs per shard under
     ``jax.shard_map`` (a Mosaic kernel cannot be partitioned by GSPMD):
     batch over (data, fsdp), heads over model, sequence and head_dim
     whole. A sequence-sharded mesh needs ring/ulysses attention."""
     if mesh is None or mesh.size == 1:
+        if _narrow(q.shape[-1]):
+            out = _flash_narrow(q, k, v, causal)
+            if out is not None:
+                return out
         return _flash(q, k, v, causal)
     batch, n_batch = mesh_axes(mesh, "data", "fsdp")
     heads, n_heads = mesh_axes(mesh, "model")
@@ -720,7 +760,9 @@ def decode_block_rows(s: int, kvh: int, hd: int) -> Optional[int]:
     where the kernel does not cover the shape or the backend, and the
     reference reads all ``s`` rows. About 256 KiB of bf16 a block for 8
     KV heads (1024 cache rows of 128 lanes); never under 128 positions,
-    so one KV head gets 512."""
+    so one KV head gets 512. ``kvh`` and ``hd`` are the cache's own
+    last two axes: a cache of packed rows (``cache_row_shape``) is
+    asked about as it is stored."""
     if not (_INTERPRET or jax_backend.on_tpu()):
         return None
     block = min(s, max(128, 512 // kvh))
@@ -810,11 +852,13 @@ def _decode_kernel(layer_ref, pos_ref, q_ref, at_ref, k_hbm, v_hbm, o_ref,
     o_ref[0] = (acc / l).astype(o_ref.dtype)
 
 
-def _decode_pallas(q, cache_k, cache_v, layer, pos, dtype, block: int):
+def _decode_pallas(q, cache_k, cache_v, layer, pos, dtype, block: int,
+                   sm_scale: Optional[float] = None):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     b, kvh, n_rep, hd = q.shape
+    sm_scale = hd ** -0.5 if sm_scale is None else sm_scale
     n_layers, _, s = cache_k.shape[:3]
     n_heads = kvh * n_rep
     # whole sublane tiles of query heads; a padded head is a zero query
@@ -829,7 +873,7 @@ def _decode_pallas(q, cache_k, cache_v, layer, pos, dtype, block: int):
                   np.iinfo(np.int32).max).astype(np.int32)
     out = pl.pallas_call(
         functools.partial(_decode_kernel, block=block, kvh=kvh,
-                          sm_scale=hd ** -0.5),
+                          sm_scale=sm_scale),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(b,),
@@ -859,6 +903,45 @@ def _decode_pallas(q, cache_k, cache_v, layer, pos, dtype, block: int):
     return out[:, :n_heads].reshape(b, kvh, n_rep, hd)
 
 
+def cache_row_shape(kvh: int, hd: int) -> Tuple[int, int]:
+    """The last two axes of a serving cache whose layers have ``kvh`` KV
+    heads of ``hd``: ``(kvh, hd)``, but for heads of 64, half a tile's
+    128 lanes, ``(kvh / 2, 128)``: two neighbouring KV heads side by
+    side in one row of 128 lanes (LFM2: 8 heads of 64 are 4 rows). A TPU
+    pads a minor axis of 64 to 128 lanes, so ``[.., 8, 64]`` would take
+    twice its bytes and could not be seen as 128 wide without a copy;
+    the packed rows are what ``decode_attention`` reads in place."""
+    if 2 * hd == _LANES and kvh % 2 == 0:
+        return kvh // 2, _LANES
+    return kvh, hd
+
+
+def _decode_packed(q, cache_k, cache_v, layer, pos, dtype):
+    """``decode_attention`` over a cache of packed rows
+    (``cache_row_shape``: [L, B, S, G, 128], ``f = 128 / HD`` KV heads a
+    row), through the kernel written for 128 lanes: it sees G KV heads
+    of 128, each with the queries of its f heads; a query is zero in the
+    lanes that are not its own head's, so its scores are its own head's,
+    and of its output the lanes of its own head are kept. None where
+    the kernel does not cover the shapes."""
+    b, kvh, n_rep, hd = q.shape
+    rows, lanes = cache_k.shape[-2:]
+    f = lanes // hd
+    block = decode_block_rows(cache_k.shape[2], rows, lanes)
+    if block is None or not (q.dtype == cache_k.dtype == cache_v.dtype):
+        return None
+    q = q.reshape(b, rows, f, n_rep, hd)
+    q = jnp.stack([jnp.pad(q[:, :, j], ((0, 0),) * 3
+                           + ((j * hd, lanes - (j + 1) * hd),))
+                   for j in range(f)], axis=2)
+    out = _decode_pallas(q.reshape(b, rows, f * n_rep, lanes), cache_k,
+                         cache_v, layer, pos, dtype, block,
+                         sm_scale=hd ** -0.5)
+    out = out.reshape(b, rows, f, n_rep, f, hd)
+    return jnp.stack([out[:, :, j, :, j] for j in range(f)],
+                     axis=2).reshape(b, kvh, n_rep, hd)
+
+
 def decode_attention(q, cache_k, cache_v, layer, pos, dtype):
     """One query position for every slot against the cache as it is
     stored. q: [B, KVH, n_rep, HD], the query heads grouped by the KV
@@ -870,8 +953,9 @@ def decode_attention(q, cache_k, cache_v, layer, pos, dtype):
     layer is sliced out of the stack.
 
     On a TPU (and in the tests' interpret mode), where ``HD % 128 ==
-    0``, S divides into ``decode_block_rows`` and q has the caches'
-    dtype, one Pallas kernel reads layer ``layer`` in place, and of each
+    0`` (or the cache's rows are packed: ``cache_row_shape``), S divides
+    into ``decode_block_rows`` and q has the caches' dtype, one Pallas
+    kernel reads layer ``layer`` in place, and of each
     slot only the blocks that hold a visible row: the decode programs
     of every model family share it. It needs every ``pos`` in ``[0, S -
     1]``, the program's caches donated (or XLA copies them for every
@@ -880,6 +964,19 @@ def decode_attention(q, cache_k, cache_v, layer, pos, dtype):
     Everything else takes ``_decode_attention_reference``, on a TPU
     with an entry in ``kernel_fallbacks``."""
     _, kvh, _, hd = q.shape
+    if cache_k.shape[-1] != hd:
+        # packed rows (cache_row_shape)
+        out = _decode_packed(q, cache_k, cache_v, layer, pos, dtype)
+        if out is not None:
+            return out
+        if jax_backend.on_tpu():
+            kernel_fallbacks.append(
+                f"decode q{list(q.shape)} {q.dtype} "
+                f"cache{list(cache_k.shape)} {cache_k.dtype}")
+        unpacked = cache_k.shape[:3] + (kvh, hd)
+        return _decode_attention_reference(
+            q, cache_k.reshape(unpacked), cache_v.reshape(unpacked), layer,
+            pos, dtype)
     block = decode_block_rows(cache_k.shape[2], kvh, hd)
     if block is None or not (q.dtype == cache_k.dtype == cache_v.dtype):
         if jax_backend.on_tpu():

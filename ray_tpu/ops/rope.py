@@ -9,13 +9,25 @@ from __future__ import annotations
 import jax.numpy as jnp
 
 
+def _inv_freq(head_dim: int, theta: float):
+    return 1.0 / (theta ** (jnp.arange(0, head_dim, 2,
+                                       dtype=jnp.float32) / head_dim))
+
+
 def rope_frequencies(head_dim: int, max_seq_len: int,
                      theta: float = 10000.0):
     """Precompute cos/sin tables: [max_seq_len, head_dim//2]."""
-    inv_freq = 1.0 / (theta ** (jnp.arange(0, head_dim, 2,
-                                           dtype=jnp.float32) / head_dim))
+    inv_freq = _inv_freq(head_dim, theta)
     t = jnp.arange(max_seq_len, dtype=jnp.float32)
     freqs = jnp.outer(t, inv_freq)
+    return jnp.cos(freqs), jnp.sin(freqs)
+
+
+def rope_at(positions, head_dim: int, theta: float = 10000.0):
+    """cos/sin at ``positions`` [n] alone: [n, head_dim//2] each, for a
+    caller whose rows each stand at a position of their own."""
+    freqs = jnp.outer(positions.astype(jnp.float32),
+                      _inv_freq(head_dim, theta))
     return jnp.cos(freqs), jnp.sin(freqs)
 
 

@@ -23,6 +23,7 @@ Components:
 
 from __future__ import annotations
 
+from dataclasses import dataclass
 from typing import Any, Dict, Optional, Tuple
 
 import jax
@@ -36,17 +37,47 @@ SCOPE_EXPERTS = "moe.experts"
 # what held_experts_ffn counts of its live rows, in this order
 EXPERT_COUNTS = ("picks_held", "picks_absent", "picks_computed",
                  "slots_hit", "slots_idle")
+# and, after those, where the router adds a selection bias: the live
+# rows' picks that are not among the k largest scores alone, and the rest
+BIAS_COUNTS = ("picks_bias_moved", "picks_bias_kept")
 
 
-def _gates(logits: jax.Array, k: int
+@dataclass(frozen=True)
+class Scoring:
+    """How a router's logits become scores and gates; a family's.
+
+    ``softmax``: scores a softmax over all experts, the k largest
+    picked, gates those scores over their sum. ``sigmoid``: scores a
+    sigmoid of each logit, the k largest of ``score + bias`` picked (the
+    bias, a buffer of the layer and no weight, is for the choice
+    alone), gates the picked SCORES over ``their sum + eps``, times
+    ``scale``."""
+    kind: str = "softmax"
+    eps: float = 0.0
+    scale: float = 1.0
+
+
+SOFTMAX = Scoring()
+
+
+def _gates(logits: jax.Array, k: int, scoring: Scoring = SOFTMAX,
+           bias: Optional[jax.Array] = None
            ) -> Tuple[jax.Array, jax.Array, jax.Array]:
-    """Router logits [T, E] -> (gates [T, E], topk_idx [T, k], probs
-    [T, E]): a softmax over all experts, the k largest, renormalized
-    over those; gates are zero outside them."""
-    probs = jax.nn.softmax(logits, axis=-1)
-    topk_vals, topk_idx = jax.lax.top_k(probs, k)                # [T, k]
-    topk_vals = topk_vals / jnp.maximum(
-        topk_vals.sum(axis=-1, keepdims=True), 1e-9)
+    """Router logits [T, E] -> (gates [T, E], topk_idx [T, k], scores
+    [T, E]) under ``scoring``; gates are zero outside the picks.
+    ``bias`` [E]: a sigmoid router's selection bias."""
+    if scoring.kind == "sigmoid":
+        probs = jax.nn.sigmoid(logits)
+        _, topk_idx = jax.lax.top_k(
+            probs if bias is None else probs + bias, k)          # [T, k]
+        topk_vals = jnp.take_along_axis(probs, topk_idx, axis=-1)
+        topk_vals = topk_vals / (topk_vals.sum(axis=-1, keepdims=True)
+                                 + scoring.eps) * scoring.scale
+    else:
+        probs = jax.nn.softmax(logits, axis=-1)
+        topk_vals, topk_idx = jax.lax.top_k(probs, k)            # [T, k]
+        topk_vals = topk_vals / jnp.maximum(
+            topk_vals.sum(axis=-1, keepdims=True), 1e-9)
     gates = jnp.zeros_like(probs).at[
         jnp.arange(logits.shape[0])[:, None], topk_idx].set(topk_vals)
     return gates, topk_idx, probs
@@ -153,7 +184,9 @@ def gated_ffn(x: jax.Array, w_in: jax.Array, w_out: jax.Array) -> jax.Array:
 
 def held_experts_ffn(x: jax.Array, router: jax.Array, w_in: jax.Array,
                      w_out: jax.Array, first: int, *, layer, top_k: int,
-                     live: Optional[jax.Array] = None
+                     live: Optional[jax.Array] = None,
+                     scoring: Scoring = SOFTMAX,
+                     bias: Optional[jax.Array] = None
                      ) -> Tuple[jax.Array, jax.Array]:
     """The part of a routed layer that the experts THIS device holds
     add: one rank of an expert-parallel group.
@@ -168,7 +201,8 @@ def held_experts_ffn(x: jax.Array, router: jax.Array, w_in: jax.Array,
     v5e: PERF.md, PR 45). Every row picks its ``top_k`` of all E by the
     router (its product at the highest precision: a TPU rounds float32
     inputs to bf16 by default, and a rounded logit changes a pick),
-    gates renormalised over the picks; a pick of a held expert adds
+    scored and gated as the family's ``scoring`` says (``bias`` [E]
+    float32: a sigmoid router's selection bias); a pick of a held expert adds
     ``gate * expert(row)``, a pick of an absent one adds nothing HERE
     (the rank that holds it adds it; the ranks' parts sum to the whole
     layer). Nothing is dropped: there is no capacity and no factor,
@@ -189,14 +223,17 @@ def held_experts_ffn(x: jax.Array, router: jax.Array, w_in: jax.Array,
     experts, those that fell on absent ones, the held picks whose
     product was computed (every one in the first regime; in the second
     those whose place in the sorted order lies inside the groups), how
-    many held experts got a live row and how many got none)."""
+    many held experts got a live row and how many got none; with a
+    ``bias`` two more, BIAS_COUNTS: the live rows' picks that are not
+    among their ``top_k`` largest scores alone, and those that are)."""
     rows, dtype = x.shape[0], w_in.dtype
     n_experts, (held, inner, _) = router.shape[-1], w_out.shape[-3:]
     live = jnp.ones((rows,), bool) if live is None else live.astype(bool)
     with jax.named_scope(SCOPE_ROUTER):
-        gates, idx, _ = _gates(
+        gates, idx, scores = _gates(
             jnp.dot(x.astype(jnp.float32), router.astype(jnp.float32),
-                    precision=jax.lax.Precision.HIGHEST), top_k)
+                    precision=jax.lax.Precision.HIGHEST), top_k, scoring,
+            bias)
         # expert e as (e - first) mod E, which is < H exactly for the
         # held; a row picks an expert at most once
         rel = (idx - first) % n_experts                       # [T, k]
@@ -204,10 +241,16 @@ def held_experts_ffn(x: jax.Array, router: jax.Array, w_in: jax.Array,
         picked_live = picked & live[:, None]
         n_held = jnp.sum(picked_live)
         n_hit = jnp.sum(jnp.any(picked_live, axis=0))
+        by_bias = []
+        if bias is not None:
+            unbiased = jax.lax.top_k(scores, top_k)[1]
+            kept = jnp.any(idx[:, :, None] == unbiased[:, None, :], axis=2)
+            n_kept = jnp.sum(kept & live[:, None])
+            by_bias = [jnp.sum(live) * top_k - n_kept, n_kept]
 
     def counts(n_computed):
         return jnp.stack([n_held, jnp.sum(live) * top_k - n_held,
-                          n_computed, n_hit, held - n_hit]
+                          n_computed, n_hit, held - n_hit, *by_bias]
                          ).astype(jnp.uint32)
 
     with jax.named_scope(SCOPE_EXPERTS):

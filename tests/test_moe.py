@@ -18,8 +18,10 @@ from ray_tpu.models.llama import (
     llama_sharding_rules,
 )
 from ray_tpu.parallel.mesh import MeshSpec, make_mesh
-from ray_tpu.parallel.moe import (gated_ffn, held_experts_ffn, moe_dispatch,
-                                  moe_ffn, top_k_gating)
+from ray_tpu.parallel.moe import (BIAS_COUNTS, EXPERT_COUNTS, SOFTMAX,
+                                  Scoring, _gates, gated_ffn,
+                                  held_experts_ffn, moe_dispatch, moe_ffn,
+                                  top_k_gating)
 from ray_tpu.parallel.sharding import shard_pytree
 
 
@@ -267,3 +269,107 @@ def test_a_stack_of_layers_and_an_index_is_the_layer_itself(rows):
                                rtol=1e-4, atol=1e-5)
     assert counts.tolist() == want_counts.tolist()
     assert float(jnp.abs(other - got).max()) > 1e-3
+
+
+# --- the router's scoring: a family's (Scoring) ---------------------------
+
+def _softmax_gates_as_before(logits, k):
+    """``_gates`` as it stood while softmax was the module's constant."""
+    probs = jax.nn.softmax(logits, axis=-1)
+    vals, idx = jax.lax.top_k(probs, k)
+    vals = vals / jnp.maximum(vals.sum(axis=-1, keepdims=True), 1e-9)
+    gates = jnp.zeros_like(probs).at[
+        jnp.arange(logits.shape[0])[:, None], idx].set(vals)
+    return gates, idx, probs
+
+
+def _sigmoid_gates_in_numpy(logits, k, bias, eps, scale):
+    p = 1.0 / (1.0 + np.exp(-np.asarray(logits, np.float64)))
+    idx = np.argsort(-(p + bias), axis=-1, kind="stable")[:, :k]
+    picked = np.take_along_axis(p, idx, -1)
+    vals = picked / (picked.sum(-1, keepdims=True) + eps) * scale
+    gates = np.zeros_like(p)
+    np.put_along_axis(gates, idx, vals, -1)
+    return gates, idx, p
+
+
+@pytest.mark.parametrize("scoring,k,bias_std", [
+    (SOFTMAX, 2, None), (SOFTMAX, 3, None), (Scoring(), 10, None),
+    (Scoring("sigmoid", eps=1e-6, scale=1.0), 4, 0.3),
+    (Scoring("sigmoid", eps=1e-6, scale=2.5), 4, 0.3),
+    (Scoring("sigmoid", eps=1e-2, scale=1.0), 2, None)])
+def test_gates_under_both_scorings(scoring, k, bias_std):
+    """Softmax, the default: to the bit what it was. Sigmoid against a
+    few lines of numpy: the picks by ``p + b``, the gates from ``p``
+    alone over ``their sum + eps``, times the scale."""
+    logits = jax.random.normal(jax.random.PRNGKey(k), (40, 32)) * 2.0
+    bias = (None if bias_std is None else np.asarray(
+        jax.random.normal(jax.random.PRNGKey(9), (32,))) * bias_std)
+    gates, idx, scores = jax.jit(
+        lambda l: _gates(l, k, scoring, None if bias is None
+                         else jnp.asarray(bias)))(logits)
+    if scoring.kind == "softmax":
+        want = jax.jit(lambda l: _softmax_gates_as_before(l, k))(logits)
+        for got, old in zip((gates, idx, scores), want):
+            assert np.array_equal(np.asarray(got), np.asarray(old))
+        return
+    want_gates, want_idx, want_p = _sigmoid_gates_in_numpy(
+        logits, k, 0.0 if bias is None else bias, scoring.eps,
+        scoring.scale)
+    assert np.asarray(idx).tolist() == want_idx.tolist()
+    np.testing.assert_allclose(np.asarray(scores), want_p, rtol=1e-5)
+    np.testing.assert_allclose(np.asarray(gates), want_gates, rtol=1e-5,
+                               atol=1e-7)
+    sums = np.asarray(gates).sum(-1)
+    picked = np.take_along_axis(want_p, want_idx, -1).sum(-1)
+    np.testing.assert_allclose(sums, scoring.scale * picked
+                               / (picked + scoring.eps), rtol=1e-5)
+    if bias is not None:
+        # the bias moved some picks and left the gates to the scores
+        plain = np.argsort(-want_p, axis=-1, kind="stable")[:, :k]
+        assert (np.sort(plain) != np.sort(want_idx)).any()
+
+
+@pytest.mark.parametrize("rows", [8, 100])
+def test_two_halves_of_32_experts_add_up_under_the_sigmoid_router(rows):
+    """``held_experts_ffn`` with experts 0-15 and 16-31 held, routed by
+    a sigmoid with a selection bias over all 32, top-4, scale 1.5:
+    their parts sum to the whole layer, in both regimes; the counts
+    grow by the two that say what the bias did."""
+    e, k = 32, 4
+    scoring = Scoring("sigmoid", eps=1e-6, scale=1.5)
+    keys = jax.random.split(jax.random.PRNGKey(5), 5)
+    router = jax.random.normal(keys[0], (_D, e))
+    w_in = jax.random.normal(keys[1], (e, _D, 2 * _I)) * 0.3
+    w_out = jax.random.normal(keys[2], (e, _I, _D)) * 0.3
+    bias = jax.random.normal(keys[3], (e,)) * 0.2
+    x = jax.random.normal(keys[4], (rows, _D))
+    live = jnp.arange(rows) < rows - 2
+
+    def half(first):
+        return jax.jit(lambda x: held_experts_ffn(
+            x, router, w_in[None, first:first + 16],
+            w_out[None, first:first + 16], first, layer=0, top_k=k,
+            live=live, scoring=scoring, bias=bias))(x)
+
+    with jax.default_matmul_precision("highest"):
+        gates, idx, _ = _sigmoid_gates_in_numpy(
+            x @ router, k, np.asarray(bias), 1e-6, 1.5)
+        want = sum(gates[:, j:j + 1] * np.asarray(
+            gated_ffn(x, w_in[j], w_out[j])) for j in range(e))
+        (low, counts_low), (high, counts_high) = half(0), half(16)
+    np.testing.assert_allclose(np.asarray(low + high), want, rtol=1e-4,
+                               atol=1e-5)
+    assert float(jnp.abs(low).max()) > 0 and float(jnp.abs(high).max()) > 0
+    assert len(counts_low) == len(EXPERT_COUNTS) + len(BIAS_COUNTS) == 7
+    n_live = rows - 2
+    assert int(counts_low[0]) == int(counts_high[1])
+    assert int(counts_low[0] + counts_low[1]) == n_live * k
+    assert int(counts_low[2]) == int(counts_low[0])
+    # what the bias moved, of the live rows' picks: both ranks alike
+    p = 1.0 / (1.0 + np.exp(-np.asarray(x @ router, np.float64)))
+    plain = np.argsort(-p, axis=-1, kind="stable")[:, :k]
+    moved = sum(j not in plain[t] for t in range(n_live) for j in idx[t])
+    assert counts_low[5:].tolist() == counts_high[5:].tolist() \
+        == [moved, n_live * k - moved]
+    assert 0 < moved < n_live * k

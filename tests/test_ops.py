@@ -412,3 +412,64 @@ def test_ssd_update_takes_the_jnp_form_where_the_kernel_does_not_engage(
     np.testing.assert_allclose(y[live], want_y[live], rtol=1e-5, atol=1e-5)
     assert (got[1][~live] == ssm[1][~live]).all() and (y[~live] == 0).all()
     assert (got[0] == ssm[0]).all()
+
+
+# --- heads of 64 through the kernels written for 128 lanes ------------
+
+@pytest.mark.parametrize("seq,heads", [(128, 4), (512, 2), (1024, 1)])
+def test_flash_attention_with_heads_of_64_matches_the_reference(
+        flash_interpreted, seq, heads):
+    """A prefill's heads of 64, zero-padded to 128 lanes with the true
+    head's scale: the forward kernel against the jnp reference, at a
+    bucket of one block and of several tiles."""
+    att = flash_interpreted
+    q, k, v = (jax.random.normal(key, (1, seq, heads, 64), jnp.float32)
+               for key in jax.random.split(jax.random.PRNGKey(seq), 3))
+    assert att._kernel_plan(q, k) is None      # not as they are
+    out = jax.jit(lambda q, k, v: att.flash_attention(q, k, v, True))(
+        q, k, v)
+    assert out.shape == q.shape
+    np.testing.assert_allclose(
+        np.asarray(out), np.asarray(att._attention_reference(q, k, v, True)),
+        atol=1e-2)
+
+
+@pytest.mark.parametrize("kvh,n_rep,dtype", [
+    (8, 4, jnp.bfloat16), (2, 1, jnp.float32), (4, 2, jnp.float32)])
+def test_decode_attention_over_packed_rows_matches_the_reference(
+        flash_interpreted, kvh, n_rep, dtype):
+    """A cache of heads of 64 kept two KV heads a row of 128 lanes
+    (``cache_row_shape``): the decode kernel reads it as it lies and
+    gives what the plain form gives over the unpacked cache, for slots
+    at the first row, inside a block and at the last row; without the
+    kernel (no TPU, no interpret mode) the same through the reference."""
+    att = flash_interpreted
+    layers, slots, rows = 2, 3, 512
+    assert att.cache_row_shape(kvh, 64) == (kvh // 2, 128)
+    assert att.cache_row_shape(kvh, 128) == (kvh, 128)
+    assert att.cache_row_shape(3, 64) == (3, 64)      # no pair to make
+    assert att.cache_row_shape(4, 16) == (4, 16)
+    keys = jax.random.split(jax.random.PRNGKey(kvh), 3)
+    ck, cv = (jax.random.normal(key, (layers, slots, rows, kvh, 64),
+                                jnp.float32).astype(dtype)
+              for key in keys[:2])
+    q = jax.random.normal(keys[2], (slots, kvh, n_rep, 64),
+                          jnp.float32).astype(dtype)
+    pos = jnp.array([0, 200, rows - 1])
+    packed = (layers, slots, rows) + att.cache_row_shape(kvh, 64)
+    # the kernel is asked about the cache as it is stored
+    assert att.decode_block_rows(rows, kvh, 64) is None
+    assert att.decode_block_rows(rows, *att.cache_row_shape(kvh, 64)) \
+        == att.decode_block_rows(rows, kvh // 2, 128) is not None
+    tol = 2e-2 if dtype == jnp.bfloat16 else 1e-4
+    for layer in range(layers):
+        want = att._decode_attention_reference(q, ck, cv, layer, pos, dtype)
+        got = jax.jit(att.decode_attention, static_argnums=5)(
+            q, ck.reshape(packed), cv.reshape(packed), layer, pos, dtype)
+        np.testing.assert_allclose(np.asarray(got, np.float32),
+                                   np.asarray(want, np.float32), atol=tol)
+    att._INTERPRET = False      # the fixture's monkeypatch puts it back
+    got = att.decode_attention(q, ck.reshape(packed), cv.reshape(packed), 1,
+                               pos, dtype)
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(want, np.float32), atol=1e-6)

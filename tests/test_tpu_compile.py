@@ -474,3 +474,76 @@ def test_granite_serving_programs_compile_at_published_widths(v5e):
                           compiled.as_text())
         assert set(made) <= {"bitcast", "parameter"}, set(made)
     assert attention.kernel_fallbacks == []
+
+
+def test_lfm2_serving_programs_compile_at_published_widths(v5e):
+    """The LFM2 cell's engine programs as the chip gets them
+    (LFM2-8B-A1B's first fourteen layers, all 32 experts of the twelve
+    routed ones, batch 32, seq 1536): heads of 64 reach both attention
+    kernels (the prefill's padded to 128 lanes, the decode step's two
+    KV heads a row) and nothing falls back; the decode program aliases
+    the whole cache, hands its seven device counts on and keeps its
+    temporaries under 64 MiB; a prefill reads the expert stack where
+    it lies; everything fits one chip."""
+    from ray_tpu.llm import engine as engine_mod
+    from ray_tpu.models.lfm2 import (EXPERT_COUNTS, Lfm2Config, lfm2_init,
+                                     lfm2_init_cache)
+    cfg = Lfm2Config(layer_types=Lfm2Config().layer_types[:14],
+                     max_seq_len=1536)
+    assert (cfg.n_conv_layers, cfg.n_attn_layers, cfg.n_moe_layers) \
+        == (11, 3, 12)
+    mesh = _mesh(v5e, 1)
+
+    def on_chip(tree):
+        return _abstract(tree, jax.tree.map(
+            lambda _: NamedSharding(mesh, P()), tree))
+
+    params = on_chip(jax.eval_shape(
+        lambda key: lfm2_init(key, cfg), jax.random.PRNGKey(0)))
+    weights = sum(x.size * x.dtype.itemsize
+                  for x in jax.tree.leaves(params))
+    assert 9.55e9 < weights < 9.65e9
+    cache = jax.tree.leaves(on_chip(jax.eval_shape(
+        lambda: lfm2_init_cache(cfg, 32, 1536))))
+    counts = _on(mesh, P(), (len(EXPERT_COUNTS),), jnp.uint32)
+    with pytest.MonkeyPatch.context() as patch:
+        # an engine around shapes: no weights and no cache are made here
+        patch.setattr(engine_mod.ContinuousBatchingEngine, "_fresh_cache",
+                      lambda self, model: cache)
+        patch.setattr(engine_mod.ContinuousBatchingEngine, "_fresh_bias",
+                      lambda self: (None, None))
+        patch.setattr(engine_mod.ContinuousBatchingEngine,
+                      "_fresh_expert_counts", lambda self: None)
+        engine = engine_mod.ContinuousBatchingEngine(
+            engine_mod.EngineConfig(model=cfg, max_batch=32, max_seq=1536),
+            params=params)
+    lowered = engine._decode.lower(
+        params, cache, _on(mesh, P(), (7, 32), jnp.int32),
+        _on(mesh, P(), (2,), jnp.uint32), None,
+        _on(mesh, P(), (32, 65536), jnp.float32), counts, want_lp=False)
+    assert sorted({k.split("(")[0] for k in _kernels(lowered)}) == [
+        "decode_attention", "rms_norm"]
+    compiled = lowered.compile()
+    _assert_sampler_branches(compiled)
+    memory = compiled.memory_analysis()
+    # the K/V rows of 3 layers and 11 layers' two columns: in place
+    kv = 2 * 3 * 32 * 1536 * 8 * 64 * 2
+    state = 11 * 32 * 2 * 2048 * 2
+    assert kv + state <= memory.alias_size_in_bytes <= 1.2 * (kv + state)
+    assert memory.temp_size_in_bytes < 64 * 2**20
+    assert (memory.argument_size_in_bytes
+            + memory.temp_size_in_bytes) < 0.75 * HBM_BYTES
+    for bucket in (128, 1024):
+        lowered = engine._prefill.lower(
+            params, _on(mesh, P(), (1, bucket), jnp.int32),
+            _on(mesh, P(), (), jnp.int32), None, counts)
+        assert sorted({k.split("(")[0] for k in _kernels(lowered)}) == [
+            "flash_fwd", "rms_norm"]
+        compiled = lowered.compile()
+        assert compiled.memory_analysis().temp_size_in_bytes < 1536 * 2**20
+        # no layer's experts are sliced out of their stack into a copy
+        # (470 MB a layer): the grouped matmul reads the stack itself
+        made = re.findall(r"= bf16\[32,2048,3584\]\S* (\S+?)\(",
+                          compiled.as_text())
+        assert set(made) <= {"bitcast", "parameter"}, set(made)
+    assert attention.kernel_fallbacks == []
