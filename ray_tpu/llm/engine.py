@@ -26,6 +26,11 @@ continuous batching, paged KV). TPU-native redesign (JetStream-style):
 - Decode is a single jitted step for the WHOLE batch every iteration;
   requests join (prefill into a free slot) and leave (EOS/length)
   between steps without recompiling — that is the continuous batching.
+- The dense step is launched one ahead of its read-back: step N+1 goes
+  out from the state and the cache that step N returns, and the host
+  reads, emits and launches beside the device (``_fly``; the comment
+  above ``_needs_order`` has what makes that safe and what keeps the
+  old order).
 - Prefill pads prompts into power-of-two buckets so only O(log S)
   prefill programs ever compile.
 
@@ -45,6 +50,7 @@ decode batch can use different adapters.
 
 from __future__ import annotations
 
+import collections
 import functools
 import itertools
 import threading
@@ -95,7 +101,8 @@ ENGINE_STEP_SECONDS = _metrics.Histogram(
 ENGINE_STEP_HOST_SECONDS = _metrics.Histogram(
     "ray_tpu_engine_step_host_seconds",
     "Engine step wall time less the time the stepper waited for the "
-    "device in it (its blocking read-backs), by phase",
+    "device in it (its blocking read-backs and its hold before a step "
+    "launched ahead): the host's own work in a step, by phase",
     boundaries=_STEP_BOUNDS, tag_keys=("phase",))
 ENGINE_STEP_UPLOAD_SECONDS = _metrics.Histogram(
     "ray_tpu_engine_step_upload_seconds",
@@ -109,6 +116,19 @@ ENGINE_STATE_UPLOADS = _metrics.Counter(
     "ray_tpu_engine_state_uploads_total",
     "Dense decode steps that sent the per-slot state from the host: "
     "the others took it from the decode step before them")
+ENGINE_DECODE_LAUNCHES = _metrics.Counter(
+    "ray_tpu_engine_decode_launches_total",
+    "Dense decode programs launched, by order: ahead (the decode step "
+    "before it was still unread: the host's work on that one runs beside "
+    "this one) or in_order (the host had read everything: an idle "
+    "engine's first step, a step behind an admission, today's order)",
+    tag_keys=("order",))
+ENGINE_DISCARDED_TOKENS = _metrics.Counter(
+    "ray_tpu_engine_discarded_tokens_total",
+    "Tokens the device sampled for a live row that no request took: "
+    "the request had ended (a stop id, a cancel) when the token was "
+    "read; a step launched ahead costs one such token per ending the "
+    "host learns from a token")
 ENGINE_SAMPLER_STEPS = _metrics.Counter(
     "ray_tpu_engine_sampler_steps_total",
     "Decode programs launched, by the sampler's branch their live slots "
@@ -228,7 +248,8 @@ _SPAN_PHASE = {
     "engine.wait": _WAIT, "engine.prefill": _ADMIT, "engine.bias": _BIAS,
     "engine.gather": _GATHER, "engine.upload": _UPLOAD,
     "engine.launch": _LAUNCH, "engine.insert": _LAUNCH,
-    "engine.readback": _BLOCKED, "engine.emit": _EMIT,
+    "engine.readback": _BLOCKED, "engine.hold": _BLOCKED,
+    "engine.emit": _EMIT,
     "engine.step": _OTHER}
 
 
@@ -736,6 +757,26 @@ class _Admission(NamedTuple):
     span: Any         # its engine.prefill span, open until the token is out
 
 
+class _Flight(NamedTuple):
+    """A dense decode step on the device's queue whose tokens the host
+    has not read."""
+    rows: tuple       # (slot, request) of every row launched live
+    state: Any        # the state the program returned: row _TOKEN is read
+    logprobs: Any     # (chosen, top_vals, top_ids) of a logprobs step
+    t_launched: float  # the account's clock when the dispatch returned
+
+
+# The hold before a step is launched ahead ends this long before the
+# host's lead (its own recent launch time) before the expected end of
+# the step that runs: room for the thread's wake. The step's length and
+# the lead are observed, not set. (0.8 ms read the same gaps and a
+# first token 1 ms later in the Mistral cell: PERF.md section 6, PR 67.)
+_HOLD_SLACK_S = 0.0004
+# a read-back that returned sooner found its step ended already
+_READ_WAITED_S = 0.00005
+_STEP_SAMPLES = 8     # decode steps whose shortest the hold reckons with
+
+
 class ContinuousBatchingEngine:
     def __init__(self, config: EngineConfig, params=None,
                  draft_params=None):
@@ -869,6 +910,33 @@ class ContinuousBatchingEngine:
         self._state = None
         self._state_slots: tuple = ()
         self._state_stale = True
+        # The dense step launched and not read (``_Flight``), None when
+        # the device holds nothing of the dense step's. While one is in
+        # flight and ``_may_launch_ahead`` allows, the next is launched
+        # from ``_state`` before this one is read; slots then change
+        # hands by ``_park`` / ``_seat`` on the device, not by a gather.
+        self._flight: Optional[_Flight] = None
+        # set by whoever brings the stepper something to look at while
+        # it holds (add_request, add_prefilled, cancel)
+        self._arrived = threading.Event()
+        # what the hold reckons with, all observed on the stepper's
+        # clock: when its last blocking read returned (the device had
+        # finished everything up to there), the device time of the last
+        # decode steps, and the host's last times from the end of a hold
+        # to the return of the launch behind it
+        self._device_free_at = 0.0
+        self._step_device_s: collections.deque = collections.deque(
+            maxlen=_STEP_SAMPLES)
+        self._launch_lead_s: collections.deque = collections.deque(
+            maxlen=_STEP_SAMPLES)
+        # and the host's time for one admission behind a step in flight
+        self._admit_cost_s: collections.deque = collections.deque(
+            maxlen=_STEP_SAMPLES)
+        # ``_park`` and ``_seat`` not compiled yet, and the device token
+        # to compile ``_seat`` on (``_warm_edits``)
+        self._edits_cold, self._warm_token = True, None
+        self.decode_launches = {"ahead": 0, "in_order": 0}
+        self.discarded_tokens = 0
         self.prefill_tokens = {"real": 0, "pad": 0}
         self.decode_steps = 0     # dense decode programs launched
         self.state_uploads = 0    # of them, with a state from the host
@@ -997,6 +1065,32 @@ class ContinuousBatchingEngine:
             # the Llama family ks / vs of [L, 1, bucket, KVH, HD]).
             return insert_slot(cache, entry, slot)
 
+        park_at = self._dense_park
+
+        def park(state, parked):
+            """``state`` with the columns that ``parked`` ([B] int32)
+            marks as ``_gather_state`` writes an empty slot's: token 0
+            at the park row, not live, the counter kept."""
+            empty = jnp.zeros_like(state).at[_POS].set(park_at).at[
+                _STEP].set(state[_STEP])
+            return jnp.where(parked[None, :] > 0, empty, state)
+
+        def seat(state, token, row):
+            """``state`` with one slot's column as ``_gather_state``
+            writes an admitted request's, its first token taken from
+            the device: ``token`` is what ``sample_one`` returned, not
+            read. ``row`` (int32): the slot, then position, temperature
+            (bit-cast), top-k, adapter and the sampler's counter, which
+            every column takes (a prefill's sampling advanced it)."""
+            column = jnp.stack([token.astype(jnp.int32), row[1], row[2],
+                                row[3], row[4], jnp.int32(1), row[5]])
+            state = state.at[_STEP].set(row[5])
+            return jax.lax.dynamic_update_slice(
+                state, column[:, None], (0, row[0]))
+
+        # neither donates: the state a step returned is still to be read
+        self._park = jax.jit(park)
+        self._seat = jax.jit(seat)
         self._decode = jax.jit(decode, donate_argnums=(1,),
                                static_argnames=("want_lp",))
         self._prefill = jax.jit(prefill)
@@ -1005,7 +1099,6 @@ class ContinuousBatchingEngine:
         self._insert = jax.jit(insert, donate_argnums=(0,))
 
         if config.enable_prefix_caching:
-            import collections
             # token-tuple -> (ks, vs, prompt_len); LRU, device-resident
             self._prefix_cache = collections.OrderedDict()
             self.prefix_hits = 0
@@ -1286,6 +1379,7 @@ class ContinuousBatchingEngine:
         with self._lock:
             self._prefilled_waiting.append(
                 (request, ks, vs, prompt_len, first_token))
+        self._arrived.set()
         return request
 
     def add_request(self, request: GenerationRequest) -> GenerationRequest:
@@ -1318,11 +1412,13 @@ class ContinuousBatchingEngine:
                 self.waiting.append(request)
         if waiting is not None:
             raise EngineSaturatedError(waiting, cap)
+        self._arrived.set()     # a stepper that holds looks now
         return request
 
     def has_work(self) -> bool:
         with self._lock:
             return (bool(self.waiting) or bool(self._prefilled_waiting)
+                    or self._flight is not None
                     or any(s.request is not None for s in self.slots))
 
     def _free_slots(self) -> List[_Slot]:
@@ -1461,15 +1557,23 @@ class ContinuousBatchingEngine:
         the time waited is counted once: a step's host time is its
         wall time less this."""
         with self._span("engine.readback"):
-            return [np.asarray(a) for a in arrays]
+            read = [np.asarray(a) for a in arrays]
+        account = self._account
+        if threading.get_ident() == account.thread:
+            # the span's end switched the account: its clock then is
+            # when the device was seen to have finished up to here
+            self._device_free_at = account.t  # graftlint: disable=GL001  # stepper-thread-only
+        return read
 
-    def _note_kv_rows(self, active) -> None:
+    def _note_kv_rows(self, positions: List[int]) -> None:
         """The cache rows the dense step just launched covers, from the
-        positions it was given, and whose recurrent state it moved
-        (``_state_layers``): counted while the device runs it."""
+        live slots' positions as it was given them, and whose recurrent
+        state it moved (``_state_layers``): counted while the device
+        runs it."""
         block = self._kv_block
-        parked = self.config.max_batch - len(active)
-        read = block * (sum(s.pos // block for s in active) + len(active)
+        live = len(positions)
+        parked = self.config.max_batch - live
+        read = block * (sum(p // block for p in positions) + live
                         + parked * (self._dense_park // block + 1))
         skipped = self.config.max_batch * self.config.max_seq - read
         # stepper-thread-only
@@ -1479,7 +1583,7 @@ class ContinuousBatchingEngine:
         self._mbuf.inc(ENGINE_DECODE_KV_ROWS, float(skipped),
                        {"kind": "skipped"})
         if self._state_layers:
-            for kind, slots in (("moved", len(active)), ("parked", parked)):
+            for kind, slots in (("moved", live), ("parked", parked)):
                 n = slots * self._state_layers
                 self.state_slots[kind] += n  # graftlint: disable=GL001
                 self._mbuf.inc(ENGINE_STATE_SLOTS, float(n), {"kind": kind})
@@ -1860,6 +1964,8 @@ class ContinuousBatchingEngine:
             biased_as=request, want_logprobs=request.logprobs is not None)
         self.admissions += 1  # graftlint: disable=GL001  # stepper-thread-only
         self.admissions_overlapped += overlapped  # graftlint: disable=GL001
+        if self._edits_cold:
+            self._warm_token = launched.sampled[0]  # graftlint: disable=GL001
         self._mbuf.observe(
             ENGINE_ADMIT_LAUNCH_SECONDS,
             max(0.0, launched.t_launched - request.t_admit),
@@ -1892,6 +1998,7 @@ class ContinuousBatchingEngine:
         if request.done:
             # cancelled from another thread mid-step: discard the
             # token and release the slot
+            self._note_discarded()
             slot.request = None
             self._state_stale = True  # graftlint: disable=GL001  # stepper-thread-only
             return
@@ -2176,6 +2283,10 @@ class ContinuousBatchingEngine:
         self._mbuf.close()
 
     def _step_impl(self) -> int:
+        if self._flight is not None:
+            # a dense step is on the device, launched ahead by the call
+            # before: admission and the next launch go behind it
+            return self._fly(self._flight)
         self._admit()
         # guided slots: re-sync device bias rows with automaton states
         # advanced by the previous step's emissions (one [V] row upload
@@ -2237,22 +2348,274 @@ class ContinuousBatchingEngine:
             # tokens, which a fused K-step scan cannot do — dense
             # fallback while any such request is active
             return self._multi_step(active, K) + handled
+        # the dense step: nothing of it is in flight, so it is launched
+        # as it always was, the state gathered if a slot changed hands
         # stepper-thread-only: the RNG counter and the state's fields
         self._step_counter += 1  # graftlint: disable=GL001
-        self.decode_steps += 1  # graftlint: disable=GL001
         want_lp = any(s.request.logprobs is not None for s in active)
         state = self._state
         live = tuple(s.index for s in active)
         if self._state_stale or live != self._state_slots:
             # a slot changed hands: the slots' own record of tokens and
-            # positions (kept up by the emit loop below) is the truth
+            # positions (kept up by the emit loop) is the truth
             (state,) = self._upload(self._gather_state(active))
             self._state_stale = False  # graftlint: disable=GL001
             self._state_slots = live  # graftlint: disable=GL001
             self.state_uploads += 1  # graftlint: disable=GL001
             self._mbuf.inc(ENGINE_STATE_UPLOADS)
-        self._note_sampler_step()
+        flight = self._launch_decode(
+            [(s, s.request) for s in active], [s.pos for s in active],
+            state, want_lp=want_lp)
+        if self._edits_cold:
+            self._warm_edits()
+        return handled + self._fly(flight)
+
+    def _warm_edits(self) -> None:
+        """Compile ``_park`` and ``_seat`` behind the engine's first
+        dense step, on its state and the last admission's first token,
+        and drop what they return: a loop's warm-up may never park a
+        slot (its requests end in one step), and neither may compile
+        under traffic."""
+        token = self._warm_token
+        if token is None:       # only adopted prefills so far
+            return
         with self._span("engine.launch"):
+            self._park(self._state,
+                       np.zeros(self.config.max_batch, np.int32))
+            self._seat(self._state, token, np.zeros(6, np.int32))
+        self._edits_cold, self._warm_token = False, None  # graftlint: disable=GL001  # stepper-thread-only
+
+    # -- the dense step's order -----------------------------------------
+    # With a dense step in flight the stepper launches the next one from
+    # the state and the cache that step returns (device arrays, resolved
+    # in order by the runtime) BEFORE it reads the step back, so that the
+    # read-back, the emit loop and the launch itself run beside the
+    # device. Three things make that safe. (1) A slot changes hands on
+    # the device: an ending the host knows before the token comes (by
+    # length, by ``_pos_limit``, a cancel already seen) parks the slot
+    # in the state the next step is given (``_park``); an admission
+    # writes its column, first token included, from device arrays
+    # (``_seat``). (2) An ending the host learns from a token (a stop
+    # id, a cancel from another thread) is found one step late: the
+    # step ahead ran the slot live, its token is discarded in ``_land``
+    # (never appended, streamed or counted), its cache row lies inside
+    # the slot's own rows (a slot is live only below ``_pos_limit``),
+    # which the next ``insert`` overwrites whole, and the slot is
+    # parked, or seated anew, before the step after. (3) An arrival gets
+    # in front: the step ahead is launched late (``_hold``), and a
+    # request that arrives before then is admitted behind the ONE step
+    # that runs. ``_may_launch_ahead`` decides, per step, from the
+    # slots: where it says no, the step in flight is landed with none
+    # behind it and the next call is in today's order.
+
+    @classmethod
+    def _needs_order(cls, request: GenerationRequest) -> bool:
+        """The host has to see this request's token before the next
+        step is launched: its bias row follows what it generated, or
+        the token's logprobs are read with it."""
+        return (cls._has_dynamic_bias(request)
+                or request.logprobs is not None)
+
+    def _may_launch_ahead(self, requests) -> bool:
+        """Whether the step after the one in flight may be launched
+        before that one is read. ``requests``: those it would run, or
+        more. No: the other step programs' engines
+        (their rows and counters are the host's), a live request that
+        ``_needs_order``, an adopted prefill or such a request at the
+        head of the queue with a slot to take (both are admitted in
+        today's order, once nothing is in flight)."""
+        config = self.config
+        if self._spec or config.multi_step > 1 \
+                or config.chunked_prefill_tokens > 0:
+            return False
+        if any(self._needs_order(request) for request in requests):
+            return False
+        with self._lock:
+            if self._prefilled_waiting:
+                return False
+            return not (self.waiting and self._needs_order(self.waiting[0])
+                        and self._free_slots())
+
+    def _fly(self, flight: _Flight) -> int:
+        """With ``flight`` on the device: hold for an arrival; nobody
+        arriving, launch the next step ahead and only then read
+        ``flight`` back and emit it. An arrival is admitted behind
+        ``flight`` at once if the host's part of an admission fits
+        before ``flight`` ends, so that its tokens are read when they
+        come; else they are read first. From there the admission goes
+        on as in order (two deep, each first token read as it comes,
+        then a look for who arrived meanwhile: a prompt that arrives
+        during a prefill goes right behind it, not behind a decode
+        step), and the next step is launched last, from the state on
+        the device. Returns the slots ``flight`` handled."""
+        account = self._account
+        following = None
+        self._flight = None  # graftlint: disable=GL001  # stepper-thread-only
+        if not self._may_launch_ahead([r for _, r in flight.rows]):
+            self._land(flight)
+            return len(flight.rows)
+        arrival = self._hold(flight)
+        now = account.clock()
+        if arrival:
+            fits = (now + max(self._admit_cost_s, default=0.0)
+                    <= self._expected_end(flight, now) - _HOLD_SLACK_S)
+            queued = collections.deque(self._admit_behind() if fits else ())
+            self._land(flight)
+            while True:
+                if len(queued) < 2:
+                    queued.extend(self._admit_behind(bool(queued)))
+                if not queued:
+                    break
+                self._first_token_out(queued.popleft())
+        rows = self._rows_after(None if arrival else flight)
+        if rows and self._may_launch_ahead([r for _, r, _ in rows]):
+            following = self._launch_ahead(rows, ahead=not arrival)
+            if not arrival:
+                self._launch_lead_s.append(  # graftlint: disable=GL001  # stepper-thread-only
+                    following.t_launched - now)
+        if not arrival:
+            self._land(flight)
+        self._flight = following  # graftlint: disable=GL001
+        return len(flight.rows)
+
+    def _expected_end(self, flight: _Flight, now: float) -> float:
+        """When the device should have finished ``flight``: its start
+        on it (the later of the launch and the last read-back's return)
+        plus the shortest of the last steps' device times; ``now``
+        before any step has been timed."""
+        samples = self._step_device_s
+        if not samples:
+            return now
+        return max(self._device_free_at, flight.t_launched) + min(samples)
+
+    def _arrival_waits(self) -> bool:
+        """Something the stepper would act on at once: an adopted
+        prefill, or a request with a slot to take."""
+        with self._lock:
+            return bool(self._prefilled_waiting) or (
+                bool(self.waiting) and bool(self._free_slots()))
+
+    def _hold(self, flight: _Flight) -> bool:
+        """Wait for an arrival, not for the device, until the last
+        moment that still hides the host's work: the expected end of
+        the step in flight less the host's own lead (the longest of its
+        last few times from the end of a hold to the return of the
+        launch behind it) and ``_HOLD_SLACK_S``. The stepper waits here
+        because the device is busy, so the time is ``blocked``, under a
+        span of its own. No hold before a few steps have been timed.
+        Returns whether an arrival waits."""
+        leads = self._launch_lead_s
+        if len(self._step_device_s) < 3 or not leads:
+            return self._arrival_waits()
+        clock = self._account.clock
+        deadline = (self._expected_end(flight, 0.0) - max(leads)
+                    - _HOLD_SLACK_S)
+        if self._arrival_waits():
+            return True
+        if clock() >= deadline:
+            return False
+        with self._span("engine.hold"):
+            while True:
+                self._arrived.clear()  # graftlint: disable=GL001  # an Event: its own lock
+                if self._arrival_waits():
+                    return True
+                left = deadline - clock()
+                if left <= 0:
+                    return False
+                self._arrived.wait(left)
+
+    def _admit_behind(self, overlapped: bool = False) -> List[_Admission]:
+        """Admit the next waiting prompt, if it has a slot, behind what
+        the device runs: everything ``_prefill_into`` queues, then the
+        slot's column of the state (``_seat``), and no read: the caller
+        reads the first token. None admitted where nobody waits, no
+        slot is free, or the head of the queue ``_needs_order``."""
+        began = self._account.clock()
+        with self._lock:
+            free = self._free_slots() if self.waiting else None
+            if not free or self._needs_order(self.waiting[0]):
+                return []
+            request = self.waiting.pop(0)
+            slot = free[0]
+            slot.request = request
+        self._admitted_last_step += 1  # graftlint: disable=GL001  # stepper-thread-only
+        self._note_admitted(request)
+        admission = self._prefill_into(slot, request, overlapped)
+        if request.max_tokens > 1 and slot.pos < self._pos_limit:
+            # else it ends at its first token and is never live
+            self._seat_slot(slot, request, admission.sampled[0])
+        self._admit_cost_s.append(  # graftlint: disable=GL001  # stepper-thread-only
+            self._account.clock() - began)
+        return [admission]
+
+    def _seat_slot(self, slot: _Slot, request: GenerationRequest,
+                   token) -> None:
+        """``request``'s column into the state on the device, live from
+        the next step on, with ``token`` (a device array) to feed."""
+        row = np.array(
+            [slot.index, slot.pos,
+             np.float32(request.temperature).view(np.int32),
+             request.top_k, self._adapter_index(request),
+             self._step_counter + 1], dtype=np.int32)
+        with self._span("engine.launch"):
+            self._state = self._seat(self._state, token, row)  # graftlint: disable=GL001  # stepper-thread-only
+        if slot.index not in self._state_slots:
+            self._state_slots = tuple(sorted(  # graftlint: disable=GL001
+                self._state_slots + (slot.index,)))
+
+    def _rows_after(self, flight: Optional[_Flight]) -> list:
+        """The (slot, request, position) the next step runs from the
+        state on the device, as the host knows them before the tokens
+        of ``flight`` come (None: everything is read): every live
+        request less those whose next token is their last by length or
+        by ``_pos_limit``. Empty also where a live request has no
+        column in that state (it is neither in ``flight`` nor live
+        there): the step is left to today's order."""
+        in_flight = ({} if flight is None else
+                     {slot.index: request for slot, request in flight.rows})
+        rows = []
+        for slot in self.slots:
+            request = slot.request
+            if request is None or request.done:
+                continue
+            unread = in_flight.get(slot.index) is request
+            if not unread and slot.index not in self._state_slots:
+                return []
+            pos = slot.pos + unread
+            if (len(request.output_ids) + unread >= request.max_tokens
+                    or pos >= self._pos_limit):
+                continue
+            rows.append((slot, request, pos))
+        return rows
+
+    def _launch_ahead(self, rows: list, ahead: bool) -> _Flight:
+        """Launch the next dense step from the state on the device,
+        with the slots that ended parked in it; ``ahead`` of the
+        read-back of the step in flight, or with everything read (an
+        admission came between)."""
+        live = tuple(slot.index for slot, _, _ in rows)
+        state = self._state
+        gone = [i for i in self._state_slots if i not in live]
+        if gone:
+            parked = np.zeros(self.config.max_batch, np.int32)
+            parked[gone] = 1
+            with self._span("engine.launch"):
+                state = self._park(state, parked)
+        self._state_slots = live  # graftlint: disable=GL001  # stepper-thread-only
+        self._state_stale = False  # graftlint: disable=GL001
+        self._step_counter += 1  # graftlint: disable=GL001
+        return self._launch_decode(
+            [(slot, request) for slot, request, _ in rows],
+            [pos for _, _, pos in rows], state, ahead=ahead)
+
+    def _launch_decode(self, rows: list, positions: List[int], state,
+                       ahead: bool = False,
+                       want_lp: bool = False) -> _Flight:
+        """Put one dense decode program on the device's queue; what the
+        host keeps of it (counters, the account of cache rows) is done
+        behind the dispatch, while the device runs."""
+        self.decode_steps += 1  # graftlint: disable=GL001  # stepper-thread-only
+        with self._span("engine.launch", decode=self.decode_steps):
             (self._state, chosen_lp, top_vals, top_ids,
              self._expert_counts, *self.cache) = self._call_program(
                 "decode_lp" if want_lp else "decode", self._decode,
@@ -2267,27 +2630,60 @@ class ContinuousBatchingEngine:
                     self._draft_sync(
                         self.draft_params, self.draft_cache_k,
                         self.draft_cache_v, state)
+        t_launched = self._account.t
         # the state this step was given, dropped while the device runs
         # (see _upload)
         del state
-        self._note_kv_rows(active)
-        (sampled,) = self._readback(self._state)
+        order = "ahead" if ahead else "in_order"
+        self.decode_launches[order] += 1  # graftlint: disable=GL001
+        self._mbuf.inc(ENGINE_DECODE_LAUNCHES, 1.0, {"order": order})
+        paths = {"topk" if request.top_k > 0 else "full"
+                 for _, request in rows if request.temperature > 0.0}
+        self._sampler_paths = tuple(paths) or ("greedy",)  # graftlint: disable=GL001
+        self._note_sampler_step()
+        self._note_kv_rows(positions)
+        return _Flight(tuple(rows), self._state,
+                       (chosen_lp, top_vals, top_ids) if want_lp else None,
+                       t_launched)
+
+    def _land(self, flight: _Flight) -> None:
+        """Read a dense step back and emit its tokens, row by row as it
+        was launched. A row whose request has ended since (the step was
+        launched ahead of the token that ended it) is discarded."""
+        account, samples = self._account, self._step_device_s
+        free_before, waited = self._device_free_at, account.wall[_BLOCKED]
+        (sampled,) = self._readback(flight.state)
+        if account.wall[_BLOCKED] - waited > _READ_WAITED_S or not samples:
+            # the read waited for the step's end, so that end was seen
+            # when it came: the step's device time
+            samples.append(self._device_free_at
+                           - max(free_before, flight.t_launched))
+        else:
+            # the step had ended before the read came (the hold was too
+            # long, or the host slower than the device): when, nobody
+            # saw. Reckon with a shorter step until a read waits again
+            samples.append(0.9 * min(samples))
         sampled = sampled[_TOKEN]
-        if want_lp:
+        if flight.logprobs is not None:
             # only logprob requests pay the extra device-to-host syncs
-            chosen_lp, top_vals, top_ids = self._readback(
-                chosen_lp, top_vals, top_ids)
-            for slot in active:
-                if slot.request.logprobs is not None:
+            chosen_lp, top_vals, top_ids = self._readback(*flight.logprobs)
+            for slot, request in flight.rows:
+                if request.logprobs is not None:
                     slot.pending_lp = (chosen_lp[slot.index],
                                        top_vals[slot.index],
                                        top_ids[slot.index])
         with self._span("engine.emit"):
-            for slot in active:
+            for slot, request in flight.rows:
+                if slot.request is not request:
+                    self._note_discarded()
+                    continue
                 slot.pos += 1
                 slot.next_token = int(sampled[slot.index])
                 self._emit(slot, slot.next_token)
-        return len(active) + handled
+
+    def _note_discarded(self) -> None:
+        self.discarded_tokens += 1  # graftlint: disable=GL001  # stepper-thread-only
+        self._mbuf.inc(ENGINE_DISCARDED_TOKENS)
 
     # ------------------------------------------------------------------
     def generate(self, prompts_ids: List[List[int]], *,
@@ -2299,7 +2695,8 @@ class ContinuousBatchingEngine:
                 prompt_ids=ids, max_tokens=max_tokens,
                 temperature=temperature, top_k=top_k, stop_ids=stop_ids))
             for ids in prompts_ids]
-        while any(not r.done for r in requests):
+        # the loop IS the stepper: each turn runs a step, nothing is polled
+        while any(not r.done for r in requests):  # graftlint: disable=GL003
             if self.step() == 0 and any(not r.done for r in requests):
                 # nothing active yet (all waiting on slots) — admit again
                 time.sleep(0)
@@ -2335,6 +2732,7 @@ class ContinuousBatchingEngine:
             slot.pending_lp = None
         self._state = None
         self._state_stale = True
+        self._flight = None
         self._account.prefills = 0   # a step that raised left them open
         self.cache = self._fresh_cache(self.config.model)
         if self._spec:
@@ -2367,6 +2765,7 @@ class ContinuousBatchingEngine:
                 e for e in self._prefilled_waiting if e[0] is not request]
             request.finish(finish_reason)
         request.push_stream(None)
+        self._arrived.set()
 
     def embed(self, prompt_ids: List[int]) -> np.ndarray:
         """Mean-pooled final-norm hidden state for a prompt — the
@@ -2422,6 +2821,11 @@ class ContinuousBatchingEngine:
                 # were sent their per-slot state from the host
                 "decode_steps": self.decode_steps,
                 "state_uploads": self.state_uploads,
+                # dense decode programs by order (ahead: launched while
+                # the step before was unread), and the tokens sampled
+                # for a row whose request had ended when they were read
+                "decode_launches": dict(self.decode_launches),
+                "discarded_tokens": self.discarded_tokens,
                 # rows of a layer's KV cache those steps' attention
                 # covered, and the rest of slots x max_seq
                 "decode_kv_rows_read": self.decode_kv_rows["read"],

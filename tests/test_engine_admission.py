@@ -242,3 +242,238 @@ def test_mixed_admissions_compile_each_program_once():
                     engine._insert, engine._decode):
         assert program._cache_size() == 1
     assert list(engine.stats()["programs"]) == ["prefill_4", "decode"]
+
+
+# -- the dense step launched ahead of its read-back (PR 67) ---------------
+# A dense step is launched from the state the step in flight returns,
+# before that one is read; slots change hands on the device. The order
+# is the stepper's own choice (``_may_launch_ahead``); a test that wants
+# today's order says so through that predicate, as nothing else can.
+
+def _in_order(engine):
+    engine._may_launch_ahead = lambda requests: False
+    return engine
+
+
+def _prompt(n, salt):
+    return [3 + (7 * i + 11 * salt) % 240 for i in range(n)]
+
+
+# ``at``: the call of step() before which the request arrives; ``cancel_at``:
+# the call after which another thread cancels it; ``stop_at``: it ends on
+# a stop id, the token it emits there without one. Three slots: the later
+# ones take slots that an ending by length, the cancel and the stop freed.
+_ALIGNED = [
+    dict(at=0, n=5, max_tokens=12),
+    dict(at=0, n=7, max_tokens=4),
+    dict(at=0, n=4, max_tokens=30, cancel_at=4),
+    dict(at=5, n=6, max_tokens=15, stop_at=3),
+    dict(at=9, n=9, max_tokens=3),
+    dict(at=13, n=3, max_tokens=6),
+]
+# a fourth prompt at the start waits for a slot, and two arrive in one
+# call: who gets which step then depends on the order, the tokens of a
+# greedy request do not
+_CONTENDED = _ALIGNED[:3] + [
+    dict(at=0, n=8, max_tokens=5),
+    dict(at=6, n=6, max_tokens=15, stop_at=3),
+    dict(at=6, n=9, max_tokens=3),
+    dict(at=11, n=3, max_tokens=6),
+]
+
+
+def _run_specs(engine, specs, temperature, shift=0):
+    """step() by hand. Returns the requests and the slot each took."""
+    import queue
+    import threading
+    requests = [GenerationRequest(
+        prompt_ids=_prompt(spec["n"], k), max_tokens=spec["max_tokens"],
+        temperature=temperature, top_k=(0, 5, 0, 3, 0, 8, 4)[k]
+        if temperature else 0, stop_ids=tuple(spec.get("stop_ids", ())),
+        stream_queue=queue.Queue()) for k, spec in enumerate(specs)]
+    slots = {}
+    prefill_into = engine._prefill_into
+
+    def noting(slot, request, overlapped):
+        slots[request.request_id] = slot.index
+        return prefill_into(slot, request, overlapped)
+
+    engine._prefill_into = noting
+    for i in range(200):
+        for spec, request in zip(specs, requests):
+            if spec["at"] + (shift if spec["at"] else 0) == i:
+                engine.add_request(request)
+        engine.step()
+        for spec, request in zip(specs, requests):
+            if spec.get("cancel_at") == i:
+                thread = threading.Thread(target=engine.cancel,
+                                          args=(request,))
+                thread.start()
+                thread.join()
+        if all(r.done for r in requests) and not engine.has_work():
+            break
+    return requests, [slots.get(r.request_id) for r in requests]
+
+
+def _streamed(request):
+    out = []
+    while not request.stream_queue.empty():
+        out.append(request.stream_queue.get_nowait())
+    return out
+
+
+@pytest.mark.parametrize("family", ["llama", "jamba"])
+@pytest.mark.parametrize("traffic", ["greedy", "greedy, contended",
+                                     "sampled"])
+def test_steps_launched_ahead_emit_what_steps_in_order_emit(
+        family, traffic):
+    """Token for token, greedy and sampled, over endings by length, by
+    a stop id and by a cancel, with admissions into the freed slots. A
+    prompt admitted behind a step in flight joins the step after it, so
+    the run in order is given each later arrival one call later: the
+    device then runs the same programs in the same order, which for a
+    sampled token (its key is the sampler's counter split by slot) is
+    the whole of the seed. The token of the step launched ahead of a
+    stop id is in no output, no stream and no count."""
+    model = (LLAMA if family == "llama"
+             else JambaConfig.tiny(dtype=jnp.float32))
+    temperature = 0.9 if traffic == "sampled" else 0.0
+    specs = [dict(s) for s in (_CONTENDED if "contended" in traffic
+                               else _ALIGNED)]
+    shift = 0 if "contended" in traffic else 1
+    stopper = next(k for k, s in enumerate(specs) if "stop_at" in s)
+    # learn the stop id from a run in order without one
+    learned, _ = _run_specs(_in_order(_engine(model)), specs,
+                            temperature, shift)
+    stop_id = learned[stopper].output_ids[specs[stopper]["stop_at"]]
+    stop_at = learned[stopper].output_ids.index(stop_id)
+    specs[stopper]["stop_ids"] = (stop_id,)
+
+    ahead = _engine(model)
+    got, got_slots = _run_specs(ahead, specs, temperature)
+    in_order = _in_order(_engine(model))
+    want, want_slots = _run_specs(in_order, specs, temperature, shift)
+    reasons = [r.finish_reason for r in got]
+    assert reasons == [r.finish_reason for r in want]
+    assert reasons.count("stop") == 1 and reasons.count("abort") == 1
+    assert reasons.count("length") == len(specs) - 2
+    if shift:
+        assert got_slots == want_slots
+    for g, w in zip(got, want):
+        if g.finish_reason == "abort" and not shift:
+            # a cancel ends what a request had got by then
+            n = min(len(g.output_ids), len(w.output_ids))
+            assert n and g.output_ids[:n] == w.output_ids[:n]
+        else:
+            assert g.output_ids == w.output_ids and g.output_ids
+        assert _streamed(g) == g.output_ids + [None]
+    assert len(got[stopper].output_ids) == stop_at + 1
+    for engine, requests in ((ahead, got), (in_order, want)):
+        assert engine.total_generated == sum(
+            len(r.output_ids) for r in requests)
+    # the stop id cost the token of the step launched ahead of it; the
+    # cancel, seen before the next launch, the one in flight, as in order
+    assert ahead.stats()["discarded_tokens"] == 2
+    assert in_order.stats()["discarded_tokens"] == 1
+    launches = ahead.stats()["decode_launches"]
+    # in order: an idle engine's first step and the steps behind an
+    # admission, which is read first
+    assert launches["ahead"] > launches["in_order"] > 0
+    assert in_order.stats()["decode_launches"]["ahead"] == 0
+    assert ahead._decode._cache_size() == 1
+    assert ahead._seat._cache_size() == ahead._park._cache_size() == 1
+
+
+@pytest.mark.parametrize("stops", [1, 3])
+def test_every_stop_id_costs_one_discarded_token(stops):
+    """Requests that end on a stop id before their length: each ran one
+    step further on the device than its output shows, and that token
+    was dropped; the counter and the series count them."""
+    series = "ray_tpu_engine_discarded_tokens_total"
+    engine = _engine(max_batch=4)
+    engine.flush_metrics()
+    def counted():
+        for line in metrics_mod.prometheus_text().splitlines():
+            if line.startswith(series + " "):
+                return float(line.split()[-1])
+        return 0.0
+
+    before = counted()
+    free = [engine.add_request(GenerationRequest(
+        prompt_ids=_prompt(4 + k, k), max_tokens=12)) for k in range(stops)]
+    _drain(engine, free)
+    stopped = [engine.add_request(GenerationRequest(
+        prompt_ids=_prompt(4 + k, k), max_tokens=12,
+        stop_ids=(r.output_ids[3 + k],))) for k, r in enumerate(free)]
+    plain = engine.add_request(GenerationRequest(
+        prompt_ids=_prompt(5, 9), max_tokens=14))
+    _drain(engine, stopped + [plain])
+    while engine.has_work():
+        engine.step()
+    for k, (r, f) in enumerate(zip(stopped, free)):
+        assert r.finish_reason == "stop"
+        assert r.output_ids == f.output_ids[:f.output_ids.index(
+            r.stop_ids[0]) + 1]
+    assert plain.finish_reason == "length" and len(plain.output_ids) == 14
+    stats = engine.stats()
+    assert stats["discarded_tokens"] == stops
+    assert counted() - before == stops
+    assert stats["total_generated"] == sum(
+        len(r.output_ids) for r in free + stopped + [plain])
+
+
+def _ordered(kind, i=7):
+    if kind == "guided":
+        return _guided(i % 3)
+    if kind == "penalties":
+        return _plain(1, max_tokens=6, presence_penalty=0.7,
+                      frequency_penalty=0.3)
+    return _plain(2, max_tokens=6, logprobs=2)
+
+
+@pytest.mark.parametrize("kind", ["guided", "penalties", "logprobs"])
+def test_a_request_the_host_must_follow_puts_the_batch_in_order_and_back(
+        kind):
+    """While a request lives whose next step needs its last token on
+    the host (a grammar's mask, a penalty's row, logprobs), every dense
+    step is in order, the plain slots' too; before it and after it they
+    are launched ahead. Everyone's tokens are what they get alone."""
+    def launches(engine):
+        return dict(engine.decode_launches)
+
+    engine = _engine(max_batch=3)
+    plain = [engine.add_request(_plain(i, max_tokens=60))
+             for i in range(2)]
+    for _ in range(6):
+        engine.step()
+    before = launches(engine)
+    assert before["ahead"] >= 6 and before["in_order"] == 1
+    special = engine.add_request(_ordered(kind))
+    _drain(engine, [special])
+    during = launches(engine)
+    # at most the step that was in flight when it arrived was ahead
+    steps = len(special.output_ids) - 1
+    assert during["in_order"] - before["in_order"] >= steps
+    assert during["ahead"] - before["ahead"] <= 1
+    for _ in range(6):
+        engine.step()
+    after = launches(engine)
+    assert after["ahead"] - during["ahead"] >= 5
+    assert after["in_order"] - during["in_order"] <= 1
+    _drain(engine, plain)
+    for request in plain + [special]:
+        alone = _engine(max_batch=3)
+        same = GenerationRequest(
+            prompt_ids=list(request.prompt_ids),
+            max_tokens=request.max_tokens, logprobs=request.logprobs,
+            presence_penalty=request.presence_penalty,
+            frequency_penalty=request.frequency_penalty,
+            guided=request.guided)
+        alone.add_request(same)
+        _drain(alone, [same])
+        assert same.output_ids == request.output_ids
+        assert same.finish_reason == request.finish_reason
+        if kind == "logprobs" and request is special:
+            assert [e["id"] for e in same.logprob_data] == \
+                [e["id"] for e in request.logprob_data]
+            assert len(request.logprob_data) == 6

@@ -241,62 +241,88 @@ def _counter(name, **tags):
     return 0.0
 
 
-def test_state_is_sent_once_for_each_change_of_the_slots():
-    """Decode steps with no admission and no ending send nothing; an
-    admission, an ending, a cancel and a fail_all each cost exactly one
-    state at the next dense step (a cancel is seen by the stepper one
-    step after it is made: that step still takes the device's state).
-    ``stats()`` and the two series say the same."""
+def _in_order(engine):
+    """The dense step as it was until PR 67 (read back before the next
+    is launched), through the stepper's own predicate."""
+    engine._may_launch_ahead = lambda requests: False
+    return engine
+
+
+@pytest.mark.parametrize("ahead", [False, True])
+def test_state_is_sent_once_for_each_change_of_the_slots(ahead):
+    """Decode steps with no admission and no ending send nothing. In
+    order, an admission, an ending, a cancel and a fail_all each cost
+    exactly one state at the next dense step (a cancel is seen by the
+    stepper one step after it is made: that step still takes the
+    device's state). Launched ahead, the slots change hands on the
+    device and a state is sent only where nothing is in flight: the
+    first step, and the first after fail_all. ``stats()`` and the two
+    series say the same."""
     engine = ContinuousBatchingEngine(_engine_config(max_batch=4))
+    if not ahead:
+        _in_order(engine)
     engine.flush_metrics()
     uploads0 = _counter(STATE_UPLOADS)
     upload_hist0 = {phase: _hist(STEP_UPLOAD, phase=phase)
                     for phase in ("decode", "prefill")}
+    sent = iter([1, 0, 0, 0, 0, 0, 0, 0, 0, 0, 0, 1, 0] if ahead
+                else [1, 0, 1, 0, 0, 1, 0, 0, 1, 0, 0, 1, 0])
 
     def steps(n):
-        """n steps; how many states they sent."""
+        """n steps; whether they sent the states expected of them."""
         was = engine.state_uploads
         for _ in range(n):
             engine.step()
-        return engine.state_uploads - was
+        return engine.state_uploads - was == next(sent)
 
     def add(max_tokens):
         return engine.add_request(GenerationRequest(
             prompt_ids=[1, 2, 3, max_tokens], max_tokens=max_tokens))
 
     long_a, long_b = add(60), add(60)
-    assert steps(1) == 1          # two admissions, one state
-    assert steps(6) == 0          # k steps, nothing changed hands
+    assert steps(1)               # two admissions, one state
+    assert steps(6)               # k steps, nothing changed hands
     short = add(4)
-    assert steps(1) == 1          # an admission
-    assert steps(1) == 0
-    assert steps(1) == 0 and short.done     # its fourth token: an ending
-    assert steps(1) == 1
-    assert steps(3) == 0
+    assert steps(1)               # an admission
+    assert steps(1)
+    # its fourth token, an ending; admitted behind a step in flight it
+    # joined the step after
+    assert steps(1) and short.done != ahead
+    assert steps(1) and short.done
+    assert steps(3)
     canceller = threading.Thread(target=engine.cancel, args=(long_a,))
     canceller.start()
     canceller.join()
-    assert steps(1) == 0          # the stepper finds it cancelled
-    assert steps(1) == 1
-    assert steps(3) == 0 and not long_b.done
+    assert steps(1)               # the stepper finds it cancelled
+    assert steps(1)
+    assert steps(3) and not long_b.done
     engine.fail_all("test")
-    assert long_b.done and steps(1) == 0 and engine._state is None
+    assert long_b.done and steps(1) and engine._state is None
     again = add(8)
-    assert steps(1) == 1          # fail_all and the admission: one state
-    assert steps(5) == 0 and not again.done
+    assert steps(1)               # fail_all and the admission: one state
+    assert steps(5) and not again.done
     stats = engine.stats()
-    assert stats["state_uploads"] == engine.state_uploads == 5
-    assert stats["decode_steps"] == engine.decode_steps == 25
-    assert _counter(STATE_UPLOADS) - uploads0 == 5
+    n_sent = 2 if ahead else 5
+    assert stats["state_uploads"] == engine.state_uploads == n_sent
+    # launched ahead, a call leaves one more step on the device: one
+    # when fail_all dropped what was in flight, one at the end
+    assert stats["decode_steps"] == engine.decode_steps == 25 + 2 * ahead
+    # in order also where the host had read everything: the first step,
+    # the step behind ``short``'s admission, the first after fail_all
+    assert stats["decode_launches"] == (
+        {"ahead": 24, "in_order": 3} if ahead
+        else {"ahead": 0, "in_order": 25})
+    assert _counter(STATE_UPLOADS) - uploads0 == n_sent
     # the upload time is observed once a step, 0.0 where nothing was
     # sent, so its count is the step count and its sum is the time of
     # the few steps that sent
     observed = 0
     for phase in ("decode", "prefill"):
         total, count = _hist(STEP_UPLOAD, phase=phase)
-        # prefill: three admitting steps sent prompts; decode: the two
-        # steps after an ending sent a state
-        assert total - upload_hist0[phase][0] > 0.0
+        # prefill: three admitting steps sent prompts; decode, in
+        # order: the two steps after an ending sent a state
+        assert total - upload_hist0[phase][0] > 0.0 or (
+            ahead and phase == "decode")
         observed += count - upload_hist0[phase][1]
     assert observed == engine._steps == 26
 
@@ -312,7 +338,8 @@ def recorder():
 
 
 def test_step_spans_lie_inside_their_step_and_carry_its_number(recorder):
-    engine = ContinuousBatchingEngine(_engine_config())
+    # the spans of a step in order; those of one launched ahead: (g)
+    engine = _in_order(ContinuousBatchingEngine(_engine_config()))
     requests = [engine.add_request(GenerationRequest(
         prompt_ids=[1, 2, 3, i], max_tokens=3,
         logit_bias={7: -100.0} if i == 0 else None)) for i in range(3)]
@@ -381,6 +408,154 @@ def test_step_spans_lie_inside_their_step_and_carry_its_number(recorder):
         q = queued[ev[5]["req"]]
         assert q[5]["step"] == ev[5]["step"]
         assert abs(q[1] + q[2] - ev[1]) < 5e6   # ns
+
+
+# -- (g) a step launched ahead: its spans, its hold, its counters ---------
+
+LAUNCHES = "ray_tpu_engine_decode_launches_total"
+DISCARDED = "ray_tpu_engine_discarded_tokens_total"
+
+
+def _held(engine, seconds):
+    """Steps that the stepper believes to take ``seconds`` on the device
+    and no time to launch: its hold lasts about that long."""
+    engine._step_device_s.extend([seconds] * 8)
+    engine._launch_lead_s.append(0.0)
+
+
+def _engine_spans(recorder, step=None):
+    spans = sorted((ev for ev in recorder.snapshot() if ev[3] == "serve"
+                    and ev[4].startswith("engine.")), key=lambda ev: ev[1])
+    return [ev for ev in spans if ev[4] != "engine.step"
+            and (step is None or ev[5]["step"] == step)]
+
+
+def test_an_arrival_during_the_hold_goes_behind_the_one_step_that_runs(
+        recorder):
+    """With decode N+1 launched, the stepper holds. A request that
+    arrives then is admitted at once: its prefill, its first token's
+    sampling, its insert and its seat are queued behind N+1, where
+    today's order would have put them, and decode N+2 is launched behind
+    them with the request live in it. Nobody arriving, the hold ends at
+    its deadline and the next plain step is launched."""
+    engine = ContinuousBatchingEngine(_engine_config(max_batch=3))
+    first = engine.add_request(GenerationRequest(
+        prompt_ids=[1, 2, 3, 4], max_tokens=60))
+    for _ in range(5):
+        engine.step()
+    _held(engine, 0.05)
+    late = GenerationRequest(prompt_ids=[4, 3, 2, 1], max_tokens=8)
+    wait = engine._arrived.wait
+
+    def someone_arrives(timeout):
+        if late.t_submit is None:
+            engine.add_request(late)
+        return wait(timeout)
+
+    engine._arrived.wait = someone_arrives
+    in_flight = engine.decode_steps            # N+1, launched by step 5
+    engine.step()
+    spans = _engine_spans(recorder)
+    decodes = {ev[5]["decode"]: ev for ev in spans
+               if ev[4] == "engine.launch" and "decode" in ev[5]}
+    (hold,) = [ev for ev in spans if ev[4] == "engine.hold"
+               and ev[5]["step"] == engine._steps]
+    (prefill,) = [ev for ev in spans if ev[4] == "engine.prefill"
+                  and ev[5]["req"] == late.request_id]
+    assert engine.decode_steps == in_flight + 1
+    assert (decodes[in_flight][1] < hold[1] < prefill[1]
+            < decodes[in_flight + 1][1])
+    # the hold ended on the arrival, well before its deadline
+    assert hold[2] < 0.04e9
+    # it fits before N+1 ends, so everything of the admission, the seat
+    # last, is queued before the host waits at all; then N+1 is read
+    # when it comes, the first token after it (and a look for who else
+    # arrived, as in order), and N+2 is launched last, from the state
+    # on the device
+    mine = _engine_spans(recorder, step=engine._steps)
+    names = [ev[4] for ev in mine]
+    read = names.index("engine.readback")
+    assert names[1:read].count("engine.launch") == 4   # prefill, sampling,
+    assert names[read - 1] == "engine.launch"          # hand-over, seat
+    assert mine[-1] is decodes[in_flight + 1]
+    assert names[read:] == ["engine.readback", "engine.emit",
+                            "engine.readback", "engine.emit",
+                            "engine.launch"]
+    assert engine.state_uploads == 1
+    assert len(late.output_ids) == 1 and late.t_first_token is not None
+    rows = {slot.index for slot, _ in engine._flight.rows}
+    assert len(rows) == 2                      # it is live in N+2
+    engine.step()
+    assert len(late.output_ids) == 2
+    # nobody arrives: the hold runs to its deadline, then a plain step
+    engine._arrived.wait = wait
+    for _ in range(2):
+        engine.step()
+        mine = [ev[4] for ev in _engine_spans(recorder, step=engine._steps)]
+        assert mine == ["engine.hold", "engine.launch", "engine.readback",
+                        "engine.emit"]
+    hold = next(ev for ev in _engine_spans(recorder, step=engine._steps)
+                if ev[4] == "engine.hold")
+    assert 0.02e9 < hold[2] < 0.2e9
+    assert first.output_ids and engine.state_uploads == 1
+
+
+def test_the_hold_is_blocked_time_and_the_launches_are_counted():
+    """The account still closes with ``engine.hold`` in it: the nine
+    wall series sum to the stepper's life, the hold under ``blocked``,
+    so a step's host time (its wall less ``blocked``) leaves the hold
+    out. The two counters of the order reach ``stats()`` and their
+    series."""
+    from ray_tpu.llm.engine import STEPPER_PHASES
+    engine = ContinuousBatchingEngine(_engine_config(max_batch=3))
+    engine.flush_metrics()
+    ahead0, in_order0 = (_counter(LAUNCHES, order=o)
+                         for o in ("ahead", "in_order"))
+    discarded0 = _counter(DISCARDED)
+    host0 = _hist(STEP_HOST, phase="decode")
+    step0 = _hist(STEP, phase="decode")
+    account = engine._account
+    account.bind()
+    born = account.t
+    learn = engine.add_request(GenerationRequest(
+        prompt_ids=[5, 6, 7, 8, 9], max_tokens=12))
+    while engine.has_work():
+        engine.step()
+    assert learn.output_ids.index(learn.output_ids[4]) == 4
+    stops = engine.add_request(GenerationRequest(
+        prompt_ids=[5, 6, 7, 8, 9], max_tokens=12,
+        stop_ids=(learn.output_ids[4],)))
+    plain = engine.add_request(GenerationRequest(
+        prompt_ids=[4, 3, 2, 1], max_tokens=30))
+    engine.step()
+    _held(engine, 0.03)
+    blocked0 = account.wall[STEPPER_PHASES.index("blocked")]
+    while engine.has_work():
+        engine.step()
+    assert stops.finish_reason == "stop" and plain.finish_reason == "length"
+    wall = dict(zip(STEPPER_PHASES, account.wall))
+    assert abs(sum(account.wall) - (account.t - born)) < 1e-9
+    # some twenty-five holds, the first of 30 ms (a tiny step has ended
+    # long before such a hold does, and the stepper reckons with a
+    # shorter one each time)
+    assert wall["blocked"] - blocked0 > 0.15
+    stats = engine.stats()
+    launches = stats["decode_launches"]
+    assert launches["in_order"] == 2 and launches["ahead"] >= 35
+    assert sum(launches.values()) == stats["decode_steps"]
+    assert stats["discarded_tokens"] == 1
+    # (another test's engine may still flush into the same series)
+    assert _counter(LAUNCHES, order="ahead") - ahead0 >= launches["ahead"]
+    assert _counter(LAUNCHES, order="in_order") - in_order0 >= 2
+    assert _counter(DISCARDED) - discarded0 >= 1
+    assert abs(sum(stats["stepper_seconds"]["wall"].values())
+               - (stats["stepper_read_at"] - born)) < 0.05
+    # the steps' wall time holds the holds, their host time does not
+    steps = _hist(STEP, phase="decode")
+    hosts = _hist(STEP_HOST, phase="decode")
+    assert steps[1] - step0[1] == hosts[1] - host0[1] >= 35
+    assert steps[0] - step0[0] > 0.15
+    assert hosts[0] - host0[0] < 0.5 * (steps[0] - step0[0])
 
 
 # -- (d2) the stepper's account of its own time ---------------------------

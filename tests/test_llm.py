@@ -203,20 +203,32 @@ _SAMPLED_ON_THE_PARENT = [
 ]
 
 
-@pytest.mark.parametrize("temperature,every_state_from_host", [
-    (0.0, False), (0.8, False), (0.8, True)])
+def _in_order(engine):
+    """The dense step as it was until PR 67: read back before the next
+    is launched. Through the stepper's own predicate, not an option."""
+    engine._may_launch_ahead = lambda requests: False
+    return engine
+
+
+@pytest.mark.parametrize("temperature,every_state_from_host,ahead", [
+    (0.0, False, False), (0.8, False, False), (0.8, True, False),
+    (0.0, False, True)])
 def test_staggered_traffic_emits_what_each_request_gets_alone(
-        temperature, every_state_from_host):
+        temperature, every_state_from_host, ahead):
     """Greedy: token for token what each request gets when generated
-    alone. Sampled with a seed: what the parent commit emitted (a
-    token's key is fold_in(base_key, step_counter) split by slot, so
-    the schedule is part of the seed), also when every step is made to
-    send its state as the parent did. Whenever a step takes the state
-    the step before it left on the device, that state equals what the
-    host would have gathered from the slots."""
+    alone, whether the steps are launched ahead or in order. Sampled
+    with a seed, in order: what the parent commit emitted (a token's
+    key is fold_in(base_key, step_counter) split by slot, so the
+    schedule is part of the seed, and a step launched ahead puts an
+    admission one step later in it), also when every step is made to
+    send its state as the parent did. Whenever a step in order takes
+    the state the step before it left on the device, that state equals
+    what the host would have gathered from the slots."""
     fed_back = []
 
     def before_step(engine):
+        if ahead:
+            return
         if every_state_from_host:
             engine._state_stale = True
         elif not engine._state_stale:
@@ -227,38 +239,57 @@ def test_staggered_traffic_emits_what_each_request_gets_alone(
             np.testing.assert_array_equal(np.asarray(engine._state), want)
             fed_back.append(engine._steps)
 
+    def make():
+        engine = tiny_engine(max_batch=3, seed=5)
+        return engine if ahead else _in_order(engine)
+
     specs = _staggered_specs(temperature)
     if temperature > 0:
         specs[4]["stop_ids"] = (199,)
     else:
         # the stop token: the fourth the request emits without one
         learn = dict(specs[4], at=0)
-        (ids, _), = _run_schedule(tiny_engine(max_batch=3, seed=5),
-                                  [learn])
+        (ids, _), = _run_schedule(make(), [learn])
         specs[4]["stop_ids"] = (ids[specs[4]["stop_at"]],)
-    engine = tiny_engine(max_batch=3, seed=5)
+    engine = make()
     got = _run_schedule(engine, specs, before_step)
     assert [why for _, why in got] == [
         "length", "length", "abort", "length", "stop", "length", "length"]
-    assert len(got[2][0]) == 6 and len(got[4][0]) == 4
+    # admitted behind a step in flight, a prompt joins the step after
+    assert len(got[2][0]) == (5 if ahead else 6) and len(got[4][0]) == 4
     if temperature > 0:
         assert got == _SAMPLED_ON_THE_PARENT
     else:
         for spec, (ids, why) in zip(specs, got):
-            (alone, _), = _run_schedule(tiny_engine(max_batch=3, seed=5),
+            (alone, _), = _run_schedule(make(),
                                         [dict(spec, at=0, cancel_at=None)])
             assert ids == alone[:len(ids)], spec
             assert len(ids) == len(alone) or why == "abort"
+    while engine.has_work():
+        engine.step()           # a step launched ahead of the last ending
     stats = engine.stats()
     assert stats["decode_steps"] == engine.decode_steps >= 15
-    if every_state_from_host:
+    assert sum(stats["decode_launches"].values()) == stats["decode_steps"]
+    if ahead:
+        # slots changed hands on the device: the state was sent where
+        # the engine had run dry; the cancel (seen before the next
+        # launch) cost the token in flight, as in order, and the stop id
+        # none: its call admitted the prompt that had waited for a slot,
+        # so its step was read before the next was launched
+        assert stats["state_uploads"] <= 3
+        assert stats["decode_launches"]["ahead"] \
+            > stats["decode_launches"]["in_order"]
+        assert stats["discarded_tokens"] == 1
+    elif every_state_from_host:
         assert stats["state_uploads"] == stats["decode_steps"]
+        assert stats["decode_launches"]["ahead"] == 0
     else:
         # 7 admissions in 6 steps (two share one), 7 endings; a step
         # that found the state good may still admit and send one
         assert len(fed_back) >= \
             stats["decode_steps"] - stats["state_uploads"] >= 4
         assert stats["state_uploads"] <= 13
+        assert stats["discarded_tokens"] == 1       # the cancel's
     assert engine._decode._cache_size() == 1
 
 
@@ -360,7 +391,18 @@ def test_decode_is_compiled_once_whichever_way_its_state_comes(
         assert run(engine) == want
     else:
         run(engine)
-    assert 2 <= engine.state_uploads < engine.decode_steps
+    if want_lp:
+        # in order: a state from the host after the admission and after
+        # the ending
+        assert 2 <= engine.state_uploads < engine.decode_steps
+    else:
+        # launched ahead: the admission is seated and the ending parked
+        # on the device, by one program each, and the state they return
+        # reaches ``decode`` as its own does
+        assert engine.state_uploads == 1
+        assert engine.decode_launches["ahead"] >= 6
+        assert engine._seat._cache_size() == 1
+        assert engine._park._cache_size() == 1
     assert engine._state.sharding.device_set == devices
     assert engine.cache_k.sharding.device_set == devices
     assert engine._decode._cache_size() == 1
