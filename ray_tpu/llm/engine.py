@@ -22,7 +22,10 @@ continuous batching, paged KV). TPU-native redesign (JetStream-style):
   caching, chunked prefill, speculative and multi-step decoding,
   LoRA banks and disaggregated prefill assume rows that can be written
   again; the engine refuses them for such a family, by name, instead of
-  corrupting a state.
+  corrupting a state. They are the Llama family's programs over a pair
+  of keys and values, so a family whose cache is rows of a latent
+  (models/mla.py) is refused them too, for that reason
+  (``ModelFamily.dense_only``).
 - Decode is a single jitted step for the WHOLE batch every iteration;
   requests join (prefill into a free slot) and leave (EOS/length)
   between steps without recompiling — that is the continuous batching.
@@ -212,8 +215,9 @@ ENGINE_STEPPER_CPU_WALL_SECONDS = _metrics.Counter(
     tag_keys=("phase",))
 ENGINE_CACHE_BYTES = _metrics.Gauge(
     "ray_tpu_engine_cache_bytes",
-    "Bytes of the serving cache, by kind: kv (rows of keys and values) "
-    "or recurrent (state a decode step consumes and replaces)",
+    "Bytes of the serving cache, by kind: kv (rows of keys and values), "
+    "recurrent (state a decode step consumes and replaces) or latent "
+    "(rows that an attention layer reads as keys and as values)",
     tag_keys=("kind",))
 ENGINE_TOKENS_PER_S = _metrics.Gauge(
     "ray_tpu_engine_tokens_per_second",
@@ -789,8 +793,8 @@ class ContinuousBatchingEngine:
         self.config = config
         c = config.model
         fam = self._family = family_of(c)
-        if fam.recurrent:
-            self._refuse_for_recurrent(config)
+        if fam.dense_only:
+            self._refuse_beyond_dense(config, fam.dense_only)
         # name -> (jitted, abstract args, static kwargs) of the first
         # call of each hot program, and the Pallas kernels its lowered
         # text holds (filled by stats())
@@ -1390,7 +1394,7 @@ class ContinuousBatchingEngine:
         if len(request.prompt_ids) > limit:
             request.prompt_ids = request.prompt_ids[-limit:]
         if request.adapter is not None:
-            if self._family.recurrent:
+            if self._family.dense_only:
                 raise ValueError(
                     "adapter: LoRA is implemented for the Llama family's "
                     "projections, not for a "
@@ -1598,16 +1602,17 @@ class ContinuousBatchingEngine:
         self._mbuf.inc(ENGINE_PREFILL_TOKENS, float(pad), {"kind": "pad"})
 
     @staticmethod
-    def _refuse_for_recurrent(config: EngineConfig) -> None:
-        """A family whose cache holds recurrent state runs the dense
-        path only. The other step programs and the prefix cache
-        rewrite, keep or ship rows of keys and values, and a state has
-        none: run over one they would corrupt it, so the engine says so
-        at construction."""
+    def _refuse_beyond_dense(config: EngineConfig, cache_is: str) -> None:
+        """A family with a word on its cache (``ModelFamily.dense_only``:
+        ``cache_is``) runs the dense path only. The other step programs
+        and the prefix cache rewrite, keep or ship rows of keys and
+        values as the Llama family lays them out: run over a consumed
+        state they would corrupt it, and a latent row is neither a key
+        nor a value, so the engine says so at construction."""
         family = type(config.model).__name__
-        rows = (f"needs cache rows that can be written again or kept "
-                f"apart; the cache of a {family} holds recurrent state "
-                "that a decode step consumes")
+        rows = (f"needs the Llama family's cache, rows of keys and values "
+                f"that can be written again or kept apart; the cache of a "
+                f"{family} {cache_is}")
         llama_only = (f"is implemented for the Llama family's "
                       f"projections, not for a {family}")
         for option, asked, why in (
@@ -1624,11 +1629,11 @@ class ContinuousBatchingEngine:
                 raise ValueError(f"{option} {why}")
 
     def _refuse_disagg(self, what: str) -> None:
-        if self._family.recurrent:
+        if self._family.dense_only:
             raise ValueError(
                 f"{what} ships a prompt's rows of keys and values; the "
-                f"cache of a {type(self.config.model).__name__} holds "
-                "recurrent state beside them, which the disaggregated "
+                f"cache of a {type(self.config.model).__name__} "
+                f"{self._family.dense_only}, which the disaggregated "
                 "path does not carry")
 
     def _call_program(self, name: str, jitted, *args, **static):

@@ -7,11 +7,14 @@ cache as an opaque pytree whose every leaf has the slot on axis 1. A
 slot is handed over by writing a batch-1 entry of that pytree over the
 slot's part of every leaf (``insert_slot``).
 
-``recurrent`` says whether the cache holds state that a step consumes
-(a state-space mixer's). Such a cache has no rows that can be written
-again or kept apart, which the engine's other step programs and its
-prefix cache assume: they stay with families whose cache is rows of
-keys and values, and the engine refuses them for a recurrent one.
+The engine's step programs beyond the dense path (a draft model,
+multi_step, the prefix cache, chunked prefill, LoRA banks, the
+disaggregated path) are the Llama family's: they take the cache as a
+pair of keys and values whose rows can be written again and kept
+apart. ``dense_only`` is a family's word on why they do not run over
+ITS cache (state that a step consumes, a state-space mixer's; rows of a
+latent that are neither keys nor values), and the engine refuses them
+for it by that reason; "" for the family they were written for.
 """
 
 from __future__ import annotations
@@ -42,9 +45,14 @@ class ModelFamily:
     # caller donates cache. ``live`` says which slots hold a request
     # (the others are parked: computed, and counted by nobody).
     decode_step: Callable
-    # cache -> bytes by kind, {"kv": ..., "recurrent": ...}
+    # cache -> bytes by kind, {"kv": ..., "recurrent": ...} or
+    # {"latent": ...}
     cache_bytes: Callable
-    recurrent: bool
+    # why the engine's step programs beyond the dense path (a draft
+    # model, multi_step, the prefix cache, chunked prefill, the
+    # disaggregated path) do not run over this family's cache, as a
+    # clause about "the cache of a <family>"; "": they do
+    dense_only: str = ""
     # parallel.moe.EXPERT_COUNTS (and, after them, a family's own
     # names, as BIAS_COUNTS) where the family's programs count their
     # expert layers' picks ON THE DEVICE: the ``counts`` above are then
@@ -74,6 +82,13 @@ def insert_slot(cache, entry, slot):
     return jax.tree.map(write, cache, entry)
 
 
+# ``dense_only`` of the families whose cache holds recurrent state, and
+# of the one whose cache is rows of a latent
+_CONSUMED = "holds recurrent state that a decode step consumes"
+_LATENT = ("is rows of a latent that two attention forms of its own "
+           "read, not keys and values")
+
+
 def _nbytes(leaves) -> int:
     return int(sum(x.size * x.dtype.itemsize for x in leaves))
 
@@ -100,8 +115,7 @@ def _llama() -> ModelFamily:
             params, tokens, config, return_hidden=True),
         init_cache=llama.llama_init_cache, prefill=prefill,
         decode_step=decode_step,
-        cache_bytes=lambda cache: {"kv": _nbytes(cache), "recurrent": 0},
-        recurrent=False)
+        cache_bytes=lambda cache: {"kv": _nbytes(cache), "recurrent": 0})
 
 
 @functools.cache
@@ -125,7 +139,7 @@ def _jamba() -> ModelFamily:
         cache_bytes=lambda cache: {
             "kv": _nbytes([cache["k"], cache["v"]]),
             "recurrent": _nbytes([cache["ssm"], cache["conv"]])},
-        recurrent=True)
+        dense_only=_CONSUMED)
 
 
 @functools.cache
@@ -149,7 +163,8 @@ def _granite() -> ModelFamily:
         cache_bytes=lambda cache: {
             "kv": _nbytes([cache["k"], cache["v"]]),
             "recurrent": _nbytes([cache["ssm"], cache["conv"]])},
-        recurrent=True, expert_counts=granite.EXPERT_COUNTS,
+        dense_only=_CONSUMED,
+        expert_counts=granite.EXPERT_COUNTS,
         skips_parked_state=True)
 
 
@@ -175,18 +190,43 @@ def _lfm2() -> ModelFamily:
         cache_bytes=lambda cache: {
             "kv": _nbytes([cache["k"], cache["v"]]),
             "recurrent": _nbytes([cache["conv"]])},
-        recurrent=True, expert_counts=lfm2.EXPERT_COUNTS,
+        dense_only=_CONSUMED,
+        expert_counts=lfm2.EXPERT_COUNTS,
         kv_row_shape=lambda c: cache_row_shape(c.n_kv_heads, c.head_dim))
+
+
+@functools.cache
+def _mla() -> ModelFamily:
+    from ray_tpu.models import mla
+
+    def prefill(params, tokens, length, config, lora):
+        return mla.mla_prefill(params, tokens, length, config)
+
+    def decode_step(params, token, cache, pos, live, config, lora_bank,
+                    lora_idx):
+        return mla.mla_decode_step(params, token, cache, pos, live, config)
+
+    return ModelFamily(
+        init=mla.mla_init,
+        hidden=lambda params, tokens, config: mla.mla_forward(
+            params, tokens, config, return_hidden=True),
+        init_cache=mla.mla_init_cache, prefill=prefill,
+        decode_step=decode_step,
+        cache_bytes=lambda cache: {"latent": _nbytes([cache["latent"]])},
+        dense_only=_LATENT,
+        expert_counts=mla.EXPERT_COUNTS,
+        kv_row_shape=lambda c: (1, c.latent_lanes))
 
 
 _FAMILIES: Dict[str, Callable[[], ModelFamily]] = {
     "LlamaConfig": _llama, "JambaConfig": _jamba,
-    "GraniteConfig": _granite, "Lfm2Config": _lfm2}
+    "GraniteConfig": _granite, "Lfm2Config": _lfm2, "MlaConfig": _mla}
 
 
 def family_of(config: Any) -> ModelFamily:
     """The family of a model configuration, by the configuration's
-    class (LlamaConfig, JambaConfig, GraniteConfig, Lfm2Config)."""
+    class (LlamaConfig, JambaConfig, GraniteConfig, Lfm2Config,
+    MlaConfig)."""
     try:
         return _FAMILIES[type(config).__name__]()
     except KeyError:

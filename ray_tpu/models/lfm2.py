@@ -40,7 +40,7 @@ Design for the TPU:
   [M, B, taps - 1, dim], all in the model's dtype (8 KiB of state a slot
   and layer at the published width). Heads of 64 reach the kernels
   written for 128 lanes through ``ops/attention.py``: a prefill's are
-  zero-padded (``_flash_narrow``), the cache keeps two KV heads a row
+  zero-padded (``_flash_padded``), the cache keeps two KV heads a row
   of 128 lanes (``cache_row_shape``; a TPU would pad a minor axis of 64
   to twice its bytes) and the decode kernel reads it as it lies.
 - A prefill into a padded bucket is exact by construction: the state it

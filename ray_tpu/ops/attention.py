@@ -120,10 +120,12 @@ def _block_size(pref: int, dim: int) -> Optional[int]:
     return None
 
 
-def _attention_reference(q, k, v, causal: bool):
+def _attention_reference(q, k, v, causal: bool,
+                         sm_scale: Optional[float] = None):
     d = q.shape[-1]
     s = jnp.einsum("bqhd,bkhd->bhqk", q.astype(jnp.float32),
-                   k.astype(jnp.float32)) / (d ** 0.5)
+                   k.astype(jnp.float32))
+    s = s / (d ** 0.5) if sm_scale is None else s * sm_scale
     if causal:
         sq, sk = q.shape[1], k.shape[1]
         mask = jnp.tril(jnp.ones((sq, sk), dtype=bool), sk - sq)
@@ -680,43 +682,64 @@ def _narrow(d: int) -> bool:
     return 2 * d == _LANES and (_INTERPRET or jax_backend.on_tpu())
 
 
-def _pad_lanes(x):
+def _pad_lanes(x, lanes: int = _LANES):
     return jnp.pad(x, ((0, 0),) * (x.ndim - 1)
-                   + ((0, _LANES - x.shape[-1]),))
+                   + ((0, lanes - x.shape[-1]),))
 
 
-def _flash_narrow(q, k, v, causal: bool):
-    """Heads narrower than a tile's 128 lanes (64: LFM2's), FORWARD
-    ONLY (the serving path's prefill): q, k and v zero-padded to 128
-    lanes, which adds nothing to a score nor to a kept output lane, and
-    the forward kernel told the scale of the true head size. None where
-    the kernel does not cover the padded shapes."""
-    d = q.shape[-1]
-    q, k, v = (_pad_lanes(x) for x in (q, k, v))
+def _flash_padded(q, k, v, causal: bool, sm_scale: Optional[float] = None):
+    """Heads that are not whole tiles of 128 lanes, or whose values are
+    narrower than their keys (64: LFM2's; keys of 192 over values of
+    128: latent attention's expanded form), FORWARD ONLY (the serving
+    path's prefill): q, k and v zero-padded to the same whole tiles,
+    which adds nothing to a score nor to a kept output lane, and the
+    forward kernel told the scale (the true key width's ``** -0.5``
+    unless the caller has its own). None where the kernel does not
+    cover the padded shapes."""
+    d, dv = q.shape[-1], v.shape[-1]
+    lanes = -(-max(d, dv) // _LANES) * _LANES
+    q, k, v = (_pad_lanes(x, lanes) for x in (q, k, v))
     plan = _kernel_plan(q, k)
     if plan is None:
         return None
     out, _ = _flash_forward(*(x.transpose(0, 2, 1, 3) for x in (q, k, v)),
-                            causal, plan, sm_scale=d ** -0.5)
-    return out.transpose(0, 2, 1, 3)[..., :d]
+                            causal, plan,
+                            sm_scale=d ** -0.5 if sm_scale is None
+                            else sm_scale)
+    return out.transpose(0, 2, 1, 3)[..., :dv]
 
 
-def flash_attention(q, k, v, causal: bool = True, mesh=None):
+def flash_attention(q, k, v, causal: bool = True, mesh=None,
+                    sm_scale: Optional[float] = None):
     """Fused causal attention: [B, S, H, D] x3 -> [B, S, H, D].
 
     K/V head count must equal Q head count (expand GQA groups first).
-    Heads of 64 on one device go through ``_flash_narrow`` (forward
-    only).
+    Heads of 64 on one device go through ``_flash_padded`` (forward
+    only), and so does a caller whose values are narrower than its keys
+    (v [B, S, H, DV] -> [B, S, H, DV]) or whose scores are not scaled by
+    ``D ** -0.5`` (``sm_scale``); where the kernel does not cover those
+    the plain form computes them, on a TPU with an entry in
+    ``kernel_fallbacks``.
     With a ``mesh`` of more than one device the op runs per shard under
     ``jax.shard_map`` (a Mosaic kernel cannot be partitioned by GSPMD):
     batch over (data, fsdp), heads over model, sequence and head_dim
     whole. A sequence-sharded mesh needs ring/ulysses attention."""
+    own = sm_scale is not None or v.shape[-1] != q.shape[-1]
     if mesh is None or mesh.size == 1:
-        if _narrow(q.shape[-1]):
-            out = _flash_narrow(q, k, v, causal)
+        if own or _narrow(q.shape[-1]):
+            out = _flash_padded(q, k, v, causal, sm_scale)
             if out is not None:
                 return out
-        return _flash(q, k, v, causal)
+        if not own:
+            return _flash(q, k, v, causal)
+        if jax_backend.on_tpu():
+            kernel_fallbacks.append(
+                f"q{list(q.shape)} k[{k.shape[1]}] v[{v.shape[-1]}] "
+                f"{q.dtype}")
+        return _attention_reference(q, k, v, causal, sm_scale)
+    if own:
+        raise ValueError("flash_attention with a scale of the caller's or "
+                         "values narrower than the keys runs on one device")
     batch, n_batch = mesh_axes(mesh, "data", "fsdp")
     heads, n_heads = mesh_axes(mesh, "model")
     spec = P(batch, None, heads, None)
@@ -737,7 +760,8 @@ def flash_attention(q, k, v, causal: bool = True, mesh=None):
 # Decode attention: one query position a slot, over the cache as stored
 # ---------------------------------------------------------------------------
 
-def _decode_attention_reference(q, cache_k, cache_v, layer, pos, dtype):
+def _decode_attention_reference(q, cache_k, cache_v, layer, pos, dtype,
+                                sm_scale: Optional[float] = None):
     """The plain XLA form of ``decode_attention``: the layer sliced out
     of the stacked cache, every one of its S rows scored and the rows
     above ``pos`` masked. What the kernel is tested against, and the
@@ -747,7 +771,8 @@ def _decode_attention_reference(q, cache_k, cache_v, layer, pos, dtype):
     visible = jnp.arange(k.shape[1])[None, :] <= pos[:, None]    # [B, S]
     scores = jnp.einsum("bgrd,bsgd->bgrs", q, k,
                         preferred_element_type=jnp.float32)
-    scores = scores * (q.shape[-1] ** -0.5)
+    scores = scores * (q.shape[-1] ** -0.5 if sm_scale is None
+                       else sm_scale)
     scores = jnp.where(visible[:, None, None, :], scores, NEG_INF)
     weights = jax.nn.softmax(scores, axis=-1).astype(dtype)
     return jnp.einsum("bgrs,bsgd->bgrd", weights, v)
@@ -942,7 +967,8 @@ def _decode_packed(q, cache_k, cache_v, layer, pos, dtype):
                      axis=2).reshape(b, kvh, n_rep, hd)
 
 
-def decode_attention(q, cache_k, cache_v, layer, pos, dtype):
+def decode_attention(q, cache_k, cache_v, layer, pos, dtype,
+                     sm_scale: Optional[float] = None):
     """One query position for every slot against the cache as it is
     stored. q: [B, KVH, n_rep, HD], the query heads grouped by the KV
     head they share (n_rep == 1: groups of one); cache_k, cache_v: the
@@ -950,7 +976,11 @@ def decode_attention(q, cache_k, cache_v, layer, pos, dtype):
     written; ``layer`` (an int, traced or not) the one to attend; pos:
     [B] int32, slot ``b`` sees rows ``t <= pos[b]``. -> [B, KVH, n_rep,
     HD] in ``dtype``. No K or V is expanded to the query's heads and no
-    layer is sliced out of the stack.
+    layer is sliced out of the stack. The scores are scaled by ``HD **
+    -0.5`` unless the caller has a scale of its own (``sm_scale``), and
+    ``cache_k`` and ``cache_v`` may be ONE array: a cache of latent rows
+    (models/mla.py) whose row is the key and, in its first lanes, the
+    value; the kernel then reads each row twice, once as either.
 
     On a TPU (and in the tests' interpret mode), where ``HD % 128 ==
     0`` (or the cache's rows are packed: ``cache_row_shape``), S divides
@@ -965,7 +995,9 @@ def decode_attention(q, cache_k, cache_v, layer, pos, dtype):
     with an entry in ``kernel_fallbacks``."""
     _, kvh, _, hd = q.shape
     if cache_k.shape[-1] != hd:
-        # packed rows (cache_row_shape)
+        # packed rows (cache_row_shape), whose scale is their head's
+        if sm_scale is not None:
+            raise ValueError("a cache of packed rows takes no sm_scale")
         out = _decode_packed(q, cache_k, cache_v, layer, pos, dtype)
         if out is not None:
             return out
@@ -984,5 +1016,6 @@ def decode_attention(q, cache_k, cache_v, layer, pos, dtype):
                 f"decode q{list(q.shape)} {q.dtype} "
                 f"cache{list(cache_k.shape)} {cache_k.dtype}")
         return _decode_attention_reference(q, cache_k, cache_v, layer, pos,
-                                           dtype)
-    return _decode_pallas(q, cache_k, cache_v, layer, pos, dtype, block)
+                                           dtype, sm_scale)
+    return _decode_pallas(q, cache_k, cache_v, layer, pos, dtype, block,
+                          sm_scale)

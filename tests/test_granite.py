@@ -85,7 +85,7 @@ def test_config_keeps_the_published_pattern_and_shares():
     assert (full.n_mamba_layers, full.n_attn_layers) == (36, 4)
     assert (full.d_inner, full.conv_dim) == (8192, 8448)
     assert full.q_scale == pytest.approx(128 ** 0.5 / 128)
-    assert family_of(CFG).recurrent
+    assert family_of(CFG).dense_only
     with pytest.raises(ValueError, match="experts_held"):
         GraniteConfig.tiny(experts_held=(6, 4))
 

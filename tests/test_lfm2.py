@@ -81,7 +81,7 @@ def test_config_keeps_the_published_pattern():
             full.dense_dim, full.conv_taps) == (64, 32, 4, 1792, 7168, 3)
     assert full.scoring.kind == "sigmoid" and full.scoring.eps == 1e-6
     family = family_of(CFG)
-    assert family.recurrent and not family.skips_parked_state
+    assert family.dense_only and not family.skips_parked_state
     assert family.expert_counts == EXPERT_COUNTS
     assert EXPERT_COUNTS[-2:] == ("picks_bias_moved", "picks_bias_kept")
     with pytest.raises(ValueError, match="layer types"):

@@ -373,3 +373,50 @@ def test_two_halves_of_32_experts_add_up_under_the_sigmoid_router(rows):
     assert counts_low[5:].tolist() == counts_high[5:].tolist() \
         == [moved, n_live * k - moved]
     assert 0 < moved < n_live * k
+
+
+@pytest.mark.parametrize("rows", [8, 100])
+@pytest.mark.parametrize("router_kind", ["even", "skewed"])
+def test_a_rank_of_32_computes_its_two_experts_part_and_drops_none(
+        rows, router_kind):
+    """``held_experts_ffn`` at a held share of 1/32: experts 10 and 11 of
+    64, routed by a sigmoid with a selection bias over all 64, top-4,
+    scale 2.827. Under an even router most rows pick no held expert and
+    most (row, pick) pairs are an absent expert's; under a skewed one
+    EVERY row picks both held experts first. Either way, in both regimes,
+    the rank's part is the dense sum over its two experts and every held
+    pick is computed: no capacity, nothing dropped."""
+    e, k, first, held = 64, 4, 10, 2
+    scoring = Scoring("sigmoid", eps=1e-20, scale=2.827)
+    keys = jax.random.split(jax.random.PRNGKey(9), 5)
+    w_in = jax.random.normal(keys[1], (e, _D, 2 * _I)) * 0.3
+    w_out = jax.random.normal(keys[2], (e, _I, _D)) * 0.3
+    bias = jax.random.normal(keys[3], (e,)) * 0.05
+    x = jax.random.normal(keys[4], (rows, _D))
+    router = jax.random.normal(keys[0], (_D, e))
+    if router_kind == "skewed":
+        # x's first feature is 1, so the router's first row is a bias
+        x = (x * 0.01).at[:, 0].set(1.0)
+        router = jnp.zeros((_D, e)).at[0, first].set(6.0) \
+            .at[0, first + 1].set(4.0)
+    with jax.default_matmul_precision("highest"):
+        gates, idx, _ = _sigmoid_gates_in_numpy(
+            x @ router, k, np.asarray(bias), 1e-20, 2.827)
+        want = sum(gates[:, j:j + 1] * np.asarray(
+            gated_ffn(x, w_in[j], w_out[j]))
+            for j in range(first, first + held))
+        got, counts = jax.jit(lambda x: held_experts_ffn(
+            x, router, w_in[None, first:first + held],
+            w_out[None, first:first + held], first, layer=0, top_k=k,
+            scoring=scoring, bias=bias))(x)
+    np.testing.assert_allclose(np.asarray(got), want, rtol=1e-4, atol=1e-5)
+    n_held = int(((idx >= first) & (idx < first + held)).sum())
+    n_held_c, n_absent, computed, hit, idle = counts[:5].tolist()
+    assert (n_held_c, n_absent) == (n_held, rows * k - n_held)
+    assert computed == n_held and hit + idle == held      # dropped: 0
+    if router_kind == "skewed":
+        assert n_held == 2 * rows and (hit, idle) == (2, 0)
+        assert float(jnp.abs(got).sum(axis=1).min()) > 0
+    else:
+        # 8 rows x 4 picks x 2 / 64: one held pick expected, none drawn
+        assert (n_held > 0 or rows == 8) and n_held < rows * k / 8

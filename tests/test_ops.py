@@ -2,6 +2,8 @@
 Pallas kernels in interpreter mode (chip_smoke.py checks the compiled
 kernels on a TPU; tests/test_tpu_compile.py compiles them for one)."""
 
+import types
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -471,5 +473,79 @@ def test_decode_attention_over_packed_rows_matches_the_reference(
     att._INTERPRET = False      # the fixture's monkeypatch puts it back
     got = att.decode_attention(q, ck.reshape(packed), cv.reshape(packed), 1,
                                pos, dtype)
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(want, np.float32), atol=1e-6)
+
+
+# --- latent attention's two forms through the kernels as they are -----
+
+@pytest.mark.parametrize("seq,heads,dk,dv", [
+    (128, 4, 192, 128), (1024, 2, 192, 128), (256, 2, 24, 16)])
+def test_flash_attention_with_values_narrower_than_keys(
+        flash_interpreted, seq, heads, dk, dv):
+    """The expanded form of latent attention: keys of 192 over values of
+    128 and a scale of the caller's (``0.1447``, not ``192 ** -0.5``),
+    all three zero-padded to 256 lanes; the forward kernel against the
+    jnp reference told the same scale, the output as wide as the
+    values."""
+    att = flash_interpreted
+    keys = jax.random.split(jax.random.PRNGKey(seq + dk), 3)
+    q, k = (jax.random.normal(key, (1, seq, heads, dk), jnp.float32)
+            for key in keys[:2])
+    v = jax.random.normal(keys[2], (1, seq, heads, dv), jnp.float32)
+    scale = dk ** -0.5 * 2.00474
+    out = jax.jit(lambda q, k, v: att.flash_attention(
+        q, k, v, True, sm_scale=scale))(q, k, v)
+    assert out.shape == v.shape
+    want = att._attention_reference(q, k, v, True, scale)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want), atol=1e-2)
+    # the scale is in the result: the default one gives another
+    plain = att._attention_reference(q, k, v, True)
+    assert float(jnp.abs(plain - want).max()) > 0.05
+    # without the kernel (no TPU, no interpret mode) the plain form
+    att._INTERPRET = False      # the fixture's monkeypatch puts it back
+    np.testing.assert_allclose(
+        np.asarray(att.flash_attention(q, k, v, True, sm_scale=scale)),
+        np.asarray(want), atol=1e-6)
+    with pytest.raises(ValueError, match="one device"):
+        att.flash_attention(q, k, v, True, sm_scale=scale,
+                            mesh=types.SimpleNamespace(size=2))
+
+
+@pytest.mark.parametrize("heads,lanes,dtype", [
+    (64, 640, jnp.bfloat16), (4, 128, jnp.float32), (16, 256, jnp.float32)])
+def test_decode_attention_with_one_leaf_as_keys_and_values(
+        flash_interpreted, heads, lanes, dtype):
+    """The absorbed form of latent attention: ONE cache of latent rows
+    handed over as the keys and as the values, one "KV head" that all
+    the query heads share, a scale of the caller's. The kernel reads
+    1024 rows in two blocks of 512 and gives what the plain form gives
+    told the same, for slots at the first row, at a block's last row,
+    at the next block's first and at the cache's last."""
+    att = flash_interpreted
+    layers, rows = 2, 1024
+    pos = jnp.array([0, 511, 512, rows - 1])
+    keys = jax.random.split(jax.random.PRNGKey(heads), 2)
+    cache = jax.random.normal(keys[0], (layers, 4, rows, 1, lanes),
+                              jnp.float32).astype(dtype)
+    q = jax.random.normal(keys[1], (4, 1, heads, lanes),
+                          jnp.float32).astype(dtype)
+    scale = 0.144680 * (192 / lanes) ** 0.5
+    assert att.decode_block_rows(rows, 1, lanes) == 512
+    tol = 3e-2 if dtype == jnp.bfloat16 else 1e-4
+    for layer in range(layers):
+        want = att._decode_attention_reference(q, cache, cache, layer, pos,
+                                               dtype, scale)
+        got = jax.jit(att.decode_attention, static_argnums=(5, 6))(
+            q, cache, cache, layer, pos, dtype, scale)
+        assert got.shape == q.shape and got.dtype == dtype
+        np.testing.assert_allclose(np.asarray(got, np.float32),
+                                   np.asarray(want, np.float32), atol=tol)
+    # the scale is in the result
+    plain = att._decode_attention_reference(q, cache, cache, 1, pos, dtype)
+    assert float(jnp.abs(plain.astype(jnp.float32)
+                         - want.astype(jnp.float32)).max()) > 0.05
+    att._INTERPRET = False      # the fixture's monkeypatch puts it back
+    got = att.decode_attention(q, cache, cache, 1, pos, dtype, scale)
     np.testing.assert_allclose(np.asarray(got, np.float32),
                                np.asarray(want, np.float32), atol=1e-6)

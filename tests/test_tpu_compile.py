@@ -547,3 +547,84 @@ def test_lfm2_serving_programs_compile_at_published_widths(v5e):
                           compiled.as_text())
         assert set(made) <= {"bitcast", "parameter"}, set(made)
     assert attention.kernel_fallbacks == []
+
+
+def test_mla_serving_programs_compile_at_published_widths(v5e):
+    """The Kimi cell's engine programs as the chip gets them
+    (Kimi-K2.7-Code's first seven layers, 12 of a routed layer's 384
+    experts, an eighth of the vocabulary, batch 32, seq 4608): a prefill
+    runs the expanded form through flash_fwd (keys of 192 over values
+    of 128, padded to 256 lanes), a decode step the absorbed form
+    through decode_attention over the latent rows, and nothing falls
+    back; the decode program aliases the whole cache, hands its seven
+    device counts on and keeps its temporaries under 64 MiB; the 4096
+    bucket's temporaries are stated; everything fits one chip."""
+    from ray_tpu.llm import engine as engine_mod
+    from ray_tpu.models.mla import (EXPERT_COUNTS, MlaConfig, mla_init,
+                                    mla_init_cache)
+    cfg = MlaConfig(vocab_size=20480, n_layers=7, experts_held=(0, 12),
+                    max_seq_len=4608)
+    mesh = _mesh(v5e, 1)
+
+    def on_chip(tree):
+        return _abstract(tree, jax.tree.map(
+            lambda _: NamedSharding(mesh, P()), tree))
+
+    params = on_chip(jax.eval_shape(
+        lambda key: mla_init(key, cfg), jax.random.PRNGKey(0)))
+    weights = sum(x.size * x.dtype.itemsize
+                  for x in jax.tree.leaves(params))
+    assert 9.65e9 < weights < 9.75e9
+    cache = jax.tree.leaves(on_chip(jax.eval_shape(
+        lambda: mla_init_cache(cfg, 32, 4608))))
+    latent = 7 * 32 * 4608 * 640 * 2
+    assert [x.shape for x in cache] == [(7, 32, 4608, 1, 640)]
+    assert attention.decode_block_rows(4608, 1, 640) == 512
+    counts = _on(mesh, P(), (len(EXPERT_COUNTS),), jnp.uint32)
+    with pytest.MonkeyPatch.context() as patch:
+        # an engine around shapes: no weights and no cache are made here
+        patch.setattr(engine_mod.ContinuousBatchingEngine, "_fresh_cache",
+                      lambda self, model: cache)
+        patch.setattr(engine_mod.ContinuousBatchingEngine, "_fresh_bias",
+                      lambda self: (None, None))
+        patch.setattr(engine_mod.ContinuousBatchingEngine,
+                      "_fresh_expert_counts", lambda self: None)
+        engine = engine_mod.ContinuousBatchingEngine(
+            engine_mod.EngineConfig(model=cfg, max_batch=32, max_seq=4608),
+            params=params)
+    lowered = engine._decode.lower(
+        params, cache, _on(mesh, P(), (7, 32), jnp.int32),
+        _on(mesh, P(), (2,), jnp.uint32), None,
+        _on(mesh, P(), (32, 20480), jnp.float32), counts, want_lp=False)
+    assert sorted({k.split("(")[0] for k in _kernels(lowered)}) == [
+        "decode_attention", "rms_norm"]
+    compiled = lowered.compile()
+    _assert_sampler_branches(compiled)
+    memory = compiled.memory_analysis()
+    print("mla decode:", memory)
+    assert latent <= memory.alias_size_in_bytes <= 1.01 * latent
+    assert memory.temp_size_in_bytes < 64 * 2**20
+    held = memory.argument_size_in_bytes       # weights, cache, bias
+    assert 0.6 * HBM_BYTES < held < 0.66 * HBM_BYTES
+    for bucket in (1024, 4096):
+        lowered = engine._prefill.lower(
+            params, _on(mesh, P(), (1, bucket), jnp.int32),
+            _on(mesh, P(), (), jnp.int32), None, counts)
+        assert sorted({k.split("(")[0] for k in _kernels(lowered)}) == [
+            "flash_fwd", "rms_norm"]
+        compiled = lowered.compile()
+        memory = compiled.memory_analysis()
+        print(f"mla prefill_{bucket}:", memory)
+        # the many-rows expert form as it stands walks all 8 x bucket
+        # (row, pick) pairs, 31/32 of them an absent expert's: 2.0 GiB
+        # of temporaries at 4096 (0.5 at 1024), which fit beside the
+        # weights, the cache and a control's changed expert stack (2.1
+        # GB); bounding them by the held pairs is queued (PERF.md 7-1)
+        assert memory.temp_size_in_bytes < bucket * 0.56 * 2**20
+        assert held + memory.temp_size_in_bytes + 2.2e9 < 0.98 * HBM_BYTES
+        # no layer's experts are sliced out of their stack into a copy
+        # (1.06 GB a layer): the grouped matmul reads the stack itself
+        made = re.findall(r"= bf16\[12,7168,4096\]\S* (\S+?)\(",
+                          compiled.as_text())
+        assert set(made) <= {"bitcast", "parameter"}, set(made)
+    assert attention.kernel_fallbacks == []
