@@ -172,6 +172,15 @@ ENGINE_EXPERT_SLOTS = _metrics.Counter(
     "hit (at least one live row picked it) or idle (none did, its "
     "weights were read for nothing)",
     tag_keys=("state",))
+ENGINE_EXPERT_PAIRS_WALKED = _metrics.Counter(
+    "ray_tpu_engine_expert_pairs_walked_total",
+    "Places of the sorted (row, pick) order that the prefills' expert "
+    "layers gathered and multiplied, padding's rows too (every place "
+    "where the rank holds half the experts or more, the walk's whole "
+    "chunks below that): over top_k x "
+    "ray_tpu_engine_prefill_tokens_total (both kinds) and the routed "
+    "layers, the share of all pairs that were walked; a decode step's "
+    "form walks none")
 ENGINE_ROUTER_PICKS = _metrics.Counter(
     "ray_tpu_engine_router_picks_total",
     "Experts picked for live rows by a router that adds a selection "
@@ -186,6 +195,7 @@ _EXPERT_COUNT_SERIES = {
     "picks_absent": (ENGINE_EXPERT_PICKS, {"where": "absent"}),
     "slots_hit": (ENGINE_EXPERT_SLOTS, {"state": "hit"}),
     "slots_idle": (ENGINE_EXPERT_SLOTS, {"state": "idle"}),
+    "pairs_walked": (ENGINE_EXPERT_PAIRS_WALKED, None),
     "picks_bias_moved": (ENGINE_ROUTER_PICKS, {"bias": "moved"}),
     "picks_bias_kept": (ENGINE_ROUTER_PICKS, {"bias": "kept"})}
 ENGINE_ADMIT_LAUNCH_SECONDS = _metrics.Histogram(
@@ -2884,6 +2894,8 @@ class ContinuousBatchingEngine:
                                        "idle": n["slots_idle"]}
                 out["dropped_rows"] = (n["picks_held"]
                                        - n["picks_computed"])
+                # the places the prefills' expert layers walked
+                out["expert_pairs_walked"] = n["pairs_walked"]
                 if "picks_bias_moved" in n:
                     # the picks a selection bias changed, and the rest
                     out["router_picks"] = {"moved": n["picks_bias_moved"],

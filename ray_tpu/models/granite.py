@@ -453,8 +453,9 @@ def granite_prefill(params, tokens, length, config: GraniteConfig):
     c = config
     x, entry, counts = _trunk(params, tokens[0], length, c)
     last = jax.lax.dynamic_slice_in_dim(x, length - 1, 1, 0)
+    hit = EXPERT_COUNTS.index("slots_hit")
     return (_head(params, last, c)[None], entry,
-            counts.at[EXPERT_COUNTS.index("slots_hit"):].set(0))
+            counts.at[hit:hit + 2].set(0))      # slots_hit, slots_idle
 
 
 def granite_decode_step(params, token, cache, pos, live,

@@ -35,9 +35,13 @@ matrices.
   with a selection bias (``noaux_tc``; one group, so no group limit),
   gates the picked sigmoids over ``their sum + 1e-20`` times
   ``routed_scaling``; this rank computes the experts ``experts_held =
-  (first, count)`` and leaves the others' part out. The shared expert is
-  on every rank's device and serves the rank's own rows, so it is
-  counted once here.
+  (first, count)`` and leaves the others' part out: a prefill's rows
+  are sorted by expert and only the (row, pick) pairs that fell on the
+  held experts are gathered and multiplied, a chunk of the sorted order
+  at a time up to their counted number (a thirty-second of the ``top_k
+  x bucket`` pairs at 12 of 384; every one of them, whatever the
+  router's skew). The shared expert is on every rank's device and
+  serves the rank's own rows, so it is counted once here.
 
 Design for the TPU:
 

@@ -36,7 +36,7 @@ SCOPE_ROUTER = "moe.router"
 SCOPE_EXPERTS = "moe.experts"
 # what held_experts_ffn counts of its live rows, in this order
 EXPERT_COUNTS = ("picks_held", "picks_absent", "picks_computed",
-                 "slots_hit", "slots_idle")
+                 "slots_hit", "slots_idle", "pairs_walked")
 # and, after those, where the router adds a selection bias: the live
 # rows' picks that are not among the k largest scores alone, and the rest
 BIAS_COUNTS = ("picks_bias_moved", "picks_bias_kept")
@@ -58,6 +58,9 @@ class Scoring:
 
 
 SOFTMAX = Scoring()
+# places of the sorted (row, pick) order that one trip of
+# held_experts_ffn's many-rows walk serves
+WALK_CHUNK = 256
 
 
 def _gates(logits: jax.Array, k: int, scoring: Scoring = SOFTMAX,
@@ -213,19 +216,47 @@ def held_experts_ffn(x: jax.Array, router: jax.Array, w_in: jax.Array,
       by the gate, which is zero where the row did not pick it. Exact,
       and bound by reading the experts' weights either way.
     - many rows (a prefill): the (row, pick) pairs sorted by expert,
-      the held experts' first, and one grouped matmul (``ragged_dot``)
-      over their rows; the absent experts' pairs are past the last
-      group and masked out. The groups are all L x H experts of the
-      stack, every other layer's of size zero.
+      the held experts' first, and grouped matmuls (``ragged_dot``)
+      over their rows, the groups all L x H experts of the stack with
+      every other layer's of size zero. Where this rank holds HALF the
+      experts or more (a static fact, ``2 H >= E``) all ``T x top_k``
+      pairs go through at once: gathered out, multiplied, gathered back
+      into (row, pick) order and masked; the absent experts' pairs lie
+      past the last group. Where it holds fewer, that order is WALKED
+      in chunks of ``WALK_CHUNK`` places (all pairs where they are
+      fewer) as far as the held experts' pairs reach: ``ceil(n /
+      chunk)`` trips of a loop whose count is read on the device, ``n``
+      the counted pairs, whatever the router's skew (every pair, if
+      every pair is a held expert's). A trip gathers its rows of ``x``,
+      multiplies over the chunk's part of each group, gates, and adds
+      the result to its rows of ``y``; a place past ``n`` in the last
+      chunk adds nothing. The absent experts' pairs are never gathered
+      or multiplied and no array over all the pairs but the sort's is
+      made: the cost follows the count, a thirty-second of the pairs
+      where a rank holds 12 of 384 experts. Only the order in which a
+      row's picks are summed differs between the two. The rule and
+      the chunk are by measurement (one layer's call on a v5e, PERF.md
+      PR 70; one-shot -> walk): 12 of 384 held, top-8, 7168 wide, 4096
+      / 2048 / 1024 rows 14.0 -> 8.0, 8.3 -> 4.1, 5.9 -> 3.7 ms (8.3,
+      6.5, 5.0 at a chunk of 512); 36 of 72, top-10, 4096 wide, 1024 /
+      512 / 256 rows 5.3 -> 4.8, 3.5 -> 3.5, 2.7 -> 2.8 (chunk 512);
+      32 of 32, top-4, 2048 wide 3.3 -> 4.1, 2.8 -> 2.9, 2.5 -> 2.6:
+      the add back to ``y`` is a scatter-add, which XLA prices by
+      shape (0.4-3.3 us a row of a 117 MB ``y``) and which the walk
+      wins back only where most pairs are absent.
 
-    -> (y [T, D] float32, EXPERT_COUNTS [5] uint32 of the ``live`` rows
+    -> (y [T, D] float32, EXPERT_COUNTS [6] uint32 of the ``live`` rows
     ([T] bool, default every row): their picks that fell on held
     experts, those that fell on absent ones, the held picks whose
     product was computed (every one in the first regime; in the second
-    those whose place in the sorted order lies inside the groups), how
-    many held experts got a live row and how many got none; with a
-    ``bias`` two more, BIAS_COUNTS: the live rows' picks that are not
-    among their ``top_k`` largest scores alone, and those that are)."""
+    those whose place in the sorted order lay inside the groups), how
+    many held experts got a live row and how many got none, and the
+    places of the sorted order that were gathered and multiplied, of
+    EVERY row and padding's too (none in the first regime; all ``T x
+    top_k`` at once or the walk's whole chunks in the second); with a
+    ``bias`` two more, BIAS_COUNTS:
+    the live rows' picks that are not among their ``top_k`` largest
+    scores alone, and those that are)."""
     rows, dtype = x.shape[0], w_in.dtype
     n_experts, (held, inner, _) = router.shape[-1], w_out.shape[-3:]
     live = jnp.ones((rows,), bool) if live is None else live.astype(bool)
@@ -248,10 +279,10 @@ def held_experts_ffn(x: jax.Array, router: jax.Array, w_in: jax.Array,
             n_kept = jnp.sum(kept & live[:, None])
             by_bias = [jnp.sum(live) * top_k - n_kept, n_kept]
 
-    def counts(n_computed):
+    def counts(n_computed, n_walked=0):
         return jnp.stack([n_held, jnp.sum(live) * top_k - n_held,
-                          n_computed, n_hit, held - n_hit, *by_bias]
-                         ).astype(jnp.uint32)
+                          n_computed, n_hit, held - n_hit, n_walked,
+                          *by_bias]).astype(jnp.uint32)
 
     with jax.named_scope(SCOPE_EXPERTS):
         if rows <= SPLIT_ROWS:
@@ -269,30 +300,66 @@ def held_experts_ffn(x: jax.Array, router: jax.Array, w_in: jax.Array,
                 "eti,eid->td", both, w_out,
                 preferred_element_type=jnp.float32)), counts(n_held)
         # the pairs by expert, the held experts' first
+        pairs = rows * top_k
         order = jnp.argsort(rel.reshape(-1))
         n_layers = w_in.shape[0]
-        group_sizes = jax.lax.dynamic_update_slice_in_dim(
-            jnp.zeros((n_layers * held,), jnp.int32),
-            jnp.sum(picked, axis=0, dtype=jnp.int32), layer * held, 0)
-        xs = x.astype(dtype)[order // top_k]                  # [T * k, D]
-        ab = jax.lax.ragged_dot(
-            xs, w_in.reshape((n_layers * held,) + w_in.shape[2:]),
-            group_sizes, preferred_element_type=jnp.float32)
-        act = jax.nn.silu(ab[:, :inner]) * ab[:, inner:]
-        out = jax.lax.ragged_dot(
-            act.astype(dtype),
-            w_out.reshape((n_layers * held,) + w_out.shape[2:]),
-            group_sizes, preferred_element_type=jnp.float32)
-        # back in (row, pick) order by a gather (a scatter-add would
-        # serialise on a TPU), then the gates; a pair of an absent
-        # expert lies past the last group, where the product wrote
-        # nothing
-        place = jnp.argsort(order)
-        out = out[place].reshape(rows, top_k, -1)
+        sizes = jnp.sum(picked, axis=0, dtype=jnp.int32)      # [H]
+        xd = x.astype(dtype)
+        w_in, w_out = (w.reshape((n_layers * held,) + w.shape[2:])
+                       for w in (w_in, w_out))
         gate = jnp.take_along_axis(gates, idx, axis=1)        # [T, k]
         mine = rel < held
-        computed = (place < jnp.sum(group_sizes)).reshape(rows, top_k)
-        return jnp.sum(jnp.where(mine[:, :, None],
-                                 out * gate[:, :, None], 0.0),
-                       axis=1), counts(
-                           jnp.sum(mine & computed & live[:, None]))
+
+        def experts(xs, sizes):
+            """The held experts on rows ``xs`` that lie expert by
+            expert, ``sizes`` [H] of them each: the groups are all L x H
+            experts of the stack, every other layer's of size zero."""
+            group_sizes = jax.lax.dynamic_update_slice_in_dim(
+                jnp.zeros((n_layers * held,), jnp.int32), sizes,
+                layer * held, 0)
+            ab = jax.lax.ragged_dot(xs, w_in, group_sizes,
+                                    preferred_element_type=jnp.float32)
+            act = jax.nn.silu(ab[:, :inner]) * ab[:, inner:]
+            return jax.lax.ragged_dot(act.astype(dtype), w_out, group_sizes,
+                                      preferred_element_type=jnp.float32)
+
+        if 2 * held >= n_experts:
+            # all pairs at once; back in (row, pick) order by a gather,
+            # then the gates; a pair of an absent expert lies past the
+            # last group, where the product wrote nothing
+            place = jnp.argsort(order)
+            out = experts(xd[order // top_k], sizes)[place]
+            computed = (place < jnp.sum(sizes)).reshape(rows, top_k)
+            return jnp.sum(jnp.where(
+                mine[:, :, None],
+                out.reshape(rows, top_k, -1) * gate[:, :, None], 0.0),
+                axis=1), counts(
+                    jnp.sum(mine & computed & live[:, None]), pairs)
+        chunk = min(WALK_CHUNK, pairs)
+        order = jnp.pad(order, (0, -pairs % chunk))
+        ends = jnp.cumsum(sizes)
+        n = ends[-1]
+        gate = gate.reshape(-1)
+        counted = (mine & live[:, None]).reshape(-1)          # [T k]
+
+        def walk(c, carry):
+            y, n_computed = carry
+            lo = c * chunk
+            pair = jax.lax.dynamic_slice_in_dim(order, lo, chunk)
+            row = pair // top_k
+            # the chunk's part of each group: the groups' running ends
+            # clipped to the chunk
+            out = experts(xd[row], jnp.clip(ends, lo, lo + chunk)
+                          - jnp.clip(ends - sizes, lo, lo + chunk))
+            # a place at or past n lies past the chunk's last group,
+            # where the product wrote nothing: it adds nothing
+            inside = lo + jnp.arange(chunk) < n
+            out = jnp.where(inside[:, None], out * gate[pair][:, None], 0.0)
+            return y.at[row].add(out), n_computed + jnp.sum(
+                inside & counted[pair])
+
+        n_chunks = (n + chunk - 1) // chunk
+        y, n_computed = jax.lax.fori_loop(
+            0, n_chunks, walk,
+            (jnp.zeros((rows, x.shape[1]), jnp.float32), jnp.int32(0)))
+        return y, counts(n_computed, n_chunks * chunk)

@@ -18,9 +18,10 @@ from benchmark.reference import granite as reference
 from ray_tpu.llm.engine import (ContinuousBatchingEngine, EngineConfig,
                                 GenerationRequest)
 from ray_tpu.models.family import family_of
-from ray_tpu.models.granite import (GraniteConfig, granite_forward,
-                                    granite_init, granite_init_cache,
-                                    granite_prefill, ssd_chunked)
+from ray_tpu.models.granite import (EXPERT_COUNTS, GraniteConfig,
+                                    granite_forward, granite_init,
+                                    granite_init_cache, granite_prefill,
+                                    ssd_chunked)
 from ray_tpu.models.llama import LlamaConfig
 from ray_tpu.ops import ssd_update as ssd_update_op
 
@@ -146,6 +147,13 @@ def test_padding_leaves_the_state_of_the_true_last_token(params):
         assert float(jnp.abs(a[leaf][:, :, :21]
                              - b[leaf][:, :, :21]).max()) < 1e-5
     assert float(jnp.abs(a["ssm"]).max()) > 0
+    # the places walked alone see the padding: none in the bucket of 32
+    # (the few-rows form), one chunk of 64 x 3 a routed layer in the
+    # bucket of 64
+    walked = EXPERT_COUNTS.index("pairs_walked")
+    assert (int(counts_a[walked]), int(counts_b[walked])) == (0, 4 * 64 * 3)
+    counts_a, counts_b = (np.delete(np.asarray(c), walked)
+                          for c in (counts_a, counts_b))
     # 21 positions x 4 layers x 3 picks, wherever the padding ends
     assert counts_a.tolist() == counts_b.tolist()
     assert int(counts_a[0] + counts_a[1]) == 21 * 4 * 3
@@ -309,10 +317,15 @@ def test_stats_and_series_tell_the_cache_the_picks_and_the_hit_experts(
     # the held picks whose product the layer computed are all of them
     assert engine._mbuf.expert_totals["picks_computed"] == picks["held"]
     assert stats["dropped_rows"] == 0
+    # the bucket of 64 walks one chunk of its 64 x 3 places in each of
+    # 4 layers, padding's too; the bucket of 8 and the decode steps
+    # take the few-rows form, which walks none
+    assert stats["expert_pairs_walked"] == 4 * 64 * 3
     # a second read adds nothing the device has not counted since
     assert engine.stats()["expert_picks"] == picks
     text = metrics.prometheus_text()
     for series in ('ray_tpu_engine_expert_picks_total{where="held"}',
+                   'ray_tpu_engine_expert_pairs_walked_total',
                    'ray_tpu_engine_expert_picks_total{where="absent"}',
                    'ray_tpu_engine_expert_slots_total{state="hit"}',
                    'ray_tpu_engine_cache_bytes{kind="recurrent"}',

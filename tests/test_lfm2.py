@@ -150,6 +150,13 @@ def test_padding_leaves_the_state_of_the_true_last_token(params):
     for leaf in ("k", "v"):
         assert float(jnp.abs(a[leaf][:, :, :21]
                              - b[leaf][:, :, :21]).max()) < 1e-5
+    # the places walked alone see the padding: none in the bucket of 32
+    # (the few-rows form), one chunk of 64 x 3 a routed layer in the
+    # bucket of 64
+    walked = EXPERT_COUNTS.index("pairs_walked")
+    assert (int(counts_a[walked]), int(counts_b[walked])) == (0, 4 * 64 * 3)
+    counts_a, counts_b = (np.delete(np.asarray(c), walked)
+                          for c in (counts_a, counts_b))
     # 21 positions x 4 routed layers x 3 picks, wherever the padding
     # ends; all 8 experts are held, every pick computed, and a prefill
     # counts no expert slots
@@ -301,6 +308,9 @@ def test_stats_and_series_tell_the_picks_the_hit_experts_and_the_bias(
     n_picks = (42 + 2 * 2) * 4 * 3
     assert stats["expert_picks"] == {"held": n_picks, "absent": 0}
     assert stats["dropped_rows"] == 0
+    # every pair is held: the bucket of 64 walks all its 64 x 3 places
+    # in each routed layer (the bucket of 8 takes the few-rows form)
+    assert stats["expert_pairs_walked"] == 4 * 64 * 3
     # 2 decode steps x 4 routed layers x 8 experts; 2 live rows of 3
     # picks hit at most 6 of a layer's 8
     slots = stats["expert_slots"]
@@ -322,7 +332,7 @@ def test_stats_and_series_tell_the_picks_the_hit_experts_and_the_bias(
 
 
 def test_a_family_without_a_bias_tells_no_router_picks():
-    """Granite's counts stay the expert layer's five: no
+    """Granite's counts stay the expert layer's six: no
     ``router_picks`` in its stats."""
     from ray_tpu.models.granite import GraniteConfig, granite_init
     cfg = GraniteConfig.tiny(dtype=jnp.float32)
@@ -333,7 +343,7 @@ def test_a_family_without_a_bias_tells_no_router_picks():
     engine.generate([_prompt(9)], max_tokens=3)
     stats = engine.stats()
     assert "router_picks" not in stats and stats["expert_picks"]["held"] > 0
-    assert len(family_of(cfg).expert_counts) == 5
+    assert len(family_of(cfg).expert_counts) == 6
     engine.close()
 
 

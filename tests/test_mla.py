@@ -219,6 +219,13 @@ def test_padding_leaves_the_latent_rows_of_the_prompt(params):
     assert a["latent"].shape == (4, 1, 32, 1, CFG.latent_lanes)
     assert float(jnp.abs(a["latent"][:, :, :21]
                          - b["latent"][:, :, :21]).max()) < 1e-5
+    # the places walked alone see the padding: none in the bucket of 32
+    # (the few-rows form), one chunk of 64 x 3 a routed layer in the
+    # bucket of 64
+    walked = EXPERT_COUNTS.index("pairs_walked")
+    assert (int(counts_a[walked]), int(counts_b[walked])) == (0, 3 * 64 * 3)
+    counts_a, counts_b = (np.delete(np.asarray(c), walked)
+                          for c in (counts_a, counts_b))
     # 21 positions x 3 routed layers x 3 picks, wherever the padding
     # ends; a quarter of the 16 experts is held, every held pick is
     # computed, and a prefill counts no expert slots
@@ -415,6 +422,8 @@ def test_stats_and_series_tell_the_latent_cache_the_picks_and_the_bias(
     assert picks["held"] + picks["absent"] == n_picks
     assert 0.1 * n_picks < picks["held"] < 0.45 * n_picks
     assert stats["dropped_rows"] == 0
+    # the bucket of 64: one chunk of 64 x 3 places a routed layer
+    assert stats["expert_pairs_walked"] == 3 * 64 * 3
     # 2 decode steps x 3 routed layers x 4 held experts
     slots = stats["expert_slots"]
     assert slots["hit"] + slots["idle"] == stats["decode_steps"] * 3 * 4

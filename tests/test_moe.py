@@ -19,7 +19,7 @@ from ray_tpu.models.llama import (
 )
 from ray_tpu.parallel.mesh import MeshSpec, make_mesh
 from ray_tpu.parallel.moe import (BIAS_COUNTS, EXPERT_COUNTS, SOFTMAX,
-                                  Scoring, _gates, gated_ffn,
+                                  WALK_CHUNK, Scoring, _gates, gated_ffn,
                                   held_experts_ffn, moe_dispatch, moe_ffn,
                                   top_k_gating)
 from ray_tpu.parallel.sharding import shard_pytree
@@ -222,9 +222,12 @@ def test_a_skewed_router_loses_no_row(rows):
                                rtol=1e-4, atol=1e-5)
     # every row's output is there: none was dropped
     assert float(jnp.abs(got).sum(axis=1).min()) > 0
-    # EXPERT_COUNTS: held, absent, computed, hit, idle
-    assert counts.tolist() == [rows * _K, 0, rows * _K, 3, 1]
-    assert counts_none.tolist() == [0, rows * _K, 0, 0, 4]
+    # EXPERT_COUNTS: held, absent, computed, hit, idle, walked (the
+    # many-rows form's places: a rank that holds half the experts
+    # takes all 300 pairs at once, whatever fell on it)
+    walked = rows * _K if rows > 32 else 0
+    assert counts.tolist() == [rows * _K, 0, rows * _K, 3, 1, walked]
+    assert counts_none.tolist() == [0, rows * _K, 0, 0, 4, walked]
     assert float(jnp.abs(none).max()) == 0.0
 
 
@@ -247,6 +250,10 @@ def test_the_two_regimes_agree_on_the_same_input():
     assert int(counts[0]) == sum(int(c[0]) for _, c in few)
     assert int(counts[0] + counts[1]) == 60 * _K
     assert int(counts[2]) == int(counts[0])
+    # the many-rows form serves padding's places too; the few-rows
+    # form has none
+    assert int(counts[5]) == 100 * _K > int(counts[0])
+    assert all(int(c[5]) == 0 for _, c in few)
 
 
 @pytest.mark.parametrize("rows", [8, 100])
@@ -361,7 +368,8 @@ def test_two_halves_of_32_experts_add_up_under_the_sigmoid_router(rows):
     np.testing.assert_allclose(np.asarray(low + high), want, rtol=1e-4,
                                atol=1e-5)
     assert float(jnp.abs(low).max()) > 0 and float(jnp.abs(high).max()) > 0
-    assert len(counts_low) == len(EXPERT_COUNTS) + len(BIAS_COUNTS) == 7
+    assert len(counts_low) == len(EXPERT_COUNTS) + len(BIAS_COUNTS) == 8
+    assert EXPERT_COUNTS[-1] == "pairs_walked"
     n_live = rows - 2
     assert int(counts_low[0]) == int(counts_high[1])
     assert int(counts_low[0] + counts_low[1]) == n_live * k
@@ -370,7 +378,7 @@ def test_two_halves_of_32_experts_add_up_under_the_sigmoid_router(rows):
     p = 1.0 / (1.0 + np.exp(-np.asarray(x @ router, np.float64)))
     plain = np.argsort(-p, axis=-1, kind="stable")[:, :k]
     moved = sum(j not in plain[t] for t in range(n_live) for j in idx[t])
-    assert counts_low[5:].tolist() == counts_high[5:].tolist() \
+    assert counts_low[6:].tolist() == counts_high[6:].tolist() \
         == [moved, n_live * k - moved]
     assert 0 < moved < n_live * k
 
@@ -411,12 +419,71 @@ def test_a_rank_of_32_computes_its_two_experts_part_and_drops_none(
             scoring=scoring, bias=bias))(x)
     np.testing.assert_allclose(np.asarray(got), want, rtol=1e-4, atol=1e-5)
     n_held = int(((idx >= first) & (idx < first + held)).sum())
-    n_held_c, n_absent, computed, hit, idle = counts[:5].tolist()
+    n_held_c, n_absent, computed, hit, idle, walked = counts[:6].tolist()
     assert (n_held_c, n_absent) == (n_held, rows * k - n_held)
     assert computed == n_held and hit + idle == held      # dropped: 0
+    # whole chunks as far as the held pairs reach, none where none is
+    chunk = min(WALK_CHUNK, rows * k)
+    assert walked == (-(-n_held // chunk) * chunk if rows > 32 else 0)
     if router_kind == "skewed":
         assert n_held == 2 * rows and (hit, idle) == (2, 0)
         assert float(jnp.abs(got).sum(axis=1).min()) > 0
     else:
         # 8 rows x 4 picks x 2 / 64: one held pick expected, none drawn
         assert (n_held > 0 or rows == 8) and n_held < rows * k / 8
+
+
+@pytest.mark.parametrize("both,one,chunks", [
+    (0, 0, 0), (WALK_CHUNK // 4, 0, 1), (WALK_CHUNK // 4, 1, 2),
+    (WALK_CHUNK // 4 + WALK_CHUNK // 8, 0, 2),
+    (700, 0, -(-2800 // WALK_CHUNK))],
+    ids=["none", "a_chunk", "a_chunk_and_one", "two_chunks", "every_pair"])
+def test_the_walk_follows_the_counted_pairs(both, one, chunks):
+    """The many-rows form walks the sorted (row, pick) order in chunks
+    of WALK_CHUNK places as far as the held experts' pairs reach: 700
+    rows, top-4 of 64, experts 10-13 held; ``both`` rows pick all four
+    held experts, ``one`` rows pick expert 10 alone, the rest pick none.
+    No held pair: no trip, and zeros. Exactly a chunk: one trip. One
+    more: two, the second all but empty. Every pair (2800 of 2800, a
+    router no capacity would survive): every chunk. Each time the
+    rank's part is the dense sum over its experts and every held pick
+    of a live row is computed."""
+    rows, e, k, first, held = 700, 64, 4, 10, 4
+    scoring = Scoring("sigmoid", eps=1e-20, scale=2.827)
+    keys = jax.random.split(jax.random.PRNGKey(70), 4)
+    w_in = jax.random.normal(keys[0], (e, _D, 2 * _I)) * 0.3
+    w_out = jax.random.normal(keys[1], (e, _I, _D)) * 0.3
+    bias = jax.random.normal(keys[2], (e,)) * 0.05
+    # feature 0 lifts the four held experts (+1) or sinks them (-1);
+    # feature 1 lifts expert 10 back over the rest
+    kind = jnp.arange(rows)
+    x = (jax.random.normal(keys[3], (rows, _D)) * 0.01) \
+        .at[:, 0].set(jnp.where(kind < both, 1.0, -1.0)) \
+        .at[:, 1].set(jnp.where((kind >= both) & (kind < both + one),
+                                1.0, 0.0))
+    router = jnp.zeros((_D, e)) \
+        .at[0, first:first + held].set(jnp.array([6., 5., 4., 3.])) \
+        .at[1, first].set(12.0)
+    live = jnp.arange(rows) % 7 != 3
+    with jax.default_matmul_precision("highest"):
+        gates, idx, _ = _sigmoid_gates_in_numpy(
+            x @ router, k, np.asarray(bias), 1e-20, 2.827)
+        want = sum(gates[:, j:j + 1] * np.asarray(
+            gated_ffn(x, w_in[j], w_out[j]))
+            for j in range(first, first + held))
+        got, counts = jax.jit(lambda x: held_experts_ffn(
+            x, router, w_in[None, first:first + held],
+            w_out[None, first:first + held], first, layer=0, top_k=k,
+            live=live, scoring=scoring, bias=bias))(x)
+    mine = (idx >= first) & (idx < first + held)
+    n = int(mine.sum())
+    assert n == held * both + one
+    np.testing.assert_allclose(np.asarray(got), want, rtol=1e-4, atol=1e-5)
+    if both == rows:
+        assert float(jnp.abs(got).sum(axis=1).min()) > 0
+    n_live = int((mine & np.asarray(live)[:, None]).sum())
+    picks_held, _, computed, _, _, walked = counts[:6].tolist()
+    assert picks_held == computed == n_live              # dropped: 0
+    assert walked == chunks * WALK_CHUNK == -(-n // WALK_CHUNK) * WALK_CHUNK
+    if not n:
+        assert float(jnp.abs(got).max()) == 0.0

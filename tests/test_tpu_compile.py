@@ -407,8 +407,8 @@ def test_granite_serving_programs_compile_at_published_widths(v5e):
     kernel (the grouped matmul is XLA's own ``ragged_dot``), and
     everything fits one chip."""
     from ray_tpu.llm import engine as engine_mod
-    from ray_tpu.models.granite import (GraniteConfig, granite_init,
-                                        granite_init_cache)
+    from ray_tpu.models.granite import (EXPERT_COUNTS, GraniteConfig,
+                                        granite_init, granite_init_cache)
     from ray_tpu.ops import ssd_update
     cfg = GraniteConfig(
         vocab_size=50176, layer_types=GraniteConfig().layer_types[:10],
@@ -426,7 +426,7 @@ def test_granite_serving_programs_compile_at_published_widths(v5e):
     assert 9.4e9 < weights < 9.6e9
     cache = jax.tree.leaves(on_chip(jax.eval_shape(
         lambda: granite_init_cache(cfg, 32, 2560))))
-    counts = _on(mesh, P(), (5,), jnp.uint32)
+    counts = _on(mesh, P(), (len(EXPERT_COUNTS),), jnp.uint32)
     with pytest.MonkeyPatch.context() as patch:
         # an engine around shapes: no weights and no cache are made here
         patch.setattr(engine_mod.ContinuousBatchingEngine, "_fresh_cache",
@@ -482,7 +482,7 @@ def test_lfm2_serving_programs_compile_at_published_widths(v5e):
     routed ones, batch 32, seq 1536): heads of 64 reach both attention
     kernels (the prefill's padded to 128 lanes, the decode step's two
     KV heads a row) and nothing falls back; the decode program aliases
-    the whole cache, hands its seven device counts on and keeps its
+    the whole cache, hands its eight device counts on and keeps its
     temporaries under 64 MiB; a prefill reads the expert stack where
     it lies; everything fits one chip."""
     from ray_tpu.llm import engine as engine_mod
@@ -556,7 +556,7 @@ def test_mla_serving_programs_compile_at_published_widths(v5e):
     runs the expanded form through flash_fwd (keys of 192 over values
     of 128, padded to 256 lanes), a decode step the absorbed form
     through decode_attention over the latent rows, and nothing falls
-    back; the decode program aliases the whole cache, hands its seven
+    back; the decode program aliases the whole cache, hands its eight
     device counts on and keeps its temporaries under 64 MiB; the 4096
     bucket's temporaries are stated; everything fits one chip."""
     from ray_tpu.llm import engine as engine_mod
@@ -615,16 +615,20 @@ def test_mla_serving_programs_compile_at_published_widths(v5e):
         compiled = lowered.compile()
         memory = compiled.memory_analysis()
         print(f"mla prefill_{bucket}:", memory)
-        # the many-rows expert form as it stands walks all 8 x bucket
-        # (row, pick) pairs, 31/32 of them an absent expert's: 2.0 GiB
-        # of temporaries at 4096 (0.5 at 1024), which fit beside the
-        # weights, the cache and a control's changed expert stack (2.1
-        # GB); bounding them by the held pairs is queued (PERF.md 7-1)
-        assert memory.temp_size_in_bytes < bucket * 0.56 * 2**20
+        # the many-rows expert form walks the held experts' (row, pick)
+        # pairs a chunk of 256 places at a time and makes no array
+        # over all 8 x bucket pairs (32 768 at 4096; at 1024 ``wo``
+        # is an [8192, 7168] of its own): 813 MiB of temporaries at 4096,
+        # 206 at 1024 (1.99 and 0.50 GiB until PR 70), most of them
+        # layer 0's dense feed-forward (its [bucket, 36864] float32);
+        # they fit beside the weights, the cache and a control's
+        # changed expert stack (2.1 GB)
+        assert memory.temp_size_in_bytes < bucket * 0.22 * 2**20
+        text = compiled.as_text()
+        assert "[32768,7168]" not in text and "[32768,4096]" not in text
         assert held + memory.temp_size_in_bytes + 2.2e9 < 0.98 * HBM_BYTES
         # no layer's experts are sliced out of their stack into a copy
         # (1.06 GB a layer): the grouped matmul reads the stack itself
-        made = re.findall(r"= bf16\[12,7168,4096\]\S* (\S+?)\(",
-                          compiled.as_text())
+        made = re.findall(r"= bf16\[12,7168,4096\]\S* (\S+?)\(", text)
         assert set(made) <= {"bitcast", "parameter"}, set(made)
     assert attention.kernel_fallbacks == []
