@@ -19,7 +19,7 @@ for it by that reason; "" for the family they were written for.
 
 from __future__ import annotations
 
-import functools
+import importlib
 from dataclasses import dataclass
 from typing import Any, Callable, Dict
 
@@ -32,13 +32,21 @@ class ModelFamily:
     init: Callable
     # (params, tokens [B, S], config) -> final-norm hidden [B, S, dim]
     hidden: Callable
-    # (config, batch, max_seq) -> cache
+    # (config, batch, max_seq) -> cache: a dict of named leaves (or the
+    # Llama family's pair of keys and values), the slot on axis 1 of
+    # each. The names say what kind of bytes a leaf holds, to
+    # ``cache_bytes`` and to the series ENGINE_CACHE_BYTES: ``k`` and
+    # ``v`` are "kv", ``latent`` is "latent", every other leaf is
+    # "recurrent" (a state that a decode step consumes); a cache with
+    # keys and values says its recurrent bytes too, 0 where it has none
     init_cache: Callable
     # (params, tokens [1, bucket], length, config, lora) -> (logits
     # [1, n, vocab] float32, entry, counts). ``length`` is the prompt's
     # true length, traced; a family that has no use for it ignores it
     # and its programs do not hold it. n is the bucket, or 1 where the
-    # family returns the row of position length - 1 alone.
+    # family returns the row of position length - 1 alone. ``lora``
+    # (and ``lora_bank``, ``lora_idx`` below) are None for every family
+    # with a ``dense_only``: its functions take them and ignore them.
     prefill: Callable
     # (params, token [B], cache, pos [B], live [B], config, lora_bank,
     # lora_idx) -> (logits [B, vocab] float32, cache, counts); the
@@ -46,7 +54,7 @@ class ModelFamily:
     # (the others are parked: computed, and counted by nobody).
     decode_step: Callable
     # cache -> bytes by kind, {"kv": ..., "recurrent": ...} or
-    # {"latent": ...}
+    # {"latent": ...}: the function ``cache_bytes`` below, for all
     cache_bytes: Callable
     # why the engine's step programs beyond the dense path (a draft
     # model, multi_step, the prefix cache, chunked prefill, the
@@ -70,6 +78,20 @@ class ModelFamily:
     # engine asks ops.attention.decode_block_rows about
     kv_row_shape: Callable = lambda c: (c.n_kv_heads, c.head_dim)
 
+    @classmethod
+    def of(cls, *, init, forward, init_cache, prefill, decode_step,
+           **words) -> "ModelFamily":
+        """A family from its module's own functions, at the end of that
+        module. ``forward(params, tokens, config, return_hidden=)`` is
+        its whole-sequence forward, of which ``hidden`` is one form;
+        ``words`` are the fields after ``cache_bytes``."""
+        return cls(
+            init=init,
+            hidden=lambda params, tokens, config: forward(
+                params, tokens, config, return_hidden=True),
+            init_cache=init_cache, prefill=prefill, decode_step=decode_step,
+            cache_bytes=cache_bytes, **words)
+
 
 def insert_slot(cache, entry, slot):
     """``entry`` (a cache of one slot; its leaves may be shorter than
@@ -84,143 +106,36 @@ def insert_slot(cache, entry, slot):
 
 # ``dense_only`` of the families whose cache holds recurrent state, and
 # of the one whose cache is rows of a latent
-_CONSUMED = "holds recurrent state that a decode step consumes"
-_LATENT = ("is rows of a latent that two attention forms of its own "
-           "read, not keys and values")
+CONSUMED = "holds recurrent state that a decode step consumes"
+LATENT = ("is rows of a latent that two attention forms of its own "
+          "read, not keys and values")
 
 
-def _nbytes(leaves) -> int:
-    return int(sum(x.size * x.dtype.itemsize for x in leaves))
+def cache_bytes(cache) -> Dict[str, int]:
+    """A cache's bytes by kind, from its leaves' names (the rule at
+    ``ModelFamily.init_cache``)."""
+    named = cache if isinstance(cache, dict) else dict(zip("kv", cache))
+    kinds = {"k": "kv", "v": "kv", "latent": "latent"}
+    total: Dict[str, int] = {}
+    for name, leaf in named.items():
+        kind = kinds.get(name, "recurrent")
+        total[kind] = total.get(kind, 0) + int(leaf.size
+                                               * leaf.dtype.itemsize)
+    if "kv" in total:
+        total.setdefault("recurrent", 0)
+    return {kind: total[kind] for kind in ("kv", "recurrent", "latent")
+            if kind in total}
 
 
-@functools.cache
-def _llama() -> ModelFamily:
-    from ray_tpu.models import llama
-
-    def prefill(params, tokens, length, config, lora):
-        logits, ks, vs = llama.llama_prefill(params, tokens, config,
-                                             lora=lora)
-        return logits, (ks, vs), None
-
-    def decode_step(params, token, cache, pos, live, config, lora_bank,
-                    lora_idx):
-        logits, ck, cv = llama.llama_decode_step(
-            params, token, *cache, pos, config, lora_bank=lora_bank,
-            lora_idx=lora_idx)
-        return logits, (ck, cv), None
-
-    return ModelFamily(
-        init=llama.llama_init,
-        hidden=lambda params, tokens, config: llama.llama_forward(
-            params, tokens, config, return_hidden=True),
-        init_cache=llama.llama_init_cache, prefill=prefill,
-        decode_step=decode_step,
-        cache_bytes=lambda cache: {"kv": _nbytes(cache), "recurrent": 0})
-
-
-@functools.cache
-def _jamba() -> ModelFamily:
-    from ray_tpu.models import jamba
-
-    def prefill(params, tokens, length, config, lora):
-        return (*jamba.jamba_prefill(params, tokens, length, config), None)
-
-    def decode_step(params, token, cache, pos, live, config, lora_bank,
-                    lora_idx):
-        return (*jamba.jamba_decode_step(params, token, cache, pos, config),
-                None)
-
-    return ModelFamily(
-        init=jamba.jamba_init,
-        hidden=lambda params, tokens, config: jamba.jamba_forward(
-            params, tokens, config, return_hidden=True),
-        init_cache=jamba.jamba_init_cache, prefill=prefill,
-        decode_step=decode_step,
-        cache_bytes=lambda cache: {
-            "kv": _nbytes([cache["k"], cache["v"]]),
-            "recurrent": _nbytes([cache["ssm"], cache["conv"]])},
-        dense_only=_CONSUMED)
-
-
-@functools.cache
-def _granite() -> ModelFamily:
-    from ray_tpu.models import granite
-
-    def prefill(params, tokens, length, config, lora):
-        return granite.granite_prefill(params, tokens, length, config)
-
-    def decode_step(params, token, cache, pos, live, config, lora_bank,
-                    lora_idx):
-        return granite.granite_decode_step(params, token, cache, pos, live,
-                                           config)
-
-    return ModelFamily(
-        init=granite.granite_init,
-        hidden=lambda params, tokens, config: granite.granite_forward(
-            params, tokens, config, return_hidden=True),
-        init_cache=granite.granite_init_cache, prefill=prefill,
-        decode_step=decode_step,
-        cache_bytes=lambda cache: {
-            "kv": _nbytes([cache["k"], cache["v"]]),
-            "recurrent": _nbytes([cache["ssm"], cache["conv"]])},
-        dense_only=_CONSUMED,
-        expert_counts=granite.EXPERT_COUNTS,
-        skips_parked_state=True)
-
-
-@functools.cache
-def _lfm2() -> ModelFamily:
-    from ray_tpu.models import lfm2
-    from ray_tpu.ops.attention import cache_row_shape
-
-    def prefill(params, tokens, length, config, lora):
-        return lfm2.lfm2_prefill(params, tokens, length, config)
-
-    def decode_step(params, token, cache, pos, live, config, lora_bank,
-                    lora_idx):
-        return lfm2.lfm2_decode_step(params, token, cache, pos, live,
-                                     config)
-
-    return ModelFamily(
-        init=lfm2.lfm2_init,
-        hidden=lambda params, tokens, config: lfm2.lfm2_forward(
-            params, tokens, config, return_hidden=True),
-        init_cache=lfm2.lfm2_init_cache, prefill=prefill,
-        decode_step=decode_step,
-        cache_bytes=lambda cache: {
-            "kv": _nbytes([cache["k"], cache["v"]]),
-            "recurrent": _nbytes([cache["conv"]])},
-        dense_only=_CONSUMED,
-        expert_counts=lfm2.EXPERT_COUNTS,
-        kv_row_shape=lambda c: cache_row_shape(c.n_kv_heads, c.head_dim))
-
-
-@functools.cache
-def _mla() -> ModelFamily:
-    from ray_tpu.models import mla
-
-    def prefill(params, tokens, length, config, lora):
-        return mla.mla_prefill(params, tokens, length, config)
-
-    def decode_step(params, token, cache, pos, live, config, lora_bank,
-                    lora_idx):
-        return mla.mla_decode_step(params, token, cache, pos, live, config)
-
-    return ModelFamily(
-        init=mla.mla_init,
-        hidden=lambda params, tokens, config: mla.mla_forward(
-            params, tokens, config, return_hidden=True),
-        init_cache=mla.mla_init_cache, prefill=prefill,
-        decode_step=decode_step,
-        cache_bytes=lambda cache: {"latent": _nbytes([cache["latent"]])},
-        dense_only=_LATENT,
-        expert_counts=mla.EXPERT_COUNTS,
-        kv_row_shape=lambda c: (1, c.latent_lanes))
-
-
-_FAMILIES: Dict[str, Callable[[], ModelFamily]] = {
-    "LlamaConfig": _llama, "JambaConfig": _jamba,
-    "GraniteConfig": _granite, "Lfm2Config": _lfm2, "MlaConfig": _mla}
+# a configuration's class name -> the module that ends with its
+# ``FAMILY``, imported on first use (this module imports no model, so a
+# model's module imports ModelFamily from here whichever comes first)
+_FAMILIES: Dict[str, str] = {
+    "LlamaConfig": "ray_tpu.models.llama",
+    "JambaConfig": "ray_tpu.models.jamba",
+    "GraniteConfig": "ray_tpu.models.granite",
+    "Lfm2Config": "ray_tpu.models.lfm2",
+    "MlaConfig": "ray_tpu.models.mla"}
 
 
 def family_of(config: Any) -> ModelFamily:
@@ -228,8 +143,9 @@ def family_of(config: Any) -> ModelFamily:
     class (LlamaConfig, JambaConfig, GraniteConfig, Lfm2Config,
     MlaConfig)."""
     try:
-        return _FAMILIES[type(config).__name__]()
+        module = _FAMILIES[type(config).__name__]
     except KeyError:
         raise TypeError(
             f"no model family for a {type(config).__name__} "
             f"(have {sorted(_FAMILIES)})") from None
+    return importlib.import_module(module).FAMILY

@@ -62,6 +62,8 @@ from typing import Any, Dict, Tuple
 import jax
 import jax.numpy as jnp
 
+from ray_tpu.models import hybrid
+from ray_tpu.models.family import CONSUMED, ModelFamily
 from ray_tpu.models.hybrid import (attn_decode, attn_sequence,
                                    layer as _layer, runs)
 from ray_tpu.ops.matmul import mm as _mm
@@ -202,17 +204,7 @@ def granite_init(rng, config: GraniteConfig) -> Dict[str, Any]:
     heads, held = c.mamba_n_heads, c.experts_held[1]
     k_embed, k_mamba, k_attn = jax.random.split(rng, 3)
 
-    def dense(key, shape, fan_in):
-        return (jax.random.normal(key, shape, dtype=jnp.float32)
-                * (fan_in ** -0.5)).astype(c.dtype)
-
-    def by_layer(key, layers, shape, fan_in):
-        return jax.lax.map(lambda k: dense(k, shape, fan_in),
-                           jax.random.split(key, layers))
-
-    def ones(*shape):
-        return jnp.ones(shape, dtype=c.dtype)
-
+    dense, by_layer, ones = hybrid.drawers(c.dtype)
     def ffn(keys, layers):
         return {
             "ff_norm": ones(layers, c.dim),
@@ -417,14 +409,8 @@ def granite_forward(params, tokens, config: GraniteConfig,
     """tokens [B, S] int32 -> logits [B, S, vocab] float32, or with
     ``return_hidden`` the final-norm hidden states [B, S, dim]. Whole
     sequences, one at a time (the tests and engine.embed)."""
-    c = config
-    hidden = jnp.stack([
-        _trunk(params, tokens[i], tokens.shape[1], c)[0]
-        for i in range(tokens.shape[0])])
-    if return_hidden:
-        return rms_norm(hidden, params["final_norm"],
-                        c.norm_eps).astype(c.dtype)
-    return _head(params, hidden, c)
+    return hybrid.forward(_trunk, _head, params, tokens, config,
+                          return_hidden)
 
 
 def granite_init_cache(config: GraniteConfig, batch: int, max_seq: int):
@@ -442,7 +428,8 @@ def granite_init_cache(config: GraniteConfig, batch: int, max_seq: int):
                               c.dtype)}
 
 
-def granite_prefill(params, tokens, length, config: GraniteConfig):
+def granite_prefill(params, tokens, length, config: GraniteConfig,
+                    lora=None):
     """Forward over one prompt padded to a bucket. tokens [1, bucket]
     int32, ``length`` its true length (traced: one program a bucket) ->
     (logits [1, 1, vocab] float32 of position length - 1, that slot's
@@ -452,14 +439,13 @@ def granite_prefill(params, tokens, length, config: GraniteConfig):
     position); the recurrent state is that of the true last token."""
     c = config
     x, entry, counts = _trunk(params, tokens[0], length, c)
-    last = jax.lax.dynamic_slice_in_dim(x, length - 1, 1, 0)
-    hit = EXPERT_COUNTS.index("slots_hit")
-    return (_head(params, last, c)[None], entry,
-            counts.at[hit:hit + 2].set(0))      # slots_hit, slots_idle
+    return hybrid.prefill_result(_head, params, c, x, length, entry,
+                                 counts, EXPERT_COUNTS)
 
 
 def granite_decode_step(params, token, cache, pos, live,
-                        config: GraniteConfig):
+                        config: GraniteConfig, lora_bank=None,
+                        lora_idx=None):
     """One token for every slot. token, pos: [B] int32 (the token at
     position ``pos``); ``live`` [B]: which slots hold a request (the
     others are parked: computed, not counted); ``cache`` as
@@ -526,3 +512,10 @@ def granite_decode_step(params, token, cache, pos, live,
     logits = _head(params, x, c)
     return logits, {"k": k_cache, "v": v_cache, "ssm": ssm,
                     "conv": conv}, counts
+
+
+FAMILY = ModelFamily.of(
+    init=granite_init, forward=granite_forward,
+    init_cache=granite_init_cache, prefill=granite_prefill,
+    decode_step=granite_decode_step, dense_only=CONSUMED,
+    expert_counts=EXPERT_COUNTS, skips_parked_state=True)

@@ -1,11 +1,18 @@
-"""What the hybrid families share (models/jamba.py, models/granite.py):
-a trunk of state-space mixers with an attention layer now and then, as
-two stacks of layers walked by runs of one kind.
+"""What the serving families behind the seam of models/family.py share
+(models/jamba.py, granite.py, lfm2.py, mla.py): what is letter for
+letter the same in two or more of them, written once. A function here
+takes a family's functions and values, never a family's name, a
+configuration's class or a flag that stands for one; what would need
+one (the heads: two tie the embedding, one divides by a scaling, two
+have a matrix of their own; the trunks; Granite's feed-forward with its
+multiplier and its own stacks) stays in the family's module.
 
-- ``runs``: the trunk as runs of consecutive layers of one kind, each a
-  ``lax.scan`` segment over indices into its kind's stack.
+- ``runs``: a trunk of two kinds of layer as runs of consecutive layers
+  of one kind, each a ``lax.scan`` segment over indices into its kind's
+  stack.
 - ``layer``: one layer's weights out of a stack by a traced index, so no
-  stack is ever sliced into a copy.
+  stack is ever sliced into a copy; ``drawers``: how a family's init
+  draws a matrix, a stack of them, and a norm's weight.
 - ``attn_sequence`` / ``attn_decode``: the attention sublayer (grouped-
   query, causal, NO position encoding of its own: the mixers carry
   position), over one sequence and over every slot against the cache. A
@@ -15,6 +22,12 @@ two stacks of layers walked by runs of one kind.
   ``qk``: what it does to ``q`` and ``k`` between projection and kernel
   (LFM2: a norm over each head, then rotary), ``qk(p, q [rows, H, HD],
   k [rows, KVH, HD], positions [rows]) -> (q, k)``, in float32.
+- ``dense_or_routed``: a layer's second half where the leading layers'
+  is a dense gated feed-forward and the others' a routed one
+  (``parallel/moe.py::held_experts_ffn``) behind a router with a
+  selection bias (LFM2, MLA).
+- ``forward`` / ``prefill_result``: a family's whole-sequence forward
+  and its prefill's last lines, from its ``trunk`` and ``head``.
 
 ``c`` below is the family's configuration: ``n_heads``, ``n_kv_heads``,
 ``head_dim``, ``norm_eps``, ``dtype``, ``attention`` ("flash" |
@@ -31,8 +44,10 @@ import jax.numpy as jnp
 from ray_tpu.ops.attention import decode_attention, flash_attention
 from ray_tpu.ops.matmul import mm
 from ray_tpu.ops.rmsnorm import rms_norm
+from ray_tpu.parallel.moe import gated_ffn, held_experts_ffn
 
 SCOPE_ATTN = "attn"
+SCOPE_MLP = "mlp"
 
 
 def runs(layer_kinds: Sequence[str]) -> Tuple[Tuple[str, int, int], ...]:
@@ -47,6 +62,26 @@ def runs(layer_kinds: Sequence[str]) -> Tuple[Tuple[str, int, int], ...]:
             out.append((kind, seen[kind], 1))
         seen[kind] += 1
     return tuple(out)
+
+
+def drawers(dtype):
+    """-> (dense, by_layer, ones), how a family's ``X_init`` makes its
+    leaves in ``dtype``: ``dense(key, shape, fan_in)`` a matrix drawn
+    normal in float32 times ``fan_in ** -0.5``; ``by_layer(key, layers,
+    shape, fan_in)`` a stack of them drawn a layer at a time, so that
+    no float32 draw of a whole stack is ever alive; ``ones(*shape)``."""
+    def dense(key, shape, fan_in):
+        return (jax.random.normal(key, shape, dtype=jnp.float32)
+                * (fan_in ** -0.5)).astype(dtype)
+
+    def by_layer(key, layers, shape, fan_in):
+        return jax.lax.map(lambda k: dense(k, shape, fan_in),
+                           jax.random.split(key, layers))
+
+    def ones(*shape):
+        return jnp.ones(shape, dtype=dtype)
+
+    return dense, by_layer, ones
 
 
 def layer(stack, index):
@@ -136,3 +171,61 @@ def attn_decode(p, x, k_cache, v_cache, a, pos, c, q_scale: float = 1.0,
         out = mm(out.reshape(b, c.n_heads * hd), p["wo"])
         x = x + (out if residual == 1.0 else residual * out)
     return x, k_cache, v_cache
+
+
+def dense_or_routed(params, ff: str, index, x, live, c, n_counts: int,
+                    first: int = 0, shared=None):
+    """A layer's second half, layer ``index`` of stack ``ff`` ("dense" |
+    "moe"). x [T, dim] -> (x, the layer's counts [n_counts] uint32 over
+    the ``live`` rows, zeros from a dense layer). ``first``: the first
+    expert this rank holds (it holds those of ``params["moe"]``'s
+    stacks); ``shared(p, h) -> [T, dim]``: what a family with a shared
+    expert adds for every row, from the layer's weights and the normed
+    input. ``c``: ``norm_eps``, ``top_k``, ``scoring``."""
+    p = layer(params[ff], index)
+    h = rms_norm(x, p["ff_norm"], c.norm_eps)
+    if ff == "dense":
+        with jax.named_scope(SCOPE_MLP):
+            return (x + gated_ffn(h, p["w_in"], p["w_out"]),
+                    jnp.zeros((n_counts,), jnp.uint32))
+    # under the scopes moe.router and moe.experts; the experts' weights
+    # go as the stack's (``p``'s slices of them are never read, so
+    # under jit they are never made)
+    routed, counts = held_experts_ffn(
+        h, p["router"], params["moe"]["w_in_e"], params["moe"]["w_out_e"],
+        first, layer=index, top_k=c.top_k, live=live, scoring=c.scoring,
+        bias=p["router_bias"])
+    if shared is None:
+        return x + routed, counts
+    also = shared(p, h)
+    return x + routed + also, counts
+
+
+def forward(trunk, head, params, tokens, c, return_hidden: bool):
+    """tokens [B, S] int32 -> logits [B, S, vocab] float32, or with
+    ``return_hidden`` the final-norm hidden states [B, S, dim]. Whole
+    sequences, one at a time (the tests and engine.embed).
+    ``trunk(params, tokens [L], length, c) -> (hidden [L, dim] before
+    the final norm, ...)``; ``head(params, x, c) -> logits``."""
+    hidden = jnp.stack([
+        trunk(params, tokens[i], tokens.shape[1], c)[0]
+        for i in range(tokens.shape[0])])
+    if return_hidden:
+        return rms_norm(hidden, params["final_norm"],
+                        c.norm_eps).astype(c.dtype)
+    return head(params, hidden, c)
+
+
+def prefill_result(head, params, c, x, length, entry, counts=None,
+                   names: Sequence[str] = ()):
+    """What a prefill returns, from its trunk's hidden rows ``x`` [bucket,
+    dim]: (logits [1, 1, vocab] float32 of position length - 1, the
+    slot's cache entry, ``counts`` with its two slot counts zeroed: a
+    prefill counts no expert slots; None from a family that counts
+    nothing). ``names``: what ``counts`` counts, in order."""
+    last = jax.lax.dynamic_slice_in_dim(x, length - 1, 1, 0)
+    logits = head(params, last, c)[None]
+    if counts is not None:
+        hit = names.index("slots_hit")
+        counts = counts.at[hit:hit + 2].set(0)  # slots_hit, slots_idle
+    return logits, entry, counts

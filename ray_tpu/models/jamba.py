@@ -57,7 +57,9 @@ from typing import Any, Dict, Tuple
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.models.hybrid import (attn_decode, attn_sequence,
+from ray_tpu.models import hybrid
+from ray_tpu.models.family import CONSUMED, ModelFamily
+from ray_tpu.models.hybrid import (SCOPE_MLP, attn_decode, attn_sequence,
                                    layer as _layer, runs)
 from ray_tpu.ops.matmul import mm as _mm
 from ray_tpu.ops.rmsnorm import rms_norm
@@ -69,7 +71,6 @@ SCOPE_CONV = "mamba.conv"
 SCOPE_SCAN = "mamba.scan"        # prefill: the recurrence over a prompt
 SCOPE_UPDATE = "mamba.update"    # decode: one step of it for every slot
 SCOPE_OUT_PROJ = "mamba.out_proj"
-SCOPE_MLP = "mlp"
 SCOPE_HEAD = "head"
 
 
@@ -146,12 +147,7 @@ def jamba_init(rng, config: JambaConfig) -> Dict[str, Any]:
     hd, di, n, r = c.head_dim, c.d_inner, c.mamba_d_state, c.mamba_dt_rank
     k_embed, k_mamba, k_attn = jax.random.split(rng, 3)
 
-    def dense(key, shape, fan_in):
-        return (jax.random.normal(key, shape, dtype=jnp.float32)
-                * (fan_in ** -0.5)).astype(c.dtype)
-
-    def ones(*shape):
-        return jnp.ones(shape, dtype=c.dtype)
+    dense, _, ones = hybrid.drawers(c.dtype)
 
     def mlp(keys, layers):
         return {
@@ -297,14 +293,8 @@ def jamba_forward(params, tokens, config: JambaConfig,
     """tokens [B, S] int32 -> logits [B, S, vocab] float32, or with
     ``return_hidden`` the final-norm hidden states [B, S, dim]. Whole
     sequences, one at a time (the tests and engine.embed)."""
-    c = config
-    hidden = jnp.stack([
-        _trunk(params, tokens[i], tokens.shape[1], c)[0]
-        for i in range(tokens.shape[0])])
-    if return_hidden:
-        return rms_norm(hidden, params["final_norm"],
-                        c.norm_eps).astype(c.dtype)
-    return _head(params, hidden, c)
+    return hybrid.forward(_trunk, _head, params, tokens, config,
+                          return_hidden)
 
 
 def jamba_init_cache(config: JambaConfig, batch: int, max_seq: int):
@@ -322,24 +312,26 @@ def jamba_init_cache(config: JambaConfig, batch: int, max_seq: int):
                               c.dtype)}
 
 
-def jamba_prefill(params, tokens, length, config: JambaConfig):
+def jamba_prefill(params, tokens, length, config: JambaConfig, lora=None):
     """Forward over one prompt padded to a bucket. tokens [1, bucket]
     int32, ``length`` its true length (traced: one program a bucket) ->
     (logits [1, 1, vocab] float32 of position length - 1, that slot's
-    cache entry). K/V rows at padded positions are junk that decode
-    never attends (it masks by position); the recurrent state is that
-    of the true last token."""
+    cache entry, None: the family counts nothing on the device). K/V
+    rows at padded positions are junk that decode never attends (it
+    masks by position); the recurrent state is that of the true last
+    token."""
     c = config
     x, entry = _trunk(params, tokens[0], length, c)
-    last = jax.lax.dynamic_slice_in_dim(x, length - 1, 1, 0)
-    return _head(params, last, c)[None], entry
+    return hybrid.prefill_result(_head, params, c, x, length, entry)
 
 
-def jamba_decode_step(params, token, cache, pos, config: JambaConfig):
+def jamba_decode_step(params, token, cache, pos, live,
+                      config: JambaConfig, lora_bank=None, lora_idx=None):
     """One token for every slot. token, pos: [B] int32 (the token at
     position ``pos``); ``cache`` as jamba_init_cache gives it. ->
     (logits [B, vocab] float32, the cache with every slot's state moved
-    one step and its K/V row written at ``pos``).
+    one step and its K/V row written at ``pos``, None). ``live`` is
+    unused: a parked slot is moved like a live one.
 
     Every slot's recurrent state is updated, a parked slot's too: what
     it holds then is junk that the next admission replaces whole. The
@@ -397,4 +389,11 @@ def jamba_decode_step(params, token, cache, pos, config: JambaConfig):
                                               pos, c)
             x = _mlp(p, x, c)
     logits = _head(params, x, c)
-    return logits, {"k": k_cache, "v": v_cache, "ssm": ssm, "conv": conv}
+    return logits, {"k": k_cache, "v": v_cache, "ssm": ssm,
+                    "conv": conv}, None
+
+
+FAMILY = ModelFamily.of(
+    init=jamba_init, forward=jamba_forward, init_cache=jamba_init_cache,
+    prefill=jamba_prefill, decode_step=jamba_decode_step,
+    dense_only=CONSUMED)

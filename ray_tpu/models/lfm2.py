@@ -64,6 +64,8 @@ from typing import Any, Dict, Tuple
 import jax
 import jax.numpy as jnp
 
+from ray_tpu.models import hybrid
+from ray_tpu.models.family import CONSUMED, ModelFamily
 from ray_tpu.models.hybrid import (attn_decode, attn_sequence,
                                    layer as _layer, runs)
 from ray_tpu.ops.attention import cache_row_shape
@@ -71,13 +73,13 @@ from ray_tpu.ops.matmul import mm as _mm
 from ray_tpu.ops.rmsnorm import rms_norm
 from ray_tpu.ops.rope import apply_rope, rope_at
 from ray_tpu.parallel.moe import (BIAS_COUNTS, EXPERT_COUNTS as _LAYER_COUNTS,
-                                  Scoring, gated_ffn, held_experts_ffn)
+                                  Scoring)
 
 # jax.named_scope names, so that a trace viewer groups device ops
 # (the attention sublayer's "attn" and the expert layer's "moe.router",
 # "moe.experts" are their modules')
 SCOPE_CONV = "lfm2.conv"
-SCOPE_MLP = "mlp"
+SCOPE_MLP = hybrid.SCOPE_MLP    # a dense feed-forward (hybrid.dense_or_routed)
 SCOPE_HEAD = "head"
 
 # what the family's programs count on the device: the expert layer's
@@ -204,17 +206,7 @@ def lfm2_init(rng, config: Lfm2Config) -> Dict[str, Any]:
     hd = c.head_dim
     keys = jax.random.split(rng, 6)
 
-    def dense(key, shape, fan_in):
-        return (jax.random.normal(key, shape, dtype=jnp.float32)
-                * (fan_in ** -0.5)).astype(c.dtype)
-
-    def by_layer(key, layers, shape, fan_in):
-        return jax.lax.map(lambda k: dense(k, shape, fan_in),
-                           jax.random.split(key, layers))
-
-    def ones(*shape):
-        return jnp.ones(shape, dtype=c.dtype)
-
+    dense, by_layer, ones = hybrid.drawers(c.dtype)
     m, a = c.n_conv_layers, c.n_attn_layers
     d, e = c.n_dense_layers, c.n_moe_layers
     kc = jax.random.split(keys[0], 3)
@@ -266,21 +258,9 @@ def _norm_rope(c: Lfm2Config):
 def _ff(params, ff: str, index, x, live, c: Lfm2Config):
     """The layer's second half, layer ``index`` of stack ``ff``. x [T,
     dim] -> (x, the layer's EXPERT_COUNTS uint32 over the ``live`` rows,
-    zeros from a dense layer)."""
-    p = _layer(params[ff], index)
-    h = rms_norm(x, p["ff_norm"], c.norm_eps)
-    if ff == "dense":
-        with jax.named_scope(SCOPE_MLP):
-            return (x + gated_ffn(h, p["w_in"], p["w_out"]),
-                    jnp.zeros((len(EXPERT_COUNTS),), jnp.uint32))
-    # under the scopes moe.router and moe.experts; the experts' weights
-    # go as the stack's (``p``'s slices of them are never read, so
-    # under jit they are never made)
-    routed, counts = held_experts_ffn(
-        h, p["router"], params["moe"]["w_in_e"], params["moe"]["w_out_e"],
-        0, layer=index, top_k=c.top_k, live=live, scoring=c.scoring,
-        bias=p["router_bias"])
-    return x + routed, counts
+    zeros from a dense layer). Every expert is held, none is shared."""
+    return hybrid.dense_or_routed(params, ff, index, x, live, c,
+                                  len(EXPERT_COUNTS))
 
 
 def _conv_in(p, x, c: Lfm2Config):
@@ -355,14 +335,8 @@ def lfm2_forward(params, tokens, config: Lfm2Config,
     """tokens [B, S] int32 -> logits [B, S, vocab] float32, or with
     ``return_hidden`` the final-norm hidden states [B, S, dim]. Whole
     sequences, one at a time (the tests and engine.embed)."""
-    c = config
-    hidden = jnp.stack([
-        _trunk(params, tokens[i], tokens.shape[1], c)[0]
-        for i in range(tokens.shape[0])])
-    if return_hidden:
-        return rms_norm(hidden, params["final_norm"],
-                        c.norm_eps).astype(c.dtype)
-    return _head(params, hidden, c)
+    return hybrid.forward(_trunk, _head, params, tokens, config,
+                          return_hidden)
 
 
 def lfm2_init_cache(config: Lfm2Config, batch: int, max_seq: int):
@@ -378,7 +352,7 @@ def lfm2_init_cache(config: Lfm2Config, batch: int, max_seq: int):
                                c.dim), c.dtype)}
 
 
-def lfm2_prefill(params, tokens, length, config: Lfm2Config):
+def lfm2_prefill(params, tokens, length, config: Lfm2Config, lora=None):
     """Forward over one prompt padded to a bucket. tokens [1, bucket]
     int32, ``length`` its true length (traced: one program a bucket) ->
     (logits [1, 1, vocab] float32 of position length - 1, that slot's
@@ -388,13 +362,12 @@ def lfm2_prefill(params, tokens, length, config: Lfm2Config):
     convolution's state is that of the true last token."""
     c = config
     x, entry, counts = _trunk(params, tokens[0], length, c)
-    last = jax.lax.dynamic_slice_in_dim(x, length - 1, 1, 0)
-    hit = EXPERT_COUNTS.index("slots_hit")
-    return (_head(params, last, c)[None], entry,
-            counts.at[hit:hit + 2].set(0))      # slots_hit, slots_idle
+    return hybrid.prefill_result(_head, params, c, x, length, entry,
+                                 counts, EXPERT_COUNTS)
 
 
-def lfm2_decode_step(params, token, cache, pos, live, config: Lfm2Config):
+def lfm2_decode_step(params, token, cache, pos, live, config: Lfm2Config,
+                     lora_bank=None, lora_idx=None):
     """One token for every slot. token, pos: [B] int32 (the token at
     position ``pos``); ``live`` [B]: which slots hold a request (the
     others are parked: computed, not counted); ``cache`` as
@@ -444,3 +417,10 @@ def lfm2_decode_step(params, token, cache, pos, live, config: Lfm2Config):
                                             jnp.arange(count))
     return (_head(params, x, c), {"k": k_cache, "v": v_cache, "conv": conv},
             counts)
+
+
+FAMILY = ModelFamily.of(
+    init=lfm2_init, forward=lfm2_forward, init_cache=lfm2_init_cache,
+    prefill=lfm2_prefill, decode_step=lfm2_decode_step,
+    dense_only=CONSUMED, expert_counts=EXPERT_COUNTS,
+    kv_row_shape=lambda c: cache_row_shape(c.n_kv_heads, c.head_dim))

@@ -43,6 +43,7 @@ from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import PartitionSpec as P
 
 from ray_tpu.accelerators import jax_backend
+from ray_tpu.models.family import ModelFamily
 from ray_tpu.ops.attention import (SAVED_LSE, SAVED_OUT, decode_attention,
                                    flash_attention)
 from ray_tpu.ops.rmsnorm import rms_norm
@@ -792,3 +793,27 @@ def llama_verify_step(params, tokens, cache_k, cache_v, pos,
     x = rms_norm(x, params["final_norm"], c.norm_eps)
     logits = (x @ params["lm_head"]).astype(jnp.float32)
     return logits, new_k, new_v
+
+
+# What the serving engine's dense path asks of a family
+# (models/family.py), for the family whose cache is a PAIR of keys and
+# values and whose prefill has no use for the prompt's length; it alone
+# takes the LoRA arguments. The engine's other step programs call the
+# functions above by name.
+
+def _family_prefill(params, tokens, length, config, lora):
+    logits, ks, vs = llama_prefill(params, tokens, config, lora=lora)
+    return logits, (ks, vs), None
+
+
+def _family_decode_step(params, token, cache, pos, live, config, lora_bank,
+                        lora_idx):
+    logits, ck, cv = llama_decode_step(
+        params, token, *cache, pos, config, lora_bank=lora_bank,
+        lora_idx=lora_idx)
+    return logits, (ck, cv), None
+
+
+FAMILY = ModelFamily.of(
+    init=llama_init, forward=llama_forward, init_cache=llama_init_cache,
+    prefill=_family_prefill, decode_step=_family_decode_step)

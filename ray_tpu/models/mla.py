@@ -71,6 +71,8 @@ from typing import Any, Dict, Tuple
 import jax
 import jax.numpy as jnp
 
+from ray_tpu.models import hybrid
+from ray_tpu.models.family import LATENT, ModelFamily
 from ray_tpu.models.hybrid import SCOPE_ATTN, layer as _layer
 from ray_tpu.ops.attention import decode_attention, flash_attention
 from ray_tpu.ops.matmul import few_rows, mm as _mm
@@ -78,7 +80,7 @@ from ray_tpu.ops.rmsnorm import rms_norm
 from ray_tpu.ops.rope import (apply_rope, rope_at, yarn_inv_freq,
                               yarn_mscale)
 from ray_tpu.parallel.moe import (BIAS_COUNTS, EXPERT_COUNTS as _LAYER_COUNTS,
-                                  Scoring, gated_ffn, held_experts_ffn)
+                                  Scoring, gated_ffn)
 
 # jax.named_scope names inside the attention sublayer's "attn", so that
 # a trace viewer groups device ops (the expert layer's "moe.router" and
@@ -87,7 +89,7 @@ SCOPE_Q = "mla.q"              # the two query projections and their norm
 SCOPE_LATENT = "mla.latent"    # the latent row: projection, norm, rotary
 SCOPE_EXPAND = "mla.expand"    # prefill: W_kvb over the positions
 SCOPE_ABSORB = "mla.absorb"    # decode: into and out of the latent space
-SCOPE_MLP = "mlp"
+SCOPE_MLP = hybrid.SCOPE_MLP    # a dense feed-forward (hybrid.dense_or_routed)
 SCOPE_SHARED = "moe.shared"
 SCOPE_HEAD = "head"
 
@@ -217,17 +219,7 @@ def mla_init(rng, config: MlaConfig) -> Dict[str, Any]:
     h, held = c.n_heads, c.experts_held[1]
     keys = jax.random.split(rng, 5)
 
-    def dense(key, shape, fan_in):
-        return (jax.random.normal(key, shape, dtype=jnp.float32)
-                * (fan_in ** -0.5)).astype(c.dtype)
-
-    def by_layer(key, layers, shape, fan_in):
-        return jax.lax.map(lambda k: dense(k, shape, fan_in),
-                           jax.random.split(key, layers))
-
-    def ones(*shape):
-        return jnp.ones(shape, dtype=c.dtype)
-
+    dense, by_layer, ones = hybrid.drawers(c.dtype)
     n, d, e = c.n_layers, c.n_dense_layers, c.n_moe_layers
     ka = jax.random.split(keys[0], 5)
     attn = {
@@ -271,23 +263,16 @@ def mla_init(rng, config: MlaConfig) -> Dict[str, Any]:
 def _ff(params, ff: str, index, x, live, c: MlaConfig):
     """The layer's second half, layer ``index`` of stack ``ff``. x [T,
     dim] -> (x, the layer's EXPERT_COUNTS uint32 over the ``live`` rows,
-    zeros from a dense layer)."""
-    p = _layer(params[ff], index)
-    h = rms_norm(x, p["ff_norm"], c.norm_eps)
-    if ff == "dense":
-        with jax.named_scope(SCOPE_MLP):
-            return (x + gated_ffn(h, p["w_in"], p["w_out"]),
-                    jnp.zeros((len(EXPERT_COUNTS),), jnp.uint32))
-    # under the scopes moe.router and moe.experts; the experts' weights
-    # go as the stack's (``p``'s slices of them are never read, so
-    # under jit they are never made)
-    routed, counts = held_experts_ffn(
-        h, p["router"], params["moe"]["w_in_e"], params["moe"]["w_out_e"],
-        c.experts_held[0], layer=index, top_k=c.top_k, live=live,
-        scoring=c.scoring, bias=p["router_bias"])
+    zeros from a dense layer). This rank's experts of the routed ones,
+    and the shared expert for every row."""
+    return hybrid.dense_or_routed(params, ff, index, x, live, c,
+                                  len(EXPERT_COUNTS), c.experts_held[0],
+                                  _shared)
+
+
+def _shared(p, h):
     with jax.named_scope(SCOPE_SHARED):
-        shared = gated_ffn(h, p["w_in_s"], p["w_out_s"])
-    return x + routed + shared, counts
+        return gated_ffn(h, p["w_in_s"], p["w_out_s"])
 
 
 def _rotate(x, positions, c: MlaConfig):
@@ -430,14 +415,8 @@ def mla_forward(params, tokens, config: MlaConfig,
     ``return_hidden`` the final-norm hidden states [B, S, dim]. Whole
     sequences, one at a time, the expanded form (the tests and
     engine.embed)."""
-    c = config
-    hidden = jnp.stack([
-        _trunk(params, tokens[i], tokens.shape[1], c)[0]
-        for i in range(tokens.shape[0])])
-    if return_hidden:
-        return rms_norm(hidden, params["final_norm"],
-                        c.norm_eps).astype(c.dtype)
-    return _head(params, hidden, c)
+    return hybrid.forward(_trunk, _head, params, tokens, config,
+                          return_hidden)
 
 
 def mla_init_cache(config: MlaConfig, batch: int, max_seq: int):
@@ -450,7 +429,7 @@ def mla_init_cache(config: MlaConfig, batch: int, max_seq: int):
                                  c.latent_lanes), c.dtype)}
 
 
-def mla_prefill(params, tokens, length, config: MlaConfig):
+def mla_prefill(params, tokens, length, config: MlaConfig, lora=None):
     """Forward over one prompt padded to a bucket. tokens [1, bucket]
     int32, ``length`` its true length (traced: one program a bucket) ->
     (logits [1, 1, vocab] float32 of position length - 1, that slot's
@@ -460,13 +439,12 @@ def mla_prefill(params, tokens, length, config: MlaConfig):
     position)."""
     c = config
     x, entry, counts = _trunk(params, tokens[0], length, c)
-    last = jax.lax.dynamic_slice_in_dim(x, length - 1, 1, 0)
-    hit = EXPERT_COUNTS.index("slots_hit")
-    return (_head(params, last, c)[None], entry,
-            counts.at[hit:hit + 2].set(0))      # slots_hit, slots_idle
+    return hybrid.prefill_result(_head, params, c, x, length, entry,
+                                 counts, EXPERT_COUNTS)
 
 
-def mla_decode_step(params, token, cache, pos, live, config: MlaConfig):
+def mla_decode_step(params, token, cache, pos, live, config: MlaConfig,
+                    lora_bank=None, lora_idx=None):
     """One token for every slot. token, pos: [B] int32 (the token at
     position ``pos``); ``live`` [B]: which slots hold a request (the
     others are parked: computed, not counted); ``cache`` as
@@ -496,3 +474,10 @@ def mla_decode_step(params, token, cache, pos, live, config: MlaConfig):
         (x, latent, counts), _ = jax.lax.scan(
             body, (x, latent, counts), jnp.arange(c.n_moe_layers))
     return _head(params, x, c), {"latent": latent}, counts
+
+
+FAMILY = ModelFamily.of(
+    init=mla_init, forward=mla_forward, init_cache=mla_init_cache,
+    prefill=mla_prefill, decode_step=mla_decode_step, dense_only=LATENT,
+    expert_counts=EXPERT_COUNTS,
+    kv_row_shape=lambda c: (1, c.latent_lanes))

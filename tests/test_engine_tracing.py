@@ -3,6 +3,7 @@ engine counters, step phases as spans on the profiler's clock and in
 the flight recorder, and no control-plane call on the stepper thread.
 """
 
+import collections
 import gc
 import json
 import threading
@@ -12,6 +13,7 @@ import urllib.request
 import numpy as np
 import pytest
 
+import hostratio
 import ray_tpu
 from ray_tpu import serve
 from ray_tpu.llm import engine as engine_mod
@@ -42,6 +44,27 @@ def _hist(name, **tags):
     """(sum, count) of one histogram series in this process's registry."""
     snap = metrics_mod.histogram_snapshot(name, tags)
     return (0.0, 0) if snap is None else (snap[2], snap[3])
+
+
+def _own_series(engine):
+    """{(series name, phase): [sum, count]} of what THIS engine's buffer
+    is given from now on. The process's registry is shared: an engine
+    of an earlier test (of any file this worker ran) may still flush
+    into the same series, so an equality is read here and the registry
+    is held to ``>=``."""
+    own = collections.defaultdict(lambda: [0.0, 0])
+    buffer = engine._mbuf
+
+    def spy(record):
+        def recorded(metric, value=1.0, tags=None):
+            entry = own[metric._name, (tags or {}).get("phase")]
+            entry[0] += value
+            entry[1] += 1
+            return record(metric, value, tags)
+        return recorded
+
+    buffer.inc, buffer.observe = spy(buffer.inc), spy(buffer.observe)
+    return own
 
 
 def _hist_lines(name, label, renamed):
@@ -418,8 +441,12 @@ DISCARDED = "ray_tpu_engine_discarded_tokens_total"
 
 def _held(engine, seconds):
     """Steps that the stepper believes to take ``seconds`` on the device
-    and no time to launch: its hold lasts about that long."""
+    and no time to launch: its hold lasts about that long. (The belief
+    is the LONGEST of its last launches' leads, so those it has seen go:
+    one that met a pause of the box, or an admission's, would leave no
+    hold at all.)"""
     engine._step_device_s.extend([seconds] * 8)
+    engine._launch_lead_s.clear()
     engine._launch_lead_s.append(0.0)
 
 
@@ -488,8 +515,11 @@ def test_an_arrival_during_the_hold_goes_behind_the_one_step_that_runs(
     engine.step()
     assert len(late.output_ids) == 2
     # nobody arrives: the hold runs to its deadline, then a plain step
+    # (believed anew each time: the admission's launches were the
+    # host's longest, and the hold reckons with the longest)
     engine._arrived.wait = wait
     for _ in range(2):
+        _held(engine, 0.05)
         engine.step()
         mine = [ev[4] for ev in _engine_spans(recorder, step=engine._steps)]
         assert mine == ["engine.hold", "engine.launch", "engine.readback",
@@ -505,9 +535,21 @@ def test_the_hold_is_blocked_time_and_the_launches_are_counted():
     wall series sum to the stepper's life, the hold under ``blocked``,
     so a step's host time (its wall less ``blocked``) leaves the hold
     out. The two counters of the order reach ``stats()`` and their
-    series."""
+    series. How long the holds last is this box's to say as much as
+    the engine's (a stepper kept off its CPU for 30 ms reckons with a
+    launch that slow and holds for nothing until eight quicker ones
+    have passed), so the three bounds on seconds are judged as
+    ``hostratio`` judges every guard of a host timing: over its limit
+    in each of three runs."""
+    hostratio.judge(_a_run_with_holds)
+
+
+def _a_run_with_holds():
+    """One run, every exact statement asserted; -> the bounds on wall
+    time as (what, ratio, limit)."""
     from ray_tpu.llm.engine import STEPPER_PHASES
     engine = ContinuousBatchingEngine(_engine_config(max_batch=3))
+    own = _own_series(engine)
     engine.flush_metrics()
     ahead0, in_order0 = (_counter(LAUNCHES, order=o)
                          for o in ("ahead", "in_order"))
@@ -535,10 +577,6 @@ def test_the_hold_is_blocked_time_and_the_launches_are_counted():
     assert stops.finish_reason == "stop" and plain.finish_reason == "length"
     wall = dict(zip(STEPPER_PHASES, account.wall))
     assert abs(sum(account.wall) - (account.t - born)) < 1e-9
-    # some twenty-five holds, the first of 30 ms (a tiny step has ended
-    # long before such a hold does, and the stepper reckons with a
-    # shorter one each time)
-    assert wall["blocked"] - blocked0 > 0.15
     stats = engine.stats()
     launches = stats["decode_launches"]
     assert launches["in_order"] == 2 and launches["ahead"] >= 35
@@ -550,12 +588,25 @@ def test_the_hold_is_blocked_time_and_the_launches_are_counted():
     assert _counter(DISCARDED) - discarded0 >= 1
     assert abs(sum(stats["stepper_seconds"]["wall"].values())
                - (stats["stepper_read_at"] - born)) < 0.05
-    # the steps' wall time holds the holds, their host time does not
-    steps = _hist(STEP, phase="decode")
-    hosts = _hist(STEP_HOST, phase="decode")
-    assert steps[1] - step0[1] == hosts[1] - host0[1] >= 35
-    assert steps[0] - step0[0] > 0.15
-    assert hosts[0] - host0[0] < 0.5 * (steps[0] - step0[0])
+    # every step fed both histograms, this engine's own and, with
+    # whatever else flushed meanwhile, the process's
+    steps, hosts = own[STEP, "decode"], own[STEP_HOST, "decode"]
+    assert steps[1] == hosts[1] >= 35
+    assert _hist(STEP, phase="decode")[1] - step0[1] >= steps[1]
+    assert _hist(STEP_HOST, phase="decode")[1] - host0[1] >= hosts[1]
+    assert _hist(STEP, phase="decode")[0] - step0[0] >= steps[0] - 1e-9
+    engine.close()
+    tiny = 1e-9
+    return [
+        # some twenty-five holds, the first of 30 ms (a tiny step has
+        # ended long before such a hold does, and the stepper reckons
+        # with a shorter one each time): over 0.15 s of them
+        ("0.15 s / the holds' blocked seconds",
+         0.15 / max(wall["blocked"] - blocked0, tiny), 1.0),
+        # the steps' wall time holds the holds, their host time does not
+        ("0.15 s / the steps' seconds", 0.15 / max(steps[0], tiny), 1.0),
+        ("the steps' host seconds / half their seconds",
+         hosts[0] / max(0.5 * steps[0], tiny), 1.0)]
 
 
 # -- (d2) the stepper's account of its own time ---------------------------
@@ -721,6 +772,7 @@ def test_account_reaches_its_counter_families_and_stats():
     histograms were fed from the account."""
     from ray_tpu.llm.engine import STEPPER_CPU_PHASES, STEPPER_PHASES
     engine = ContinuousBatchingEngine(_engine_config())
+    own = _own_series(engine)
     switches = []        # the wall clock, as the account read it
 
     def clock():
@@ -762,8 +814,13 @@ def test_account_reaches_its_counter_families_and_stats():
         assert list(stats[kind]) == list(phases)
         assert sorted(after) == sorted(phases)
         for phase in phases:
+            # stats() tells what this engine's buffer sent; the
+            # process's series grew by that and by what any other
+            # engine of this process flushed meanwhile
+            assert abs(own[name, phase][0] - stats[kind][phase]) < 1e-9, (
+                name, phase)
             grew = after[phase] - before[name].get(phase, 0.0)
-            assert abs(grew - stats[kind][phase]) < 1e-9, (name, phase)
+            assert grew >= stats[kind][phase] - 1e-9, (name, phase)
             assert stats[kind][phase] >= 0.0
     assert len(stats["wall"]) == 9 and len(stats["cpu"]) == 5
     account = engine._account
@@ -776,8 +833,10 @@ def test_account_reaches_its_counter_families_and_stats():
     assert stats["cpu_wall"]["blocked"] == stats["wall"]["blocked"]
 
     def fed(name):
-        return sum(_hist(name, phase=phase)[0] - hist0[(name, phase)]
-                   for phase in ("decode", "prefill"))
+        mine = sum(own[name, phase][0] for phase in ("decode", "prefill"))
+        assert sum(_hist(name, phase=phase)[0] - hist0[(name, phase)]
+                   for phase in ("decode", "prefill")) >= mine - 1e-9
+        return mine
 
     # host time is step time less the account's blocked seconds, and
     # the upload histogram took the account's upload seconds
@@ -787,8 +846,14 @@ def test_account_reaches_its_counter_families_and_stats():
     in_steps = sum(account.wall) - stats["wall"]["wait"]
     assert fed(STEP) <= in_steps + 1e-9
     # a second flush with nothing new adds nothing
+    account_series = (STEPPER, STEPPER_CPU, STEPPER_CPU_WALL)
+    sent = {key: total for key, (total, _) in own.items()
+            if key[0] in account_series}
     engine.flush_metrics()
-    assert _phase_series(STEPPER_CPU_WALL) == after
+    assert {key: total for key, (total, _) in own.items()
+            if key[0] in account_series} == sent
+    again = _phase_series(STEPPER_CPU_WALL)
+    assert all(again[phase] >= after[phase] for phase in after)
 
 
 # -- (d2) the stall watch reads the account and writes nothing ------------

@@ -15,13 +15,12 @@ import numpy as np
 import pytest
 
 from benchmark.reference import granite as reference
-from ray_tpu.llm.engine import (ContinuousBatchingEngine, EngineConfig,
-                                GenerationRequest)
+from family_contract import *        # noqa: F401,F403 the contract, over ROW
+from family_contract import Row, Variant, engine_of, prompt, weights
+from ray_tpu.llm.engine import ContinuousBatchingEngine, EngineConfig
 from ray_tpu.models.family import family_of
-from ray_tpu.models.granite import (EXPERT_COUNTS, GraniteConfig,
-                                    granite_forward, granite_init,
-                                    granite_init_cache, granite_prefill,
-                                    ssd_chunked)
+from ray_tpu.models.granite import (GraniteConfig, granite_forward,
+                                    granite_init_cache, ssd_chunked)
 from ray_tpu.models.llama import LlamaConfig
 from ray_tpu.ops import ssd_update as ssd_update_op
 
@@ -34,47 +33,36 @@ KERNEL_CFG = GraniteConfig.tiny(dtype=jnp.float32, dim=512,
 TOL = 1e-4
 
 
+def _kernel_covers(covers):
+    """After a run: whether the decode step went through the
+    ``ssd_update`` kernel (interpret mode) or its ``jax.numpy`` form."""
+    def check(engine, stats):
+        cfg = engine.config.model
+        assert (ssd_update_op.head_block(
+            cfg.mamba_n_heads, cfg.mamba_d_head, cfg.mamba_d_state)
+            is not None) == covers
+    return check
+
+
+# 16 and 64 end on a chunk's boundary (chunks of 16), 5, 37 and 100 do
+# not; 5 is shorter than a chunk. ``kernel``: the decode step through
+# the ``ssd_update`` kernel at KERNEL_CFG, a parked slot's state left
+# where it lies
+ROW = Row(reference=reference, forward=granite_forward,
+          variants={"": Variant(CFG, check=_kernel_covers(False)),
+                    "kernel": Variant(KERNEL_CFG,
+                                      interpret=(ssd_update_op,),
+                                      check=_kernel_covers(True))},
+          decode_cases=((5, ""), (16, ""), (37, ""), (64, ""), (100, ""),
+                        (37, "kernel"), (100, "kernel")),
+          admission_cases=("", "kernel"),
+          refusal="holds recurrent state that a decode step consumes",
+          routed_layers=4)
+
+
 @pytest.fixture(scope="module")
 def params():
-    return jax.jit(granite_init, static_argnums=1)(jax.random.PRNGKey(0),
-                                                   CFG)
-
-
-@pytest.fixture(scope="module")
-def kernel_params():
-    return jax.jit(granite_init, static_argnums=1)(jax.random.PRNGKey(0),
-                                                   KERNEL_CFG)
-
-
-@pytest.fixture
-def model(request, params, monkeypatch):
-    """(configuration, weights) by the test's ``kernel`` parameter:
-    True is the decode step through the ``ssd_update`` kernel (interpret
-    mode) at KERNEL_CFG, False its ``jax.numpy`` form at CFG."""
-    if not request.getfixturevalue("kernel"):
-        return CFG, params
-    monkeypatch.setattr(ssd_update_op, "_INTERPRET", True)
-    return KERNEL_CFG, request.getfixturevalue("kernel_params")
-
-
-def _engine(params, cfg=CFG, **kw):
-    return ContinuousBatchingEngine(
-        EngineConfig(model=cfg, max_batch=3, max_seq=128, **kw),
-        params=params)
-
-
-def _prompt(n, seed=0):
-    return np.random.default_rng(seed).integers(0, 512, n).tolist()
-
-
-def _reference_logprobs(params, ids, n_out, cfg=CFG):
-    """The reference's log-probability of each of the last ``n_out``
-    tokens of ``ids``, from one full forward pass."""
-    seq = jnp.asarray(ids, jnp.int32)
-    logp = jax.nn.log_softmax(reference.logits(
-        params, seq[:-1], **reference.kwargs_from(cfg)), -1)
-    at = np.arange(len(ids) - 1 - n_out, len(ids) - 1)
-    return np.asarray(logp[at, seq[at + 1]])
+    return weights(CFG)
 
 
 def test_config_keeps_the_published_pattern_and_shares():
@@ -89,77 +77,6 @@ def test_config_keeps_the_published_pattern_and_shares():
     assert family_of(CFG).dense_only
     with pytest.raises(ValueError, match="experts_held"):
         GraniteConfig.tiny(experts_held=(6, 4))
-
-
-def test_forward_matches_the_reference(params):
-    tokens = jax.random.randint(jax.random.PRNGKey(1), (2, 37), 0, 512)
-    got = jax.jit(lambda p, t: granite_forward(p, t, CFG))(params, tokens)
-    for i in range(2):
-        want = reference.logits(params, tokens[i],
-                                **reference.kwargs_from(CFG))
-        assert float(jnp.abs(got[i] - want).max()) < TOL
-
-
-@pytest.mark.parametrize("length,kernel", [
-    (5, False), (16, False), (37, False), (64, False), (100, False),
-    (37, True), (100, True)])
-def test_engine_prefill_then_decode_matches_the_reference(model, length,
-                                                          kernel):
-    """A bucketed prefill told the prompt's true length, then whole-
-    batch decode steps with two parked slots: every token's
-    log-probability against the reference's one full pass. 16 and 64
-    end on a chunk's boundary (chunks of 16), 5, 37 and 100 do not; 5
-    is shorter than a chunk."""
-    cfg, params = model
-    engine = _engine(params, cfg)
-    ids = _prompt(length, seed=length)
-    request = engine.add_request(GenerationRequest(
-        prompt_ids=ids, max_tokens=20, logprobs=0))
-    while engine.has_work():
-        engine.step()
-    assert request.error is None and len(request.output_ids) == 20
-    got = [e["logprob"] for e in request.logprob_data]
-    want = _reference_logprobs(params, ids + request.output_ids, 20, cfg)
-    assert np.abs(np.asarray(got) - want).max() < TOL
-    assert engine._decode._cache_size() == 1
-    assert engine.stats()["dropped_rows"] == 0
-    assert (ssd_update_op.head_block(
-        cfg.mamba_n_heads, cfg.mamba_d_head, cfg.mamba_d_state)
-        is not None) == kernel
-
-
-def test_padding_leaves_the_state_of_the_true_last_token(params):
-    """The same prompt through two buckets: the cache entry (recurrent
-    state, convolution inputs, the K/V rows of the prompt), the logits
-    and the expert counts do not see the padding."""
-    ids = _prompt(21, seed=3)
-    outs = []
-    for bucket in (32, 64):
-        padded = np.zeros((1, bucket), np.int32)
-        padded[0, :21] = ids
-        outs.append(jax.jit(lambda p, t, n: granite_prefill(p, t, n, CFG))(
-            params, padded, np.int32(21)))
-    (logits_a, a, counts_a), (logits_b, b, counts_b) = outs
-    assert float(jnp.abs(logits_a - logits_b).max()) < 1e-5
-    for leaf in ("ssm", "conv"):
-        assert float(jnp.abs(a[leaf] - b[leaf]).max()) < 1e-5
-    for leaf in ("k", "v"):
-        assert float(jnp.abs(a[leaf][:, :, :21]
-                             - b[leaf][:, :, :21]).max()) < 1e-5
-    assert float(jnp.abs(a["ssm"]).max()) > 0
-    # the places walked alone see the padding: none in the bucket of 32
-    # (the few-rows form), one chunk of 64 x 3 a routed layer in the
-    # bucket of 64
-    walked = EXPERT_COUNTS.index("pairs_walked")
-    assert (int(counts_a[walked]), int(counts_b[walked])) == (0, 4 * 64 * 3)
-    counts_a, counts_b = (np.delete(np.asarray(c), walked)
-                          for c in (counts_a, counts_b))
-    # 21 positions x 4 layers x 3 picks, wherever the padding ends
-    assert counts_a.tolist() == counts_b.tolist()
-    assert int(counts_a[0] + counts_a[1]) == 21 * 4 * 3
-    # every held pick computed; a prefill counts no expert slots
-    assert int(counts_a[2]) == int(counts_a[0])
-    assert counts_a[3:].tolist() == [0, 0]
 
 
 @pytest.mark.parametrize("seq,length,chunk", [
@@ -218,83 +135,15 @@ def test_each_multiplier_moves_the_output_as_the_reference_says(
     assert float(jnp.abs(got - base).max()) > 1e-2
 
 
-@pytest.mark.parametrize("kernel", [False, True])
-def test_requests_admitted_at_different_steps_equal_their_solo_outputs(
-        model, kernel):
-    """Two requests of unequal length share the batch from different
-    steps on; a third takes the slot the first one left. A parked
-    slot's state stays as it lies and is replaced whole at admission."""
-    cfg, params = model
-    prompts = [_prompt(9, 1), _prompt(40, 2), _prompt(17, 3)]
-    lengths = [6, 14, 8]
-    solo = []
-    for ids, n in zip(prompts, lengths):
-        engine = _engine(params, cfg)
-        solo.append(engine.generate([ids], max_tokens=n)[0])
-    engine = _engine(params, cfg)
-    first = engine.add_request(GenerationRequest(
-        prompt_ids=prompts[0], max_tokens=lengths[0]))
-    for _ in range(3):
-        engine.step()
-    second = engine.add_request(GenerationRequest(
-        prompt_ids=prompts[1], max_tokens=lengths[1]))
-    while not first.done:
-        engine.step()
-    third = engine.add_request(GenerationRequest(
-        prompt_ids=prompts[2], max_tokens=lengths[2]))
-    engine.step()
-    assert engine.slots[0].request is third
-    while engine.has_work():
-        engine.step()
-    assert [first.output_ids, second.output_ids, third.output_ids] == solo
-    assert engine._decode._cache_size() == 1
-
-
-_DRAFT = LlamaConfig.tiny(vocab_size=512)
-
-
-@pytest.mark.parametrize("option,kwargs", [
-    ("draft_model", {"draft_model": _DRAFT}),
-    ("multi_step", {"multi_step": 2}),
-    ("enable_prefix_caching", {"enable_prefix_caching": True}),
-    ("chunked_prefill_tokens", {"chunked_prefill_tokens": 16}),
-    ("max_loras", {"max_loras": 2}),
-    ("quantization", {"quantization": "int8"}),
-    ("adapter", None), ("prefill_only", None), ("add_prefilled", None)])
-def test_engine_refuses_what_a_recurrent_cache_cannot_honour(
-        params, option, kwargs):
-    """Each by name, at construction or, for what a request or a call
-    asks, there: the refusals PR 34 wrote apply to this family as they
-    stand."""
-    if kwargs is not None:
-        with pytest.raises(ValueError, match=option):
-            _engine(params, **kwargs)
-        return
-    engine = _engine(params)
-    with pytest.raises(ValueError, match=option):
-        if option == "adapter":
-            engine.add_request(GenerationRequest(
-                prompt_ids=[1, 2, 3], adapter="tuned"))
-        elif option == "prefill_only":
-            engine.prefill_only([1, 2, 3])
-        else:
-            engine.add_prefilled(
-                GenerationRequest(prompt_ids=[1, 2, 3]),
-                np.zeros((1, 1, 4, 2, 16), np.float32),
-                np.zeros((1, 1, 4, 2, 16), np.float32), 3, 7)
-    assert not engine.has_work()
-
-
-def test_stats_and_series_tell_the_cache_the_picks_and_the_hit_experts(
-        params):
+def test_stats_and_series_tell_the_cache_the_picks_and_the_hit_experts():
     """The device counts reach ``stats()`` and the series through the
     metrics flush: a live row's picks by where the expert lives (parked
     slots and padding not counted), and for every layer of every dense
     decode step the held experts that a live row used and those none
     did. Nothing is dropped."""
     from ray_tpu.util import metrics
-    engine = _engine(params)
-    engine.generate([_prompt(5), _prompt(37)], max_tokens=3)
+    engine = engine_of(CFG)
+    engine.generate([prompt(5), prompt(37)], max_tokens=3)
     stats = engine.stats()
     cache = granite_init_cache(CFG, 3, 128)
     assert stats["cache_bytes"] == {
@@ -342,17 +191,16 @@ def _state_slots_series():
                 if name == "ray_tpu_engine_state_slots_total"}
 
 
-def test_state_slots_count_what_the_decode_step_moved_and_left_parked(
-        params):
+def test_state_slots_count_what_the_decode_step_moved_and_left_parked():
     """Every dense decode step counts its slots x 3 recurrent layers,
     a live slot's as moved and an empty one's as parked, in ``stats()``
     and in the series; the families whose step moves every slot's state
     (Llama has none, Jamba's ``mamba.update``) emit no such series."""
     from ray_tpu.models.jamba import JambaConfig, jamba_init
     before = _state_slots_series()
-    engine = _engine(params)
-    engine.generate([_prompt(5), _prompt(37)], max_tokens=3)
-    engine.generate([_prompt(9)], max_tokens=4)
+    engine = engine_of(CFG)
+    engine.generate([prompt(5), prompt(37)], max_tokens=3)
+    engine.generate([prompt(9)], max_tokens=4)
     stats = engine.stats()
     # two steps of two live slots of three, then three steps of one
     assert stats["decode_steps"] == 5
@@ -370,7 +218,7 @@ def test_state_slots_count_what_the_decode_step_moved_and_left_parked(
             EngineConfig(model=cfg, max_batch=3, max_seq=128),
             params=init and jax.jit(init, static_argnums=1)(
                 jax.random.PRNGKey(0), cfg))
-        other.generate([_prompt(5), _prompt(9)], max_tokens=3)
+        other.generate([prompt(5), prompt(9)], max_tokens=3)
         stats = other.stats()
         assert stats["decode_steps"] > 0
         assert "state_slots" not in stats and other._state_layers == 0
@@ -378,31 +226,15 @@ def test_state_slots_count_what_the_decode_step_moved_and_left_parked(
         other.close()
 
 
-def test_the_stepper_never_reads_the_expert_counts(params, monkeypatch):
+def test_the_stepper_never_reads_the_expert_counts(monkeypatch):
     """The counts come to the host with the metrics flush (its thread,
     ``stats()``), not on a step's path: with the flush held off, steps
     run and the totals stay where the last flush left them."""
-    engine = _engine(params)
+    engine = engine_of(CFG)
     monkeypatch.setattr(engine._mbuf, "flush_interval_s", 3600.0)
-    engine.generate([_prompt(9)], max_tokens=4)
+    engine.generate([prompt(9)], max_tokens=4)
     assert set(engine._mbuf.expert_totals.values()) == {0}
     assert int(np.asarray(engine._expert_counts).sum()) > 0
     stats = engine.stats()
     assert sum(stats["expert_picks"].values()) == (9 + 3) * 4 * 3
     engine.close()
-
-
-def test_embed_and_fail_all_go_through_the_family(params):
-    engine = _engine(params)
-    vector = engine.embed(_prompt(11))
-    assert vector.shape == (CFG.dim,) and np.isfinite(vector).all()
-    request = engine.add_request(GenerationRequest(
-        prompt_ids=_prompt(7), max_tokens=50))
-    engine.step()
-    engine.fail_all("boom")
-    assert request.error == "boom"
-    assert [leaf.shape for leaf in engine.cache] == [
-        leaf.shape for leaf in jax.tree.leaves(
-            granite_init_cache(CFG, 3, 128))]
-    again = engine.generate([_prompt(7)], max_tokens=4)
-    assert again == _engine(params).generate([_prompt(7)], max_tokens=4)

@@ -11,17 +11,16 @@ import dataclasses
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 import pytest
 
 from benchmark.reference import lfm2 as reference
-from ray_tpu.llm.engine import (ContinuousBatchingEngine, EngineConfig,
-                                GenerationRequest)
+from family_contract import *        # noqa: F401,F403 the contract, over ROW
+from family_contract import Row, Variant, engine_of, prompt, weights
+from ray_tpu.llm.engine import ContinuousBatchingEngine, EngineConfig
 from ray_tpu.models import lfm2
 from ray_tpu.models.family import family_of
 from ray_tpu.models.lfm2 import (EXPERT_COUNTS, Lfm2Config, lfm2_forward,
-                                 lfm2_init, lfm2_init_cache, lfm2_prefill)
-from ray_tpu.models.llama import LlamaConfig
+                                 lfm2_init_cache)
 from ray_tpu.ops import attention as attention_op
 
 CFG = Lfm2Config.tiny(dtype=jnp.float32)
@@ -31,33 +30,40 @@ WIDE_CFG = Lfm2Config.tiny(dtype=jnp.float32, dim=256)
 TOL = 1e-4
 
 
-def _init(cfg):
-    return jax.jit(lfm2_init, static_argnums=1)(jax.random.PRNGKey(0), cfg)
+def _rows_are_packed(engine, stats):
+    assert lfm2_init_cache(engine.config.model, 3, 128)["k"].shape \
+        == (2, 3, 128, 1, 128)
+
+
+def _two_columns_a_layer(a, b):
+    assert a["conv"].shape == (3, 1, 2, 64)
+
+
+def _all_held_and_some_moved(count):
+    """All 8 experts are held; the bias moved some picks, not most."""
+    assert count["picks_absent"] == 0
+    moved, kept = count["picks_bias_moved"], count["picks_bias_kept"]
+    assert moved + kept == count["picks_held"] and 0 < moved < kept
+
+
+# 5, 37 and 100 are shorter than their buckets of 8, 64 and 128; 16
+# fills its own; 2 is shorter than the convolution's three taps.
+# ``wide``: heads of 64, the cache's rows packed to 128 lanes, the
+# decode kernel in interpret mode
+ROW = Row(reference=reference, forward=lfm2_forward,
+          variants={"": Variant(CFG),
+                    "wide": Variant(WIDE_CFG, interpret=(attention_op,),
+                                    kv_block=128, check=_rows_are_packed)},
+          decode_cases=((2, ""), (5, ""), (16, ""), (37, ""), (100, ""),
+                        (37, "wide"), (100, "wide")),
+          refusal="holds recurrent state that a decode step consumes",
+          routed_layers=4, check_entry=_two_columns_a_layer,
+          check_prefill_counts=_all_held_and_some_moved)
 
 
 @pytest.fixture(scope="module")
 def params():
-    return _init(CFG)
-
-
-def _engine(params, cfg=CFG, **kw):
-    return ContinuousBatchingEngine(
-        EngineConfig(model=cfg, max_batch=3, max_seq=128, **kw),
-        params=params)
-
-
-def _prompt(n, seed=0):
-    return np.random.default_rng(seed).integers(0, 512, n).tolist()
-
-
-def _reference_logprobs(params, ids, n_out, cfg=CFG):
-    """The reference's log-probability of each of the last ``n_out``
-    tokens of ``ids``, from one full forward pass."""
-    seq = jnp.asarray(ids, jnp.int32)
-    logp = jax.nn.log_softmax(reference.logits(
-        params, seq[:-1], **reference.kwargs_from(cfg)), -1)
-    at = np.arange(len(ids) - 1 - n_out, len(ids) - 1)
-    return np.asarray(logp[at, seq[at + 1]])
+    return weights(CFG)
 
 
 def test_config_keeps_the_published_pattern():
@@ -86,85 +92,6 @@ def test_config_keeps_the_published_pattern():
     assert EXPERT_COUNTS[-2:] == ("picks_bias_moved", "picks_bias_kept")
     with pytest.raises(ValueError, match="layer types"):
         Lfm2Config.tiny(layer_types=("conv", "mamba"))
-
-
-def test_forward_matches_the_reference(params):
-    tokens = jax.random.randint(jax.random.PRNGKey(1), (2, 37), 0, 512)
-    got = jax.jit(lambda p, t: lfm2_forward(p, t, CFG))(params, tokens)
-    for i in range(2):
-        want = reference.logits(params, tokens[i],
-                                **reference.kwargs_from(CFG))
-        assert float(jnp.abs(got[i] - want).max()) < TOL
-
-
-@pytest.mark.parametrize("length,wide", [
-    (2, False), (5, False), (16, False), (37, False), (100, False),
-    (37, True), (100, True)])
-def test_engine_prefill_then_decode_matches_the_reference(
-        params, length, wide, monkeypatch):
-    """A bucketed prefill told the prompt's true length (5, 37 and 100
-    are shorter than their buckets of 8, 64 and 128; 16 fills its own;
-    2 is shorter than the convolution's three taps), then whole-batch
-    decode steps with two parked slots: every token's log-probability
-    against the reference's one full pass. ``wide``: heads of 64, the
-    cache's rows packed to 128 lanes, the decode kernel in interpret
-    mode."""
-    cfg = CFG
-    if wide:
-        monkeypatch.setattr(attention_op, "_INTERPRET", True)
-        cfg, params = WIDE_CFG, _init(WIDE_CFG)
-        assert lfm2_init_cache(cfg, 3, 128)["k"].shape == (2, 3, 128, 1, 128)
-    engine = _engine(params, cfg)
-    # the engine counts the rows the decode kernel reads by the blocks
-    # of the cache as this family stores it
-    assert engine._kv_block == (128 if wide else engine.config.max_seq)
-    ids = _prompt(length, seed=length)
-    request = engine.add_request(GenerationRequest(
-        prompt_ids=ids, max_tokens=20, logprobs=0))
-    while engine.has_work():
-        engine.step()
-    assert request.error is None and len(request.output_ids) == 20
-    got = [e["logprob"] for e in request.logprob_data]
-    want = _reference_logprobs(params, ids + request.output_ids, 20, cfg)
-    assert np.abs(np.asarray(got) - want).max() < TOL
-    assert engine._decode._cache_size() == 1
-    assert engine.stats()["dropped_rows"] == 0
-
-
-def test_padding_leaves_the_state_of_the_true_last_token(params):
-    """The same prompt through two buckets: the cache entry (the
-    convolution's two columns, the K/V rows of the prompt), the logits
-    and the expert counts do not see the padding."""
-    ids = _prompt(21, seed=3)
-    outs = []
-    for bucket in (32, 64):
-        padded = np.zeros((1, bucket), np.int32)
-        padded[0, :21] = ids
-        outs.append(jax.jit(lambda p, t, n: lfm2_prefill(p, t, n, CFG))(
-            params, padded, np.int32(21)))
-    (logits_a, a, counts_a), (logits_b, b, counts_b) = outs
-    assert float(jnp.abs(logits_a - logits_b).max()) < 1e-5
-    assert a["conv"].shape == (3, 1, 2, 64)
-    assert float(jnp.abs(a["conv"] - b["conv"]).max()) < 1e-5
-    assert float(jnp.abs(a["conv"]).max()) > 0
-    for leaf in ("k", "v"):
-        assert float(jnp.abs(a[leaf][:, :, :21]
-                             - b[leaf][:, :, :21]).max()) < 1e-5
-    # the places walked alone see the padding: none in the bucket of 32
-    # (the few-rows form), one chunk of 64 x 3 a routed layer in the
-    # bucket of 64
-    walked = EXPERT_COUNTS.index("pairs_walked")
-    assert (int(counts_a[walked]), int(counts_b[walked])) == (0, 4 * 64 * 3)
-    counts_a, counts_b = (np.delete(np.asarray(c), walked)
-                          for c in (counts_a, counts_b))
-    # 21 positions x 4 routed layers x 3 picks, wherever the padding
-    # ends; all 8 experts are held, every pick computed, and a prefill
-    # counts no expert slots
-    assert counts_a.tolist() == counts_b.tolist()
-    held, absent, computed, hit, idle, moved, kept = counts_a.tolist()
-    assert (held, absent, computed, hit, idle) == (21 * 4 * 3, 0,
-                                                   21 * 4 * 3, 0, 0)
-    assert moved + kept == held and 0 < moved < kept
 
 
 def _leave_out_of_q_and_k(monkeypatch, part):
@@ -233,66 +160,15 @@ def test_each_part_moves_the_output_as_the_reference_says(
     assert float(jnp.abs(got - base).max()) > 1e-2
 
 
-def test_requests_admitted_at_different_steps_equal_their_solo_outputs(
-        params):
-    """Two requests of unequal length share the batch from different
-    steps on; a third takes the slot the first one left. A parked
-    slot's state is written by every step and replaced whole at
-    admission."""
-    prompts = [_prompt(9, 1), _prompt(40, 2), _prompt(17, 3)]
-    lengths = [6, 14, 8]
-    solo = []
-    for ids, n in zip(prompts, lengths):
-        engine = _engine(params)
-        solo.append(engine.generate([ids], max_tokens=n)[0])
-    engine = _engine(params)
-    first = engine.add_request(GenerationRequest(
-        prompt_ids=prompts[0], max_tokens=lengths[0]))
-    for _ in range(3):
-        engine.step()
-    second = engine.add_request(GenerationRequest(
-        prompt_ids=prompts[1], max_tokens=lengths[1]))
-    while not first.done:
-        engine.step()
-    third = engine.add_request(GenerationRequest(
-        prompt_ids=prompts[2], max_tokens=lengths[2]))
-    engine.step()
-    assert engine.slots[0].request is third
-    while engine.has_work():
-        engine.step()
-    assert [first.output_ids, second.output_ids, third.output_ids] == solo
-    assert engine._decode._cache_size() == 1
-
-
-_DRAFT = LlamaConfig.tiny(vocab_size=512)
-
-
-@pytest.mark.parametrize("option,kwargs", [
-    ("draft_model", {"draft_model": _DRAFT}),
-    ("multi_step", {"multi_step": 2}),
-    ("enable_prefix_caching", {"enable_prefix_caching": True}),
-    ("chunked_prefill_tokens", {"chunked_prefill_tokens": 16}),
-    ("max_loras", {"max_loras": 2}),
-    ("quantization", {"quantization": "int8"})])
-def test_engine_refuses_what_a_consumed_state_cannot_honour(
-        params, option, kwargs):
-    """Each by name, at construction: the refusals PR 34 wrote apply to
-    this family as they stand (its two columns a layer are consumed by
-    every step like any recurrent state)."""
-    with pytest.raises(ValueError, match=option):
-        _engine(params, **kwargs)
-
-
-def test_stats_and_series_tell_the_picks_the_hit_experts_and_the_bias(
-        params):
+def test_stats_and_series_tell_the_picks_the_hit_experts_and_the_bias():
     """The device counts reach ``stats()`` and the series through the
     metrics flush, the family's own two among them: a live row's picks
     (all held), the experts a live row used in every routed layer of
     every dense decode step, and the picks that the selection bias
     moved (not among the row's 3 largest scores) and kept."""
     from ray_tpu.util import metrics
-    engine = _engine(params)
-    engine.generate([_prompt(5), _prompt(37)], max_tokens=3)
+    engine = engine_of(CFG)
+    engine.generate([prompt(5), prompt(37)], max_tokens=3)
     stats = engine.stats()
     cache = lfm2_init_cache(CFG, 3, 128)
     assert stats["cache_bytes"] == {
@@ -340,24 +216,8 @@ def test_a_family_without_a_bias_tells_no_router_picks():
         EngineConfig(model=cfg, max_batch=3, max_seq=128),
         params=jax.jit(granite_init, static_argnums=1)(
             jax.random.PRNGKey(0), cfg))
-    engine.generate([_prompt(9)], max_tokens=3)
+    engine.generate([prompt(9)], max_tokens=3)
     stats = engine.stats()
     assert "router_picks" not in stats and stats["expert_picks"]["held"] > 0
     assert len(family_of(cfg).expert_counts) == 6
     engine.close()
-
-
-def test_embed_and_fail_all_go_through_the_family(params):
-    engine = _engine(params)
-    vector = engine.embed(_prompt(11))
-    assert vector.shape == (CFG.dim,) and np.isfinite(vector).all()
-    request = engine.add_request(GenerationRequest(
-        prompt_ids=_prompt(7), max_tokens=50))
-    engine.step()
-    engine.fail_all("boom")
-    assert request.error == "boom"
-    assert [leaf.shape for leaf in engine.cache] == [
-        leaf.shape for leaf in jax.tree.leaves(
-            lfm2_init_cache(CFG, 3, 128))]
-    again = engine.generate([_prompt(7)], max_tokens=4)
-    assert again == _engine(params).generate([_prompt(7)], max_tokens=4)

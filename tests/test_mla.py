@@ -19,13 +19,12 @@ import numpy as np
 import pytest
 
 from benchmark.reference import mla as reference
-from ray_tpu.llm.engine import (ContinuousBatchingEngine, EngineConfig,
-                                GenerationRequest)
+from family_contract import *        # noqa: F401,F403 the contract, over ROW
+from family_contract import Row, Variant, engine_of, prompt, weights
 from ray_tpu.models import mla
 from ray_tpu.models.family import family_of, insert_slot
-from ray_tpu.models.llama import LlamaConfig
 from ray_tpu.models.mla import (EXPERT_COUNTS, MlaConfig, mla_forward,
-                                mla_init, mla_init_cache, mla_prefill)
+                                mla_init_cache)
 from ray_tpu.ops import attention as attention_op
 from ray_tpu.ops.rope import yarn_inv_freq, yarn_mscale
 
@@ -33,36 +32,65 @@ CFG = MlaConfig.tiny(dtype=jnp.float32)
 TOL = 1e-4
 
 
-def _init(cfg):
-    return jax.jit(mla_init, static_argnums=1)(jax.random.PRNGKey(0), cfg)
+def _two_blocks_read(engine, stats):
+    """Every step read the live slot's two blocks and a parked slot's
+    one (the first read happens with the prompt's last token at
+    position 599, in the second block)."""
+    steps = stats["decode_steps"]
+    assert stats["decode_kv_rows_read"] == steps * 512 * (2 + 2)
+
+
+def _latent_rows_in_their_slot(a, b):
+    """The entry is the prompt's latent rows and nothing else, and
+    ``insert_slot`` lands it in its slot and in no other."""
+    assert list(a) == ["latent"]
+    assert a["latent"].shape == (4, 1, 32, 1, CFG.latent_lanes)
+    cache = insert_slot(mla_init_cache(CFG, 3, 128), a, 1)
+    assert cache["latent"].shape == (4, 3, 128, 1, CFG.latent_lanes)
+    assert float(jnp.abs(cache["latent"][:, 1, :32]
+                         - a["latent"][:, 0]).max()) == 0.0
+    assert float(jnp.abs(cache["latent"][:, [0, 2]]).max()) == 0.0
+    assert float(jnp.abs(cache["latent"][:, 1, 32:]).max()) == 0.0
+
+
+def _a_quarter_held_and_some_moved(count):
+    """A quarter of the 16 experts is held; the bias moved some picks,
+    not most."""
+    held, absent = count["picks_held"], count["picks_absent"]
+    moved, kept = count["picks_bias_moved"], count["picks_bias_kept"]
+    assert moved + kept == held + absent
+    assert 0 < held < absent and 0 < moved < kept
+
+
+# A prefill runs the expanded form (5, 37 and 100 are shorter than their
+# buckets of 8, 64 and 128) and hands the prompt's LATENT rows to the
+# slot; the decode steps run the absorbed form. ``flash``: the expanded
+# form through the forward kernel (interpret mode), keys of 24 over
+# values of 16 padded to 128 lanes, the scale the family's own.
+# ``blocks``: the decode kernel in interpret mode over a cache of 1024
+# rows, which it reads in blocks of 512: a prompt of 600 spans two
+ROW = Row(reference=reference, forward=mla_forward,
+          variants={"": Variant(CFG),
+                    "flash": Variant(
+                        dataclasses.replace(CFG, attention="flash"),
+                        interpret=(attention_op,)),
+                    "blocks": Variant(CFG, interpret=(attention_op,),
+                                      max_seq=1024, new_tokens=6,
+                                      kv_block=512, check=_two_blocks_read)},
+          forward_cases=(("", 128), ("flash", 128)),
+          decode_cases=((5, ""), (37, ""), (100, ""), (600, "blocks")),
+          refusal="rows of a latent", routed_layers=3,
+          check_entry=_latent_rows_in_their_slot,
+          check_prefill_counts=_a_quarter_held_and_some_moved)
 
 
 @pytest.fixture(scope="module")
 def params():
-    return _init(CFG)
-
-
-def _engine(params, cfg=CFG, max_seq=128, **kw):
-    return ContinuousBatchingEngine(
-        EngineConfig(model=cfg, max_batch=3, max_seq=max_seq, **kw),
-        params=params)
-
-
-def _prompt(n, seed=0):
-    return np.random.default_rng(seed).integers(0, 512, n).tolist()
+    return weights(CFG)
 
 
 def _reference_logits(params, tokens, cfg=CFG):
     return reference.logits(params, tokens, **reference.kwargs_from(cfg))
-
-
-def _reference_logprobs(params, ids, n_out, cfg=CFG):
-    """The reference's log-probability of each of the last ``n_out``
-    tokens of ``ids``, from one full forward pass."""
-    seq = jnp.asarray(ids, jnp.int32)
-    logp = jax.nn.log_softmax(_reference_logits(params, seq[:-1], cfg), -1)
-    at = np.arange(len(ids) - 1 - n_out, len(ids) - 1)
-    return np.asarray(logp[at, seq[at + 1]])
 
 
 def test_config_holds_the_published_sizes_and_the_scale_holds_m_squared():
@@ -126,22 +154,6 @@ def test_yarn_frequencies_against_numbers_worked_by_hand():
     assert math.isclose(reference.mscale(64.0, 1.0), yarn_mscale(64.0))
 
 
-@pytest.mark.parametrize("attention", ["reference", "flash"])
-def test_forward_matches_the_reference(params, attention, monkeypatch):
-    """``flash``: the expanded form through the forward kernel (interpret
-    mode), keys of 24 over values of 16 padded to 128 lanes, the scale
-    the family's own."""
-    cfg = CFG
-    if attention == "flash":
-        monkeypatch.setattr(attention_op, "_INTERPRET", True)
-        cfg = dataclasses.replace(CFG, attention="flash")
-    tokens = jax.random.randint(jax.random.PRNGKey(1), (2, 128), 0, 512)
-    got = jax.jit(lambda p, t: mla_forward(p, t, cfg))(params, tokens)
-    for i in range(2):
-        want = _reference_logits(params, tokens[i])
-        assert float(jnp.abs(got[i] - want).max()) < TOL
-
-
 def test_the_absorbed_form_equals_the_expanded_one(params):
     """One attention layer, the last of 40 positions: the expanded form
     over the sequence and the absorbed form over the latent rows that
@@ -161,88 +173,6 @@ def test_the_absorbed_form_equals_the_expanded_one(params):
     assert float(jnp.abs(cache_after[1, 1, 39, 0] - latent[39]).max()) < 1e-6
 
 
-@pytest.mark.parametrize("length,blocks", [(5, 0), (37, 0), (100, 0),
-                                           (600, 2)])
-def test_engine_prefill_then_decode_matches_the_reference(
-        params, length, blocks, monkeypatch):
-    """A bucketed prefill (the expanded form; 5, 37 and 100 are shorter
-    than their buckets of 8, 64 and 128), the prompt's LATENT rows
-    handed to the slot, then whole-batch decode steps (the absorbed
-    form) with two parked slots: every token's log-probability against
-    the reference's one full pass. ``blocks``: the decode kernel in
-    interpret mode over a cache of 1024 rows, which it reads in blocks
-    of 512: a prompt of 600 spans two."""
-    max_seq = 128
-    if blocks:
-        monkeypatch.setattr(attention_op, "_INTERPRET", True)
-        max_seq = 1024
-    engine = _engine(params, max_seq=max_seq)
-    # the engine counts the rows the decode kernel reads by the blocks
-    # of the cache as this family stores it
-    assert engine._kv_block == (512 if blocks else max_seq)
-    ids = _prompt(length, seed=length)
-    n_out = 6 if blocks else 20
-    request = engine.add_request(GenerationRequest(
-        prompt_ids=ids, max_tokens=n_out, logprobs=0))
-    while engine.has_work():
-        engine.step()
-    assert request.error is None and len(request.output_ids) == n_out
-    got = [e["logprob"] for e in request.logprob_data]
-    want = _reference_logprobs(params, ids + request.output_ids, n_out)
-    assert np.abs(np.asarray(got) - want).max() < TOL
-    assert engine._decode._cache_size() == 1
-    stats = engine.stats()
-    assert stats["dropped_rows"] == 0
-    if blocks:
-        # every step read the live slot's two blocks and a parked
-        # slot's one (the first read happens with the prompt's last
-        # token at position 599, in the second block)
-        steps = stats["decode_steps"]
-        assert stats["decode_kv_rows_read"] == steps * 512 * (2 + 2)
-    engine.close()
-
-
-def test_padding_leaves_the_latent_rows_of_the_prompt(params):
-    """The same prompt through two buckets: the cache entry (the
-    prompt's latent rows), the logits and the expert counts do not see
-    the padding; the entry lands in its slot and in no other."""
-    ids = _prompt(21, seed=3)
-    outs = []
-    for bucket in (32, 64):
-        padded = np.zeros((1, bucket), np.int32)
-        padded[0, :21] = ids
-        outs.append(jax.jit(lambda p, t, n: mla_prefill(p, t, n, CFG))(
-            params, padded, np.int32(21)))
-    (logits_a, a, counts_a), (logits_b, b, counts_b) = outs
-    assert float(jnp.abs(logits_a - logits_b).max()) < 1e-5
-    assert list(a) == ["latent"]
-    assert a["latent"].shape == (4, 1, 32, 1, CFG.latent_lanes)
-    assert float(jnp.abs(a["latent"][:, :, :21]
-                         - b["latent"][:, :, :21]).max()) < 1e-5
-    # the places walked alone see the padding: none in the bucket of 32
-    # (the few-rows form), one chunk of 64 x 3 a routed layer in the
-    # bucket of 64
-    walked = EXPERT_COUNTS.index("pairs_walked")
-    assert (int(counts_a[walked]), int(counts_b[walked])) == (0, 3 * 64 * 3)
-    counts_a, counts_b = (np.delete(np.asarray(c), walked)
-                          for c in (counts_a, counts_b))
-    # 21 positions x 3 routed layers x 3 picks, wherever the padding
-    # ends; a quarter of the 16 experts is held, every held pick is
-    # computed, and a prefill counts no expert slots
-    assert counts_a.tolist() == counts_b.tolist()
-    held, absent, computed, hit, idle, moved, kept = counts_a.tolist()
-    assert held + absent == 21 * 3 * 3 == moved + kept
-    assert 0 < held == computed < absent and (hit, idle) == (0, 0)
-    assert 0 < moved < kept
-    # insert_slot of a latent entry
-    cache = insert_slot(mla_init_cache(CFG, 3, 128), a, 1)
-    assert cache["latent"].shape == (4, 3, 128, 1, CFG.latent_lanes)
-    assert float(jnp.abs(cache["latent"][:, 1, :32]
-                         - a["latent"][:, 0]).max()) == 0.0
-    assert float(jnp.abs(cache["latent"][:, [0, 2]]).max()) == 0.0
-    assert float(jnp.abs(cache["latent"][:, 1, 32:]).max()) == 0.0
-
-
 @pytest.mark.parametrize("rows", [8, 40])
 def test_the_ranks_partial_sums_add_up_to_the_uncut_layer(rows):
     """Four ranks of four experts each compute their part of one routed
@@ -251,7 +181,7 @@ def test_the_ranks_partial_sums_add_up_to_the_uncut_layer(rows):
     alike counted once, add up to what the uncut reference gives for
     the whole layer."""
     whole = dataclasses.replace(CFG, experts_held=(0, 16))
-    params = _init(whole)
+    params = weights(whole)
     x = jax.random.normal(jax.random.PRNGKey(5), (rows, whole.dim))
     layer = mla._layer(params["moe"], 1)
     u = reference._rms_norm(x, layer["ff_norm"], whole.norm_eps)
@@ -325,79 +255,7 @@ def test_each_part_moves_the_output_as_the_reference_says(
     assert float(jnp.abs(got - base).max()) > 1e-2
 
 
-def test_requests_admitted_at_different_steps_equal_their_solo_outputs(
-        params):
-    """Two requests of unequal length share the batch from different
-    steps on; a third takes the slot the first one left, whose rows it
-    overwrites up to its own prompt and masks beyond."""
-    prompts = [_prompt(9, 1), _prompt(40, 2), _prompt(17, 3)]
-    lengths = [6, 14, 8]
-    solo = []
-    for ids, n in zip(prompts, lengths):
-        engine = _engine(params)
-        solo.append(engine.generate([ids], max_tokens=n)[0])
-    engine = _engine(params)
-    first = engine.add_request(GenerationRequest(
-        prompt_ids=prompts[0], max_tokens=lengths[0]))
-    for _ in range(3):
-        engine.step()
-    second = engine.add_request(GenerationRequest(
-        prompt_ids=prompts[1], max_tokens=lengths[1]))
-    while not first.done:
-        engine.step()
-    third = engine.add_request(GenerationRequest(
-        prompt_ids=prompts[2], max_tokens=lengths[2]))
-    engine.step()
-    assert engine.slots[0].request is third
-    while engine.has_work():
-        engine.step()
-    assert [first.output_ids, second.output_ids, third.output_ids] == solo
-    assert engine._decode._cache_size() == 1
-
-
-_DRAFT = LlamaConfig.tiny(vocab_size=512)
-
-
-@pytest.mark.parametrize("option,kwargs", [
-    ("draft_model", {"draft_model": _DRAFT}),
-    ("multi_step", {"multi_step": 2}),
-    ("enable_prefix_caching", {"enable_prefix_caching": True}),
-    ("chunked_prefill_tokens", {"chunked_prefill_tokens": 16}),
-    ("max_loras", {"max_loras": 2}),
-    ("quantization", {"quantization": "int8"}),
-    ("adapter", None), ("prefill_only", None), ("add_prefilled", None)])
-def test_engine_refuses_the_llama_familys_programs_by_name(
-        params, option, kwargs):
-    """This family's cache IS rows and holds no consumed state, so
-    nothing would be corrupted: the other step programs, the prefix
-    cache and the disaggregated path are the Llama family's, written
-    over a pair of keys and values, and the engine says that, at
-    construction or, for what a request or a call asks, there."""
-    llama = "Llama family's"
-    if kwargs is not None:
-        with pytest.raises(ValueError, match=option) as refused:
-            _engine(params, **kwargs)
-        assert llama in str(refused.value)
-        assert "MlaConfig" in str(refused.value)
-        if option not in ("max_loras", "quantization"):
-            assert "rows of a latent" in str(refused.value)
-        return
-    engine = _engine(params)
-    with pytest.raises(ValueError, match=option) as refused:
-        if option == "adapter":
-            engine.add_request(GenerationRequest(
-                prompt_ids=[1, 2, 3], adapter="tuned"))
-        elif option == "prefill_only":
-            engine.prefill_only([1, 2, 3])
-        else:
-            engine.add_prefilled(GenerationRequest(prompt_ids=[1, 2, 3]),
-                                 None, None, 3, 0)
-    assert "MlaConfig" in str(refused.value)
-    engine.close()
-
-
-def test_stats_and_series_tell_the_latent_cache_the_picks_and_the_bias(
-        params):
+def test_stats_and_series_tell_the_latent_cache_the_picks_and_the_bias():
     """The device counts reach ``stats()`` and the series through the
     metrics flush: the latent cache's bytes under a kind of their own, a
     live row's picks by where the expert lives (a quarter held), the
@@ -405,8 +263,8 @@ def test_stats_and_series_tell_the_latent_cache_the_picks_and_the_bias(
     decode step, the picks that the selection bias moved, and the rows
     of LATENT cache a step covered."""
     from ray_tpu.util import metrics
-    engine = _engine(params)
-    engine.generate([_prompt(5), _prompt(37)], max_tokens=3)
+    engine = engine_of(CFG)
+    engine.generate([prompt(5), prompt(37)], max_tokens=3)
     stats = engine.stats()
     cache = mla_init_cache(CFG, 3, 128)
     assert stats["cache_bytes"] == {"latent": cache["latent"].nbytes}
@@ -443,18 +301,3 @@ def test_stats_and_series_tell_the_latent_cache_the_picks_and_the_bias(
                    'ray_tpu_engine_cache_bytes{kind="latent"}'):
         assert series in text
     engine.close()
-
-
-def test_embed_and_fail_all_go_through_the_family(params):
-    engine = _engine(params)
-    vector = engine.embed(_prompt(11))
-    assert vector.shape == (CFG.dim,) and np.isfinite(vector).all()
-    request = engine.add_request(GenerationRequest(
-        prompt_ids=_prompt(7), max_tokens=50))
-    engine.step()
-    engine.fail_all("boom")
-    assert request.error == "boom"
-    assert [leaf.shape for leaf in engine.cache] == [
-        leaf.shape for leaf in jax.tree.leaves(mla_init_cache(CFG, 3, 128))]
-    again = engine.generate([_prompt(7)], max_tokens=4)
-    assert again == _engine(params).generate([_prompt(7)], max_tokens=4)

@@ -9,18 +9,19 @@ import re
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 import optax
 import pytest
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 import chip_smoke
+from abstract_engine import (abstract_engine, lowering_for_tpu as _for_tpu,
+                             mesh_of as _mesh, on as _on, replicated,
+                             v5e_devices)
 from ray_tpu.accelerators import jax_backend
 from ray_tpu.models.llama import (
     LlamaConfig, llama_decode_step, llama_init, llama_init_cache,
     llama_prefill)
 from ray_tpu.ops import attention, quant_matmul, rmsnorm
-from ray_tpu.parallel.mesh import AXIS_ORDER
 from test_models import products_carrying
 
 HBM_BYTES = 16 * 2**30  # one v5e chip
@@ -29,34 +30,21 @@ HBM_BYTES = 16 * 2**30  # one v5e chip
 @pytest.fixture(scope="module")
 def v5e():
     try:
-        from jax.experimental import topologies
-        return topologies.get_topology_desc(
-            platform="tpu", topology_name="v5e:2x2").devices
+        return v5e_devices()
     except Exception as exc:  # noqa: BLE001 — no libtpu, other jax
         pytest.skip(f"no v5e:2x2 compile-only topology here: {exc!r}")
 
 
 @pytest.fixture(autouse=True)
-def lowering_for_tpu(monkeypatch):
-    # the host backend is the CPU; the programs are lowered for the
-    # topology's devices, so kernel selection must answer for those
-    monkeypatch.setattr(jax_backend, "on_tpu", lambda: True)
-
-
-def _mesh(devices, fsdp):
-    shape = tuple(fsdp if a == "fsdp" else 1 for a in AXIS_ORDER)
-    return Mesh(np.asarray(devices[:fsdp]).reshape(shape), AXIS_ORDER)
+def lowering_for_tpu():
+    with _for_tpu():
+        yield
 
 
 def _abstract(tree, shardings):
     return jax.tree.map(
         lambda a, s: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=s),
         tree, shardings)
-
-
-def _on(mesh, spec, *shape_dtype):
-    return jax.ShapeDtypeStruct(*shape_dtype,
-                                sharding=NamedSharding(mesh, spec))
 
 
 def _kernels(lowered):
@@ -212,18 +200,15 @@ def test_fsdp4_train_step_compiles_with_per_shard_kernels(v5e):
 
 
 def _abstract_params(mesh, cfg):
-    params = jax.eval_shape(lambda k: llama_init(k, cfg),
-                            jax.random.PRNGKey(0))
-    return _abstract(params, jax.tree.map(
-        lambda _: NamedSharding(mesh, P()), params))
+    return replicated(mesh, jax.eval_shape(lambda k: llama_init(k, cfg),
+                                           jax.random.PRNGKey(0)))
 
 
 def _lower_decode_step(mesh, cfg, params, *, batch, seq):
     """``llama_decode_step`` with both caches donated, as the engine's
     decode program calls it."""
-    cache = jax.eval_shape(lambda: llama_init_cache(cfg, batch, seq))
-    cache_k, cache_v = _abstract(cache, jax.tree.map(
-        lambda _: NamedSharding(mesh, P()), cache))
+    cache_k, cache_v = replicated(mesh, jax.eval_shape(
+        lambda: llama_init_cache(cfg, batch, seq)))
     ints = _on(mesh, P(), (batch,), jnp.int32)
     return jax.jit(
         lambda p, tok, ck, cv, pos: llama_decode_step(p, tok, ck, cv,
@@ -290,8 +275,7 @@ def test_decode_step_attends_over_the_cache_in_place(v5e):
 
 
 @pytest.mark.parametrize("want_lp", [False, True])
-def test_engine_decode_program_feeds_its_state_back(v5e, monkeypatch,
-                                                    want_lp):
+def test_engine_decode_program_feeds_its_state_back(v5e, want_lp):
     """The engine's own ``decode`` / ``decode_lp`` program at the two
     serving cells' sizes, its per-slot inputs and the sampler's counter
     one packed [7, 32] int32 state that it also returns: both caches
@@ -301,27 +285,11 @@ def test_engine_decode_program_feeds_its_state_back(v5e, monkeypatch,
     are the kernels, and the state comes back
     with the shape and type it went in with, so the next step can take
     it as it is."""
-    from ray_tpu.llm import engine as engine_mod
     cfg = LlamaConfig(vocab_size=32768, dim=4096, n_layers=16, n_heads=32,
                       n_kv_heads=8, hidden_dim=14336, max_seq_len=1024,
                       rope_theta=1e6)
-    mesh = _mesh(v5e, 1)
-    params = _abstract_params(mesh, cfg)
-    cache = jax.eval_shape(lambda: llama_init_cache(cfg, 32, 1024))
-    cache_k, cache_v = _abstract(cache, jax.tree.map(
-        lambda _: NamedSharding(mesh, P()), cache))
-    # an engine around shapes: no weights and no cache are made here
-    monkeypatch.setattr(engine_mod.ContinuousBatchingEngine, "_fresh_cache",
-                        lambda self, model: (cache_k, cache_v))
-    monkeypatch.setattr(engine_mod.ContinuousBatchingEngine, "_fresh_bias",
-                        lambda self: (None, None))
-    engine = engine_mod.ContinuousBatchingEngine(
-        engine_mod.EngineConfig(model=cfg, max_batch=32, max_seq=1024),
-        params=params)
-    state = _on(mesh, P(), (7, 32), jnp.int32)
-    lowered = engine._decode.lower(
-        params, [cache_k, cache_v], state, _on(mesh, P(), (2,), jnp.uint32),
-        None, _on(mesh, P(), (32, 32768), jnp.float32), want_lp=want_lp)
+    lowered = abstract_engine(cfg, 32, 1024, v5e).lower_decode(
+        want_lp=want_lp)
     assert [k.split("(")[0] for k in _kernels(lowered)] == [
         "decode_attention", "rms_norm"]
     compiled = lowered.compile()
@@ -343,34 +311,10 @@ def test_jamba_serving_programs_compile_at_published_widths(v5e):
     keeps its temporaries under 64 MiB; a prefill program holds its
     bucket's scan kernel
     beside flash attention, and everything fits one chip."""
-    from ray_tpu.llm import engine as engine_mod
-    from ray_tpu.models.jamba import (JambaConfig, jamba_init,
-                                      jamba_init_cache)
+    from ray_tpu.models.jamba import JambaConfig
     from ray_tpu.ops import selective_scan
-    cfg = JambaConfig(max_seq_len=1024)
-    mesh = _mesh(v5e, 1)
-
-    def on_chip(tree):
-        return _abstract(tree, jax.tree.map(
-            lambda _: NamedSharding(mesh, P()), tree))
-
-    params = on_chip(jax.eval_shape(
-        lambda key: jamba_init(key, cfg), jax.random.PRNGKey(0)))
-    cache = jax.tree.leaves(on_chip(jax.eval_shape(
-        lambda: jamba_init_cache(cfg, 32, 1024))))
-    with pytest.MonkeyPatch.context() as patch:
-        # an engine around shapes: no weights and no cache are made here
-        patch.setattr(engine_mod.ContinuousBatchingEngine, "_fresh_cache",
-                      lambda self, model: cache)
-        patch.setattr(engine_mod.ContinuousBatchingEngine, "_fresh_bias",
-                      lambda self: (None, None))
-        engine = engine_mod.ContinuousBatchingEngine(
-            engine_mod.EngineConfig(model=cfg, max_batch=32, max_seq=1024),
-            params=params)
-    lowered = engine._decode.lower(
-        params, cache, _on(mesh, P(), (7, 32), jnp.int32),
-        _on(mesh, P(), (2,), jnp.uint32), None,
-        _on(mesh, P(), (32, 65536), jnp.float32), want_lp=False)
+    built = abstract_engine(JambaConfig(max_seq_len=1024), 32, 1024, v5e)
+    lowered = built.lower_decode()
     assert [k.split("(")[0] for k in _kernels(lowered)] == [
         "decode_attention", "rms_norm"]
     compiled = lowered.compile()
@@ -383,9 +327,7 @@ def test_jamba_serving_programs_compile_at_published_widths(v5e):
     assert memory.temp_size_in_bytes < 64 * 2**20
     assert (memory.argument_size_in_bytes
             + memory.temp_size_in_bytes) < HBM_BYTES // 2
-    lowered = engine._prefill.lower(
-        params, _on(mesh, P(), (1, 256), jnp.int32),
-        _on(mesh, P(), (), jnp.int32), None)
+    lowered = built.lower_prefill(256)
     assert [k.split("(")[0] for k in _kernels(lowered)] == [
         "flash_fwd", "rms_norm", "selective_scan_256"]
     memory = lowered.compile().memory_analysis()
@@ -406,42 +348,14 @@ def test_granite_serving_programs_compile_at_published_widths(v5e):
     MiB; a prefill program holds flash attention and no other Pallas
     kernel (the grouped matmul is XLA's own ``ragged_dot``), and
     everything fits one chip."""
-    from ray_tpu.llm import engine as engine_mod
-    from ray_tpu.models.granite import (EXPERT_COUNTS, GraniteConfig,
-                                        granite_init, granite_init_cache)
+    from ray_tpu.models.granite import GraniteConfig
     from ray_tpu.ops import ssd_update
     cfg = GraniteConfig(
         vocab_size=50176, layer_types=GraniteConfig().layer_types[:10],
         experts_held=(0, 36), max_seq_len=2560)
-    mesh = _mesh(v5e, 1)
-
-    def on_chip(tree):
-        return _abstract(tree, jax.tree.map(
-            lambda _: NamedSharding(mesh, P()), tree))
-
-    params = on_chip(jax.eval_shape(
-        lambda key: granite_init(key, cfg), jax.random.PRNGKey(0)))
-    weights = sum(x.size * x.dtype.itemsize
-                  for x in jax.tree.leaves(params))
-    assert 9.4e9 < weights < 9.6e9
-    cache = jax.tree.leaves(on_chip(jax.eval_shape(
-        lambda: granite_init_cache(cfg, 32, 2560))))
-    counts = _on(mesh, P(), (len(EXPERT_COUNTS),), jnp.uint32)
-    with pytest.MonkeyPatch.context() as patch:
-        # an engine around shapes: no weights and no cache are made here
-        patch.setattr(engine_mod.ContinuousBatchingEngine, "_fresh_cache",
-                      lambda self, model: cache)
-        patch.setattr(engine_mod.ContinuousBatchingEngine, "_fresh_bias",
-                      lambda self: (None, None))
-        patch.setattr(engine_mod.ContinuousBatchingEngine,
-                      "_fresh_expert_counts", lambda self: None)
-        engine = engine_mod.ContinuousBatchingEngine(
-            engine_mod.EngineConfig(model=cfg, max_batch=32, max_seq=2560),
-            params=params)
-    lowered = engine._decode.lower(
-        params, cache, _on(mesh, P(), (7, 32), jnp.int32),
-        _on(mesh, P(), (2,), jnp.uint32), None,
-        _on(mesh, P(), (32, 50176), jnp.float32), counts, want_lp=False)
+    built = abstract_engine(cfg, 32, 2560, v5e)
+    assert 9.4e9 < built.weight_bytes < 9.6e9
+    lowered = built.lower_decode()
     # rms_norm twice: over the model's width and over d_inner
     assert sorted({k.split("(")[0] for k in _kernels(lowered)}) == [
         "decode_attention", "rms_norm", "ssd_update"]
@@ -461,9 +375,7 @@ def test_granite_serving_programs_compile_at_published_widths(v5e):
     assert (memory.argument_size_in_bytes
             + memory.temp_size_in_bytes) < 0.75 * HBM_BYTES
     for bucket in (256, 1024):
-        lowered = engine._prefill.lower(
-            params, _on(mesh, P(), (1, bucket), jnp.int32),
-            _on(mesh, P(), (), jnp.int32), None, counts)
+        lowered = built.lower_prefill(bucket)
         assert sorted({k.split("(")[0] for k in _kernels(lowered)}) == [
             "flash_fwd", "rms_norm"]
         compiled = lowered.compile()
@@ -485,42 +397,14 @@ def test_lfm2_serving_programs_compile_at_published_widths(v5e):
     the whole cache, hands its eight device counts on and keeps its
     temporaries under 64 MiB; a prefill reads the expert stack where
     it lies; everything fits one chip."""
-    from ray_tpu.llm import engine as engine_mod
-    from ray_tpu.models.lfm2 import (EXPERT_COUNTS, Lfm2Config, lfm2_init,
-                                     lfm2_init_cache)
+    from ray_tpu.models.lfm2 import Lfm2Config
     cfg = Lfm2Config(layer_types=Lfm2Config().layer_types[:14],
                      max_seq_len=1536)
     assert (cfg.n_conv_layers, cfg.n_attn_layers, cfg.n_moe_layers) \
         == (11, 3, 12)
-    mesh = _mesh(v5e, 1)
-
-    def on_chip(tree):
-        return _abstract(tree, jax.tree.map(
-            lambda _: NamedSharding(mesh, P()), tree))
-
-    params = on_chip(jax.eval_shape(
-        lambda key: lfm2_init(key, cfg), jax.random.PRNGKey(0)))
-    weights = sum(x.size * x.dtype.itemsize
-                  for x in jax.tree.leaves(params))
-    assert 9.55e9 < weights < 9.65e9
-    cache = jax.tree.leaves(on_chip(jax.eval_shape(
-        lambda: lfm2_init_cache(cfg, 32, 1536))))
-    counts = _on(mesh, P(), (len(EXPERT_COUNTS),), jnp.uint32)
-    with pytest.MonkeyPatch.context() as patch:
-        # an engine around shapes: no weights and no cache are made here
-        patch.setattr(engine_mod.ContinuousBatchingEngine, "_fresh_cache",
-                      lambda self, model: cache)
-        patch.setattr(engine_mod.ContinuousBatchingEngine, "_fresh_bias",
-                      lambda self: (None, None))
-        patch.setattr(engine_mod.ContinuousBatchingEngine,
-                      "_fresh_expert_counts", lambda self: None)
-        engine = engine_mod.ContinuousBatchingEngine(
-            engine_mod.EngineConfig(model=cfg, max_batch=32, max_seq=1536),
-            params=params)
-    lowered = engine._decode.lower(
-        params, cache, _on(mesh, P(), (7, 32), jnp.int32),
-        _on(mesh, P(), (2,), jnp.uint32), None,
-        _on(mesh, P(), (32, 65536), jnp.float32), counts, want_lp=False)
+    built = abstract_engine(cfg, 32, 1536, v5e)
+    assert 9.55e9 < built.weight_bytes < 9.65e9
+    lowered = built.lower_decode()
     assert sorted({k.split("(")[0] for k in _kernels(lowered)}) == [
         "decode_attention", "rms_norm"]
     compiled = lowered.compile()
@@ -534,9 +418,7 @@ def test_lfm2_serving_programs_compile_at_published_widths(v5e):
     assert (memory.argument_size_in_bytes
             + memory.temp_size_in_bytes) < 0.75 * HBM_BYTES
     for bucket in (128, 1024):
-        lowered = engine._prefill.lower(
-            params, _on(mesh, P(), (1, bucket), jnp.int32),
-            _on(mesh, P(), (), jnp.int32), None, counts)
+        lowered = built.lower_prefill(bucket)
         assert sorted({k.split("(")[0] for k in _kernels(lowered)}) == [
             "flash_fwd", "rms_norm"]
         compiled = lowered.compile()
@@ -559,43 +441,15 @@ def test_mla_serving_programs_compile_at_published_widths(v5e):
     back; the decode program aliases the whole cache, hands its eight
     device counts on and keeps its temporaries under 64 MiB; the 4096
     bucket's temporaries are stated; everything fits one chip."""
-    from ray_tpu.llm import engine as engine_mod
-    from ray_tpu.models.mla import (EXPERT_COUNTS, MlaConfig, mla_init,
-                                    mla_init_cache)
+    from ray_tpu.models.mla import MlaConfig
     cfg = MlaConfig(vocab_size=20480, n_layers=7, experts_held=(0, 12),
                     max_seq_len=4608)
-    mesh = _mesh(v5e, 1)
-
-    def on_chip(tree):
-        return _abstract(tree, jax.tree.map(
-            lambda _: NamedSharding(mesh, P()), tree))
-
-    params = on_chip(jax.eval_shape(
-        lambda key: mla_init(key, cfg), jax.random.PRNGKey(0)))
-    weights = sum(x.size * x.dtype.itemsize
-                  for x in jax.tree.leaves(params))
-    assert 9.65e9 < weights < 9.75e9
-    cache = jax.tree.leaves(on_chip(jax.eval_shape(
-        lambda: mla_init_cache(cfg, 32, 4608))))
+    built = abstract_engine(cfg, 32, 4608, v5e)
+    assert 9.65e9 < built.weight_bytes < 9.75e9
     latent = 7 * 32 * 4608 * 640 * 2
-    assert [x.shape for x in cache] == [(7, 32, 4608, 1, 640)]
+    assert [x.shape for x in built.cache] == [(7, 32, 4608, 1, 640)]
     assert attention.decode_block_rows(4608, 1, 640) == 512
-    counts = _on(mesh, P(), (len(EXPERT_COUNTS),), jnp.uint32)
-    with pytest.MonkeyPatch.context() as patch:
-        # an engine around shapes: no weights and no cache are made here
-        patch.setattr(engine_mod.ContinuousBatchingEngine, "_fresh_cache",
-                      lambda self, model: cache)
-        patch.setattr(engine_mod.ContinuousBatchingEngine, "_fresh_bias",
-                      lambda self: (None, None))
-        patch.setattr(engine_mod.ContinuousBatchingEngine,
-                      "_fresh_expert_counts", lambda self: None)
-        engine = engine_mod.ContinuousBatchingEngine(
-            engine_mod.EngineConfig(model=cfg, max_batch=32, max_seq=4608),
-            params=params)
-    lowered = engine._decode.lower(
-        params, cache, _on(mesh, P(), (7, 32), jnp.int32),
-        _on(mesh, P(), (2,), jnp.uint32), None,
-        _on(mesh, P(), (32, 20480), jnp.float32), counts, want_lp=False)
+    lowered = built.lower_decode()
     assert sorted({k.split("(")[0] for k in _kernels(lowered)}) == [
         "decode_attention", "rms_norm"]
     compiled = lowered.compile()
@@ -607,9 +461,7 @@ def test_mla_serving_programs_compile_at_published_widths(v5e):
     held = memory.argument_size_in_bytes       # weights, cache, bias
     assert 0.6 * HBM_BYTES < held < 0.66 * HBM_BYTES
     for bucket in (1024, 4096):
-        lowered = engine._prefill.lower(
-            params, _on(mesh, P(), (1, bucket), jnp.int32),
-            _on(mesh, P(), (), jnp.int32), None, counts)
+        lowered = built.lower_prefill(bucket)
         assert sorted({k.split("(")[0] for k in _kernels(lowered)}) == [
             "flash_fwd", "rms_norm"]
         compiled = lowered.compile()
